@@ -1776,7 +1776,7 @@ def _r_cond(interp, eqn, ins, where):
     return outs
 
 
-@_rule("pjit", "closed_call", "core_call", "remat", "checkpoint")
+@_rule("jit", "closed_call", "core_call", "remat", "checkpoint")
 def _r_call(interp, eqn, ins, where):
     closed = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
     name = eqn.params.get("name", eqn.primitive.name)
@@ -1816,7 +1816,7 @@ FLOAT_VETTED = {
     "dynamic_update_slice", "scatter", "iota",
     "device_put", "copy", "stop_gradient",
     # control flow: certificates propagate through the recursive walk
-    "scan", "while", "cond", "pjit", "closed_call", "core_call",
+    "scan", "while", "cond", "jit", "closed_call", "core_call",
     "remat", "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr",
 }

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax import tree_util
 
+from jax.experimental import pallas as pl
 from jax.extend import core as jax_core
 
 from . import interval as IV
@@ -111,12 +112,14 @@ def _origin(bm, i) -> str:
 
 
 def _block_dim(b) -> int:
-    if isinstance(b, (int, np.integer)):
-        return int(b)
-    try:
-        return int(b)
-    except Exception:
-        return 1  # squeezed/mapped block dim
+    """Extent of one BlockMapping.block_shape entry: `pl.Blocked(n)` (what
+    a plain int in a BlockSpec canonicalizes to) is n; a squeezed dim
+    spans one element."""
+    if isinstance(b, pl.Blocked):
+        return int(b.block_size)
+    if isinstance(b, pl.Squeezed):
+        return 1
+    raise TypeError(f"unsupported block dim {b!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -417,7 +420,7 @@ def _check_grid(ctx, grid, bms, nin, nout, where):
     for bi, bm in enumerate(bms):
         name = _origin(bm, bi)
         bw = f"{where}/blockspec[{name}]"
-        ashape = tuple(int(s) for s in bm.array_shape_dtype.shape)
+        ashape = tuple(int(s) for s in bm.array_aval.shape)
         bshape = tuple(_block_dim(b) for b in bm.block_shape)
         for d, (adim, bdim) in enumerate(zip(ashape, bshape)):
             if bdim and adim % bdim:
@@ -559,7 +562,7 @@ def _vmem_peak(jaxpr, bms, grid, nin, nout) -> int:
     blocks = 0
     for bm in bms:
         bshape = tuple(_block_dim(b) for b in bm.block_shape)
-        blocks += _nbytes(bshape, bm.array_shape_dtype.dtype)
+        blocks += _nbytes(bshape, bm.array_aval.dtype)
     scratch = 0
     for v in jaxpr.invars[nin + nout:]:
         aval = v.aval

@@ -326,7 +326,7 @@ def _sym_eval_jaxpr(jaxpr, consts, args):
         prim = eqn.primitive.name
         ins = [read(v) for v in eqn.invars]
         p = eqn.params
-        if prim in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        if prim in ("jit", "closed_call", "core_call", "custom_jvp_call",
                     "custom_vjp_call", "remat", "checkpoint"):
             inner = p.get("jaxpr") or p.get("call_jaxpr")
             if hasattr(inner, "jaxpr"):        # ClosedJaxpr

@@ -164,11 +164,9 @@ def merkle_root_device(hashes: List[bytes]) -> Tuple[bytes, bool]:
     `mutated` flag with the host's exact don't-count-the-odd-duplicate
     semantics.
 
-    When to use which: each level's shape compiles once, so this pays off
-    for recurring block sizes on co-located chips where dispatch is ~µs;
-    over a high-RTT tunnel the single readback still costs one link
-    round-trip, which exceeds the ~1 ms the native/host path needs for a
-    whole mainnet block. `check_block(device_merkle=True)` or
+    Each level's shape compiles once, so this suits recurring block
+    sizes; the native/host path needs ~1 ms for a whole mainnet block and
+    stays the default. `check_block(device_merkle=True)` or
     BITCOINCONSENSUS_TPU_DEVICE_MERKLE=1 selects it.
     """
     import numpy as np

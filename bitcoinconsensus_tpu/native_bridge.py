@@ -26,11 +26,12 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["available", "lib", "prep_pack", "NativeSecp"]
+__all__ = ["available", "lib", "why_absent", "prep_pack", "NativeSecp"]
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native"
@@ -46,35 +47,62 @@ _SOURCES = ("nat.cpp", "secp.hpp", "sha256.hpp", "hash_extra.hpp", "interp.hpp",
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_why_absent: Optional[str] = None
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """(Re)build `libnat.so` from the checked-in sources when it is older
+    than any of them. Returns None on success, else the reason — the
+    compiler's stderr included."""
     srcs = [os.path.join(_NATIVE_DIR, s) for s in _SOURCES]
-    if not all(os.path.exists(s) for s in srcs):
-        return False
+    missing = [s for s in srcs if not os.path.exists(s)]
+    if missing:
+        return "native sources missing: " + ", ".join(missing)
     if os.path.exists(_SO_PATH) and all(
         os.path.getmtime(_SO_PATH) >= os.path.getmtime(s) for s in srcs
     ):
-        return True
+        return None
+    cmd = [
+        os.environ.get("CXX", "g++"),
+        "-O3",
+        "-std=c++17",
+        "-fPIC",
+        "-shared",
+        os.path.join(_NATIVE_DIR, "nat.cpp"),
+        "-o",
+        _SO_PATH,
+    ]
     try:
         subprocess.run(
-            [
-                os.environ.get("CXX", "g++"),
-                "-O3",
-                "-std=c++17",
-                "-fPIC",
-                "-shared",
-                os.path.join(_NATIVE_DIR, "nat.cpp"),
-                "-o",
-                _SO_PATH,
-            ],
-            check=True,
-            capture_output=True,
-            timeout=300,
+            cmd, check=True, capture_output=True, text=True, timeout=300
         )
-        return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        return f"`{' '.join(cmd)}` exited {e.returncode}:\n{e.stderr}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"`{' '.join(cmd)}` did not run: {e}"
+    return None
+
+
+def _absent(reason: str) -> None:
+    """Record and announce why the native core is absent. `lib()` tries
+    once per process, so this warns once; every caller then drops to the
+    pure-Python engine (consensus-exact, roughly 10x slower host side)."""
+    global _why_absent
+    _why_absent = reason
+    warnings.warn(
+        "bitcoinconsensus_tpu: native host core unavailable, using the "
+        f"pure-Python engine: {reason}",
+        RuntimeWarning,
+        stacklevel=3,
+    )
+
+
+def why_absent() -> Optional[str]:
+    """Why `available()` is False (None while the core is loaded)."""
+    if os.environ.get("BITCOINCONSENSUS_TPU_NATIVE", "") in ("0", "off"):
+        return "disabled by BITCOINCONSENSUS_TPU_NATIVE"
+    lib()
+    return _why_absent
 
 
 def lib() -> Optional[ctypes.CDLL]:
@@ -93,23 +121,25 @@ def lib() -> Optional[ctypes.CDLL]:
         override = os.environ.get("BITCOINCONSENSUS_NAT_SO", "")
         if override:
             so = override
-        elif _build():
-            so = _SO_PATH
-        elif os.path.exists(_PACKAGED_SO):
-            so = _PACKAGED_SO
         else:
-            return None
+            build_error = _build()
+            if build_error is None:
+                so = _SO_PATH
+            elif os.path.exists(_PACKAGED_SO):
+                so = _PACKAGED_SO
+            else:
+                return _absent(build_error)
         try:
             L = ctypes.CDLL(so)
-        except OSError:
-            return None
+        except OSError as e:
+            return _absent(f"cannot load {so}: {e}")
         # ABI gate: a stale override/packaged .so with an older exported
         # surface (e.g. the pre-v4 recidx_data signature) must not load —
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
         if L.nat_version() < 4:
-            return None
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 4)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -51,7 +51,7 @@ place in the tree allowed to read clocks: the host AST lint rejects
 direct `time.perf_counter()` timing in `models/` and `crypto/` so all
 timing flows through spans.
 
-Metric name catalogue and span taxonomy: README "Observability".
+Metric name catalogue and span names: README "Observability".
 """
 
 from .metrics import (

@@ -74,7 +74,7 @@ __all__ = [
 _PHASE_SECONDS = histogram(
     "consensus_pipeline_phase_seconds",
     "per-ticket pipeline phase durations (README: Performance "
-    "observatory phase taxonomy)",
+    "observatory phase names)",
     ("phase",),
 )
 _OVERLAP = gauge(
@@ -286,10 +286,8 @@ def while_trips(eqn) -> int:
     holds the static upper bound as a scalar int literal — take the
     largest such literal; exact for every fori in the verify kernel:
     window loop, G loop, the _sqr_n chains)."""
-    try:
-        from jax._src.core import Literal
-    except Exception:  # pragma: no cover - jax internal move
-        from jax.core import Literal
+    from jax.extend.core import Literal
+
     trips = 1
     for v in eqn.invars:
         if isinstance(v, Literal) and getattr(v.aval, "shape", None) == ():
@@ -356,7 +354,7 @@ def _block(x) -> None:
 
 def timed_best(fn: Callable[[], Any], reps: int = 5):
     """(best_s, median_s, walls) over `reps` synchronized calls of `fn`
-    — min-of-N approximates the uncontended kernel on a shared chip."""
+    — min-of-N approximates the uncontended kernel."""
     walls = []
     for _ in range(max(1, int(reps))):
         t0 = monotonic()

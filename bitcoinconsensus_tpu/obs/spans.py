@@ -60,7 +60,7 @@ __all__ = [
 
 _SPAN_SECONDS = histogram(
     "consensus_span_duration_seconds",
-    "wall-clock duration of pipeline spans (see README span taxonomy)",
+    "wall-clock duration of pipeline spans (see README span names)",
     ("span",),
 )
 _SPAN_ERRORS = counter(

@@ -46,20 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.4.35: the supported spelling
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-# The varying-axes checker kwarg was renamed check_rep -> check_vma; key on
-# the actual signature, not the import location (mid-range jax exposes
-# jax.shard_map but still spells it check_rep).
-import inspect as _inspect
-
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..crypto import jax_backend as _jb
@@ -227,7 +214,7 @@ def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
         mesh=mesh,
         in_specs=(P(axis, None, None),) + (P(axis),) * 7,
         out_specs=(P(axis), P(axis), P(), P(axis), P(axis)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(
         sharded,

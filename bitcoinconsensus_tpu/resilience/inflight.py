@@ -98,6 +98,12 @@ _BACKPRESSURE = _obs_counter(
     "queue was at max depth, by site",
     ("site",),
 )
+_FAILURES = _obs_counter(
+    "consensus_inflight_failures_total",
+    "launch/settle attempts that failed (each is a ladder failure), by "
+    "site, ladder level and exception class",
+    ("site", "level", "exc"),
+)
 
 
 def settle_array(x) -> np.ndarray:
@@ -193,6 +199,9 @@ class InflightQueue:
         self.backoff_s = float(backoff_s)
         self._pending: List[Ticket] = []
         self._seq = 0
+        # Most recent launch/settle failure (see `_note_failure`): the
+        # ladder absorbs the fault, this keeps its reason readable.
+        self.last_failure: Optional[dict] = None
 
     # -- dispatch side -------------------------------------------------
 
@@ -241,6 +250,27 @@ class InflightQueue:
         # Re-stamped on relaunch: the settled attempt owns the edge.
         ticket.timeline.stamp("launch")
 
+    def _note_failure(self, ticket: Ticket, stage: str,
+                      exc: BaseException) -> None:
+        """Keep the reason for a failed attempt: counted by exception
+        class, recorded in the flight ring, and held as `last_failure`
+        (the retry/ladder policy below would otherwise discard it — a
+        compiler error and a flipped lane look identical from outside)."""
+        shape = getattr(ticket.args[0], "shape", None) if ticket.args else None
+        self.last_failure = {
+            "stage": stage,
+            "level": ticket.level,
+            "lanes": ticket.n,
+            "shape": None if shape is None else tuple(shape),
+            "attempt": ticket.attempts,
+            "exc": type(exc).__name__,
+            "error": str(exc),
+        }
+        _FAILURES.inc(site=self.site, level=ticket.level,
+                      exc=type(exc).__name__)
+        _flight.record("inflight.failure", site=self.site,
+                       **self.last_failure)
+
     # -- settle side ---------------------------------------------------
 
     def settle(self, ticket: Ticket):
@@ -265,13 +295,14 @@ class InflightQueue:
         start_idx = ladder.levels.index(ladder.current)
         outcome = None
         while ticket.level != HOST_LEVEL:
-            failure = ticket.error
-            if failure is None:
+            if ticket.error is not None:
+                self._note_failure(ticket, "launch", ticket.error)
+            else:
                 ticket.timeline.stamp("settle_start")
                 try:
                     ok, needs, all_ok = self._materialize(ticket)
                 except Exception as exc:
-                    failure = exc
+                    self._note_failure(ticket, "settle", exc)
                 else:
                     ladder.report(ticket.level, True, probe=ticket.probe)
                     _SETTLE_SECONDS.observe(_monotonic() - ticket.born)

@@ -9,7 +9,7 @@ clients decorrelate) and gives up after a bounded number of attempts,
 re-raising the final error for the caller to surface.
 
 `IngressClient` is the wire transport (serving/ingress.py framing)
-with the same error taxonomy the retry loop keys on:
+with the same error classes the retry loop keys on:
 
 - `OverloadError` — the server said `ERR_OVERLOADED`: retryable.
 - `ConnectionError` — the connection died mid-exchange (server

@@ -71,7 +71,7 @@ from ..utils.hashes import hash160, sha256, tagged_hash
 
 __all__ = ["SHAPES", "CorpusCase", "build_corpus", "shape_batch"]
 
-# Corpus taxonomy (README "Adversarial workloads & gauntlet"). The first
+# Corpus classes (README "Adversarial workloads & gauntlet"). The first
 # four are the per-shape bench/baseline axes; the rest are
 # verdict-pinning shapes (cheap, correctness-only).
 SHAPES = (

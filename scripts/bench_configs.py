@@ -138,7 +138,7 @@ def bench_block_replay(verifier, iters: int = 5):
     (best_secs, n_inputs, n_txs, phase_breakdown): the breakdown is the
     best iteration's per-phase wall clock plus the derived link/non-link
     split (`sync`+`dispatch` is the device/link wait; the round target is
-    non-link < 100 ms — VERDICT r4 task 1)."""
+    non-link < 100 ms)."""
     from bitcoinconsensus_tpu import native_bridge
     from bitcoinconsensus_tpu.models.validate import connect_block
     from bitcoinconsensus_tpu.utils.blockgen import (
@@ -212,12 +212,13 @@ def bench_block_replay(verifier, iters: int = 5):
 
 
 def main() -> None:
+    import chip_guard
     from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
 
-    # One dispatch per 10k-input batch where possible: the link's
-    # per-dispatch cost is not hidden by chunk pipelining (see bench.py),
-    # so a 10k-check config rides a single 10240-lane shape (pad ladder
-    # capped at 2048 steps) instead of 8192+2048.
+    chip_guard.require_tpu()
+    # One dispatch per 10k-input batch: a 10k-check config rides a single
+    # 10240-lane shape (pad ladder capped at 2048 steps) instead of
+    # 8192+2048.
     verifier = TpuSecpVerifier(min_batch=2048, chunk=16384, pad_step=2048)
     out = {}
 
@@ -255,10 +256,12 @@ def main() -> None:
 
     print("config 5: block replay", file=sys.stderr)
     # Same tuning as scripts/bench_block.py: one dispatch for the whole
-    # block (the per-dispatch link round-trip costs more than padding),
-    # pad ladder capped at 2048-steps so ~5.6k checks ride a 6144 shape.
+    # block, pad ladder capped at 2048-steps so ~5.6k checks ride a 6144
+    # shape.
     block_verifier = TpuSecpVerifier(min_batch=512, chunk=8192, pad_step=2048)
     secs, n_inputs, n_txs, phases = bench_block_replay(block_verifier)
+    chip_guard.assert_clean(verifier, "bench_configs.py batches")
+    chip_guard.assert_clean(block_verifier, "bench_configs.py block replay")
     out["block_replay_ms"] = round(secs * 1000, 1)
     out["block_replay_inputs"] = n_inputs
     out["block_replay_txs"] = n_txs

@@ -59,14 +59,14 @@ def main():
         reps=REPS,
     )
 
-    # Keep the historical KERNEL_r{N}.json key set (KERNEL_r05 et al.)
-    # alongside the shared-module fields.
+    # Keep the historical KERNEL_r{N}.json key set alongside the
+    # shared-module fields.
     out = dict(rep)
     out["tile"] = T
     out["note"] = (
         "ops counted from the traced kernel jaxpr (arith/logic/select/"
         "compare element counts); peak assumes v5e VPU 8x128x4 ALUs at "
-        "0.94 GHz; min-of-N timing on the shared chip"
+        "0.94 GHz; min-of-N timing"
     )
     out["provenance"] = perf.provenance()
     print(json.dumps(out, indent=2))

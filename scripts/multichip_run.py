@@ -22,6 +22,14 @@ the real thing — drop the forcing with --no-force.
 Usage:
     python scripts/multichip_run.py --out MULTICHIP_r06.json
     python scripts/multichip_run.py --devices 8 --iters 5
+    python scripts/multichip_run.py --no-force --devices 4 --lanes 2044
+
+On real chips give the clean run enough lanes for a 512-row tile per
+shard (`--lanes 2044` on four devices: 511 real lanes + 1 sentinel each),
+or every shard takes the XLA branch of `mesh._pick_backend`. The document
+then records how many Mosaic kernels the program that ran contains, and
+the run fails unless it stayed on the mesh rung with every fallback
+counter at zero (`chip_guard.assert_clean`).
 """
 
 from __future__ import annotations
@@ -51,6 +59,8 @@ def main(argv=None) -> int:
     ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--iters", type=int, default=5,
                     help="timed iterations after warmup (default: 5)")
+    ap.add_argument("--lanes", type=int, default=13,
+                    help="real lanes in the clean run (default: 13)")
     ap.add_argument("--out", metavar="PATH",
                     help="write the JSON document to this path")
     ap.add_argument("--no-force", action="store_true",
@@ -66,6 +76,7 @@ def main(argv=None) -> int:
     import numpy as np  # noqa: E402
 
     import __graft_entry__ as ge
+    import chip_guard
     from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
     from bitcoinconsensus_tpu.parallel import mesh as M
     from bitcoinconsensus_tpu.resilience import FaultPlan, FaultSpec, inject
@@ -86,27 +97,51 @@ def main(argv=None) -> int:
     assert oracle.all(), "workload checks must all be valid"
 
     # --- clean measured run -------------------------------------------
+    clean_checks, clean_oracle = checks, oracle
+    if args.lanes != len(checks):
+        clean_checks = ge._example_checks(args.lanes)
+        host = TpuSecpVerifier(min_batch=8)
+        clean_oracle = np.asarray(
+            [host._host_check(c) for c in clean_checks], dtype=bool
+        )
     sv = M.ShardedSecpVerifier(mesh=M.make_mesh(args.devices))
+    # Keep the first call's arguments: the program that ran is lowered
+    # again below to count its Mosaic kernels (what ran, not the intent).
+    step, step_args = sv._step, []
+
+    def recording_step(*a):
+        step_args.append(a)
+        return step(*a)
+
+    sv._step = recording_step
     disp0 = M._MESH_DISPATCH.value()
-    res, verdict = sv.verify_checks_with_verdict(checks)  # warm/compile
-    assert np.array_equal(np.asarray(res, dtype=bool), oracle) and verdict
+    res, verdict = sv.verify_checks_with_verdict(clean_checks)  # warm/compile
+    assert np.array_equal(np.asarray(res, dtype=bool), clean_oracle) and verdict
     walls = []
     for _ in range(args.iters):
         t0 = time.perf_counter()
-        res, verdict = sv.verify_checks_with_verdict(checks)
+        res, verdict = sv.verify_checks_with_verdict(clean_checks)
         walls.append(time.perf_counter() - t0)
-        assert np.array_equal(np.asarray(res, dtype=bool), oracle) and verdict
+        assert np.array_equal(np.asarray(res, dtype=bool), clean_oracle) and verdict
+    if devs[0].platform == "tpu":
+        chip_guard.assert_clean(sv, "multichip clean run")
     best = min(walls)
     clean = {
-        "lanes": len(checks),
+        "lanes": len(clean_checks),
+        "shard_rows": int(step_args[0][0].shape[0]) // args.devices,
+        "mosaic_kernels_in_program": step.lower(*step_args[0]).as_text().count(
+            "tpu_custom_call"
+        ),
         "iters": args.iters,
         "wall_s": [round(w, 6) for w in walls],
         "best_s": round(best, 6),
-        "lanes_per_s": round(len(checks) / best, 1),
+        "lanes_per_s": round(len(clean_checks) / best, 1),
         "bit_identical": True,
         "verdict": bool(verdict),
         "mesh_dispatches": int(M._MESH_DISPATCH.value() - disp0),
     }
+    print(json.dumps({"clean": clean, "platform": devs[0].platform}),
+          file=sys.stderr, flush=True)
 
     # --- eviction-and-continue trial ----------------------------------
     sv2 = M.ShardedSecpVerifier(mesh=M.make_mesh(args.devices), evict_after=1)

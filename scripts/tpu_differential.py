@@ -106,26 +106,29 @@ def host_oracle(chk) -> bool:
 def main() -> None:
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 8192
     seed = int(sys.argv[2]) if len(sys.argv) > 2 else 7
-    import jax
-
+    import chip_guard
     from bitcoinconsensus_tpu import native_bridge
     from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
 
-    assert native_bridge.available(), "native host oracle required"
+    dev = chip_guard.require_tpu()
+    if not native_bridge.available():
+        sys.exit(f"native host oracle required: {native_bridge.why_absent()}")
     checks = build_adversarial_checks(n, seed)
     print(f"built {n} adversarial checks", file=sys.stderr)
 
     v = TpuSecpVerifier()
-    assert v._use_pallas or jax.default_backend() != "tpu"
     got = np.asarray(v.verify_checks(checks))
+    # The host rung IS this oracle: a run that fell back to it would agree
+    # with itself. Require that it did not before comparing.
+    chip_guard.assert_clean(v, "tpu_differential.py")
     want = np.fromiter((host_oracle(c) for c in checks), dtype=bool, count=n)
     diverged = np.nonzero(got != want)[0]
     out = {
         "metric": "tpu_pallas_differential",
         "n": n,
         "seed": seed,
-        "backend": jax.default_backend(),
-        "pallas": bool(v._use_pallas),
+        "device": dev,
+        "dispatches": chip_guard.dispatches(),
         "valid_fraction": round(float(want.mean()), 4),
         "diverged": int(diverged.size),
     }

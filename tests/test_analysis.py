@@ -111,7 +111,7 @@ def test_int64_intermediate_is_flagged():
         y = x.astype(jnp.int64)
         return (y * y).astype(jnp.int32)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(bad)(jax.ShapeDtypeStruct((4,), jnp.int32))
     rep = IV.analyze_closed(closed, "bad.int64", in_bounds={0: (0, 10)})
     assert not rep.ok

@@ -129,10 +129,19 @@ def test_settle_retries_transient_failure_then_succeeds():
     be = _Backend(settle_fails=1)
     q, res = _mk_queue(be)
     t = q.dispatch(("a",), 4)
+    fails0 = I._FAILURES.value(
+        site="test.inflight", level="stub", exc="VerdictAnomaly")
+    assert q.last_failure is None
     ok, _needs = q.settle(t)
     assert ok.all() and t.attempts == 2
     assert be.launches == [(4, "stub"), (4, "stub")]  # relaunched once
     assert res.ladder.current == "stub"
+    # The retry absorbed the fault; its reason is kept, and counted.
+    assert q.last_failure["stage"] == "settle"
+    assert q.last_failure["exc"] == "VerdictAnomaly"
+    assert I._FAILURES.value(
+        site="test.inflight", level="stub", exc="VerdictAnomaly"
+    ) == fails0 + 1
 
 
 def test_launch_exception_is_a_settle_failure():
@@ -142,6 +151,11 @@ def test_launch_exception_is_a_settle_failure():
     assert t.error is not None  # captured, not raised, at dispatch time
     ok, _needs = q.settle(t)
     assert ok.all() and t.attempts == 2
+    assert q.last_failure == {
+        "stage": "launch", "level": "stub", "lanes": 4, "shape": None,
+        "attempt": 1, "exc": "RuntimeError",
+        "error": "injected launch failure",
+    }
 
 
 def test_quarantine_cancels_and_redispatches_queued_tickets():

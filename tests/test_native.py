@@ -184,3 +184,16 @@ def test_randomized_differential_vs_oracle():
         assert ns.verify_ecdsa(pub, sig, msg) == H.verify_ecdsa(pub, sig, msg), i
         pk32, s64 = rng.bytes(32), rng.bytes(64)
         assert ns.verify_schnorr(pk32, s64, msg) == H.verify_schnorr(pk32, s64, msg)
+
+
+def test_build_failure_keeps_the_compilers_stderr(tmp_path, monkeypatch):
+    cxx = tmp_path / "cxx"
+    cxx.write_text("#!/bin/sh\necho 'nat.cpp:1: fatal error: boom' >&2\nexit 3\n")
+    cxx.chmod(0o755)
+    monkeypatch.setenv("CXX", str(cxx))
+    monkeypatch.setattr(NB, "_SO_PATH", str(tmp_path / "libnat.so"))
+    reason = NB._build()
+    assert "exited 3" in reason and "fatal error: boom" in reason
+    monkeypatch.setenv("CXX", str(tmp_path / "no-such-compiler"))
+    assert "did not run" in NB._build()
+    assert NB.why_absent() is None  # the loaded core is unaffected
