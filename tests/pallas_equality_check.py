@@ -1,16 +1,22 @@
 """Standalone pallas-vs-XLA equality checks, run in a FRESH process.
 
 Why a subprocess: the interpret-mode pallas compiles are the largest XLA
-programs in the suite, and XLA:CPU segfaults compiling (or cache-writing)
-them late in a long-lived pytest process that has already compiled ~100
-other programs — reproducibly at `tests/test_pallas_kernel.py`, and
+programs in the suite (`verify_tiles` alone compiles for 7 minutes on an
+idle core), and XLA:CPU segfaults compiling (or cache-writing) them late
+in a long-lived pytest process that has already compiled ~100 other
+programs — reproducibly at `tests/test_pallas_kernel.py`, and
 reproducibly NOT when the same compile runs in a clean process (the crash
-is inside jaxlib, with the native core disabled too). Each check here
-runs in its own interpreter via `test_pallas_kernel.py`'s subprocess
-wrappers, which also warms the persistent compile cache for direct runs.
+is inside jaxlib, with the native core disabled too).
 
-Usage: python tests/pallas_equality_check.py {small|production|collision}
-Exit code 0 = the equality/deferral assertions passed.
+Checks that compile the same programs share a process: `small` and
+`collision` both run the 8-lane `_verify_kernel` and the `tile=8`
+interpret-mode `verify_tiles`, so `test_pallas_kernel.py` starts them as
+one child (`production`, the 512-lane tile, is a `slow` test with a child
+of its own). Each check is reported by name on a line of its own, so each
+stays a test of its own.
+
+Usage: python tests/pallas_equality_check.py {small|production|collision}...
+Exit code 0 = every named check passed.
 """
 
 import os
@@ -24,10 +30,21 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import numpy as np  # noqa: E402
 
 
+def _xla_kernel(*args):
+    """The XLA side as the verifier dispatches it: `_verify_kernel` under
+    jit. At 8 lanes that is the program the suite's workers compile for
+    themselves, so called after the Pallas side (minutes into the run) it
+    loads from the persistent cache; run op by op it would compile a
+    dozen scans of its own."""
+    import jax
+    from bitcoinconsensus_tpu.crypto.jax_backend import _verify_kernel
+
+    return jax.jit(_verify_kernel)(*args)
+
+
 def check_small() -> None:
     """tile=8 adversarial mix: bit-equality with the XLA kernel."""
     import __graft_entry__ as ge
-    from bitcoinconsensus_tpu.crypto.jax_backend import _verify_kernel
     from bitcoinconsensus_tpu.ops.pallas_kernel import verify_tiles
 
     fields, want_odd, parity, has_t2, neg1, neg2, valid = ge._example_arrays(8)
@@ -42,14 +59,14 @@ def check_small() -> None:
     want_odd[2] ^= 1  # wrong y parity for lane 2's pubkey -> wrong R
     neg1[4] ^= 1  # flip a GLV half sign -> wrong R for lane 4
 
-    want = np.asarray(
-        _verify_kernel(fields, want_odd, parity, has_t2, neg1, neg2, valid)
-    )
     got_ok, got_needs = verify_tiles(
         fields, want_odd, parity, has_t2, neg1, neg2, valid,
         tile=8, interpret=True,
     )
     got = np.asarray(got_ok)
+    want = np.asarray(
+        _xla_kernel(fields, want_odd, parity, has_t2, neg1, neg2, valid)
+    )
     assert not np.asarray(got_needs).any()  # no group-law deferrals here
     assert (got == want).all(), (got, want)
     assert not want[3] and not want[5] and not want[2] and not want[4]
@@ -121,11 +138,7 @@ def check_collision() -> None:
     complete kernel resolves it TRUE directly."""
     import __graft_entry__ as ge
     from bitcoinconsensus_tpu.crypto import secp_host as H
-    from bitcoinconsensus_tpu.crypto.jax_backend import (
-        SigCheck,
-        TpuSecpVerifier,
-        _verify_kernel,
-    )
+    from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck, TpuSecpVerifier
     from bitcoinconsensus_tpu.ops.pallas_kernel import verify_tiles
 
     qx, qy = H.G.mul(2).to_affine()
@@ -143,13 +156,13 @@ def check_collision() -> None:
     v = TpuSecpVerifier(min_batch=8)
     args = v._pack_lanes(v._prep_lanes(checks))
 
-    want = np.asarray(_verify_kernel(*args))
-    assert want[:7].all()  # XLA complete kernel: collision resolves TRUE
-
     ok, needs = verify_tiles(*args, tile=8, interpret=True)
     ok, needs = np.asarray(ok), np.asarray(needs)
     assert needs[0] and not ok[0], "collision lane must defer"
     assert not needs[1:7].any() and ok[1:7].all(), "others unaffected"
+
+    want = np.asarray(_xla_kernel(*args))
+    assert want[:7].all()  # XLA complete kernel: collision resolves TRUE
 
 
 CHECKS = {
@@ -159,6 +172,6 @@ CHECKS = {
 }
 
 if __name__ == "__main__":
-    name = sys.argv[1]
-    CHECKS[name]()
-    print(f"pallas equality check '{name}': PASS")
+    from child_checks import main
+
+    sys.exit(main(CHECKS, sys.argv[1:]))

@@ -17,10 +17,11 @@ import pytest
 import chip_guard
 import chip_smoke
 
+pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Every dispatch stays on the 8- and 16-lane rungs test_batch.py already
-# compiled in this process.
+# Every dispatch stays on the 8- and 16-lane rungs `warm_kernel` has called.
 TINY = dict(
     batch_inputs=8, block_inputs=8, serve_requests=8, serve_cold=4,
     serve_threads=2,

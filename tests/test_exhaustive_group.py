@@ -229,6 +229,7 @@ def test_double_every_point():
             assert got[i] == want, k
 
 
+@pytest.mark.limit(600)  # a cold compile of minutes beside five other workers
 def test_exhaustive_small_scalar_rectangle_through_glv_kernel():
     """Every (a, b) in [0, 24) x [0, 24) through the GLV double-scalar
     schedule in ONE batch: a·G + b·P vs the oracle. Covers all-zero

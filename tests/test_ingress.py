@@ -62,6 +62,8 @@ from bitcoinconsensus_tpu.serving.ingress import (
 
 from test_batch import make_p2wpkh_spend
 
+pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
+
 
 def _items(n=4, bad_first=True):
     out = []

@@ -32,9 +32,12 @@ from bitcoinconsensus_tpu.models.batch import BatchItem, verify_batch
 from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
 from bitcoinconsensus_tpu.utils.hashes import hash160
 
-pytestmark = pytest.mark.skipif(
-    not native_bridge.available(), reason="native core unavailable"
-)
+pytestmark = [
+    pytest.mark.skipif(
+        not native_bridge.available(), reason="native core unavailable"
+    ),
+    pytest.mark.usefixtures("warm_kernel"),  # conftest.py: first calls
+]
 
 
 def _sk(seed: str) -> int:

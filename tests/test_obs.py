@@ -342,6 +342,7 @@ def _make_items(n):
     return items
 
 
+@pytest.mark.usefixtures("warm_kernel")
 def test_no_sink_overhead_under_one_percent(monkeypatch):
     """Telemetry left on by default must cost < 1% of a small
     verify_batch. Direct A/B wall-clock timing of so small a difference

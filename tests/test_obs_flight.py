@@ -155,6 +155,7 @@ def test_trigger_unwritable_dir_fails_closed():
     assert F.trigger("cli", out_dir="/nonexistent/dir/path") is None
 
 
+@pytest.mark.usefixtures("warm_kernel")
 def test_disarmed_overhead_under_one_percent():
     """Event-cost accounting, mirroring the perf/obs budget tests: the
     disarmed `record()` hook priced by microbenchmark must cost < 1% of

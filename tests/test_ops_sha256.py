@@ -6,6 +6,7 @@ import hashlib
 import random
 
 import numpy as np
+import pytest
 
 from conftest import *  # noqa: F401,F403 (env setup)
 
@@ -95,6 +96,7 @@ def test_merkle_root_device_matches_host():
     assert merkle_root_device([]) == merkle_root([])
 
 
+@pytest.mark.usefixtures("warm_kernel")
 def test_device_challenge_prep_matches_host():
     """TpuSecpVerifier(device_challenge=True): the ops/sha256-batched
     BIP340 challenge path must produce bit-identical verdicts to the
@@ -102,7 +104,7 @@ def test_device_challenge_prep_matches_host():
     import __graft_entry__ as ge
     from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck, TpuSecpVerifier
 
-    checks = ge._example_checks(24)  # mixed ecdsa/schnorr/tweak
+    checks = ge._example_checks(15)  # mixed ecdsa/schnorr/tweak, 16-lane rung
     # corrupt one schnorr sig and one schnorr pubkey
     for i in (1, 4):
         pk, sig, msg = checks[i].data

@@ -3,23 +3,19 @@
 The pallas path (`ops/pallas_kernel.py`) is the TPU production backend;
 the XLA kernel is the reference semantics (itself oracle-tested against
 `crypto/secp_host.py`). On CPU the pallas kernel runs in interpreter
-mode; each equality check executes in a FRESH subprocess
-(`pallas_equality_check.py`) because the interpret-mode compiles are the
-largest programs in the suite and XLA:CPU reproducibly segfaults
-compiling them late in a long-lived pytest process (clean-process runs
-of the identical compile pass; the crash reproduces with the native core
-disabled, i.e. it is jaxlib-internal). The subprocess also warms the
-persistent compile cache, so repeat runs are fast.
+mode, in fresh processes (`pallas_equality_check.py`, for the reason
+`child_checks.py` gives). Both children start with the file's first test
+and each test waits for its own check.
 """
 
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
 from conftest import *  # noqa: F401,F403 (env setup)
+
+from child_checks import Children
 
 RUN = os.environ.get("PALLAS_INTERPRET_TESTS", "1") != "0"
 
@@ -29,35 +25,40 @@ pytestmark = pytest.mark.skipif(
 
 _HELPER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "pallas_equality_check.py")
+# `small` and `collision` compile the same interpret-mode program (tile=8)
+# and share a child; its limit is from its cold time under the tier-1
+# command (CHANGES.md, PR 25).
+_CHILDREN = {("small", "collision"): 900}
 
 
-def _run_check(name: str, timeout: int = 1800) -> None:
-    proc = subprocess.run(
-        [sys.executable, _HELPER, name],
-        capture_output=True, text=True, timeout=timeout,
-    )
-    assert proc.returncode == 0, (
-        f"pallas equality check '{name}' failed (rc={proc.returncode})\n"
-        f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr[-4000:]}"
-    )
+@pytest.fixture(scope="module")
+def children(tmp_path_factory):
+    with Children(_HELPER, _CHILDREN, tmp_path_factory.mktemp("pallas")) as started:
+        yield started
 
 
-def test_pallas_matches_xla_kernel():
+@pytest.mark.limit(930)
+def test_pallas_matches_xla_kernel(children):
     """tile=8 adversarial mix, bit-equality (fresh process)."""
-    _run_check("small")
+    children.expect("small")
 
 
-def test_pallas_production_shape_matches_xla():
+@pytest.mark.slow  # a second 10-minute compile the tier-1 run has no room for
+@pytest.mark.limit(1530)
+def test_pallas_production_shape_matches_xla(tmp_path):
     """PRODUCTION tile (LANE_TILE=512) equality incl. the w=128 Fermat
     narrowing in _tile_batch_inv (fresh process)."""
-    _run_check("production")
+    with Children(_HELPER, {("production",): 1500}, tmp_path) as child:
+        child.expect("production")
 
 
-def test_exceptional_case_deferred_to_host():
+@pytest.mark.limit(930)
+@pytest.mark.usefixtures("warm_kernel")
+def test_exceptional_case_deferred_to_host(children):
     """Crafted equal-points tweak: device-side deferral flag asserted in
     the subprocess; the verify_checks host-fixup loop asserted here
     in-process (it runs the XLA kernel, no pallas compile)."""
-    _run_check("collision")
+    children.expect("collision")
 
     import __graft_entry__ as ge
     from bitcoinconsensus_tpu.crypto import secp_host as H

@@ -17,13 +17,13 @@ identical paths in a clean process.
 
 import hashlib
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
 from conftest import *  # noqa: F401,F403 (env setup)
+
+from child_checks import Children
 
 from bitcoinconsensus_tpu.crypto import secp_host as H
 from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck
@@ -33,37 +33,45 @@ from bitcoinconsensus_tpu.resilience import guards as G
 from bitcoinconsensus_tpu.resilience.faults import FaultPlan, FaultSpec, inject
 
 _HELPER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "mesh_checks.py")
+# hostreject, dryrun and sharded compile the same program (the 8-device
+# step at 16 lanes) and share a child; sharded comes last so that the
+# unsharded kernel it compares with is in the workers' cache by then.
+# Limits from the children's cold times under the tier-1 command
+# (CHANGES.md, PR 25).
+_CHILDREN = {("hostreject", "dryrun", "sharded"): 750, ("np2",): 600}
 
 
-def _run_check(name: str, timeout: int = 1800) -> None:
-    proc = subprocess.run(
-        [sys.executable, _HELPER, name],
-        capture_output=True, text=True, timeout=timeout,
-    )
-    assert proc.returncode == 0, (
-        f"mesh check '{name}' failed (rc={proc.returncode})\n"
-        f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr[-4000:]}"
-    )
+@pytest.fixture(scope="module")
+def children(tmp_path_factory):
+    with Children(_HELPER, _CHILDREN, tmp_path_factory.mktemp("mesh")) as started:
+        yield started
 
 
-def test_dryrun_multichip():
-    _run_check("dryrun")
+@pytest.mark.limit(780)
+def test_dryrun_multichip(children):
+    children.expect("dryrun")
 
 
-def test_sharded_matches_unsharded():
-    _run_check("sharded")
+@pytest.mark.limit(780)
+def test_sharded_matches_unsharded(children):
+    children.expect("sharded")
 
 
-def test_sharded_non_power_of_two_mesh():
-    _run_check("np2")
+@pytest.mark.limit(630)
+def test_sharded_non_power_of_two_mesh(children):
+    children.expect("np2")
 
 
-def test_sharded_verdict_counts_host_rejected_lane():
-    _run_check("hostreject")
+@pytest.mark.limit(780)
+def test_sharded_verdict_counts_host_rejected_lane(children):
+    children.expect("hostreject")
 
 
-def test_shard_fault_domains_real_kernels():
-    _run_check("faultdomains")
+@pytest.mark.slow  # 12 minutes and 22 CPU-minutes of compiles, alone and cold
+@pytest.mark.limit(1530)
+def test_shard_fault_domains_real_kernels(tmp_path):
+    with Children(_HELPER, {("faultdomains",): 1500}, tmp_path) as child:
+        child.expect("faultdomains")
 
 
 # ---------------------------------------------------------------------------

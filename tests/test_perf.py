@@ -202,6 +202,7 @@ def test_compare_reports_skips_on_provenance_mismatch():
 # Disarmed-path overhead: event-cost accounting against a stub workload.
 
 
+@pytest.mark.usefixtures("warm_kernel")
 def test_disarmed_stamp_overhead_under_one_percent():
     """With timelines disarmed, the per-ticket hook cost (new_timeline +
     8 no-op stamps, all priced by microbenchmark) must stay under 1% of

@@ -27,6 +27,8 @@ from bitcoinconsensus_tpu.resilience import faults as F
 from bitcoinconsensus_tpu.resilience import guards as G
 from bitcoinconsensus_tpu.resilience.faults import FaultPlan, FaultSpec, inject
 
+pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
+
 
 # ---------------------------------------------------------------------------
 # Workload helpers.

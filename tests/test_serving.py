@@ -38,6 +38,10 @@ from bitcoinconsensus_tpu.serving import (
 
 from test_batch import make_p2wpkh_spend
 
+# The end-to-end tests are about admission, drain and span parentage, not
+# about the kernel: its first call is made before any of them waits.
+pytestmark = pytest.mark.usefixtures("warm_kernel")
+
 
 def _entry(tenant, enqueued=0.0):
     return types.SimpleNamespace(tenant=tenant, enqueued=enqueued)
@@ -272,6 +276,21 @@ def test_admission_rejects_bad_config():
 # -- VerifyServer end to end ------------------------------------------
 
 
+@pytest.fixture
+def serve():
+    """`serve(**config)` starts a VerifyServer; every server started is
+    closed (drained; close is idempotent) when the test ends."""
+    started = []
+
+    def start(**config):
+        started.append(VerifyServer(**config).start())
+        return started[-1]
+
+    yield start
+    for srv in started:
+        srv.close(drain=True)
+
+
 @pytest.mark.slow
 def test_server_concurrent_verdicts_bit_identical():
     """The serving layer is pure transport: concurrent multi-tenant
@@ -297,28 +316,26 @@ def test_server_concurrent_verdicts_bit_identical():
     assert srv.pending == 0
 
 
-def test_server_tenant_full_sheds_explicitly():
+def test_server_tenant_full_sheds_explicitly(serve):
     """tenant_depth=1 with a never-firing flush: the second submit from
     the same tenant must raise ERR_OVERLOADED immediately — an explicit
     reject, never a hang — while the queued request still settles on
     drain."""
     items = _items(2, bad_first=False)
-    srv = VerifyServer(max_batch=64, flush_s=30.0, tenant_depth=1).start()
-    try:
-        queued = srv.submit(items[0])
-        with pytest.raises(OverloadError) as ei:
-            srv.submit(items[1])
-        assert ei.value.code == Error.ERR_OVERLOADED
-        assert ei.value.reason == SHED_TENANT_FULL
-    finally:
-        srv.close(drain=True)
+    srv = serve(max_batch=64, flush_s=30.0, tenant_depth=1)
+    queued = srv.submit(items[0])
+    with pytest.raises(OverloadError) as ei:
+        srv.submit(items[1])
+    assert ei.value.code == Error.ERR_OVERLOADED
+    assert ei.value.reason == SHED_TENANT_FULL
+    srv.close(drain=True)
     assert queued.result(timeout=60).ok
     assert srv.pending == 0
 
 
-def test_server_drain_settles_and_post_close_rejects():
+def test_server_drain_settles_and_post_close_rejects(serve):
     items = _items(3, bad_first=False)
-    srv = VerifyServer(max_batch=64, flush_s=30.0, tenant_depth=8).start()
+    srv = serve(max_batch=64, flush_s=30.0, tenant_depth=8)
     pend = [srv.submit(it) for it in items]
     assert not any(p.done() for p in pend)  # flush never fired
     srv.close(drain=True)  # drain trigger flushes + settles everything
@@ -330,9 +347,9 @@ def test_server_drain_settles_and_post_close_rejects():
     srv.close()  # idempotent
 
 
-def test_server_nondrain_close_cancels_explicitly():
+def test_server_nondrain_close_cancels_explicitly(serve):
     items = _items(1, bad_first=False)
-    srv = VerifyServer(max_batch=64, flush_s=30.0, tenant_depth=8).start()
+    srv = serve(max_batch=64, flush_s=30.0, tenant_depth=8)
     pend = srv.submit(items[0])
     srv.close(drain=False)
     with pytest.raises(OverloadError) as ei:
@@ -341,7 +358,7 @@ def test_server_nondrain_close_cancels_explicitly():
     assert srv.pending == 0
 
 
-def test_server_worker_exception_fails_requests_explicitly(monkeypatch):
+def test_server_worker_exception_fails_requests_explicitly(serve, monkeypatch):
     """A batch-driver crash must fail every windowed request with the
     exception — explicitly, not by leaving futures unresolved."""
     import bitcoinconsensus_tpu.serving.server as server_mod
@@ -352,16 +369,14 @@ def test_server_worker_exception_fails_requests_explicitly(monkeypatch):
 
     monkeypatch.setattr(server_mod, "verify_batch_stream", boom)
     items = _items(2, bad_first=False)
-    srv = VerifyServer(max_batch=2, flush_s=0.001, tenant_depth=8).start()
-    try:
-        p0 = srv.submit(items[0])
-        p1 = srv.submit(items[1])
-        with pytest.raises(RuntimeError, match="driver crashed"):
-            p0.result(timeout=30)
-        with pytest.raises(RuntimeError, match="driver crashed"):
-            p1.result(timeout=30)
-    finally:
-        srv.close(drain=True)
+    srv = serve(max_batch=2, flush_s=0.001, tenant_depth=8)
+    p0 = srv.submit(items[0])
+    p1 = srv.submit(items[1])
+    with pytest.raises(RuntimeError, match="driver crashed"):
+        p0.result(timeout=30)
+    with pytest.raises(RuntimeError, match="driver crashed"):
+        p1.result(timeout=30)
+    srv.close(drain=True)
     assert srv.pending == 0
 
 
@@ -478,14 +493,14 @@ def test_settle_span_parents_to_submit_span_across_worker_thread():
 # -- close() vs a concurrently-crashing worker (race-free shutdown) ----
 
 
-def test_close_race_with_crashing_worker_settles_stranded_put():
+def test_close_race_with_crashing_worker_settles_stranded_put(serve):
     """A submit racing a worker crash can land its request in the queue
     AFTER the dead worker's backstop drain swept it; close(drain=True)
     must sweep again after the join, or that caller hangs forever."""
     from bitcoinconsensus_tpu.serving.server import PendingVerify
 
     items = _items(2, bad_first=False)
-    srv = VerifyServer(max_batch=2, flush_s=0.001, tenant_depth=8).start()
+    srv = serve(max_batch=2, flush_s=0.001, tenant_depth=8)
 
     # Simulate an unexpected worker death (anything escaping the burst
     # handler): settle what was popped — _run_burst's contract — then
@@ -514,7 +529,7 @@ def test_close_race_with_crashing_worker_settles_stranded_put():
     assert srv.pending == 0
 
 
-def test_double_close_concurrent_with_worker_crash(monkeypatch):
+def test_double_close_concurrent_with_worker_crash(serve, monkeypatch):
     """Two concurrent close() calls racing a crashing worker: both must
     return (no deadlock, no exception), everything admitted settles."""
     import threading as _threading
@@ -527,7 +542,7 @@ def test_double_close_concurrent_with_worker_crash(monkeypatch):
 
     monkeypatch.setattr(server_mod, "verify_batch_stream", boom)
     items = _items(2, bad_first=False)
-    srv = VerifyServer(max_batch=2, flush_s=0.001, tenant_depth=8).start()
+    srv = serve(max_batch=2, flush_s=0.001, tenant_depth=8)
     pend = [srv.submit(it) for it in items]
     errs = []
 

@@ -4,6 +4,8 @@ never be cached; keys must commit to the spent outputs.
 Reference contract: `script/sigcache.cpp:22-122` (salted, success-only)
 and `validation.cpp:1529-1536` (script cache keyed on wtxid+flags)."""
 
+import pytest
+
 from conftest import *  # noqa: F401,F403 (env setup)
 
 from bitcoinconsensus_tpu.core.flags import VERIFY_ALL_LIBCONSENSUS
@@ -11,6 +13,8 @@ from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier, default_ver
 from bitcoinconsensus_tpu.models.batch import BatchItem, verify_batch
 from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
 from test_batch import make_p2wpkh_spend
+
+pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
 
 
 class CountingVerifier(TpuSecpVerifier):

@@ -35,6 +35,8 @@ from bitcoinconsensus_tpu.resilience.faults import (
 
 from test_batch import make_p2wpkh_spend
 
+pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
+
 
 def _keys(n, seed=0):
     """n distinct 32-byte keys spread over shard bytes."""

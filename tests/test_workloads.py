@@ -37,12 +37,15 @@ from bitcoinconsensus_tpu.workloads import (
 from bitcoinconsensus_tpu.workloads import diff_fuzz as df
 from bitcoinconsensus_tpu.workloads.corpus import run_corpus_check, shape_batch
 
+pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
+
 REF = load_reference_lib()
 
 
 # ---------------------------------------------------------------- corpus
 
 
+@pytest.mark.limit(600)  # the suite's only 128-lane dispatch: a cold compile
 def test_corpus_pins_hold_on_every_engine():
     """Every adversarial entry reproduces its pinned (ok, Error,
     ScriptError) triple on the python, batch/device and (when built)
