@@ -163,15 +163,22 @@ class BatchItem:
     spent_outputs: Optional[Sequence[Tuple[int, bytes]]] = None
 
 
-@dataclass
+@dataclass(frozen=True)
 class BatchResult:
+    """One input's verdict. Frozen, so every passing input can be the one
+    `success()` instance: a 6,000-input block costs no result object of
+    its own unless an input fails."""
+
     ok: bool
     error: Error
     script_error: Optional[ScriptError] = None
 
     @staticmethod
     def success() -> "BatchResult":
-        return BatchResult(True, Error.ERR_OK, ScriptError.OK)
+        return _SUCCESS
+
+
+_SUCCESS = BatchResult(True, Error.ERR_OK, ScriptError.OK)
 
 
 class DeferringSignatureChecker(TransactionSignatureChecker):
@@ -426,38 +433,32 @@ def _dispatch_uniq(nsess, verifier, sig_cache, state: _UniqState):
     _UNIQ_CHECKS.inc(U - lo)
     grow = np.arange(lo, U, dtype=np.int32)
     with verifier.phases("host_prep"):
-        digs = nsess.uniq_digests(sig_cache._salt, grow)
-    raw = digs.tobytes()
-    keys = {int(i): raw[32 * j : 32 * j + 32] for j, i in enumerate(grow)}
+        raw = nsess.uniq_digests(sig_cache._salt, grow).tobytes()
     state.val = np.concatenate([state.val, np.zeros(U - lo, dtype=bool)])
 
     if len(sig_cache) == 0 and _faults.active() is None:
-        miss = [int(i) for i in grow]  # cold cache: every probe misses
+        miss = grow  # cold cache: every probe misses
     else:
-        audit = _guards.audit_cache_hits()
-        miss = []
-        for i in grow:
-            if sig_cache.contains_key(keys[int(i)]):
-                # Audit mode (resilience): a hit certifies a past success,
-                # but a poisoned entry certifies nothing — re-verify on
-                # the exact oracle and evict entries proven wrong.
-                if audit and not nsess.uniq_host_verify(int(i)):
+        hit = sig_cache.contains_keys(raw, U - lo)
+        if _guards.audit_cache_hits():
+            # Audit mode (resilience): a hit certifies a past success,
+            # but a poisoned entry certifies nothing — re-verify on
+            # the exact oracle and evict entries proven wrong.
+            for j in np.nonzero(hit)[0].tolist():
+                if not nsess.uniq_host_verify(lo + j):
                     _guards.CACHE_POISON_CAUGHT.inc(cache="sig")
-                    sig_cache.discard_key(keys[int(i)])
-                    miss.append(int(i))
-                else:
-                    state.val[i] = True
-            else:
-                miss.append(int(i))
+                    sig_cache.discard_key(raw[32 * j : 32 * j + 32])
+                    hit[j] = False
+        state.val[lo:] = hit
+        miss = grow[~hit]
     pending = []
-    if miss:
-        cap = verifier.lane_capacity
-        for s in range(0, len(miss), cap):
-            sub = np.asarray(miss[s : s + cap], dtype=np.int32)
-            with verifier.phases("host_prep"):
-                lanes = nsess.uniq_lanes(sub, verifier.pad(len(sub)))
-            pending.append((verifier.dispatch_lanes(lanes, len(sub)), sub))
-    return grow, keys, pending
+    cap = verifier.lane_capacity
+    for s in range(0, len(miss), cap):
+        sub = miss[s : s + cap]
+        with verifier.phases("host_prep"):
+            lanes = nsess.uniq_lanes(sub, verifier.pad(len(sub)))
+        pending.append((verifier.dispatch_lanes(lanes, len(sub)), sub))
+    return grow, raw, pending
 
 
 def _settle_uniq(nsess, verifier, sig_cache, state: _UniqState,
@@ -468,7 +469,7 @@ def _settle_uniq(nsess, verifier, sig_cache, state: _UniqState,
     the native oracle and successes into the salted sig cache."""
     if round_rec is None:
         return
-    grow, keys, pending = round_rec
+    grow, raw, pending = round_rec
     for pend, sub in pending:
         okv, needs = verifier.sync_lanes(pend, len(sub))
         okv = np.array(okv, dtype=bool, copy=True)
@@ -481,8 +482,8 @@ def _settle_uniq(nsess, verifier, sig_cache, state: _UniqState,
                 if not r:
                     verifier._fixup_failed = True
         state.val[sub] = okv
-        for t in np.nonzero(okv)[0]:  # success-only, like the reference
-            sig_cache.add_key(keys[int(sub[int(t)])])
+        # success-only, like the reference; raw's row j is entry grow[0]+j
+        sig_cache.add_keys(raw, (sub - grow[0])[okv])
 
     nsess.publish_uniq(grow, state.val[grow].astype(np.int32))
 
@@ -505,7 +506,10 @@ class IdxFixpoint:
     them, accepts inputs whose verdicts are exact (no misses, or every
     optimistic guess confirmed true), and runs any remaining rounds to
     the fixpoint; inputs still pending at the round cap go through
-    `exact_fallback(idx) -> (ok, err_code)`. A stream driver calls batch
+    `exact_fallback(idx) -> (ok, err_code)`. Verdicts live in two int32
+    arrays indexed by input (`ok`, `err`; only the `live` rows are
+    written) and the pending set is an index array, so a round moves
+    masks, not one tuple an input. A stream driver calls batch
     N+1's `begin()` between batch N's `begin()` and `finish()`, so host
     interpretation runs while the previous batch is on the wire —
     `verify_batch_stream` is that driver."""
@@ -519,6 +523,7 @@ class IdxFixpoint:
         run_idx,
         exact_fallback,
         max_rounds: int = 24,  # > MAX_PUBKEYS_PER_MULTISIG cursor retries
+        n_inputs: Optional[int] = None,  # rows of ok/err; default max(live)+1
     ):
         self.nsess = nsess
         self.verifier = verifier
@@ -526,15 +531,18 @@ class IdxFixpoint:
         self.run_idx = run_idx
         self.exact_fallback = exact_fallback
         self.max_rounds = max_rounds
-        self.final: Dict[int, Tuple[bool, int]] = {}
+        self._pending = np.asarray(live, dtype=np.int64)
+        if n_inputs is None:
+            n_inputs = int(self._pending.max()) + 1 if len(self._pending) else 0
+        self.ok = np.zeros(n_inputs, dtype=np.int32)
+        self.err = np.zeros(n_inputs, dtype=np.int32)
         self._state = _UniqState()
-        self._pending = list(live)
         self._rounds = 0
         self._in_flight = None  # (interp tuple, uniq round record)
 
     def begin(self) -> None:
         """Start one round: interpret + dispatch, nothing synchronized."""
-        if self._in_flight is not None or not self._pending:
+        if self._in_flight is not None or not len(self._pending):
             return
         if self._rounds >= self.max_rounds:
             return
@@ -556,13 +564,10 @@ class IdxFixpoint:
         # exact verdict (unk == 0), or optimistic with every guess
         # confirmed true — equivalent to an exact pass
         accept = _accept_mask(self._state, rec_idx, bounds, unk)
-        still: List[int] = []
-        for k, idx in enumerate(self._pending):
-            if accept[k]:
-                self.final[idx] = (bool(ok[k]), int(err[k]))
-            else:
-                still.append(idx)
-        self._pending = still
+        done = self._pending[accept]
+        self.ok[done] = np.asarray(ok)[accept]
+        self.err[done] = np.asarray(err)[accept]
+        self._pending = self._pending[~accept]
 
     def abandon(self) -> None:
         """Settle-and-discard the in-flight round without running the
@@ -571,35 +576,35 @@ class IdxFixpoint:
         verifier's in-flight queue, so they must settle even when nobody
         wants the verdicts; settle failures are already contained by the
         guards and irrelevant to a dead run."""
+        self._pending = self._pending[:0]
         if self._in_flight is None:
-            self._pending = []
             return
         _interp, rec = self._in_flight
         self._in_flight = None
         if rec is not None:
-            _grow, _keys, pending = rec
+            _grow, _raw, pending = rec
             for pend, sub in pending:
                 try:
                     self.verifier.sync_lanes(pend, len(sub))
                 except Exception:
                     pass
-        self._pending = []
 
-    def finish(self) -> Dict[int, Tuple[bool, int]]:
-        """Settle the in-flight round, then loop to the fixpoint."""
+    def finish(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Settle the in-flight round, then loop to the fixpoint; returns
+        the (ok, err) arrays."""
         if self._in_flight is not None:
             self._settle_round()
-        while self._pending and self._rounds < self.max_rounds:
+        while len(self._pending) and self._rounds < self.max_rounds:
             self.begin()
             if self._in_flight is None:  # defensive: begin refused
                 break
             self._settle_round()
         _FIXPOINT_ROUNDS.observe(self._rounds)
-        if self._pending:  # round cap hit: exact host fallback
+        if len(self._pending):  # round cap hit: exact host fallback
             _EXACT_FALLBACK.inc(len(self._pending))
-        for idx in self._pending:
-            self.final[idx] = self.exact_fallback(idx)
-        return self.final
+        for idx in self._pending.tolist():
+            self.ok[idx], self.err[idx] = self.exact_fallback(idx)
+        return self.ok, self.err
 
 
 def run_idx_fixpoint(
@@ -610,11 +615,13 @@ def run_idx_fixpoint(
     run_idx,
     exact_fallback,
     max_rounds: int = 24,
-) -> Dict[int, Tuple[bool, int]]:
+    n_inputs: Optional[int] = None,
+) -> Tuple[np.ndarray, np.ndarray]:
     """Synchronous fixpoint (begin + finish back-to-back); the signature
     models/validate.py `_connect_block_native` drives."""
     run = IdxFixpoint(nsess, verifier, sig_cache, live, run_idx,
-                      exact_fallback, max_rounds=max_rounds)
+                      exact_fallback, max_rounds=max_rounds,
+                      n_inputs=n_inputs)
     run.begin()
     return run.finish()
 
@@ -639,7 +646,7 @@ def _verify_batch_idx(
     (checkqueue.h:29-163 shape). Results are bit-identical to the wire
     driver and the per-input API (tests/test_batch.py runs both paths)."""
     run = _idx_fixpoint_for(items, preps, nsess, verifier, sig_cache)
-    final: Dict[int, Tuple[bool, int]] = {}
+    final = None
     if run is not None:
         run.begin()
         final = run.finish()
@@ -661,7 +668,8 @@ def _idx_fixpoint_for(
         return None
     n_threads = _idx_threads()
 
-    def run_idx(pos: List[int]):
+    def run_idx(pos: np.ndarray):
+        pos = pos.tolist()
         with verifier.phases("interpret"):
             return nsess.verify_inputs_idx(
                 [preps[i].ntx for i in pos],
@@ -681,27 +689,35 @@ def _idx_fixpoint_for(
         return okx, err_code
 
     return IdxFixpoint(nsess, verifier, sig_cache, live, run_idx,
-                       exact_fallback)
+                       exact_fallback, n_inputs=len(preps))
 
 
 def _assemble_idx_results(
     preps: List[_Prepared],
-    final: Dict[int, Tuple[bool, int]],
+    final: Optional[Tuple[np.ndarray, np.ndarray]],
     script_cache: ScriptExecutionCache,
     script_keys: List[Optional[bytes]],
 ) -> List[BatchResult]:
+    """Lay the fixpoint's (ok, err) arrays out per item (None: every input
+    resolved before interpretation); the passing inputs' script-cache
+    keys go in as one bulk insert, in item order."""
+    if final is None:
+        return [prep.result for prep in preps]
+    ok, err = (a.tolist() for a in final)
     out: List[BatchResult] = []
+    passed: List[bytes] = []
     for idx, prep in enumerate(preps):
         if prep.result is not None:
             out.append(prep.result)
-            continue
-        ok, err = final[idx]
-        if ok:
+        elif ok[idx]:
             if script_keys[idx] is not None:
-                script_cache.add_key(script_keys[idx])
-            out.append(BatchResult.success())
+                passed.append(script_keys[idx])
+            out.append(_SUCCESS)
         else:
-            out.append(BatchResult(False, Error.ERR_SCRIPT, ScriptError(err)))
+            out.append(
+                BatchResult(False, Error.ERR_SCRIPT, ScriptError(err[idx]))
+            )
+    script_cache.add_keys(b"".join(passed))
     return out
 
 
@@ -787,7 +803,7 @@ def verify_batch_stream(
             return handle[1]
         _tag, run, preps, script_keys = handle
         with gc_paused(), _span("batch.stream_finish", n=len(preps)):
-            final = run.finish() if run is not None else {}
+            final = run.finish() if run is not None else None
             out = _assemble_idx_results(preps, final, script_cache,
                                         script_keys)
         _record_batch_results(out)

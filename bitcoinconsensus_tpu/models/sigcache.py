@@ -37,6 +37,8 @@ import threading
 from collections import OrderedDict
 from typing import Iterable, Optional, Tuple
 
+import numpy as np
+
 from ..obs import counter as _obs_counter
 from ..obs import gauge as _obs_gauge
 from ..resilience import faults as _faults
@@ -142,6 +144,43 @@ class _SaltedLRU:
             self._m_misses.inc()
         return hit
 
+    def contains_keys(
+        self, blob: bytes, n: int, erase: bool = False
+    ) -> np.ndarray:
+        """Probe the `n` digests packed in `blob` (32 bytes each); returns
+        the hit mask. One lock hold, and inside it `contains_key`'s steps
+        per key in blob order, so answers, LRU order and the counters end
+        where `n` single probes would leave them; the registry is raised
+        once, by the call's totals. With a fault plan armed the probes go
+        one by one through `contains_key`: the poison site counts visits."""
+        if _faults.active() is not None:
+            return self._contains_each(blob, n, erase)
+        hit = [False] * n
+        with self._lock:
+            s = self._set
+            touch = s.__delitem__ if erase else s.move_to_end
+            for j in range(n):
+                k = blob[32 * j : 32 * j + 32]
+                if k in s:
+                    touch(k)
+                    hit[j] = True
+            hits = hit.count(True)
+            self.hits += hits
+            self.misses += n - hits
+            if erase:
+                self.erases += hits
+            size = len(s)
+        if n:
+            self._m_lookups.inc(n)
+        if hits:
+            self._m_hits.inc(hits)
+        if n - hits:
+            self._m_misses.inc(n - hits)
+        if erase and hits:
+            self._m_erases.inc(hits)
+            self._m_entries.set(size)
+        return np.array(hit, dtype=bool)
+
     def discard_key(self, k: bytes) -> None:
         """Drop a proven-wrong entry (resilience cache-audit containment).
 
@@ -179,6 +218,60 @@ class _SaltedLRU:
         if evicted:
             self._m_evicts.inc(evicted)
         self._m_entries.set(size)
+
+    def add_keys(self, blob: bytes, select=None) -> None:
+        """Insert digests of `blob` (32 bytes each): those a bool mask
+        marks, in ascending order; those an index array names, in its
+        order; all of them when `select` is None. One lock hold, `add_key`'s
+        steps per key inside it, the registry raised once by the totals."""
+        idx = self._selected(blob, select)
+        if not len(idx):
+            return
+        inserted = evicted = 0
+        with self._lock:
+            s = self._set
+            for j in idx:
+                k = blob[32 * j : 32 * j + 32]
+                if k in s:  # a freshness touch, not an insertion
+                    s.move_to_end(k)
+                    continue
+                s[k] = None
+                inserted += 1
+                while len(s) > self._max:
+                    s.popitem(last=False)
+                    evicted += 1
+            self.insertions += inserted
+            self.evictions += evicted
+            size = len(s)
+        if inserted:
+            self._m_inserts.inc(inserted)
+        if evicted:
+            self._m_evicts.inc(evicted)
+        self._m_entries.set(size)
+
+    @staticmethod
+    def _selected(blob: bytes, select):
+        if select is None:
+            return range(len(blob) // 32)
+        sel = np.asarray(select)
+        return (np.nonzero(sel)[0] if sel.dtype == bool else sel).tolist()
+
+    # The bulk forms as `n` single-key calls: what `contains_keys` does
+    # under a fault plan, and what a subclass with a `contains_key` /
+    # `add_key` of its own binds the bulk names to (models/sigstore.py).
+
+    def _contains_each(
+        self, blob: bytes, n: int, erase: bool = False
+    ) -> np.ndarray:
+        return np.fromiter(
+            (self.contains_key(blob[32 * j : 32 * j + 32], erase)
+             for j in range(n)),
+            dtype=bool, count=n,
+        )
+
+    def _add_each(self, blob: bytes, select=None) -> None:
+        for j in self._selected(blob, select):
+            self.add_key(blob[32 * j : 32 * j + 32])
 
     def contains(self, parts: Iterable[bytes], erase: bool = False) -> bool:
         return self.contains_key(self._key(parts), erase=erase)

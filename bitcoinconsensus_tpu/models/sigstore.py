@@ -445,6 +445,11 @@ class PersistentSigCache(SigCache):
             self._m_inserts.inc()
         self._m_entries.set(self._entries)
 
+    # Every key takes this class's own probe and insert (disk tier,
+    # journal append): here the bulk forms are the per-key path.
+    contains_keys = SigCache._contains_each
+    add_keys = SigCache._add_each
+
     def discard_key(self, k: bytes) -> None:
         """Drop a proven-wrong entry from BOTH tiers and tombstone it on
         disk — the audit-mode containment path (resilience/guards.py):

@@ -268,8 +268,17 @@ def _connect_block_native(
 
     if flags is None:
         flags = height_to_flags(height, extended=True)
+
+    phases = verifier.phases if verifier is not None else None
+
+    def phase(name):
+        from contextlib import nullcontext
+
+        return phases(name) if phases is not None else nullcontext()
+
     if isinstance(block, (bytes, bytearray)):
-        nblk = native_bridge.NativeBlock(bytes(block))
+        with phase("parse"):
+            nblk = native_bridge.NativeBlock(bytes(block))
     else:
         # The cached parse is keyed on a cheap content fingerprint (header
         # bytes + per-tx txid/wtxid) so a Block mutated between calls is
@@ -285,15 +294,9 @@ def _connect_block_native(
         if cached is not None and cached[0] == fp:
             nblk = cached[1]
         else:
-            nblk = native_bridge.NativeBlock(block.serialize())
+            with phase("parse"):
+                nblk = native_bridge.NativeBlock(block.serialize())
             block._native = (fp, nblk)
-
-    phases = verifier.phases if verifier is not None else None
-
-    def phase(name):
-        from contextlib import nullcontext
-
-        return phases(name) if phases is not None else nullcontext()
 
     with phase("block_check"):
         reason = nblk.check(check_pow, pow_limit)
@@ -328,41 +331,36 @@ def _connect_block_native(
         n = len(tx_index)
         with phase("probe"):
             raw_keys = nblk.script_keys(script_cache._salt, flags).tobytes()
-            keys = [raw_keys[32 * j : 32 * j + 32] for j in range(n)]
             if len(script_cache) == 0:  # cold cache: every probe misses
-                hit = [False] * n
+                hit = np.zeros(n, dtype=bool)
             else:
-                hit = [script_cache.contains_key(k) for k in keys]
+                hit = script_cache.contains_keys(raw_keys, n)
 
         nsess = native_bridge.NativeSession()
-        live = [j for j in range(n) if not hit[j]]
+        live = np.nonzero(~hit)[0]
         n_threads = _idx_threads()
         flags_a = np.full(n, flags, dtype=np.int32)
 
-        # Raw per-tx pointers, resolved once: the NTx objects are owned by
-        # the (live) nblk, so the pointers outlast any handle wrapper.
-        ptr_by_tx = [nblk.tx(t)._ptr for t in range(nblk.n_tx)]
+        # Raw NTx pointers, one per input: the txs are owned by the (live)
+        # nblk, so the column outlasts every call below.
+        tx_ptrs = nblk.tx_ptrs()[tx_index]
 
         def run_idx(pos):
             if len(pos) == n:  # common path: whole block, zero-copy
-                tx_ptrs = [ptr_by_tx[t] for t in tx_index.tolist()]
                 return nsess.verify_inputs_idx_raw(
                     tx_ptrs, n_in, amounts, spk_blob, spk_offs, flags_a,
                     n_threads,
                 )
-            sel = np.asarray(pos, dtype=np.int64)
+            # A subset (script-cache hits left out, or a later fixpoint
+            # round): gather its scriptPubKeys into one blob, vectorized.
+            lens = spk_offs[pos + 1] - spk_offs[pos]
             sub_offs = np.zeros(len(pos) + 1, dtype=np.int64)
-            chunks = []
-            for k, j in enumerate(pos):
-                chunks.append(spk_blob[int(spk_offs[j]) : int(spk_offs[j + 1])])
-                sub_offs[k + 1] = sub_offs[k] + len(chunks[-1])
-            sub_blob = (
-                np.concatenate(chunks) if chunks else np.zeros(1, np.uint8)
-            )
+            np.cumsum(lens, out=sub_offs[1:])
+            src = np.repeat(spk_offs[pos] - sub_offs[:-1], lens)
+            src += np.arange(sub_offs[-1], dtype=np.int64)
             return nsess.verify_inputs_idx_raw(
-                [ptr_by_tx[int(tx_index[j])] for j in pos],
-                n_in[sel], amounts[sel], sub_blob, sub_offs, flags_a[sel],
-                n_threads,
+                tx_ptrs[pos], n_in[pos], amounts[pos], spk_blob[src],
+                sub_offs, flags_a[pos], n_threads,
             )
 
         def timed_run_idx(pos):
@@ -380,28 +378,26 @@ def _connect_block_native(
 
         from .batch import run_idx_fixpoint
 
-        final = run_idx_fixpoint(
-            nsess, verifier, sig_cache, live, timed_run_idx, exact_fallback
+        ok, err = run_idx_fixpoint(
+            nsess, verifier, sig_cache, live, timed_run_idx, exact_fallback,
+            n_inputs=n,
         )
 
         from ..core.script_error import ScriptError
 
-        input_results = []
-        all_ok = True
-        for j in range(n):
-            if hit[j]:
-                input_results.append(BatchResult.success())
-                continue
-            okj, errj = final[j]
-            if okj:
-                script_cache.add_key(keys[j])
-                input_results.append(BatchResult.success())
-            else:
-                all_ok = False
-                input_results.append(
-                    BatchResult(False, Error.ERR_SCRIPT, ScriptError(errj))
+        with phase("results"):
+            # ok/err are written on the live rows only; a hit passed before.
+            passed = hit | (ok != 0)
+            script_cache.add_keys(raw_keys, passed & ~hit)
+            # Every passing input is the one frozen success instance; only
+            # a failing input gets a result object of its own.
+            input_results = [BatchResult.success()] * n
+            failed = np.nonzero(~passed)[0].tolist()
+            for j in failed:
+                input_results[j] = BatchResult(
+                    False, Error.ERR_SCRIPT, ScriptError(int(err[j]))
                 )
-        if not all_ok:
+        if failed:
             return ConnectResult(
                 False, "block-validation-failed", fees, sigop_cost,
                 input_results,

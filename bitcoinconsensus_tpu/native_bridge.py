@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 4:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 4)")
+        if L.nat_version() < 5:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 5)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -239,6 +239,10 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_block_n_inputs.restype = ctypes.c_int32
         L.nat_block_tx.argtypes = [vp, ctypes.c_int32]
         L.nat_block_tx.restype = vp
+        L.nat_block_tx_ptrs.argtypes = [
+            vp, ctypes.POINTER(ctypes.c_void_p), ctypes.c_int32,
+        ]
+        L.nat_block_tx_ptrs.restype = ctypes.c_int32
         L.nat_block_txid.argtypes = [vp, ctypes.c_int32, u8p]
         L.nat_block_wtxid.argtypes = [vp, ctypes.c_int32, u8p]
         L.nat_block_check.argtypes = [vp, ctypes.c_int32, u8p, ctypes.c_int32]
@@ -699,7 +703,7 @@ class NativeSession:
 
     def verify_inputs_idx_raw(
         self,
-        tx_ptrs: Sequence,
+        tx_ptrs: np.ndarray,
         n_ins: np.ndarray,
         amounts: np.ndarray,
         spk_blob: np.ndarray,
@@ -710,12 +714,14 @@ class NativeSession:
         """Array-native variant of verify_inputs_idx: the scriptPubKeys
         arrive as one (blob, offs) pair — zero copies when the caller
         already holds the block accounting's arrays (models/validate.py
-        _connect_block_native). `tx_ptrs` are raw NTx pointers."""
+        _connect_block_native). `tx_ptrs` is the per-input column of raw
+        NTx pointers (a gather from `NativeBlock.tx_ptrs()`)."""
         n = len(tx_ptrs)
         if n == 0:
             z32 = np.zeros(0, np.int32)
             return z32, z32, z32, z32, np.zeros(1, np.int64)
-        ptrs = (ctypes.c_void_p * n)(*tx_ptrs)
+        ptr_a = np.ascontiguousarray(tx_ptrs, dtype=np.uintp)
+        ptrs = ptr_a.ctypes.data_as(ctypes.POINTER(ctypes.c_void_p))
         nin_a = np.ascontiguousarray(n_ins, dtype=np.int32)
         amt_a = np.ascontiguousarray(amounts, dtype=np.int64)
         flg_a = np.ascontiguousarray(flags, dtype=np.int32)
@@ -944,6 +950,19 @@ class NativeBlock:
             assert ptr, i
             t = self._txs[i] = NativeBlockTx(self, i, ptr)
         return t
+
+    def tx_ptrs(self) -> np.ndarray:
+        """(n_tx,) uintp raw NTx pointers, one C call for the whole block.
+        The block owns the txs, so the table is good for as long as the
+        caller holds this handle; index it with `tx_index` for the
+        per-input column `verify_inputs_idx_raw` takes."""
+        out = np.zeros(max(self.n_tx, 1), dtype=np.uintp)
+        got = lib().nat_block_tx_ptrs(
+            self._ptr, out.ctypes.data_as(ctypes.POINTER(ctypes.c_void_p)),
+            self.n_tx,
+        )
+        assert got == self.n_tx, (got, self.n_tx)
+        return out[: self.n_tx]
 
     def txid(self, i: int) -> bytes:
         out = np.zeros(32, dtype=np.uint8)

@@ -201,7 +201,8 @@ extern "C" {
 
 // 4: nat_session_recidx_data grew a capacity argument + i64 return;
 //    the nat_block_* / nat_view_* block layer landed.
-int nat_version() { return 4; }
+// 5: nat_block_tx_ptrs.
+int nat_version() { return 5; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -234,6 +235,16 @@ void* nat_block_tx(void* b, i32 i) {
     auto* blk = static_cast<NBlock*>(b);
     if (i < 0 || (size_t)i >= blk->vtx.size()) return nullptr;
     return blk->vtx[(size_t)i].get();
+}
+
+// Every tx of the block at once: the first min(cap, n_tx) borrowed
+// pointers go to out, n_tx is returned (the index-mode driver gathers its
+// per-input pointer column from this table with one array index).
+i32 nat_block_tx_ptrs(void* b, void** out, i32 cap) {
+    auto* blk = static_cast<NBlock*>(b);
+    i32 n = (i32)blk->vtx.size();
+    for (i32 i = 0; i < n && i < cap; i++) out[i] = blk->vtx[(size_t)i].get();
+    return n;
 }
 
 void nat_block_txid(void* b, i32 i, u8* out32) {

@@ -445,13 +445,17 @@ def test_fixpoint_round_cap_exact_fallback():
         return (idx % 2 == 1, 0 if idx % 2 else 39)
 
     before = _EXACT_FALLBACK.value()
-    final = run_idx_fixpoint(
+    ok, err = run_idx_fixpoint(
         _StuckSession(), None, None, live, run_idx, exact_fallback,
         max_rounds=3,
     )
     assert calls["rounds"] == 3  # the cap really bounded the loop
     assert sorted(calls["fallback"]) == live
-    assert final == {idx: (idx % 2 == 1, 0 if idx % 2 else 39) for idx in live}
+    # verdict arrays indexed by input; rows outside `live` are not written
+    assert len(ok) == len(err) == max(live) + 1
+    assert {idx: (bool(ok[idx]), int(err[idx])) for idx in live} == {
+        idx: (idx % 2 == 1, 0 if idx % 2 else 39) for idx in live
+    }
     assert _EXACT_FALLBACK.value() == before + len(live)
 
 
@@ -523,7 +527,7 @@ def test_idx_fixpoint_abandon_settles_inflight_tickets():
     )
     run.abandon()
     assert v.calls == [("pend1", 2), ("pend2", 1)]
-    assert run._in_flight is None and run._pending == []
+    assert run._in_flight is None and len(run._pending) == 0
 
 
 def test_idx_fixpoint_abandon_contains_settle_failures():
@@ -536,13 +540,13 @@ def test_idx_fixpoint_abandon_contains_settle_failures():
     )
     run.abandon()  # must not raise
     assert v.calls == [("bad", 1), ("good", 2)]
-    assert run._in_flight is None and run._pending == []
+    assert run._in_flight is None and len(run._pending) == 0
 
 
 def test_idx_fixpoint_abandon_without_inflight_round():
     run = _stub_fixpoint(_RecordingVerifier())
     run.abandon()
-    assert run._pending == [] and run._in_flight is None
+    assert len(run._pending) == 0 and run._in_flight is None
 
 
 def test_abandon_stream_window_only_touches_idx_handles():

@@ -290,3 +290,135 @@ def test_block_trailing_data_rejected():
         native_bridge.NativeBlock(raw + b"\x00")
     nblk = native_bridge.NativeBlock(raw)
     assert nblk.n_tx == 2
+
+
+def _twin_caches(label):
+    """Two (sig, script) cache pairs under one salt each way, so the two
+    pipelines' salted key sets can be compared."""
+    sig_py, script_py = SigCache(cache_label=label + "-sig-py"), \
+        ScriptExecutionCache(cache_label=label + "-script-py")
+    sig_nat, script_nat = SigCache(cache_label=label + "-sig-nat"), \
+        ScriptExecutionCache(cache_label=label + "-script-nat")
+    sig_nat._salt, script_nat._salt = sig_py._salt, script_py._salt
+    return (sig_py, script_py), (sig_nat, script_nat)
+
+
+def test_hits_multisig_and_two_failures_parity(monkeypatch):
+    """The bulk driver against the spec on a block that takes every branch
+    of the verdict assembly at once: two inputs the mempool saw (script-
+    cache hits), 2-of-3 multisigs signed by the lower keys (a second
+    fixpoint round, on a subset of the inputs), and two failing inputs
+    in different transactions."""
+    from bitcoinconsensus_tpu.core.flags import height_to_flags
+    from bitcoinconsensus_tpu.models import batch as batch_mod
+    from bitcoinconsensus_tpu.models.batch import BatchItem, verify_batch
+
+    kinds = ("p2wpkh", "p2wpkh", "p2wsh_multisig", "p2wpkh", "p2tr",
+             "p2wpkh", "p2wsh_multisig")
+    coins, funded = make_funded_view(7, kinds=kinds, seed="nb18")
+    seen = build_spend_tx(funded[0:2], fee=700)
+    txs = [
+        seen,
+        build_spend_tx(funded[2:4], fee=700, corrupt_input=1),  # input 3
+        build_spend_tx(funded[4:6], fee=700, corrupt_input=0),  # input 4
+        build_spend_tx(funded[6:7], fee=700),
+    ]
+    block = build_block(txs, HEIGHT, fees=2800)
+    flags = height_to_flags(HEIGHT, extended=True)
+    outs = [(f.amount, f.wallet.spk) for f in funded[0:2]]
+    mempool = [BatchItem(seen.serialize(), i, flags, spent_outputs=outs)
+               for i in range(2)]
+    py, nat = _twin_caches("nb18")
+    for sig, script in (py, nat):
+        assert all(r.ok for r in verify_batch(
+            mempool, sig_cache=sig, script_cache=script))
+    assert len(py[1]) == len(nat[1]) == 2
+
+    rounds = []
+    settle = batch_mod.IdxFixpoint._settle_round
+    monkeypatch.setattr(
+        batch_mod.IdxFixpoint, "_settle_round",
+        lambda self: (rounds.append(len(self._pending)), settle(self))[1],
+    )
+    nview = to_native_view(coins)
+    n_coins = len(coins)
+    res_py = connect_block(block, coins, HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                           sig_cache=py[0], script_cache=py[1])
+    rounds.clear()
+    res_nat = connect_block(block, nview, HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                            sig_cache=nat[0], script_cache=nat[1])
+    # the hits stayed out of interpretation; both multisigs (a guessed
+    # pairing was false) and both failures went round again, as a subset
+    assert rounds == [5, 4]
+
+    assert (res_nat.ok, res_nat.reason) == (res_py.ok, res_py.reason) == (
+        False, "block-validation-failed")
+    assert (res_nat.fees, res_nat.sigop_cost) == (res_py.fees, res_py.sigop_cost)
+    assert len(res_nat.input_results) == len(res_py.input_results) == 7
+    for got, want in zip(res_nat.input_results, res_py.input_results):
+        assert (got.ok, got.error, got.script_error) == (
+            want.ok, want.error, want.script_error)
+        assert got == want
+    assert res_nat.script_failures == res_py.script_failures == [3, 4]
+    assert [r.ok for r in res_nat.input_results] == [
+        True, True, True, False, False, True, True]
+    assert len(nview) == len(coins) == n_coins  # view untouched on reject
+    # successes, and only successes, went into both caches of both paths
+    assert set(nat[1]._set) == set(py[1]._set) and len(nat[1]) == 5
+    assert set(nat[0]._set) == set(py[0]._set)
+    for a, b in zip(py, nat):
+        assert (a.hits, a.misses, a.insertions) == (b.hits, b.misses, b.insertions)
+
+
+def test_native_connect_touches_no_input_one_at_a_time(monkeypatch):
+    """Does the bulk mechanism engage: one native connect of an all-valid
+    block makes no single-key cache call, asks for no per-tx handle and
+    builds no result object for a passing input, whatever the block's
+    size; and the two stretches that had no phase have one."""
+    from bitcoinconsensus_tpu.crypto.jax_backend import default_verifier
+    from bitcoinconsensus_tpu.models.batch import BatchResult
+
+    coins, funded = make_funded_view(
+        6, kinds=("p2wpkh", "p2tr", "p2wsh_multisig"), seed="nb19"
+    )
+    txs = [build_spend_tx(funded[i : i + 2], fee=800) for i in range(0, 6, 2)]
+    raw = build_block(txs, HEIGHT, fees=2400).serialize()
+    nview = to_native_view(coins)
+    sig, script = SigCache(), ScriptExecutionCache()
+    sig.add_key(b"\x01" * 32)  # not empty: the probes are really made
+    script.add_key(b"\x02" * 32)
+
+    calls = {"contains_key": 0, "add_key": 0, "tx": 0, "BatchResult": 0,
+             "contains_keys": 0, "add_keys": 0}
+
+    def counted(owner, name, key=None):
+        real = getattr(owner, name)
+
+        def wrapper(*a, **k):
+            calls[key or name] += 1
+            return real(*a, **k)
+
+        monkeypatch.setattr(owner, name, wrapper)
+
+    for cls in (SigCache, ScriptExecutionCache):
+        for name in ("contains_key", "add_key", "contains_keys", "add_keys"):
+            counted(cls, name)
+    counted(native_bridge.NativeBlock, "tx")
+    counted(BatchResult, "__init__", "BatchResult")
+
+    verifier = default_verifier()
+    verifier.phases.reset()
+    res = connect_block(raw, nview, HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        verifier=verifier, sig_cache=sig, script_cache=script)
+    assert res.ok and len(res.input_results) == 6
+    assert all(r is BatchResult.success() for r in res.input_results)
+    assert res.script_failures == []
+    assert (calls["contains_key"], calls["add_key"], calls["tx"],
+            calls["BatchResult"]) == (0, 0, 0, 0), calls
+    # one probe and one insert a cache: the second fixpoint round finds
+    # every pairing it needs already resolved
+    assert calls["contains_keys"] == 2 and calls["add_keys"] == 2, calls
+    assert len(script) == 1 + 6 and len(sig) > 1
+    report = verifier.phases.report()
+    assert {"parse", "results", "probe", "interpret"} <= set(report)
+    assert report["parse"]["calls"] == report["results"]["calls"] == 1

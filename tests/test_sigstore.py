@@ -111,6 +111,28 @@ def test_hot_tier_overflow_never_loses_entries(tmp_path):
     s.close()
 
 
+def test_bulk_forms_take_the_store_path(tmp_path):
+    """`add_keys`/`contains_keys` (what the index-mode drivers call) go
+    key by key through the store's own insert and probe: journalled,
+    served from the disk tier past the hot bound, erase-on-hit durable."""
+    ks = _keys(20)
+    blob = b"".join(ks)
+    s = _store(tmp_path, hot_entries=4)
+    s.add_keys(blob, [i % 2 == 0 for i in range(20)])
+    assert len(s) == 10 and s.insertions == 10
+    s.close()
+
+    s2 = _store(tmp_path, hot_entries=4)
+    assert s2.replay_applied == 10
+    assert s2.contains_keys(blob, 20).tolist() == [i % 2 == 0 for i in range(20)]
+    assert (s2.hits, s2.misses) == (10, 10)
+    assert s2.contains_keys(blob[:64], 2, erase=True).tolist() == [True, False]
+    s2.close()
+    s3 = _store(tmp_path, hot_entries=4)
+    assert len(s3) == 9 and not s3.contains_key(ks[0])
+    s3.close()
+
+
 def test_erase_on_hit_persists(tmp_path):
     ks = _keys(4)
     s = _store(tmp_path)
