@@ -496,7 +496,7 @@ def _resolve_uniq(nsess, verifier, sig_cache, state: _UniqState) -> None:
 
 class IdxFixpoint:
     """The deferral fixpoint both index-mode drivers share
-    (`_verify_batch_idx` and models/validate.py `_connect_block_native` —
+    (`_verify_batch_idx` and models/validate.py `_NativeConnect` —
     ONE copy of the consensus-critical loop), split into an async `begin`
     and a settling `finish` so stream drivers can overlap batches.
 
@@ -512,7 +512,8 @@ class IdxFixpoint:
     masks, not one tuple an input. A stream driver calls batch
     N+1's `begin()` between batch N's `begin()` and `finish()`, so host
     interpretation runs while the previous batch is on the wire —
-    `verify_batch_stream` is that driver."""
+    `verify_batch_stream` is that driver for item batches, and
+    models/validate.py `connect_block_stream` for whole blocks."""
 
     def __init__(
         self,
@@ -617,8 +618,7 @@ def run_idx_fixpoint(
     max_rounds: int = 24,
     n_inputs: Optional[int] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Synchronous fixpoint (begin + finish back-to-back); the signature
-    models/validate.py `_connect_block_native` drives."""
+    """Synchronous fixpoint (begin + finish back-to-back)."""
     run = IdxFixpoint(nsess, verifier, sig_cache, live, run_idx,
                       exact_fallback, max_rounds=max_rounds,
                       n_inputs=n_inputs)

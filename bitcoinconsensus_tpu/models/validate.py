@@ -19,8 +19,18 @@ they sit above `CheckInputScripts` in the reference.
 
 from __future__ import annotations
 
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import (
+    Callable,
+    Dict,
+    Iterable,
+    Iterator,
+    List,
+    Optional,
+    Tuple,
+    Union,
+)
 
 from ..api import Error
 from ..core.block import (
@@ -47,6 +57,7 @@ from ..core.tx import COIN, MAX_MONEY, OutPoint, Tx, TxOut
 from ..core.tx_check import WITNESS_SCALE_FACTOR
 from ..crypto.jax_backend import TpuSecpVerifier
 from ..obs import counter as _obs_counter
+from ..obs import histogram as _obs_histogram
 from ..obs import span as _span
 from ..utils.gcpause import gc_paused
 from .batch import BatchItem, BatchResult, verify_batch
@@ -57,6 +68,7 @@ __all__ = [
     "CoinsView",
     "ConnectResult",
     "connect_block",
+    "connect_block_stream",
     "count_witness_sigops",
     "get_transaction_sigop_cost",
     "get_block_subsidy",
@@ -75,6 +87,25 @@ _BLOCK_REJECTS = _obs_counter(
     "consensus_block_reject_total",
     "connect_block rejections by reason string",
     ("reason",),
+)
+# A block stream (connect_block_stream): how each block it took in ended,
+# how many speculative applies were taken back, and how many blocks were
+# begun and not yet finished each time one was begun.
+_STREAM_BLOCKS = _obs_counter(
+    "consensus_stream_blocks_total",
+    "blocks a connect_block_stream took in, by how they ended "
+    "(abandoned: begun behind a failed block or before an early close)",
+    ("result",),
+)
+_STREAM_ROLLBACKS = _obs_counter(
+    "consensus_stream_rollbacks_total",
+    "speculative block applies a connect_block_stream undid",
+)
+_STREAM_IN_FLIGHT = _obs_histogram(
+    "consensus_stream_blocks_in_flight",
+    "blocks begun and not yet finished in a connect_block_stream, "
+    "observed at each begin (the one being begun included)",
+    buckets=(1, 2, 3, 4, 6, 8, 16),
 )
 
 
@@ -222,6 +253,14 @@ def connect_block(
     the index-mode session directly — the production replay path
     (`_connect_block_native`). Results are identical to the Python
     pipeline (tests/test_native_block.py replays both).
+
+    For successive blocks use `connect_block_stream`: it yields the
+    `ConnectResult`s this function would give one after the other, while
+    it overlaps block N+1's host work with block N's device time behind a
+    speculative view (its docstring lists the five things a stream
+    guarantees; its cache hits may be fewer than a loop's, its verdicts
+    not different). This function is the depth-1 case of the same two
+    halves (`_NativeConnect.begin` and `.finish`), without speculation.
     """
     from .. import native_bridge
 
@@ -241,9 +280,7 @@ def connect_block(
                 check_scripts, enforce_witness_commitment, pow_limit,
                 sig_cache, script_cache,
             )
-    _BLOCKS.inc(result="ok" if res.ok else "reject")
-    if not res.ok and res.reason:
-        _BLOCK_REJECTS.inc(reason=res.reason)
+    _count_block(res)
     return res
 
 
@@ -252,34 +289,88 @@ def _connect_block_native(
     enforce_witness_commitment, pow_limit, sig_cache, script_cache,
 ) -> ConnectResult:
     """`connect_block` with the block layer in C++ (native/block.hpp) and
-    the script phase on the index-mode session protocol.
+    the script phase on the index-mode session protocol: `_NativeConnect`
+    begun and finished back to back, with no speculation (the view is
+    written after the verdicts). `connect_block_stream` drives the same
+    two halves with other blocks' halves in between."""
+    run = _NativeConnect(
+        block, coins, height, flags, verifier, check_pow, check_scripts,
+        enforce_witness_commitment, pow_limit, sig_cache, script_cache,
+    )
+    run.begin()
+    return run.finish()
 
-    Phase map (validation.cpp:1946-2228): CheckBlock + witness commitment
-    + BIP30/maturity/value/sigop accounting + per-tx hash precompute all
-    happen in three C calls; the script phase interprets every input in
-    one nat_verify_inputs_idx call and resolves the deduped checks with
-    one device dispatch (models/batch.py's driver, shared helpers); the
-    view update is one C call. Verdicts and reject reasons are identical
-    to `_connect_block_impl` (tests/test_native_block.py)."""
-    import numpy as np
 
-    from .. import native_bridge
-    from .batch import _idx_threads
+class _NativeConnect:
+    """One block's connect on the native path, cut in two at the seam
+    `IdxFixpoint` has (models/batch.py): the one block driver behind
+    `connect_block` and `connect_block_stream`.
 
-    if flags is None:
-        flags = height_to_flags(height, extended=True)
+    Phase map (validation.cpp:1946-2228). `begin()`: CheckBlock + witness
+    commitment + BIP30/maturity/value/sigop accounting + per-tx hash
+    precompute in three C calls, the script-cache probe, then
+    `IdxFixpoint.begin()`, which interprets every input in one
+    nat_verify_inputs_idx call and launches the deduped checks: lanes in
+    flight, nothing synchronized. `finish()`: `IdxFixpoint.finish()`
+    (settle, further rounds to the fixpoint), the script-cache insert and
+    the result list, and the view update in one C call. Verdicts and
+    reject reasons are identical to `_connect_block_impl`
+    (tests/test_native_block.py).
 
-    phases = verifier.phases if verifier is not None else None
+    With `begin(speculate=True)` the view takes the block at the end of
+    `begin`, before its verdicts, with an undo record (undo.h CBlockUndo),
+    so that the next block's accounting can run while this block's lanes
+    are on the device. The caller then owes the block one of `commit()`
+    (after an ok `finish()`) or `rollback()` (after a failed one, or
+    `abandon()` for a block whose verdicts nobody will read), and owes the
+    view the order: blocks applied later are rolled back first.
 
-    def phase(name):
-        from contextlib import nullcontext
+    `result` is None until the connect has ended: a failure inside
+    `begin()` sets it there (nothing was launched or applied)."""
 
-        return phases(name) if phases is not None else nullcontext()
+    def __init__(
+        self, block, coins, height, flags, verifier, check_pow,
+        check_scripts, enforce_witness_commitment, pow_limit, sig_cache,
+        script_cache,
+    ):
+        if flags is None:
+            flags = height_to_flags(height, extended=True)
+        if check_scripts:
+            if verifier is None:
+                from ..crypto.jax_backend import default_verifier
 
-    if isinstance(block, (bytes, bytearray)):
-        with phase("parse"):
-            nblk = native_bridge.NativeBlock(bytes(block))
-    else:
+                verifier = default_verifier()
+            from .sigcache import default_script_cache, default_sig_cache
+
+            if sig_cache is None:
+                sig_cache = default_sig_cache()
+            if script_cache is None:
+                script_cache = default_script_cache()
+        if enforce_witness_commitment is None:
+            enforce_witness_commitment = bool(flags & VERIFY_WITNESS)
+        self.block, self.coins, self.height, self.flags = block, coins, height, flags
+        self.verifier = verifier
+        self.check_pow, self.pow_limit = check_pow, pow_limit
+        self.check_scripts = check_scripts
+        self.enforce_witness_commitment = enforce_witness_commitment
+        self.sig_cache, self.script_cache = sig_cache, script_cache
+        self.result: Optional[ConnectResult] = None
+        self._nblk = None
+        self._run = None  # the script phase's IdxFixpoint, once begun
+        self._undo = None  # the speculative apply's undo record, until commit
+
+    def _phase(self, name: str):
+        if self.verifier is None:
+            return nullcontext()
+        return self.verifier.phases(name)
+
+    def _parse(self):
+        from .. import native_bridge
+
+        block = self.block
+        if isinstance(block, (bytes, bytearray)):
+            with self._phase("parse"):
+                return native_bridge.NativeBlock(bytes(block))
         # The cached parse is keyed on a cheap content fingerprint (header
         # bytes + per-tx txid/wtxid) so a Block mutated between calls is
         # re-serialized instead of validated stale. Mutating a Tx without
@@ -292,120 +383,324 @@ def _connect_block_native(
         )
         cached = getattr(block, "_native", None)
         if cached is not None and cached[0] == fp:
-            nblk = cached[1]
-        else:
-            with phase("parse"):
-                nblk = native_bridge.NativeBlock(block.serialize())
-            block._native = (fp, nblk)
+            return cached[1]
+        with self._phase("parse"):
+            nblk = native_bridge.NativeBlock(block.serialize())
+        block._native = (fp, nblk)
+        return nblk
 
-    with phase("block_check"):
-        reason = nblk.check(check_pow, pow_limit)
-        if reason:
-            return ConnectResult(False, reason)
-        if enforce_witness_commitment is None:
-            enforce_witness_commitment = bool(flags & VERIFY_WITNESS)
-        if enforce_witness_commitment:
-            reason = nblk.check_witness_commitment()
+    def begin(self, speculate: bool = False) -> None:
+        import numpy as np
+
+        from .. import native_bridge
+        from .batch import IdxFixpoint, _idx_threads
+
+        phase = self._phase
+        flags, coins = self.flags, self.coins
+        nblk = self._nblk = self._parse()
+
+        with phase("block_check"):
+            reason = nblk.check(self.check_pow, self.pow_limit)
+            if not reason and self.enforce_witness_commitment:
+                reason = nblk.check_witness_commitment()
             if reason:
-                return ConnectResult(False, reason)
+                self.result = ConnectResult(False, reason)
+                return
 
-    with phase("accounting"):
-        (reason, fees, sigop_cost, tx_index, n_in, amounts, spk_offs,
-         spk_blob) = nblk.accounting(coins, height, flags)
-        if reason:
-            return ConnectResult(False, reason)
+        with phase("accounting"):
+            (reason, fees, sigop_cost, tx_index, n_in, amounts, spk_offs,
+             spk_blob) = nblk.accounting(coins, self.height, flags)
+            if reason:
+                self.result = ConnectResult(False, reason)
+                return
+        self._fees, self._sigop_cost = fees, sigop_cost
 
-    input_results: Optional[List[BatchResult]] = None
-    if check_scripts:
-        if verifier is None:
-            from ..crypto.jax_backend import default_verifier
+        if self.check_scripts:
+            verifier, script_cache = self.verifier, self.script_cache
+            n = self._n = len(tx_index)
+            with phase("probe"):
+                raw_keys = nblk.script_keys(script_cache._salt, flags).tobytes()
+                if len(script_cache) == 0:  # cold cache: every probe misses
+                    hit = np.zeros(n, dtype=bool)
+                else:
+                    hit = script_cache.contains_keys(raw_keys, n)
+            self._raw_keys, self._hit = raw_keys, hit
 
-            verifier = default_verifier()
-        from .sigcache import default_script_cache, default_sig_cache
+            nsess = native_bridge.NativeSession()
+            live = np.nonzero(~hit)[0]
+            n_threads = _idx_threads()
+            flags_a = np.full(n, flags, dtype=np.int32)
 
-        if sig_cache is None:
-            sig_cache = default_sig_cache()
-        if script_cache is None:
-            script_cache = default_script_cache()
+            # Raw NTx pointers, one per input: the txs are owned by the
+            # (live) nblk, so the column outlasts every call below.
+            tx_ptrs = nblk.tx_ptrs()[tx_index]
 
-        n = len(tx_index)
-        with phase("probe"):
-            raw_keys = nblk.script_keys(script_cache._salt, flags).tobytes()
-            if len(script_cache) == 0:  # cold cache: every probe misses
-                hit = np.zeros(n, dtype=bool)
-            else:
-                hit = script_cache.contains_keys(raw_keys, n)
-
-        nsess = native_bridge.NativeSession()
-        live = np.nonzero(~hit)[0]
-        n_threads = _idx_threads()
-        flags_a = np.full(n, flags, dtype=np.int32)
-
-        # Raw NTx pointers, one per input: the txs are owned by the (live)
-        # nblk, so the column outlasts every call below.
-        tx_ptrs = nblk.tx_ptrs()[tx_index]
-
-        def run_idx(pos):
-            if len(pos) == n:  # common path: whole block, zero-copy
+            def run_idx(pos):
+                if len(pos) == n:  # common path: whole block, zero-copy
+                    return nsess.verify_inputs_idx_raw(
+                        tx_ptrs, n_in, amounts, spk_blob, spk_offs, flags_a,
+                        n_threads,
+                    )
+                # A subset (script-cache hits left out, or a later fixpoint
+                # round): gather its scriptPubKeys into one blob, vectorized.
+                lens = spk_offs[pos + 1] - spk_offs[pos]
+                sub_offs = np.zeros(len(pos) + 1, dtype=np.int64)
+                np.cumsum(lens, out=sub_offs[1:])
+                src = np.repeat(spk_offs[pos] - sub_offs[:-1], lens)
+                src += np.arange(sub_offs[-1], dtype=np.int64)
                 return nsess.verify_inputs_idx_raw(
-                    tx_ptrs, n_in, amounts, spk_blob, spk_offs, flags_a,
-                    n_threads,
+                    tx_ptrs[pos], n_in[pos], amounts[pos], spk_blob[src],
+                    sub_offs, flags_a[pos], n_threads,
                 )
-            # A subset (script-cache hits left out, or a later fixpoint
-            # round): gather its scriptPubKeys into one blob, vectorized.
-            lens = spk_offs[pos + 1] - spk_offs[pos]
-            sub_offs = np.zeros(len(pos) + 1, dtype=np.int64)
-            np.cumsum(lens, out=sub_offs[1:])
-            src = np.repeat(spk_offs[pos] - sub_offs[:-1], lens)
-            src += np.arange(sub_offs[-1], dtype=np.int64)
-            return nsess.verify_inputs_idx_raw(
-                tx_ptrs[pos], n_in[pos], amounts[pos], spk_blob[src],
-                sub_offs, flags_a[pos], n_threads,
+
+            def timed_run_idx(pos):
+                with phase("interpret"):
+                    return run_idx(pos)
+
+            def exact_fallback(j: int) -> Tuple[bool, int]:
+                t = int(tx_index[j])
+                spk = spk_blob[int(spk_offs[j]) : int(spk_offs[j + 1])].tobytes()
+                okx, err_code, _ = nsess.verify_input(
+                    nblk.tx(t), int(n_in[j]), int(amounts[j]), spk, flags,
+                    mode=native_bridge.NativeSession.MODE_EXACT,
+                )
+                return okx, err_code
+
+            self._run = IdxFixpoint(
+                nsess, verifier, self.sig_cache, live, timed_run_idx,
+                exact_fallback, n_inputs=n,
             )
+            self._run.begin()
 
-        def timed_run_idx(pos):
-            with phase("interpret"):
-                return run_idx(pos)
+        if speculate:
+            with phase("apply"):
+                self._undo = coins.apply_block(nblk, self.height, undo=True)
 
-        def exact_fallback(j: int) -> Tuple[bool, int]:
-            t = int(tx_index[j])
-            spk = spk_blob[int(spk_offs[j]) : int(spk_offs[j + 1])].tobytes()
-            okx, err_code, _ = nsess.verify_input(
-                nblk.tx(t), int(n_in[j]), int(amounts[j]), spk, flags,
-                mode=native_bridge.NativeSession.MODE_EXACT,
-            )
-            return okx, err_code
+    def finish(self) -> ConnectResult:
+        if self.result is not None:
+            return self.result
+        input_results: Optional[List[BatchResult]] = None
+        if self._run is not None:
+            import numpy as np
 
-        from .batch import run_idx_fixpoint
+            from ..core.script_error import ScriptError
 
-        ok, err = run_idx_fixpoint(
-            nsess, verifier, sig_cache, live, timed_run_idx, exact_fallback,
-            n_inputs=n,
+            ok, err = self._run.finish()
+            self._run = None
+            with self._phase("results"):
+                # ok/err are written on the live rows only; a hit passed
+                # before.
+                hit = self._hit
+                passed = hit | (ok != 0)
+                self.script_cache.add_keys(self._raw_keys, passed & ~hit)
+                # Every passing input is the one frozen success instance;
+                # only a failing input gets a result object of its own.
+                input_results = [BatchResult.success()] * self._n
+                failed = np.nonzero(~passed)[0].tolist()
+                for j in failed:
+                    input_results[j] = BatchResult(
+                        False, Error.ERR_SCRIPT, ScriptError(int(err[j]))
+                    )
+            if failed:
+                self.result = ConnectResult(
+                    False, "block-validation-failed", self._fees,
+                    self._sigop_cost, input_results,
+                )
+                return self.result
+        if self._undo is None:  # not applied speculatively in begin
+            with self._phase("apply"):
+                self.coins.apply_block(self._nblk, self.height)
+        self.result = ConnectResult(
+            True, None, self._fees, self._sigop_cost, input_results
         )
+        return self.result
 
-        from ..core.script_error import ScriptError
+    def commit(self) -> None:
+        """The speculative apply stands: drop its undo record."""
+        self._undo = None
 
-        with phase("results"):
-            # ok/err are written on the live rows only; a hit passed before.
-            passed = hit | (ok != 0)
-            script_cache.add_keys(raw_keys, passed & ~hit)
-            # Every passing input is the one frozen success instance; only
-            # a failing input gets a result object of its own.
-            input_results = [BatchResult.success()] * n
-            failed = np.nonzero(~passed)[0].tolist()
-            for j in failed:
-                input_results[j] = BatchResult(
-                    False, Error.ERR_SCRIPT, ScriptError(int(err[j]))
-                )
-        if failed:
-            return ConnectResult(
-                False, "block-validation-failed", fees, sigop_cost,
-                input_results,
+    def rollback(self) -> bool:
+        """Take the speculative apply back; True when there was one."""
+        if self._undo is None:
+            return False
+        undo, self._undo = self._undo, None
+        with self._phase("undo"):
+            self.coins.undo_block(self._nblk, undo)
+        return True
+
+    def abandon(self) -> bool:
+        """For a block whose verdicts nobody will read: settle and
+        discard its tickets (`IdxFixpoint.abandon`), insert nothing into a
+        cache, take its speculative apply back. Returns `rollback()`'s."""
+        run, self._run = self._run, None
+        if run is not None:
+            run.abandon()
+        return self.rollback()
+
+
+def connect_block_stream(
+    blocks: Iterable[Union[bytes, Block]],
+    coins: CoinsView,
+    start_height: int,
+    *,
+    depth: int = 2,
+    flags: Union[None, int, Callable[[int], int]] = None,
+    verifier: Optional[TpuSecpVerifier] = None,
+    check_pow: bool = True,
+    pow_limit: int = POW_LIMIT_MAINNET,
+    sig_cache: Optional[SigCache] = None,
+    script_cache: Optional[ScriptExecutionCache] = None,
+) -> Iterator[ConnectResult]:
+    """Connect successive blocks through one overlapped stream (initial
+    block download: `ActivateBestChain -> ConnectTip -> ConnectBlock`).
+
+    `blocks` are raw blocks (bytes) or `Block`s in height order; block `k`
+    is connected at `start_height + k` under `height_to_flags(start_height
+    + k, extended=True)`, or under `flags` (an int for every block, or a
+    callable of the height). Yields one `ConnectResult` a block, in order,
+    with up to `depth` blocks begun and not yet finished: while block N's
+    lanes are on the device the host parses, checks, accounts and
+    interprets block N+1. Block N+1's accounting reads coins that block N
+    creates, so the view takes each block speculatively when it is begun
+    and keeps the coins it spent (an undo record) until its verdicts are
+    in. `connect_block` is the depth-1, unspeculative case of the same
+    two halves (`_NativeConnect`).
+
+    What a stream guarantees:
+
+    1. Every yielded `ConnectResult` (`ok`, `reason`, `fees`,
+       `sigop_cost`, every `input_results[i]`) equals what a loop of
+       `connect_block` over the same blocks gives, at every `depth`.
+    2. A block that fails, in either half, is yielded as that failure and
+       is the last thing yielded. The blocks begun after it are abandoned
+       (their tickets settled and discarded), their speculative applies
+       and its own are undone newest first, and the view is the view
+       after the last block yielded `ok`. No result of a block behind a
+       failed one is ever yielded.
+    3. Closing the generator early does the same to what was begun and
+       not yielded: no ticket, buffer or backpressure slot stays held.
+    4. The caches stay success-only, and a block's inserts happen in its
+       own finish, never at begin. Block N+1 probes before block N
+       inserts, so a stream may miss a cache hit that a loop would find:
+       it then verifies again what the loop would have skipped, and
+       verdicts cannot differ.
+    5. The fail-closed guards are what they were: every ticket settles
+       through resilience/inflight.py and the checksum and sentinel
+       checks; a retry, demotion or containment during a stream gives the
+       same verdicts and is counted as in `connect_block`.
+
+    With a Python `CoinsView`, or without the native core, the blocks are
+    connected one by one through `connect_block`, in order, as
+    `verify_batch_stream` falls back to `verify_batch`.
+    """
+    from .. import native_bridge
+
+    depth = max(1, int(depth))
+
+    def flags_at(height: int) -> Optional[int]:
+        return flags(height) if callable(flags) else flags
+
+    if not (
+        isinstance(coins, native_bridge.NativeCoinsView)
+        and native_bridge.available()
+    ):
+        for k, block in enumerate(blocks):
+            res = connect_block(
+                block, coins, start_height + k, flags_at(start_height + k),
+                verifier, check_pow, pow_limit=pow_limit,
+                sig_cache=sig_cache, script_cache=script_cache,
             )
+            _STREAM_BLOCKS.inc(result="ok" if res.ok else "reject")
+            yield res
+            if not res.ok:
+                return
+        return
 
-    with phase("apply"):
-        coins.apply_block(nblk, height)
-    return ConnectResult(True, None, fees, sigop_cost, input_results)
+    window: List[_NativeConnect] = []
+
+    def begin(block, height: int) -> _NativeConnect:
+        run = _NativeConnect(
+            block, coins, height, flags_at(height), verifier, check_pow,
+            True, None, pow_limit, sig_cache, script_cache,
+        )
+        _STREAM_IN_FLIGHT.observe(len(window) + 1)
+        with gc_paused(), _stream_span("block.stream_begin", height=height):
+            try:
+                run.begin(speculate=True)
+            except BaseException:
+                run.abandon()  # whatever it launched settles; then re-raise
+                raise
+        return run
+
+    def finish(run: _NativeConnect) -> ConnectResult:
+        with gc_paused(), _stream_span("block.stream_finish", height=run.height):
+            res = run.finish()
+            if res.ok:
+                run.commit()
+        _count_block(res)
+        _STREAM_BLOCKS.inc(result="ok" if res.ok else "reject")
+        return res
+
+    def undo(run: _NativeConnect, abandoned: bool) -> None:
+        with gc_paused():
+            if run.abandon():
+                _STREAM_ROLLBACKS.inc()
+        if abandoned:
+            _STREAM_BLOCKS.inc(result="abandoned")
+
+    def drop(runs: List[_NativeConnect]) -> None:
+        """Abandon the blocks begun and never yielded, newest first."""
+        while runs:
+            undo(runs.pop(), abandoned=True)
+
+    with _span("block.stream", depth=depth, start_height=start_height):
+        try:
+            source = iter(blocks)
+            height = start_height
+            more = True
+            while more or window:
+                # Fill: begin blocks until `depth` are in flight, the source
+                # ends, or one fails where it is begun (nothing behind a
+                # failed block is begun).
+                while more and len(window) < depth:
+                    block = next(source, None)
+                    if block is None:
+                        more = False
+                        break
+                    window.append(begin(block, height))
+                    height += 1
+                    more = window[-1].result is None
+                if not window:
+                    break
+                res = finish(window[0])
+                run = window.pop(0)
+                if not res.ok:
+                    drop(window)
+                    undo(run, abandoned=False)  # last: it was begun first
+                    yield res
+                    return
+                yield res
+        except GeneratorExit:
+            pass  # closed early: `finally` settles what was begun
+        finally:
+            drop(window)
+
+
+def _count_block(res: ConnectResult) -> None:
+    _BLOCKS.inc(result="ok" if res.ok else "reject")
+    if not res.ok and res.reason:
+        _BLOCK_REJECTS.inc(reason=res.reason)
+
+
+@contextmanager
+def _stream_span(name: str, **attrs):
+    """A span that is also a `jax.profiler.TraceAnnotation`, so that a
+    device trace shows which block's host half ran under which block's
+    kernel."""
+    import jax
+
+    with _span(name, **attrs), jax.profiler.TraceAnnotation(name, **attrs):
+        yield
 
 
 def _connect_block_impl(

@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 5:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 5)")
+        if L.nat_version() < 6:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 6)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -272,6 +272,14 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_view_spend.argtypes = [vp, u8p, ctypes.c_int32]
         L.nat_view_spend.restype = ctypes.c_int32
         L.nat_view_apply_block.argtypes = [vp, vp, ctypes.c_int64]
+        L.nat_view_apply_block_undo.argtypes = [vp, vp, ctypes.c_int64]
+        L.nat_view_apply_block_undo.restype = vp
+        L.nat_view_undo_block.argtypes = [vp, vp, vp]
+        L.nat_view_undo_block.restype = ctypes.c_int32
+        L.nat_undo_len.argtypes = [vp]
+        L.nat_undo_len.restype = ctypes.c_int64
+        L.nat_undo_free.argtypes = [vp]
+        L.nat_view_digest.argtypes = [vp, u8p]
         _lib = L
         return _lib
 
@@ -1074,31 +1082,44 @@ class NativeCoinsView:
 
     def add_coins_batch(self, coins) -> None:
         """coins: sequence of (txid32, n, value, height, coinbase, spk)."""
-        L = lib()
         n = len(coins)
         if n == 0:
             return
-        txids = np.frombuffer(
-            b"".join(c[0] for c in coins), dtype=np.uint8
-        )
-        ns = np.asarray([c[1] for c in coins], dtype=np.int32)
-        values = np.asarray([c[2] for c in coins], dtype=np.int64)
-        heights = np.asarray([c[3] for c in coins], dtype=np.int32)
-        cbs = np.asarray([1 if c[4] else 0 for c in coins], dtype=np.int32)
         offs = np.zeros(n + 1, dtype=np.int64)
-        for i, c in enumerate(coins):
-            offs[i + 1] = offs[i] + len(c[5])
-        blob_b = b"".join(c[5] for c in coins)
-        blob = (
-            np.frombuffer(blob_b, dtype=np.uint8)
-            if blob_b
-            else np.zeros(1, np.uint8)
+        np.cumsum([len(c[5]) for c in coins], out=offs[1:])
+        self.add_coins_arrays(
+            np.frombuffer(b"".join(c[0] for c in coins), dtype=np.uint8),
+            [c[1] for c in coins], [c[2] for c in coins],
+            [c[3] for c in coins], [1 if c[4] else 0 for c in coins],
+            np.frombuffer(b"".join(c[5] for c in coins), dtype=np.uint8),
+            offs,
         )
-        L.nat_view_add_coins(
-            self._ptr, n, _u8p(txids), _i32p(ns),
-            values.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            _i32p(heights), _i32p(cbs), _u8p(blob),
-            offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+
+    def add_coins_arrays(self, txids, ns, values, heights, coinbases,
+                         spk_blob, spk_offs) -> None:
+        """Bulk insert from columns: `txids` is 32 bytes a coin, `ns`,
+        `values`, `heights`, `coinbases` one entry a coin, and coin i's
+        scriptPubKey is `spk_blob[spk_offs[i]:spk_offs[i + 1]]`."""
+        ns = np.ascontiguousarray(ns, dtype=np.int32)
+        n = len(ns)
+        txids = np.ascontiguousarray(txids, dtype=np.uint8).reshape(-1)
+        values = np.ascontiguousarray(values, dtype=np.int64)
+        heights = np.ascontiguousarray(heights, dtype=np.int32)
+        cbs = np.ascontiguousarray(coinbases, dtype=np.int32)
+        offs = np.ascontiguousarray(spk_offs, dtype=np.int64)
+        blob = np.ascontiguousarray(spk_blob, dtype=np.uint8).reshape(-1)
+        if (len(txids) != 32 * n or len(values) != n or len(heights) != n
+                or len(cbs) != n or len(offs) != n + 1 or offs[0] != 0
+                or (np.diff(offs) < 0).any() or offs[-1] > len(blob)):
+            raise ValueError("coin columns disagree in length")
+        if n == 0:
+            return
+        if not len(blob):
+            blob = np.zeros(1, np.uint8)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        lib().nat_view_add_coins(
+            self._ptr, n, _u8p(txids), _i32p(ns), values.ctypes.data_as(i64p),
+            _i32p(heights), _i32p(cbs), _u8p(blob), offs.ctypes.data_as(i64p),
         )
 
     # CoinsView duck API (models/validate.py) -------------------------
@@ -1146,8 +1167,53 @@ class NativeCoinsView:
             lib().nat_view_spend(self._ptr, _u8p(txid), outpoint.n)
         return coin
 
-    def apply_block(self, blk: NativeBlock, height: int) -> None:
-        lib().nat_view_apply_block(self._ptr, blk._ptr, height)
+    def apply_block(self, blk: NativeBlock, height: int, undo: bool = False):
+        """UpdateCoins over the whole block. With `undo`, returns the
+        `NativeBlockUndo` that `undo_block` takes to put the view back."""
+        if not undo:
+            lib().nat_view_apply_block(self._ptr, blk._ptr, height)
+            return None
+        return NativeBlockUndo(
+            lib().nat_view_apply_block_undo(self._ptr, blk._ptr, height)
+        )
+
+    def undo_block(self, blk: NativeBlock, undo: "NativeBlockUndo") -> None:
+        """Take back `apply_block(blk, height, undo=True)`: the block's
+        outputs go, the coins it spent return as they were (amount,
+        script, height, coinbase flag). Blocks applied on top of it must
+        be undone first, newest first. The record keeps its coins, so it
+        puts back any view that stands where that apply left one."""
+        if not lib().nat_view_undo_block(self._ptr, blk._ptr, undo._ptr):
+            raise ValueError("undo record was not made from this block")
+
+    def digest(self) -> bytes:
+        """32 bytes over every coin, independent of order: with `len`,
+        equal exactly when two views hold the same coins."""
+        out = np.zeros(32, np.uint8)
+        lib().nat_view_digest(self._ptr, _u8p(out))
+        return out.tobytes()
+
+
+class NativeBlockUndo:
+    """The coins one `apply_block` removed from a view (native/block.hpp
+    NBlockUndo; undo.h CBlockUndo). `len` counts them."""
+
+    __slots__ = ("_ptr",)
+
+    def __init__(self, _ptr):
+        self._ptr = _ptr
+
+    def __del__(self):
+        try:
+            L = lib()
+        except TypeError:
+            return
+        if L is not None and getattr(self, "_ptr", None):
+            L.nat_undo_free(self._ptr)
+            self._ptr = None
+
+    def __len__(self) -> int:
+        return int(lib().nat_undo_len(self._ptr))
 
 
 class NativeSecp:

@@ -262,7 +262,13 @@ def span(name: str, **attrs):
     finally:
         dt = time.perf_counter() - sp.t0
         sp.duration_s = dt
-        stack.pop()
+        if stack[-1] is sp:
+            stack.pop()
+        else:
+            # A generator holds its span open across `yield` (block.stream),
+            # so its consumer can leave a span opened before a `next()`
+            # while this one is on top: take out this span, not the top.
+            stack.remove(sp)
         _SPAN_SECONDS.observe(dt, span=name)
         if sp.error is not None:
             _SPAN_ERRORS.inc(span=name)

@@ -556,17 +556,113 @@ inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
     return BR_OK;
 }
 
+// What a block's apply took out of the view, kept so that the apply can
+// be taken back (undo.h CBlockUndo: the coins a block spent). `replaced`
+// holds a coin that an output of the block overwrote: accounting's BIP30
+// scan refuses such a block, but apply is reachable on its own through
+// the C ABI and its inverse has to be exact there too. The `_end` columns
+// cut both lists per transaction, so the undo replays the apply's steps
+// backwards one transaction at a time.
+struct NBlockUndo {
+    struct Entry {
+        std::string key;
+        NCoin coin;
+    };
+    std::vector<Entry> spent, replaced;
+    std::vector<size_t> spent_end, replaced_end;
+};
+
 // UpdateCoins over the whole block (coins.cpp / validate.py phase 4).
-inline void view_apply_block(NView& view, const NBlock& blk, i64 height) {
+// With `undo`, every coin the block removes or overwrites is moved into
+// the record first.
+inline void view_apply_block(NView& view, const NBlock& blk, i64 height,
+                             NBlockUndo* undo = nullptr) {
+    if (undo) *undo = NBlockUndo();
     for (size_t t = 0; t < blk.vtx.size(); t++) {
         const NTx& tx = *blk.vtx[t];
         bool cb = tx_is_coinbase(tx);
         if (!cb)
-            for (const auto& in : tx.vin)
-                view.map.erase(NView::key(in.prevout_hash, in.prevout_n));
+            for (const auto& in : tx.vin) {
+                std::string k = NView::key(in.prevout_hash, in.prevout_n);
+                if (!undo) {
+                    view.map.erase(k);
+                    continue;
+                }
+                auto it = view.map.find(k);
+                if (it == view.map.end()) continue;
+                undo->spent.push_back({std::move(k), std::move(it->second)});
+                view.map.erase(it);
+            }
+        for (u32 n = 0; n < tx.vout.size(); n++) {
+            NCoin coin{tx.vout[n].value, tx.vout[n].spk, (i32)height, cb};
+            std::string k = NView::key(blk.txids[t].data(), n);
+            if (!undo) {
+                view.map[std::move(k)] = std::move(coin);
+                continue;
+            }
+            auto it = view.map.find(k);
+            if (it == view.map.end()) {
+                view.map.emplace(std::move(k), std::move(coin));
+            } else {
+                undo->replaced.push_back({std::move(k), std::move(it->second)});
+                it->second = std::move(coin);
+            }
+        }
+        if (undo) {
+            undo->spent_end.push_back(undo->spent.size());
+            undo->replaced_end.push_back(undo->replaced.size());
+        }
+    }
+}
+
+// DisconnectBlock's view half (validation.cpp): the exact inverse of
+// view_apply_block(view, blk, height, &undo), transactions last to first:
+// a transaction's outputs go (or give way to the coin they overwrote),
+// then the coins it spent come back, among them one that an earlier
+// transaction of this block created and that transaction's own turn then
+// removes. The record holds its coins by value and is left as it was: it
+// puts back any view that is in the state the apply left, as often as
+// asked. Returns false, with the view untouched, when the record was not
+// made from a block of this many transactions.
+inline bool view_undo_block(NView& view, const NBlock& blk,
+                            const NBlockUndo& undo) {
+    size_t n_tx = blk.vtx.size();
+    if (undo.spent_end.size() != n_tx || undo.replaced_end.size() != n_tx)
+        return false;
+    for (size_t t = n_tx; t-- > 0;) {
+        const NTx& tx = *blk.vtx[t];
         for (u32 n = 0; n < tx.vout.size(); n++)
-            view.map[NView::key(blk.txids[t].data(), n)] =
-                NCoin{tx.vout[n].value, tx.vout[n].spk, (i32)height, cb};
+            view.map.erase(NView::key(blk.txids[t].data(), n));
+        size_t lo = t ? undo.replaced_end[t - 1] : 0;
+        for (size_t i = undo.replaced_end[t]; i-- > lo;)
+            view.map[undo.replaced[i].key] = undo.replaced[i].coin;
+        lo = t ? undo.spent_end[t - 1] : 0;
+        for (size_t i = undo.spent_end[t]; i-- > lo;)
+            view.map[undo.spent[i].key] = undo.spent[i].coin;
+    }
+    return true;
+}
+
+// Order-free digest of the whole view: the XOR of sha256(outpoint ||
+// value || height || coinbase || scriptPubKey) over its coins. Two views
+// hold the same coins exactly when their sizes and digests agree (a map
+// has no duplicate key, so no pair cancels).
+inline void view_digest(const NView& view, u8 out[32]) {
+    std::memset(out, 0, 32);
+    for (const auto& kv : view.map) {
+        Sha256 h;
+        h.write(reinterpret_cast<const u8*>(kv.first.data()), kv.first.size());
+        u8 meta[13];
+        u64 v = (u64)kv.second.value;
+        for (int j = 0; j < 8; j++) meta[j] = u8(v >> (8 * j));
+        u32 ht = (u32)kv.second.height;
+        for (int j = 0; j < 4; j++) meta[8 + j] = u8(ht >> (8 * j));
+        meta[12] = kv.second.coinbase ? 1 : 0;
+        h.write(meta, 13);
+        h.write(kv.second.spk.data(), kv.second.spk.size());
+        u8 d[32];
+        h.finalize(d);
+        for (int j = 0; j < 32; j++) out[j] ^= d[j];
     }
 }
 

@@ -202,7 +202,7 @@ extern "C" {
 // 4: nat_session_recidx_data grew a capacity argument + i64 return;
 //    the nat_block_* / nat_view_* block layer landed.
 // 5: nat_block_tx_ptrs.
-int nat_version() { return 5; }
+int nat_version() { return 6; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -392,6 +392,39 @@ i32 nat_view_spend(void* v, const u8* txid, i32 n) {
 void nat_view_apply_block(void* v, void* b, i64 height) {
     view_apply_block(*static_cast<NView*>(v), *static_cast<NBlock*>(b),
                      height);
+}
+
+// Apply that keeps what it removed: returns a new undo record (free with
+// nat_undo_free) holding the coins the block spent.
+void* nat_view_apply_block_undo(void* v, void* b, i64 height) {
+    auto* undo = new NBlockUndo();
+    view_apply_block(*static_cast<NView*>(v), *static_cast<NBlock*>(b),
+                     height, undo);
+    return undo;
+}
+
+// The inverse of nat_view_apply_block_undo for the same block, on a view
+// in the state that apply left: 1 when the view was put back (the record
+// keeps its coins), 0 when the record was not made from such a block (the
+// view is untouched).
+i32 nat_view_undo_block(void* v, void* b, void* u) {
+    return view_undo_block(*static_cast<NView*>(v), *static_cast<NBlock*>(b),
+                           *static_cast<NBlockUndo*>(u))
+               ? 1
+               : 0;
+}
+
+// Coins the record holds (spent + overwritten).
+i64 nat_undo_len(void* u) {
+    auto* undo = static_cast<NBlockUndo*>(u);
+    return (i64)(undo->spent.size() + undo->replaced.size());
+}
+
+void nat_undo_free(void* u) { delete static_cast<NBlockUndo*>(u); }
+
+// out: 32 bytes (block.hpp view_digest).
+void nat_view_digest(void* v, u8* out) {
+    view_digest(*static_cast<NView*>(v), out);
 }
 
 // The three libbitcoinconsensus exports (bitcoinconsensus.h:67-75).

@@ -76,6 +76,10 @@ REQUIRED_METRICS = [
     # block connect
     "consensus_blocks_total",
     "consensus_block_reject_total",
+    # block stream (native core: connect_block_stream's speculative view)
+    "consensus_stream_blocks_total",
+    "consensus_stream_rollbacks_total",
+    "consensus_stream_blocks_in_flight",
     # resilience (clean-path samples: ladder gauge set at verifier
     # construction, sentinel lanes ride every padded dispatch; the fault
     # counters only light up under scripts/consensus_chaos.py)
@@ -362,6 +366,29 @@ def run_mini_workload() -> None:
     assert r.ok, r.reason
     r2 = connect_block(blk, bview, 200, check_pow=False)  # inputs now spent
     assert not r2.ok
+
+    # --- block stream: a block that connects, then one whose bad signature
+    # shows in its finish, after its speculative apply (one rollback) ---
+    from bitcoinconsensus_tpu import native_bridge
+
+    if native_bridge.available():
+        from bitcoinconsensus_tpu.models.validate import connect_block_stream
+
+        sview, sfunded = blockgen.make_funded_view(8, height=1, seed="stats-stream")
+        nview = native_bridge.NativeCoinsView()
+        nview.add_coins_batch([
+            (op_txid, n, c.out.value, c.height, c.coinbase, c.out.script_pubkey)
+            for (op_txid, n), c in sview._map.items()
+        ])
+        chain = [
+            blockgen.build_block(
+                [blockgen.build_spend_tx(sfunded[:4], fee=2000)], height=200, fees=2000),
+            blockgen.build_block(
+                [blockgen.build_spend_tx(sfunded[4:], fee=2000, corrupt_input=0)],
+                height=201, fees=2000),
+        ]
+        streamed = list(connect_block_stream(chain, nview, 200, check_pow=False))
+        assert [r.ok for r in streamed] == [True, False]
 
     # --- mesh: a sharded dispatch over the (virtual) device mesh ---
     sv = ShardedSecpVerifier(mesh=make_mesh())
