@@ -485,7 +485,8 @@ def _settle_uniq(nsess, verifier, sig_cache, state: _UniqState,
         # success-only, like the reference; raw's row j is entry grow[0]+j
         sig_cache.add_keys(raw, (sub - grow[0])[okv])
 
-    nsess.publish_uniq(grow, state.val[grow].astype(np.int32))
+    with verifier.phases("publish"):
+        nsess.publish_uniq(grow, state.val[grow].astype(np.int32))
 
 
 def _resolve_uniq(nsess, verifier, sig_cache, state: _UniqState) -> None:
@@ -590,6 +591,13 @@ class IdxFixpoint:
                 except Exception:
                     pass
 
+    def release(self) -> None:
+        """Free the native session now, timed as the `release` phase. The
+        session's owner calls it after `finish()` (whose exact fallback is
+        the session's last reader) or `abandon()`."""
+        with self.verifier.phases("release"):
+            self.nsess.release()
+
     def finish(self) -> Tuple[np.ndarray, np.ndarray]:
         """Settle the in-flight round, then loop to the fixpoint; returns
         the (ok, err) arrays."""
@@ -650,6 +658,7 @@ def _verify_batch_idx(
     if run is not None:
         run.begin()
         final = run.finish()
+        run.release()
     return _assemble_idx_results(preps, final, script_cache, script_keys)
 
 
@@ -803,7 +812,10 @@ def verify_batch_stream(
             return handle[1]
         _tag, run, preps, script_keys = handle
         with gc_paused(), _span("batch.stream_finish", n=len(preps)):
-            final = run.finish() if run is not None else None
+            final = None
+            if run is not None:
+                final = run.finish()
+                run.release()
             out = _assemble_idx_results(preps, final, script_cache,
                                         script_keys)
         _record_batch_results(out)
@@ -833,6 +845,7 @@ def _abandon_stream_window(window: List[tuple]) -> None:
         handle = window.pop(0)
         if handle[0] == "idx" and handle[1] is not None:
             handle[1].abandon()
+            handle[1].release()
 
 
 def _prepare_and_probe(

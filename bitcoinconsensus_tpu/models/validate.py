@@ -486,6 +486,7 @@ class _NativeConnect:
             from ..core.script_error import ScriptError
 
             ok, err = self._run.finish()
+            self._run.release()
             self._run = None
             with self._phase("results"):
                 # ok/err are written on the live rows only; a hit passed
@@ -535,6 +536,7 @@ class _NativeConnect:
         run, self._run = self._run, None
         if run is not None:
             run.abandon()
+            run.release()
         return self.rollback()
 
 

@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 6:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 6)")
+        if L.nat_version() < 7:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 7)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -511,9 +511,11 @@ class NativeTx:
 
 
 class NativeSession:
-    """Deferral session (oracle map + per-call check records)."""
+    """Deferral session (the oracle + per-call check records). In index
+    mode the deduped check list is the oracle: every check lies once in
+    the session's arena and its verdict is a byte at its uniq index."""
 
-    __slots__ = ("_ptr",)
+    __slots__ = ("_h",)
 
     MODE_DEFER = 0
     MODE_EXACT = 1
@@ -521,16 +523,32 @@ class NativeSession:
     def __init__(self):
         L = lib()
         assert L is not None
-        self._ptr = L.nat_session_new()
+        self._h = L.nat_session_new()
+
+    @property
+    def _ptr(self):
+        """The native handle; a released session raises here instead of
+        handing a freed pointer to C."""
+        if not self._h:
+            raise RuntimeError("NativeSession used after release()")
+        return self._h
+
+    def release(self) -> None:
+        """Free the native session now. For the owner to call once its
+        last reader is done (the batch and block drivers time it as the
+        `release` phase); calling it again, or `__del__` after it, does
+        nothing."""
+        h, self._h = getattr(self, "_h", None), None
+        if h:
+            L = lib()
+            if L is not None:
+                L.nat_session_free(h)
 
     def __del__(self):
         try:
-            L = lib()
+            self.release()
         except TypeError:  # interpreter shutdown tore down module globals
-            return
-        if L is not None and getattr(self, "_ptr", None):
-            L.nat_session_free(self._ptr)
-            self._ptr = None
+            pass
 
     def add_known(self, kind: str, data: Tuple, result: bool) -> None:
         """Publish one resolved check into the native oracle; key layout
@@ -810,7 +828,8 @@ class NativeSession:
 
     def publish_uniq(self, idxs: np.ndarray, results: np.ndarray) -> None:
         """Publish verdicts for uniq entries `idxs` into the native
-        oracle (known map) without round-tripping check bytes."""
+        oracle (a verdict byte an entry) without round-tripping check
+        bytes."""
         L = lib()
         n = len(idxs)
         if n == 0:

@@ -63,6 +63,15 @@ i64 nat_session_records_bytes(void*);
 void nat_session_records_data(void*, u8*);
 i32 nat_verify_input(void*, void*, i32, i64, const u8*, i64, i32, i32, i32*,
                      i32*);
+void nat_verify_inputs_idx(void*, void**, const i32*, const i64*, const u8*,
+                           const i64*, const i32*, i32, i32, i32*, i32*, i32*,
+                           i64*);
+i32 nat_session_uniq_count(void*);
+void nat_session_uniq_lanes(void*, const i32*, i32, u8*, i32*, i32*, i32*, i32*,
+                            i32*, i32*);
+void nat_session_uniq_digests(void*, const u8*, i64, const i32*, i32, u8*);
+void nat_session_publish_uniq(void*, const i32*, i32, const i32*);
+i32 nat_session_uniq_host_verify(void*, i32);
 int nat_verify_ecdsa(const u8*, i64, const u8*, i64, const u8*);
 int nat_verify_schnorr(const u8*, const u8*, const u8*);
 int nat_tweak_add_check(const u8*, i32, const u8*, const u8*);
@@ -220,6 +229,52 @@ static void target_verify_differential(const uint8_t* d, size_t n) {
         std::abort();
     }
     nat_session_free(sess);
+
+    // Index-mode leg: the same input through the session-resident
+    // protocol. Each round's new checks are read back out of the arena
+    // (lanes, digests, the exact host verdict) and published by index; the
+    // fixpoint's verdict must be the exact one too.
+    void* isess = nat_session_new();
+    void* txs[1] = {tx};
+    i64 spk_offs[2] = {0, (i64)spk_len};
+    i64 bounds[2];
+    i32 ok_idx = 0, err_idx = 0, published = 0;
+    bool resolved_idx = false;
+    for (int round = 0; round < 64; round++) {
+        i32 unknown = 0;
+        nat_verify_inputs_idx(isess, txs, &n_in, &amount, spk, spk_offs, &flags,
+                              1, 1, &ok_idx, &err_idx, &unknown, bounds);
+        if (unknown == 0) {
+            resolved_idx = true;
+            break;
+        }
+        i32 fresh = nat_session_uniq_count(isess) - published;
+        if (fresh <= 0) break;  // a miss must be a new, unpublished entry
+        std::vector<i32> idxs((size_t)fresh), verdicts((size_t)fresh);
+        for (i32 j = 0; j < fresh; j++) {
+            idxs[(size_t)j] = published + j;
+            verdicts[(size_t)j] =
+                nat_session_uniq_host_verify(isess, published + j);
+        }
+        std::vector<u8> fields((size_t)fresh * 128), digests((size_t)fresh * 32);
+        std::vector<i32> cols((size_t)fresh * 6);
+        nat_session_uniq_lanes(isess, idxs.data(), fresh, fields.data(),
+                               cols.data(), cols.data() + fresh,
+                               cols.data() + 2 * fresh, cols.data() + 3 * fresh,
+                               cols.data() + 4 * fresh, cols.data() + 5 * fresh);
+        nat_session_uniq_digests(isess, spk, (i64)spk_len, idxs.data(), fresh,
+                                 digests.data());
+        nat_session_publish_uniq(isess, idxs.data(), fresh, verdicts.data());
+        published += fresh;
+    }
+    if (resolved_idx &&
+        (ok_idx != ok_exact || (!ok_idx && err_idx != err_exact))) {
+        std::fprintf(stderr,
+                     "FUZZ BUG: index/exact divergence ok=%d/%d err=%d/%d\n",
+                     ok_idx, ok_exact, err_idx, err_exact);
+        std::abort();
+    }
+    nat_session_free(isess);
 
     // The libbitcoinconsensus entry must never crash (verdict may differ:
     // it applies the flag gate + exact-size checks first).

@@ -16,12 +16,13 @@
 #pragma once
 
 #include <cstdint>
+#include <cstring>
 #include <map>
 #include <memory>
+#include <random>
 #include <set>
 #include <stdexcept>
 #include <string>
-#include <unordered_map>
 #include <vector>
 
 #include "hash_extra.hpp"
@@ -882,14 +883,159 @@ inline bool bip341_sighash(const NTx& tx, size_t n_in, int hash_type,
 // Checker with the deferral seam (models/batch.py DeferringSignatureChecker
 // + core/interpreter.py TransactionSignatureChecker semantics).
 
+// One check's parts, independent of where the bytes live (a Bytes triple
+// on the interpreter's stack, the wire blob from Python, a session's
+// arena) — the shared input shape of the oracle, the lane-prep and the
+// digest cores. Part order: ecdsa pubkey|sig|msg; schnorr pk32|sig64|msg;
+// tweak q32|internal32|tweak32.
+struct PartsView {
+    int kind;    // 0 ecdsa, 1 schnorr, 2 tweak
+    int parity;  // tweak parity bit
+    const u8* p0;
+    i64 l0;
+    const u8* p1;
+    i64 l1;
+    const u8* p2;
+    i64 l2;
+};
+
+inline PartsView parts_of(int kind, int parity, const Bytes& a, const Bytes& b,
+                          const Bytes& c) {
+    return PartsView{kind,     parity,        a.data(), (i64)a.size(),
+                     b.data(), (i64)b.size(), c.data(), (i64)c.size()};
+}
+
 struct Record {
-    int kind;  // 0 ecdsa, 1 schnorr, 2 tweak
+    int kind;
     int parity;
-    Bytes p0, p1, p2;  // ecdsa: pubkey|sig|msg; schnorr: pk32|sig64|msg;
-                       // tweak: q32|internal32|tweak32
+    Bytes p0, p1, p2;
+};
+
+// The index-mode check list: every deduped check stored ONCE and addressed
+// by its discovery index. The three parts lie back to back in one byte
+// arena, offsets/lengths/verdict in one flat array, and the dedup table
+// is open addressing over indices that compares against the arena — no
+// per-check heap object, so recording is an append, publishing a verdict
+// is a store, and releasing the store is three frees.
+struct CheckStore {
+    enum : u8 { V_UNKNOWN = 0, V_FALSE = 1, V_TRUE = 2 };
+    struct Entry {
+        u64 off;  // p0 starts here in `arena`; p1, p2 follow
+        u64 hash;
+        u32 l0, l1, l2;
+        u8 kind, parity, verdict;
+    };
+    std::vector<u8> arena;
+    std::vector<Entry> entries;
+    std::vector<i32> slots;  // power-of-two table of indices, -1 empty
+
+    size_t size() const { return entries.size(); }
+
+    // Valid until the next intern() (the arena may move).
+    PartsView view(size_t i) const {
+        const Entry& e = entries[i];
+        const u8* p = arena.data() + e.off;
+        return PartsView{e.kind,   e.parity,         p,
+                         e.l0,     p + e.l0,         e.l1,
+                         p + e.l0 + e.l1,            e.l2};
+    }
+
+    static u64 mix(u64 a, u64 b) {
+        u128 m = (u128)a * b;
+        return (u64)m ^ (u64)(m >> 64);
+    }
+    static u64 hash_part(u64 h, const u8* p, size_t n) {
+        for (; n >= 8; p += 8, n -= 8) {
+            u64 w;
+            std::memcpy(&w, p, 8);
+            h = mix(h ^ w, 0x9e3779b97f4a7c15ULL);
+        }
+        if (n) {
+            u64 w = 0;
+            std::memcpy(&w, p, n);
+            h = mix(h ^ w, 0xc2b2ae3d27d4eb4fULL);
+        }
+        return h;
+    }
+    // Drawn once a process: a block's author chooses most of a check's
+    // bytes, and with a known start could zero the running state (w == h)
+    // and collide as many checks as the block holds.
+    static u64 seed() {
+        static const u64 s = [] {
+            std::random_device rd;
+            return ((u64)rd() << 32) ^ (u64)rd() ^ 0x2545f4914f6cdd1dULL;
+        }();
+        return s;
+    }
+    // In-process only (seeded, and word loads are host-endian); covers
+    // exactly what `same` compares: kind, parity, the lengths, the bytes.
+    static u64 hash_of(const PartsView& v) {
+        u64 h = mix(seed() ^ (u64)v.kind ^ ((u64)v.parity << 8),
+                    0x9e3779b97f4a7c15ULL);
+        h = mix(h ^ (u64)v.l0 ^ ((u64)v.l1 << 21) ^ ((u64)v.l2 << 42),
+                0xc2b2ae3d27d4eb4fULL);
+        h = hash_part(h, v.p0, (size_t)v.l0);
+        h = hash_part(h, v.p1, (size_t)v.l1);
+        return hash_part(h, v.p2, (size_t)v.l2);
+    }
+
+    bool same(const Entry& e, u64 h, const PartsView& v) const {
+        if (e.hash != h || e.kind != v.kind || e.parity != v.parity ||
+            e.l0 != v.l0 || e.l1 != v.l1 || e.l2 != v.l2)
+            return false;
+        const u8* p = arena.data() + e.off;
+        auto eq = [](const u8* x, const u8* y, size_t n) {
+            return n == 0 || std::memcmp(x, y, n) == 0;
+        };
+        return eq(p, v.p0, e.l0) && eq(p + e.l0, v.p1, e.l1) &&
+               eq(p + e.l0 + e.l1, v.p2, e.l2);
+    }
+
+    // Index of the entry equal to `v` (whose hash_of is `h`), or -1.
+    i32 find(u64 h, const PartsView& v) const {
+        if (slots.empty()) return -1;
+        size_t mask = slots.size() - 1;
+        for (size_t s = (size_t)h & mask;; s = (s + 1) & mask) {
+            i32 i = slots[s];
+            if (i < 0) return -1;
+            if (same(entries[(size_t)i], h, v)) return i;
+        }
+    }
+
+    void place(i32 i) {
+        size_t mask = slots.size() - 1;
+        size_t s = (size_t)entries[(size_t)i].hash & mask;
+        while (slots[s] >= 0) s = (s + 1) & mask;
+        slots[s] = i;
+    }
+    void grow() {
+        slots.assign(slots.empty() ? 64 : slots.size() * 2, -1);
+        for (size_t i = 0; i < entries.size(); i++) place((i32)i);
+    }
+
+    // find, or append `v` (its bytes copied once, verdict unknown).
+    // `v` must not point into this store's own arena.
+    i32 intern(u64 h, const PartsView& v) {
+        i32 at = find(h, v);
+        if (at >= 0) return at;
+        if ((entries.size() + 1) * 2 > slots.size()) grow();
+        at = (i32)entries.size();
+        entries.push_back(Entry{(u64)arena.size(), h, (u32)v.l0, (u32)v.l1,
+                                (u32)v.l2, (u8)v.kind, (u8)v.parity,
+                                V_UNKNOWN});
+        arena.insert(arena.end(), v.p0, v.p0 + v.l0);
+        arena.insert(arena.end(), v.p1, v.p1 + v.l1);
+        arena.insert(arena.end(), v.p2, v.p2 + v.l2);
+        place(at);
+        return at;
+    }
 };
 
 struct Session {
+    // Oracle verdicts published WITH their bytes (nat_session_add_known,
+    // _batch: the wire driver and the tests' executable spec). Index mode
+    // never writes here: its verdicts are bytes in `uniq`, and `lookup`
+    // answers from both.
     std::map<std::string, bool> known;
     std::vector<Record> records;
     // --- Index-mode (session-resident uniq protocol) -----------------
@@ -900,30 +1046,48 @@ struct Session {
     // only int32 indices into it (`rec_idx`). Lanes, cache digests and
     // verdict publication all read uniq in place — zero byte round-trips
     // across the ctypes bridge (the round-3 profile showed ~200 ms of a
-    // 3.2k-input block replay in exactly that shuffling).
+    // 3.2k-input block replay in exactly that shuffling). The list IS the
+    // oracle: a published verdict is entry i's verdict byte.
     bool index_mode = false;
-    std::vector<Record> uniq;
-    std::vector<std::string> uniq_keys;  // parallel: known-map key per uniq
-    std::unordered_map<std::string, i32> uniq_seen;  // key -> uniq index
+    CheckStore uniq;
     std::vector<i32> rec_idx;  // per-call flat index stream
     // Read-only oracle for worker-scratch sessions (checkqueue.h analogue:
-    // the threaded interpretation shards share the main session's known
-    // map; scratch sessions collect records locally and merge serially).
+    // the threaded interpretation shards read the main session's verdicts;
+    // scratch sessions collect checks in a store of their own and merge
+    // serially).
     const Session* oracle = nullptr;
 
-    const std::map<std::string, bool>& known_view() const {
-        return oracle ? oracle->known : known;
+    // The one oracle read. Returns the verdict of the check `v` (hash `h`)
+    // from whichever store holds it, V_UNKNOWN when neither has one.
+    // *at is its index in the oracle's uniq list, or -1.
+    u8 lookup(u64 h, const PartsView& v, i32* at) const {
+        const Session& o = oracle ? *oracle : *this;
+        *at = o.uniq.find(h, v);
+        if (*at >= 0) return o.uniq.entries[(size_t)*at].verdict;
+        if (!o.known.empty()) {
+            auto it = o.known.find(key(v));
+            if (it != o.known.end())
+                return it->second ? CheckStore::V_TRUE : CheckStore::V_FALSE;
+        }
+        return CheckStore::V_UNKNOWN;
     }
 
-    // Record an oracle miss in index mode: dedup into uniq, emit index.
-    void index_record(std::string&& k, int kind, int parity, const Bytes& a,
-                      const Bytes& b, const Bytes& c) {
-        auto ins = uniq_seen.try_emplace(std::move(k), (i32)uniq.size());
-        if (ins.second) {
-            uniq.push_back(Record{kind, parity, a, b, c});
-            uniq_keys.push_back(ins.first->first);
-        }
-        rec_idx.push_back(ins.first->second);
+    // Publish a verdict with its bytes: into the uniq entry when the check
+    // is one, else into `known` — so a check lives in one store and the
+    // last write wins whichever protocol made it.
+    void set_known(const PartsView& v, bool result) {
+        i32 at = uniq.size() ? uniq.find(CheckStore::hash_of(v), v) : -1;
+        if (at >= 0)
+            uniq.entries[(size_t)at].verdict =
+                result ? CheckStore::V_TRUE : CheckStore::V_FALSE;
+        else
+            known[key(v)] = result;
+    }
+
+    // Index mode: the uniq index of an oracle miss, deduped. `at` is what
+    // `lookup` found (a recorded, still-unpublished check keeps its index).
+    i32 index_record(u64 h, const PartsView& v, i32 at) {
+        return (at >= 0 && !oracle) ? at : uniq.intern(h, v);
     }
     // Speculative CHECKMULTISIG pairings: every (sig, key) pair the cursor
     // walk could reach (key-index minus sig-index in [0, nkeys-nsigs]) is
@@ -936,19 +1100,18 @@ struct Session {
     std::set<std::string> spec_seen;
     int unknown = 0;
 
-    static std::string key(int kind, int parity, const Bytes& a, const Bytes& b,
-                           const Bytes& c) {
+    static std::string key(const PartsView& v) {
         std::string k;
-        k.push_back(char(kind));
-        k.push_back(char(parity));
-        auto add = [&](const Bytes& v) {
-            u64 n = v.size();
+        k.push_back(char(v.kind));
+        k.push_back(char(v.parity));
+        auto add = [&](const u8* p, i64 len) {
+            u64 n = (u64)len;
             for (int i = 0; i < 8; i++) k.push_back(char(u8(n >> (8 * i))));
-            k.append(reinterpret_cast<const char*>(v.data()), v.size());
+            k.append(reinterpret_cast<const char*>(p), (size_t)len);
         };
-        add(a);
-        add(b);
-        add(c);
+        add(v.p0, v.l0);
+        add(v.p1, v.l1);
+        add(v.p2, v.l2);
         return k;
     }
 };
@@ -982,13 +1145,15 @@ struct Checker {
             if (kind == 1) return verify_schnorr(a.data(), b.data(), c.data());
             return tweak_add_check(a.data(), parity, b.data(), c.data());
         }
-        std::string k = Session::key(kind, parity, a, b, c);
-        const auto& known = sess->known_view();
-        auto it = known.find(k);
-        if (it != known.end()) return it->second;
+        PartsView v = parts_of(kind, parity, a, b, c);
+        u64 h = CheckStore::hash_of(v);
+        i32 at;
+        u8 verdict = sess->lookup(h, v, &at);
+        if (verdict != CheckStore::V_UNKNOWN)
+            return verdict == CheckStore::V_TRUE;
         sess->unknown++;
         if (sess->index_mode)
-            sess->index_record(std::move(k), kind, parity, a, b, c);
+            sess->rec_idx.push_back(sess->index_record(h, v, at));
         else
             sess->records.push_back(Record{kind, parity, a, b, c});
         return true;
@@ -1043,21 +1208,18 @@ struct Checker {
     void speculate_ecdsa_record(const Bytes& pubkey, const Bytes& sig_body,
                                 const Bytes& msg) {
         if (!pubkey_plausible(pubkey)) return;
-        std::string k = Session::key(0, 0, pubkey, sig_body, msg);
-        if (sess->known_view().count(k)) return;
+        PartsView v = parts_of(0, 0, pubkey, sig_body, msg);
+        u64 h = CheckStore::hash_of(v);
+        i32 at;
+        if (sess->lookup(h, v, &at) != CheckStore::V_UNKNOWN) return;
         if (sess->index_mode) {
             // Resolve-only: dedup into uniq WITHOUT emitting a rec_idx
             // entry, so a speculative pair can never affect an
             // optimistic verdict (same contract as the spec vector).
-            auto ins = sess->uniq_seen.try_emplace(std::move(k),
-                                                   (i32)sess->uniq.size());
-            if (ins.second) {
-                sess->uniq.push_back(Record{0, 0, pubkey, sig_body, msg});
-                sess->uniq_keys.push_back(ins.first->first);
-            }
+            sess->index_record(h, v, at);
             return;
         }
-        if (!sess->spec_seen.insert(k).second) return;
+        if (!sess->spec_seen.insert(Session::key(v)).second) return;
         sess->spec.push_back(Record{0, 0, pubkey, sig_body, msg});
     }
 

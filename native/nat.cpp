@@ -202,7 +202,10 @@ extern "C" {
 // 4: nat_session_recidx_data grew a capacity argument + i64 return;
 //    the nat_block_* / nat_view_* block layer landed.
 // 5: nat_block_tx_ptrs.
-int nat_version() { return 6; }
+// 6: apply_block's undo record, nat_view_undo_block.
+// 7: the index-mode session keeps its checks in one arena and its verdicts
+//    by uniq index (same symbols; an older .so is a different structure).
+int nat_version() { return 7; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -490,20 +493,6 @@ int nat_tweak_add_check(const u8* tweaked32, i32 parity, const u8* internal32,
     return tweak_add_check(tweaked32, parity, internal32, tweak32) ? 1 : 0;
 }
 
-// One check's parts, independent of where the bytes live (wire blob from
-// Python or a session-resident Record) — the shared input shape of the
-// lane-prep and digest cores.
-struct PartsView {
-    int kind;    // 0 ecdsa, 1 schnorr, 2 tweak
-    int parity;  // tweak parity bit
-    const u8* p0;
-    i64 l0;
-    const u8* p1;
-    i64 l1;
-    const u8* p2;
-    i64 l2;
-};
-
 inline PartsView parts_from_wire(const u8* blob, const i64* offs,
                                  const i32* kinds, i32 i) {
     return PartsView{
@@ -514,28 +503,13 @@ inline PartsView parts_from_wire(const u8* blob, const i64* offs,
     };
 }
 
-// Record/digest part order (ecdsa pubkey|sig|msg, schnorr pk32|sig64|msg,
-// tweak q32|internal32|tweak32 — the models/sigcache.py stream order).
-inline PartsView parts_from_record(const Record& r) {
-    return PartsView{
-        r.kind,          r.parity,
-        r.p0.data(),     (i64)r.p0.size(),
-        r.p1.data(),     (i64)r.p1.size(),
-        r.p2.data(),     (i64)r.p2.size(),
-    };
-}
-
-// Lane-prep part order: the prep core expects tweak checks as
-// internal32 | tweak32 | tweaked32 (the prep_pack wire permutation).
-inline PartsView parts_from_record_lanes(const Record& r) {
-    if (r.kind == KIND_TWEAK)
-        return PartsView{
-            r.kind,          r.parity,
-            r.p1.data(),     (i64)r.p1.size(),
-            r.p2.data(),     (i64)r.p2.size(),
-            r.p0.data(),     (i64)r.p0.size(),
-        };
-    return parts_from_record(r);
+// Lane-prep part order of a check held in record order (PartsView's): the
+// prep core expects tweak checks as internal32 | tweak32 | tweaked32 (the
+// prep_pack wire permutation).
+inline PartsView lanes_order(const PartsView& v) {
+    if (v.kind == KIND_TWEAK)
+        return PartsView{v.kind, v.parity, v.p1, v.l1, v.p2, v.l2, v.p0, v.l0};
+    return v;
 }
 
 // Lane-prep core: parts -> packed kernel lanes. Parts per kind:
@@ -692,9 +666,8 @@ void nat_session_free(void* s) { delete static_cast<Session*>(s); }
 void nat_session_add_known(void* s, i32 kind, i32 parity, const u8* p0, i64 l0,
                            const u8* p1, i64 l1, const u8* p2, i64 l2,
                            i32 result) {
-    auto* sess = static_cast<Session*>(s);
-    Bytes a(p0, p0 + l0), b(p1, p1 + l1), c(p2, p2 + l2);
-    sess->known[Session::key(kind, parity, a, b, c)] = result != 0;
+    static_cast<Session*>(s)->set_known(
+        PartsView{kind, parity, p0, l0, p1, l1, p2, l2}, result != 0);
 }
 
 i32 nat_session_records_count(void* s) {
@@ -743,14 +716,9 @@ void nat_session_add_known_batch(void* s, i32 n, const i32* kinds,
                                  const u8* blob, const i64* offs,
                                  const i32* results) {
     auto* sess = static_cast<Session*>(s);
-    for (i32 i = 0; i < n; i++) {
-        const u8* p0 = blob + offs[3 * i];
-        const u8* p1 = blob + offs[3 * i + 1];
-        const u8* p2 = blob + offs[3 * i + 2];
-        Bytes a(p0, p1), b(p1, p2), c(p2, blob + offs[3 * i + 3]);
-        sess->known[Session::key(kinds[i] & 0xff, (kinds[i] >> 8) & 1, a, b,
-                                 c)] = results[i] != 0;
-    }
+    for (i32 i = 0; i < n; i++)
+        sess->set_known(parts_from_wire(blob, offs, kinds, i),
+                        results[i] != 0);
 }
 
 // Batched salted cache-key digests, byte-identical to the Python
@@ -974,21 +942,16 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     }
     for (auto& w : workers) w.join();
     // Serial merge in shard order: dedup each scratch's uniq into the
-    // shared session, remap its rec_idx entries, and lay down global
+    // shared session (a new entry's bytes are copied once, its hash is
+    // the scratch's), remap its rec_idx entries, and lay down global
     // rec_bounds — identical discovery order to a single-threaded run
     // over the same shard sequence.
     for (i32 t = 0; t < T; t++) {
-        Session& sc = scratch[t];
+        const Session& sc = scratch[t];
         std::vector<i32> remap(sc.uniq.size());
-        for (size_t j = 0; j < sc.uniq.size(); j++) {
-            auto ins = sess->uniq_seen.try_emplace(std::move(sc.uniq_keys[j]),
-                                                   (i32)sess->uniq.size());
-            if (ins.second) {
-                sess->uniq.push_back(std::move(sc.uniq[j]));
-                sess->uniq_keys.push_back(ins.first->first);
-            }
-            remap[j] = ins.first->second;
-        }
+        for (size_t j = 0; j < sc.uniq.size(); j++)
+            remap[j] = sess->uniq.intern(sc.uniq.entries[j].hash,
+                                         sc.uniq.view(j));
         i32 lo = (i32)((i64)n * t / T);
         i32 hi = (i32)((i64)n * (t + 1) / T);
         for (i32 i = lo; i < hi; i++) {
@@ -1007,13 +970,18 @@ i32 nat_session_uniq_count(void* s) {
 // A stale or negative uniq index from the driver is an OOB read / heap
 // corruption; fail loudly instead (same pattern as digest_one's kind
 // guard).
-inline const Record& uniq_at(Session* sess, i32 idx) {
+inline void uniq_guard(const Session* sess, i32 idx) {
     if (idx < 0 || (size_t)idx >= sess->uniq.size()) {
         std::fprintf(stderr, "uniq_at: index %d out of range (uniq size %zu)\n",
                      idx, sess->uniq.size());
         std::abort();
     }
-    return sess->uniq[(size_t)idx];
+}
+
+// Entry idx's parts, in place (a view into the session's arena).
+inline PartsView uniq_at(const Session* sess, i32 idx) {
+    uniq_guard(sess, idx);
+    return sess->uniq.view((size_t)idx);
 }
 
 // `capacity` is the caller's buffer size (the rec_idx length observed at
@@ -1037,7 +1005,7 @@ void nat_session_uniq_lanes(void* s, const i32* idxs, i32 nidx, u8* fields,
     std::vector<PartsView> parts;
     parts.reserve((size_t)nidx);
     for (i32 j = 0; j < nidx; j++)
-        parts.push_back(parts_from_record_lanes(uniq_at(sess, idxs[j])));
+        parts.push_back(lanes_order(uniq_at(sess, idxs[j])));
     prep_lanes_impl(parts, fields, want_odd, parity, has_t2, neg1, neg2,
                     valid);
 }
@@ -1048,17 +1016,19 @@ void nat_session_uniq_digests(void* s, const u8* salt, i64 salt_len,
                               const i32* idxs, i32 nidx, u8* out) {
     auto* sess = static_cast<Session*>(s);
     for (i32 j = 0; j < nidx; j++)
-        digest_one(salt, salt_len, parts_from_record(uniq_at(sess, idxs[j])),
+        digest_one(salt, salt_len, uniq_at(sess, idxs[j]),
                    out + 32 * (size_t)j);
 }
 
-// Publish device/cache verdicts for uniq[idxs[0..nidx)] into the oracle.
+// Publish device/cache verdicts for uniq[idxs[0..nidx)] into the oracle:
+// one guarded store an entry.
 void nat_session_publish_uniq(void* s, const i32* idxs, i32 nidx,
                               const i32* results) {
     auto* sess = static_cast<Session*>(s);
     for (i32 j = 0; j < nidx; j++) {
-        uniq_at(sess, idxs[j]);  // bounds guard (uniq_keys is parallel)
-        sess->known[sess->uniq_keys[(size_t)idxs[j]]] = results[j] != 0;
+        uniq_guard(sess, idxs[j]);
+        sess->uniq.entries[(size_t)idxs[j]].verdict =
+            results[j] != 0 ? CheckStore::V_TRUE : CheckStore::V_FALSE;
     }
 }
 
@@ -1067,18 +1037,13 @@ void nat_session_publish_uniq(void* s, const i32* idxs, i32 nidx,
 // traffic).
 i32 nat_session_uniq_host_verify(void* s, i32 idx) {
     auto* sess = static_cast<Session*>(s);
-    const Record& r = uniq_at(sess, idx);
-    if (r.kind == KIND_ECDSA)
-        return verify_ecdsa(r.p0.data(), r.p0.size(), r.p1.data(),
-                            r.p1.size(), r.p2.data())
-                   ? 1
-                   : 0;
-    if (r.kind == KIND_SCHNORR)
-        return verify_schnorr(r.p0.data(), r.p1.data(), r.p2.data()) ? 1 : 0;
+    PartsView v = uniq_at(sess, idx);
+    if (v.kind == KIND_ECDSA)
+        return verify_ecdsa(v.p0, (size_t)v.l0, v.p1, (size_t)v.l1, v.p2) ? 1
+                                                                           : 0;
+    if (v.kind == KIND_SCHNORR) return verify_schnorr(v.p0, v.p1, v.p2) ? 1 : 0;
     // tweak record order: q32 | internal32 | tweak32
-    return tweak_add_check(r.p0.data(), r.parity, r.p1.data(), r.p2.data())
-               ? 1
-               : 0;
+    return tweak_add_check(v.p0, v.parity, v.p1, v.p2) ? 1 : 0;
 }
 
 }  // extern "C"

@@ -554,9 +554,14 @@ def test_abandon_stream_window_only_touches_idx_handles():
 
     class _Run:
         abandoned = 0
+        released = 0
 
         def abandon(self):
             _Run.abandoned += 1
+
+        def release(self):  # the native session goes with the handle
+            assert _Run.released < _Run.abandoned
+            _Run.released += 1
 
     window = [
         ("idx", _Run(), [], []),
@@ -565,7 +570,7 @@ def test_abandon_stream_window_only_touches_idx_handles():
         ("idx", _Run(), [], []),
     ]
     _abandon_stream_window(window)
-    assert _Run.abandoned == 2
+    assert _Run.abandoned == 2 and _Run.released == 2
     assert window == []
 
 

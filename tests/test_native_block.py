@@ -422,3 +422,6 @@ def test_native_connect_touches_no_input_one_at_a_time(monkeypatch):
     report = verifier.phases.report()
     assert {"parse", "results", "probe", "interpret"} <= set(report)
     assert report["parse"]["calls"] == report["results"]["calls"] == 1
+    # the session's verdicts go in by index once (round 2 discovers nothing
+    # new) and its owner frees it once, before the connect returns
+    assert report["publish"]["calls"] == report["release"]["calls"] == 1

@@ -247,3 +247,277 @@ def test_recidx_capacity_clamp():
     )
     assert got == 2
     assert np.array_equal(buf, rec_idx[:2])
+
+
+# --- The check store: verdicts by uniq index, every check once -----------
+
+_SALT = b"idx-store"
+
+
+def _uniq_digests(sess):
+    idx = np.arange(sess.uniq_count(), dtype=np.int32)
+    return [d.tobytes() for d in sess.uniq_digests(_SALT, idx)]
+
+
+def _wire_round(args, known=()):
+    """The executable spec of one round: a fresh wire-protocol session that
+    knows exactly `known` interprets `args`. Returns (ok, err, unk,
+    per-input record digests, digest -> (kind, data) of every check it
+    drained, speculative pairings included: the bytes behind an index-mode
+    session's uniq entries)."""
+    ref = native_bridge.NativeSession()
+    ref.add_known_batch(list(known))
+    ok, err, unk, recs = ref.verify_inputs(
+        *args, mode=native_bridge.NativeSession.MODE_DEFER
+    )
+    flat = [r for per in recs for r in per] + ref.take_spec()
+    checks = dict(zip(native_bridge.digest_checks(_SALT, flat), flat, strict=True))
+    return ok, err, unk, [native_bridge.digest_checks(_SALT, r) for r in recs], checks
+
+
+@pytest.mark.parametrize("victim", ["ordinary", "speculative"])
+def test_published_false_is_what_the_second_round_reads(victim):
+    """One TRUE check published FALSE by index — a p2wpkh input's own
+    check, or a multisig pairing that only speculation recorded — fails
+    its input in the next round exactly as the wire protocol does when
+    told the same verdicts with the bytes."""
+    args = _mixed_inputs(n=6, seed="idx-false")
+    sess = native_bridge.NativeSession()
+    _, _, _, rec_idx, bounds = sess.verify_inputs_idx(*args)
+    U = sess.uniq_count()
+    verdicts = np.asarray(
+        [1 if sess.uniq_host_verify(i) else 0 for i in range(U)], dtype=np.int32
+    )
+    if victim == "ordinary":
+        flip, owner = int(rec_idx[int(bounds[0])]), 0  # input 0: p2wpkh
+    else:
+        spec_only = sorted(set(range(U)) - set(rec_idx.tolist()))
+        flip = next(i for i in spec_only if verdicts[i])
+        owner = 2  # the first p2wsh 2-of-3
+    assert verdicts[flip]
+    verdicts[flip] = 0
+    sess.publish_uniq(np.arange(U, dtype=np.int32), verdicts)
+    ok2, err2, unk2, _, _ = sess.verify_inputs_idx(*args)
+
+    checks = _wire_round(args)[4]
+    known = [
+        (*checks[d], bool(v))
+        for d, v in zip(_uniq_digests(sess), verdicts, strict=True)
+    ]
+    w_ok, w_err, w_unk, _, _ = _wire_round(args, known)
+    assert np.array_equal(ok2, w_ok) and np.array_equal(err2, w_err)
+    assert np.array_equal(unk2, w_unk) and not unk2.any()
+    assert not ok2[owner] and int(ok2.sum()) == len(ok2) - 1
+    assert sess.uniq_count() == U  # nothing was recorded twice
+
+
+def test_unpublished_check_keeps_its_index():
+    """A check recorded and not yet published is recorded again at the
+    same uniq index by the next round, not duplicated."""
+    args = _mixed_inputs(n=9, seed="idx-again")
+    sess = native_bridge.NativeSession()
+    first = sess.verify_inputs_idx(*args)
+    U, digests = sess.uniq_count(), _uniq_digests(sess)
+    half = np.arange(0, U, 2, dtype=np.int32)  # publish every other one TRUE
+    sess.publish_uniq(half, np.ones(len(half), dtype=np.int32))
+    again = sess.verify_inputs_idx(*args, n_threads=3)
+    assert sess.uniq_count() == U and _uniq_digests(sess) == digests
+    published = set(half.tolist())
+    _, _, unk1, ri1, b1 = first
+    _, _, unk2, ri2, b2 = again
+    for i in range(len(args[0])):
+        mine = ri2[int(b2[i]) : int(b2[i + 1])].tolist()
+        # every miss of round 2 is an unpublished entry, at its old index
+        assert not published & set(mine)
+        assert len(mine) == int(unk2[i])
+        before = ri1[int(b1[i]) : int(b1[i + 1])].tolist()
+        assert [j for j in before if j not in published] == mine
+
+
+def test_publish_exact_fallback_then_index_round():
+    """The exact fallback between two index rounds (it switches the
+    session to the wire protocol) loses no published verdict."""
+    args = _mixed_inputs(n=6, seed="idx-exact", corrupt=(1,))
+    sess = native_bridge.NativeSession()
+    sess.verify_inputs_idx(*args)
+    U = sess.uniq_count()
+    verdicts = np.asarray(
+        [1 if sess.uniq_host_verify(i) else 0 for i in range(U)], dtype=np.int32
+    )
+    sess.publish_uniq(np.arange(U, dtype=np.int32), verdicts)
+    exact = [
+        sess.verify_input(
+            args[0][i], i, args[2][i], args[3][i], args[4][i],
+            mode=native_bridge.NativeSession.MODE_EXACT,
+        )
+        for i in range(6)
+    ]
+    ok, err, unk, rec_idx, _ = sess.verify_inputs_idx(*args)
+    assert not unk.any() and len(rec_idx) == 0 and sess.uniq_count() == U
+    assert [bool(o) for o in ok] == [e[0] for e in exact]
+    assert [int(e) for e in err] == [e[1] for e in exact]
+    assert not ok[1] and int(ok.sum()) == 5
+    # and a deferring wire call on the same session answers from them too
+    w_ok, w_err, w_unk, w_recs = sess.verify_inputs(
+        *args, mode=native_bridge.NativeSession.MODE_DEFER
+    )
+    assert np.array_equal(w_ok, ok) and np.array_equal(w_err, err)
+    assert not w_unk.any() and not any(w_recs)
+
+
+def _tripled(args):
+    """The same inputs three times over: every check of the first copy is
+    found again by the shards that interpret the second and third."""
+    return tuple(list(a) * 3 for a in args)
+
+
+@pytest.fixture(scope="module")
+def tripled_single_thread():
+    args = _tripled(_mixed_inputs(n=12, seed="idx-dup"))
+    sess = native_bridge.NativeSession()
+    out = sess.verify_inputs_idx(*args, n_threads=1)
+    U = sess.uniq_count()
+    idx = np.arange(U, dtype=np.int32)
+    return args, out, _uniq_digests(sess), sess.uniq_lanes(idx, U)
+
+
+@pytest.mark.parametrize("n_threads", [2, 4, 7])
+def test_threads_identical_with_duplicates_across_shards(
+    tripled_single_thread, n_threads
+):
+    args, (ok0, err0, unk0, ri0, b0), digests0, lanes0 = tripled_single_thread
+    assert len(digests0) == len(set(digests0))  # deduped: 36 inputs, 12's checks
+    sess = native_bridge.NativeSession()
+    ok, err, unk, ri, b = sess.verify_inputs_idx(*args, n_threads=n_threads)
+    assert np.array_equal(ok, ok0) and np.array_equal(err, err0)
+    assert np.array_equal(unk, unk0)
+    assert np.array_equal(ri, ri0) and np.array_equal(b, b0)
+    assert _uniq_digests(sess) == digests0
+    U = sess.uniq_count()
+    lanes = sess.uniq_lanes(np.arange(U, dtype=np.int32), U)
+    for mine, ref in zip(lanes, lanes0, strict=True):
+        assert np.asarray(mine).tobytes() == np.asarray(ref).tobytes()
+
+
+def test_release_frees_once_and_a_released_session_raises(monkeypatch):
+    L = native_bridge.lib()
+    freed = []
+    real_free = L.nat_session_free
+    monkeypatch.setattr(
+        L, "nat_session_free", lambda p: (freed.append(p), real_free(p))
+    )
+    args = _mixed_inputs(n=3, seed="idx-rel")
+    sess = native_bridge.NativeSession()
+    sess.verify_inputs_idx(*args)
+    sess.release()
+    assert len(freed) == 1
+    sess.release()
+    sess.__del__()
+    assert len(freed) == 1  # the backstop after an explicit release: harmless
+    one = np.zeros(1, dtype=np.int32)
+    for call in (
+        sess.uniq_count,
+        lambda: sess.verify_inputs_idx(*args),
+        lambda: sess.publish_uniq(one, one),
+        lambda: sess.uniq_lanes(one, 8),
+        lambda: sess.uniq_digests(b"s", one),
+        lambda: sess.uniq_host_verify(0),
+        lambda: sess.add_known("ecdsa", (b"k", b"s", b"m"), True),
+        lambda: sess.verify_input(args[0][0], 0, args[2][0], args[3][0], args[4][0]),
+        sess.take_records,
+    ):
+        with pytest.raises(RuntimeError, match="after release"):
+            call()
+    assert len(freed) == 1
+    # a session nobody released is still freed by __del__, once
+    other = native_bridge.NativeSession()
+    other.verify_inputs_idx(*args)
+    del other
+    assert len(freed) == 2
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4, 5, 6])
+def test_random_protocol_interleavings_match_a_dict_oracle(seed):
+    """Random interleavings of index rounds, `publish_uniq`,
+    `add_known_batch`, deferring wire calls and the exact fallback on ONE
+    session, against a dict model of the oracle (check -> verdict, the last
+    write wins). Each deferring call must give what a fresh wire-protocol
+    session told exactly the model's entries gives: ok, err, unk, and the
+    same misses an input. Verdicts are random, not the curve's, so the
+    CHECKMULTISIG cursor walks every way it can."""
+    import random
+
+    rng = random.Random(seed)
+    n = 12
+    args = _mixed_inputs(n=n, seed=f"idx-rand-{seed}", corrupt=(rng.randrange(n),))
+    exact_ref = native_bridge.NativeSession().verify_inputs(
+        *args, mode=native_bridge.NativeSession.MODE_EXACT
+    )
+    sess = native_bridge.NativeSession()
+    model = {}  # digest -> verdict
+    universe = {}  # digest -> (kind, data), every check any round has seen
+
+    def subset():
+        pos = sorted(rng.sample(range(n), rng.randint(1, n)))
+        return pos, tuple([a[i] for i in pos] for a in args)
+
+    def known():
+        return [(*universe[d], v) for d, v in model.items()]
+
+    for _step in range(40):
+        op = rng.choice(["idx", "idx", "wire", "publish", "add_known", "exact"])
+        if op in ("idx", "wire"):
+            pos, sub = subset()
+            w_ok, w_err, w_unk, w_recs, seen = _wire_round(sub, known())
+            universe.update(seen)
+            if op == "idx":
+                ok, err, unk, ri, b = sess.verify_inputs_idx(
+                    *sub, n_threads=rng.choice([1, 2, 3])
+                )
+                digests = _uniq_digests(sess)
+                assert len(digests) == len(set(digests))
+                recs = [
+                    [digests[j] for j in ri[int(b[i]) : int(b[i + 1])]]
+                    for i in range(len(pos))
+                ]
+            else:
+                ok, err, unk, raw = sess.verify_inputs(
+                    *sub, mode=native_bridge.NativeSession.MODE_DEFER
+                )
+                recs = [native_bridge.digest_checks(_SALT, r) for r in raw]
+            assert np.array_equal(ok, w_ok) and np.array_equal(err, w_err), op
+            assert np.array_equal(unk, w_unk), op
+            assert recs == w_recs, op
+            assert not set(d for r in recs for d in r) & set(model)
+        elif op == "publish":
+            digests = _uniq_digests(sess)
+            if not digests:
+                continue
+            idx = rng.sample(range(len(digests)), rng.randint(1, len(digests)))
+            verdicts = [rng.random() < 0.6 for _ in idx]
+            sess.publish_uniq(
+                np.asarray(idx, dtype=np.int32), np.asarray(verdicts, dtype=np.int32)
+            )
+            for i, v in zip(idx, verdicts, strict=True):
+                model[digests[i]] = v
+        elif op == "add_known":
+            if not universe:
+                continue
+            picks = rng.sample(sorted(universe), rng.randint(1, min(5, len(universe))))
+            entries = [(*universe[d], rng.random() < 0.6) for d in picks]
+            if rng.random() < 0.5:
+                sess.add_known_batch(entries)
+            else:
+                for kind, data, v in entries:
+                    sess.add_known(kind, data, v)
+            for d, (_, _, v) in zip(picks, entries, strict=True):
+                model[d] = v
+        else:  # the exact fallback: answers from the curve, touches no verdict
+            i = rng.randrange(n)
+            okx, errx, _ = sess.verify_input(
+                args[0][i], i, args[2][i], args[3][i], args[4][i],
+                mode=native_bridge.NativeSession.MODE_EXACT,
+            )
+            assert bool(okx) == bool(exact_ref[0][i])
+            assert int(errx) == int(exact_ref[1][i])
+    assert universe and sess.uniq_count()
