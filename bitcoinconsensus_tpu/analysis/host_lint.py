@@ -33,7 +33,7 @@ from __future__ import annotations
 import ast
 import os
 from dataclasses import dataclass
-from typing import FrozenSet, Iterator, List, Sequence
+from typing import Dict, FrozenSet, Iterator, List, Optional, Sequence, Tuple
 
 # Rule groups, selectable per scanned tree.
 ALL_RULES = frozenset({"float", "nondeterminism", "time"})
@@ -405,31 +405,104 @@ def lint_scalar_recoders(
 #
 # Not an AST rule: this one traces. Every kernel registered in
 # `analysis/registry` must execute under a `region:` named scope
-# (`ops/regions.py`) so the xprof observatory can attribute its device
-# time — a kernel landing without annotation would silently grow the
-# `unattributed` share of every capture. Kept in this module because it
-# is a lint (finding-shaped, wired into `scripts/consensus_lint.py`),
-# with lazy imports so the pure-AST rules above stay dependency-free.
+# (`ops/regions.py`): the scopes are the op names a profiler trace of
+# the chip shows (`region_verify_tiles` is the one the benchmark's trace
+# reduction sums), so a kernel landing without one is anonymous there.
+# Kept in this module because it is a lint (finding-shaped, wired into
+# `scripts/consensus_lint.py`), with lazy imports so the pure-AST rules
+# above stay dependency-free.
 
 # A kernel passes when at least this fraction of its element ops sit
 # under some region scope. Below 1.0 because trace plumbing (argument
 # converts, output reshapes) legitimately sits outside the scopes.
 REGION_MIN_COVERAGE = 0.90
 
+# Primitives whose output elements count as element ops.
+_ELEMENT_OPS = frozenset({
+    "add", "sub", "mul", "and", "or", "xor", "shift_left",
+    "shift_right_logical", "shift_right_arithmetic", "select_n", "eq", "ne",
+    "lt", "le", "gt", "ge", "min", "max", "neg", "abs", "rem", "not",
+    "convert_element_type", "broadcast_in_dim", "concatenate", "iota",
+    "reduce_and", "reduce_or", "reduce_sum", "reduce_min", "reduce_max",
+    "dot_general",
+})
+
+
+def _while_trips(eqn) -> int:
+    """Trip count of a lowered `fori_loop` (a `while` whose carry init
+    holds the static upper bound as a scalar int literal — take the
+    largest such literal; exact for every fori in the verify kernel)."""
+    from jax.extend.core import Literal
+
+    trips = 1
+    for v in eqn.invars:
+        if isinstance(v, Literal) and getattr(v.aval, "shape", None) == ():
+            try:
+                trips = max(trips, int(v.val))
+            except (TypeError, ValueError):
+                pass
+    return trips
+
+
+def walk_jaxpr_regions(
+    jaxpr, inherited: Tuple[str, ...] = (),
+    acc: Optional[Dict[Tuple[str, ...], int]] = None, mult: int = 1,
+) -> Dict[Tuple[str, ...], int]:
+    """Element ops of a jaxpr by kernel-region stack.
+
+    Returns ``{region_stack: ops}`` where ``region_stack`` is the tuple
+    of region frames (outermost first; the last entry is the innermost
+    region the op is charged to — empty tuple = under no region). Counts
+    output elements of `_ELEMENT_OPS`, while×trips, scan×length, and
+    recurses into any param carrying a jaxpr; sub-jaxprs inherit the
+    parent equation's region stack, because scan/while bodies are
+    re-traced without the caller's name stack.
+    """
+    import numpy as np
+
+    from ..ops.regions import extract_regions
+
+    if acc is None:
+        acc = {}
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        regions = (
+            tuple(extract_regions(str(eqn.source_info.name_stack)))
+            or inherited
+        )
+        if prim == "while":
+            walk_jaxpr_regions(eqn.params["body_jaxpr"].jaxpr, regions, acc,
+                               mult * _while_trips(eqn))
+            continue
+        if prim == "scan":
+            walk_jaxpr_regions(eqn.params["jaxpr"].jaxpr, regions, acc,
+                               mult * eqn.params["length"])
+            continue
+        recursed = False
+        for p in eqn.params.values():
+            # ClosedJaxpr (.jaxpr) or raw Jaxpr (.eqns) — pallas_call
+            # carries the latter.
+            sub = getattr(p, "jaxpr", p if hasattr(p, "eqns") else None)
+            if sub is not None:
+                walk_jaxpr_regions(sub, regions, acc, mult)
+                recursed = True
+        if recursed or prim not in _ELEMENT_OPS:
+            continue
+        outs = sum(int(np.prod(v.aval.shape)) for v in eqn.outvars)
+        acc[regions] = acc.get(regions, 0) + outs * mult
+    return acc
+
 
 def region_coverage(fn, args) -> float:
     """Fraction of a traced callable's element ops under region scopes."""
     import jax
 
-    from ..obs import xprof
-
     closed = jax.make_jaxpr(fn)(*args)
-    acc = xprof.walk_jaxpr_regions(closed.jaxpr)
-    total = sum(b["ops"] for b in acc.values())
+    acc = walk_jaxpr_regions(closed.jaxpr)
+    total = sum(acc.values())
     if total <= 0:
         return 0.0
-    named = sum(b["ops"] for stack, b in acc.items() if stack)
-    return named / total
+    return sum(n for stack, n in acc.items() if stack) / total
 
 
 def lint_kernel_regions(
@@ -461,6 +534,6 @@ def lint_kernel_regions(
                 spec.name, 0, "region",
                 f"only {cov:.0%} of element ops run under a region: "
                 f"scope (< {min_coverage:.0%}) — annotate the kernel "
-                f"with ops/regions.named_region so xprof can attribute "
-                f"its device time"))
+                f"with ops/regions.named_region so a profiler trace "
+                f"names its ops"))
     return findings

@@ -7,7 +7,7 @@ attribution across the host→device boundary (host parse vs limb pack vs
 XLA dispatch vs readback, sigcache hits vs deferred TPU resolves) with
 zero external dependencies.
 
-Three pieces:
+Four pieces:
 
 - ``metrics`` — a process-global, thread-safe registry of counters,
   gauges and fixed-bucket histograms, all label-aware. Every layer of the
@@ -22,19 +22,6 @@ Three pieces:
 - ``exposition`` — Prometheus-text and JSON renderings of a snapshot,
   plus snapshot validation/diff helpers for the CLI
   (`scripts/consensus_stats.py`) and the CI `obs-smoke` artifact.
-- ``perf`` — the performance observatory: `PhaseTimeline` phase
-  attribution riding every in-flight dispatch ticket
-  (`consensus_pipeline_phase_seconds{phase=...}` + the
-  overlap-efficiency gauge), the reusable roofline/cost walk shared by
-  the perf scripts, and provenance-stamped report comparison for the CI
-  `perf-smoke` regression gate (`scripts/consensus_perf.py`).
-- ``xprof`` — the device-truth kernel observatory: programmatic
-  profiler capture sessions attributing device time to the named
-  kernel regions threaded through the kernels via `ops/regions.py`
-  (`consensus_kernel_region_seconds{region=...}` + MXU/VPU
-  busy-fraction gauges, `XPROF_r{N}.json` artifacts, the
-  `consensus_xprof.py --check` drift gate). Degrades to the op-walk
-  estimate on CPU containers under the same `comparable()` discipline.
 - ``flight`` — the black-box flight recorder: a bounded ring of recent
   resilience events/spans/metric deltas, dumped redacted +
   provenance-stamped on conviction (quarantine, checksum mismatch,
@@ -44,9 +31,7 @@ Three pieces:
 Design constraint (hard): nothing in this package is ever imported by —
 or traced into — device kernel code. Instrumentation is host-side only,
 so the jaxpr determinism gate (`analysis/`) and every registered kernel
-jaxpr are untouched by telemetry. (`ops/regions.py` — imported by
-``xprof`` — is the one sanctioned kernel-adjacent dependency: pure
-naming metadata, importable both ways.) Conversely this is the ONE
+jaxpr are untouched by telemetry. Conversely this is the ONE
 place in the tree allowed to read clocks: the host AST lint rejects
 direct `time.perf_counter()` timing in `models/` and `crypto/` so all
 timing flows through spans.
@@ -73,13 +58,10 @@ from .spans import (
     trace_context,
 )
 from . import flight
-from . import perf
-from . import xprof
 
 __all__ = [
     "JsonlSink",
     "flight",
-    "xprof",
     "MetricsRegistry",
     "Span",
     "add_sink",
@@ -90,7 +72,6 @@ __all__ = [
     "get_registry",
     "histogram",
     "monotonic",
-    "perf",
     "remove_sink",
     "span",
     "trace_context",

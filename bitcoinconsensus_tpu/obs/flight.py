@@ -11,7 +11,7 @@ CLI flag) the ring is dumped — redacted and provenance-stamped — to a
 ``flight_dump_<reason>_*.json`` the chaos harness and operators can
 read post-mortem.
 
-Disarmed-by-default discipline (same as `perf.set_enabled`): the fast
+Disarmed by default: the fast
 path of `record()` is a single module-global read, so the recorder
 costs nothing measurable inside the <1% resilience overhead budget
 until armed via ``BITCOINCONSENSUS_TPU_FLIGHT=1`` or `set_enabled()`.
@@ -33,13 +33,14 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 import threading
 from collections import deque
 from typing import Any, Dict, List, Optional
 
 from .metrics import counter, gauge, get_registry
 from . import exposition as _exposition
-from . import perf as _perf
 from . import spans as _spans
 
 __all__ = [
@@ -47,6 +48,7 @@ __all__ = [
     "MAX_DUMPS",
     "enabled",
     "events",
+    "provenance",
     "record",
     "reset",
     "set_enabled",
@@ -193,6 +195,53 @@ def _dump_dir() -> str:
     return os.environ.get("BITCOINCONSENSUS_TPU_FLIGHT_DIR", "/tmp")
 
 
+def _git_rev() -> str:
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    try:
+        out = subprocess.run(
+            ["git", "rev-parse", "--short", "HEAD"],
+            cwd=root, capture_output=True, text=True, timeout=5,
+        )
+        rev = out.stdout.strip()
+        return rev if out.returncode == 0 and rev else "unknown"
+    except Exception:
+        return "unknown"
+
+
+def provenance() -> dict:
+    """Where a dump or a record came from: backend platform and device
+    kind, jax/jaxlib/python versions, git revision and the command."""
+    doc = {
+        "platform": "unavailable",
+        "device_kind": "unavailable",
+        "device_count": 0,
+        "jax": "unavailable",
+        "jaxlib": "unavailable",
+        "python": sys.version.split()[0],
+        "git_rev": _git_rev(),
+        "cmd": " ".join(sys.argv),
+    }
+    try:
+        import jax
+
+        doc["jax"] = jax.__version__
+        try:
+            import jaxlib
+
+            doc["jaxlib"] = jaxlib.__version__
+        except Exception:
+            pass
+        doc["platform"] = jax.default_backend()
+        devs = jax.devices()
+        if devs:
+            doc["device_kind"] = devs[0].device_kind
+            doc["device_count"] = len(devs)
+    except Exception:
+        pass
+    return doc
+
+
 def trigger(reason: str, out_dir: Optional[str] = None,
             **attrs) -> Optional[str]:
     """Dump the flight ring; returns the written path (None when
@@ -220,7 +269,7 @@ def trigger(reason: str, out_dir: Optional[str] = None,
         "schema": SCHEMA,
         "trigger": reason,
         "attrs": _redact(dict(attrs)),
-        "provenance": _perf.provenance(),
+        "provenance": provenance(),
         "events": [_redact(ev) for ev in window],
         "events_dropped": evicted,
         "metric_deltas": deltas,

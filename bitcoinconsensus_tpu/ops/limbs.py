@@ -206,8 +206,7 @@ def _pad_rows(x, lo: int, hi: int):
     """x padded with `lo` zero rows below and `hi` above, as ONE lax.pad
     op — the convolutions pad every row product into the output width,
     and materializing the zeros as separate arrays + concatenate doubled
-    the kernel's data-movement op count (see scripts/kernel_roofline.py
-    `move_ops_per_lane`)."""
+    the kernel's data-movement op count."""
     if lo == 0 and hi == 0:
         return x
     from jax import lax

@@ -6,17 +6,17 @@ ops to the traced program, so the interval prover, the exactness
 prover, and the A/B bit-identity harness see byte-identical jaxprs.
 What it *does* do is stamp every equation's ``source_info.name_stack``
 (and, on real hardware, every XLA op's metadata) with the region name,
-which is what lets `obs/xprof.py` attribute measured device time to
-kernel regions — and what the host-lint annotation-coverage rule
-checks so new kernels can't land unattributable.
+which is how a profiler trace of the chip names the kernel's ops — and
+what the host-lint annotation-coverage rule (`analysis/host_lint.py`)
+checks so new kernels can't land anonymous.
 
 This module deliberately lives in ``ops/`` (not ``obs/``): kernel code
-must never import the observability layer, but the observability layer
-may import this.  It has no dependencies beyond a lazy ``jax`` import.
+must never import the observability layer.  It has no dependencies
+beyond a lazy ``jax`` import.
 
-Region names are stable identifiers — `XPROF_r{N}.json` artifacts and
-the CI drift gate compare shares per region name across runs, so
-renaming one is a breaking change to the perf-gate contract.
+Region names are stable identifiers — the benchmark's trace reduction
+finds the verify kernel by `region_verify_tiles`, so renaming one
+changes what a per-layer metric reads.
 """
 
 from __future__ import annotations

@@ -186,8 +186,8 @@ def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
     local_kernel = _pick_backend(use_pallas)
 
     def local_step(fields, want_odd, parity_req, has_t2, neg1, neg2, valid, live):
-        # region scope only — metadata for device-time attribution
-        # (obs/xprof); the traced program is unchanged.
+        # region scope only — metadata: the op's name in a profiler
+        # trace; the traced program is unchanged.
         with region_scope("shard_step"):
             per_lane, needs = local_kernel(
                 fields, want_odd, parity_req, has_t2, neg1, neg2, valid
@@ -488,8 +488,7 @@ class ShardedSecpVerifier(TpuSecpVerifier):
             )
         elapsed = _monotonic() - ticket.born
         ok_v, needs_v, bad = self._check_shards(
-            ok_np, needs_np, cnts_np, wsums_np, layout, elapsed,
-            timeline=ticket.timeline,
+            ok_np, needs_np, cnts_np, wsums_np, layout, elapsed
         )
         # Per-device health feeds the eviction ladder at the PRIMARY
         # settle only (re-dispatch retries must not double-convict).
@@ -545,8 +544,7 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         return ok_r, needs_r, None
 
     def _check_shards(self, ok_np, needs_np, cnts_np, wsums_np,
-                      layout: _ShardLayout, elapsed: float,
-                      timeline=None):
+                      layout: _ShardLayout, elapsed: float):
         """Validate each shard's verdict slice independently.
 
         Returns `(ok, needs, bad)` where ok/needs are padded bool buffers
@@ -555,9 +553,6 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         structural validation, then the per-shard checksum (so a
         single-lane flip always convicts as "checksum" — the chaos
         sweep's hard criterion), then the shard's rotating sentinel.
-        `timeline` (the settling ticket's PhaseTimeline, when present)
-        receives one stamp per shard so the perf observatory can
-        attribute settle time shard-by-shard.
         """
         shard = layout.shard_size
         ok_v = np.zeros(layout.padded, dtype=bool)
@@ -602,11 +597,6 @@ class ShardedSecpVerifier(TpuSecpVerifier):
             else:
                 ok_v[sl] = ok_s
                 needs_v[sl] = needs_s
-            finally:
-                # Completion stamp: consecutive deltas (from settle_start)
-                # are this shard's check duration.
-                if timeline is not None:
-                    timeline.stamp_shard(s)
         return ok_v, needs_v, bad
 
     # --- shard re-dispatch ---------------------------------------------

@@ -3,8 +3,7 @@
 JAX arrays are futures: a dispatched verify batch is already
 asynchronous until something on the host materializes it. PR 5's
 dispatch/settle seam blocked immediately after every launch, which made
-containment easy but serialized the pipeline — block replay spent 208 ms
-of 282.7 ms waiting on the device link. This module makes the seam
+containment easy but serialized the pipeline. This module makes the seam
 asynchronous *without* loosening it: every dispatch returns a
 :class:`Ticket` and every ticket still settles through the verdict
 guards, the bounded-retry budget, and the degradation ladder before any
@@ -55,11 +54,9 @@ import numpy as np
 
 from ..obs import counter as _obs_counter
 from ..obs import flight as _flight
-from ..obs import current_trace as _current_trace
 from ..obs import gauge as _obs_gauge
 from ..obs import histogram as _obs_histogram
 from ..obs import monotonic as _monotonic
-from ..obs import perf as _perf
 from . import guards as _guards
 from .degrade import HOST_LEVEL, DispatchResilience
 
@@ -128,12 +125,10 @@ class Ticket:
     __slots__ = (
         "args", "n", "level", "probe", "attempts", "born", "deadline",
         "sset", "result", "aux", "error", "settled", "outcome", "seq",
-        "timeline",
     )
 
     def __init__(self, args, n: int, level: str, probe: bool,
-                 deadline: float, born: float, seq: int,
-                 timeline=None):
+                 deadline: float, born: float, seq: int):
         self.args = args
         self.n = n                  # real (padded) lane count dispatched
         self.level = level          # ladder level the launch ran at
@@ -148,9 +143,6 @@ class Ticket:
         self.settled = False
         self.outcome = None         # (ok, needs) after settle; None=host
         self.seq = seq
-        # PhaseTimeline (or the disarmed no-op): per-ticket phase stamps
-        # feeding consensus_pipeline_phase_seconds at settle.
-        self.timeline = _perf.NULL_TIMELINE if timeline is None else timeline
 
 
 class InflightQueue:
@@ -210,21 +202,15 @@ class InflightQueue:
         while len(self._pending) >= self.max_depth:
             _BACKPRESSURE.inc(site=self.site)
             self.settle(self._pending[0])
-        # Timeline starts before prepare so host-side sentinel/copy work
-        # is attributed; it adopts the submitting request's trace id so
-        # the ticket stitches into the serving-side span tree.
-        timeline = _perf.new_timeline(trace=_current_trace())
-        timeline.stamp("submit")
         if self._prepare is not None:
             args, sset = self._prepare(args, n)
         else:
             sset = None
-        timeline.stamp("prepare")
         level, probe = self._res.ladder.pick_level()
         now = _monotonic()
         ticket = Ticket(args, n, level, probe,
                         deadline=now + self.deadline_s, born=now,
-                        seq=self._seq, timeline=timeline)
+                        seq=self._seq)
         self._seq += 1
         ticket.sset = sset
         _TICKETS.inc(site=self.site)
@@ -239,7 +225,6 @@ class InflightQueue:
         ticket.aux = None
         ticket.error = None
         if ticket.level == HOST_LEVEL:
-            ticket.timeline.stamp("launch")
             return
         try:
             ticket.result, ticket.aux = self._launch_cb(
@@ -247,8 +232,6 @@ class InflightQueue:
             )
         except Exception as exc:  # settled as a dispatch failure
             ticket.error = exc
-        # Re-stamped on relaunch: the settled attempt owns the edge.
-        ticket.timeline.stamp("launch")
 
     def _note_failure(self, ticket: Ticket, stage: str,
                       exc: BaseException) -> None:
@@ -282,9 +265,6 @@ class InflightQueue:
         """
         if ticket.settled:
             return ticket.outcome
-        # First host touch after launch: everything between "launch" and
-        # here is the overlap window — wire time the host did not wait on.
-        ticket.timeline.stamp_once("first_poll")
         try:
             self._pending.remove(ticket)
         except ValueError:
@@ -298,7 +278,6 @@ class InflightQueue:
             if ticket.error is not None:
                 self._note_failure(ticket, "launch", ticket.error)
             else:
-                ticket.timeline.stamp("settle_start")
                 try:
                     ok, needs, all_ok = self._materialize(ticket)
                 except Exception as exc:
@@ -333,8 +312,6 @@ class InflightQueue:
                 ladder.report(HOST_LEVEL, True)
         ticket.settled = True
         ticket.outcome = outcome
-        ticket.timeline.stamp("settle_end")
-        ticket.timeline.finalize()
         if ladder.levels.index(ladder.current) > start_idx:
             self._requeue_stale()
         return outcome

@@ -411,8 +411,8 @@ class VerifyServer:
                     self._inflight_reqs += len(reqs)
 
         current: Optional[list] = None
-        # The burst leader's trace contexts the driver's own spans (and
-        # the dispatch tickets' timelines) on this worker thread; each
+        # The burst leader's trace contexts the driver's own spans on
+        # this worker thread; each
         # request additionally gets a settle span inside its OWN trace,
         # parented to its submit span — the cross-thread stitch.
         leader = first[0]

@@ -1,5 +1,9 @@
 """Per-phase wall-clock timers — a thin adapter over the obs telemetry.
 
+`verifier.phases` (a `Phases`) is the one phase clock of the verify path:
+every per-layer phase metric of the benchmark reads it and nothing else
+times those seams.
+
 Historically `Phases` owned its own perf_counter pairs and bare dicts;
 the dict read-modify-writes raced under the `_idx_threads()` worker pool
 in `models/batch.py` (two threads could each read `_calls["x"] == 3` and
@@ -32,7 +36,7 @@ from typing import Dict
 
 from ..obs import spans as _spans
 
-__all__ = ["Phases", "xla_trace"]
+__all__ = ["Phases"]
 
 
 class Phases:
@@ -73,20 +77,3 @@ class Phases:
     def total(self) -> float:
         with self._lock:
             return sum(self._secs.values())
-
-
-@contextmanager
-def xla_trace(log_dir: str = "/tmp/bitcoinconsensus_tpu_trace"):
-    """XLA/TPU profiler hook (LOCKED thin adapter — same CLI surface as
-    always, used by `scripts/profile_verify.py --xla-trace`).
-
-    The actual capture session lives in `obs/xprof.trace_session`, the
-    device-truth observatory that also parses these traces into
-    per-region attribution; this wrapper only keeps the historical
-    entry point and its print. New profiling code should call
-    `obs.xprof` directly."""
-    from ..obs.xprof import trace_session
-
-    with trace_session(log_dir):
-        yield
-    print(f"xla trace written to {log_dir}")

@@ -9,9 +9,7 @@ and enforced three ways:
   engine — plus the reference `.so` differential where available;
 - `scripts/consensus_gauntlet.py --corpus` re-checks the pins on every
   backend and is a CI gate (`consensus_chaos.py --gauntlet` runs it
-  under the fault sweep too);
-- `scripts/bench_gauntlet.py` benches `shape_batch()` scale-ups of the
-  same constructors so worst-case throughput is tracked per shape.
+  under the fault sweep too).
 
 The shapes are the reference's hard cases (SURVEY §7, ROADMAP
 "Scenario diversity"): CHECKMULTISIG fan-out is the measured deferral
@@ -23,9 +21,9 @@ chain, and the malleation/boundary-flag entries pin the exact flag
 bits where a verdict legally flips.
 
 Adding a shape: write a `_case_*` constructor returning `CorpusCase`
-rows with pinned verdicts, register its shape tag in `SHAPES`, extend
-`shape_batch()` if it should be benched, and land a baseline via
-`scripts/bench_gauntlet.py --measure` (README "Adversarial workloads &
+rows with pinned verdicts, register its shape tag in `SHAPES`, and
+extend `shape_batch()` (all-valid scale-ups of the same constructors)
+if a benchmark cell should run it (README "Adversarial workloads &
 gauntlet"). A wrong pin fails the gauntlet — that is the point.
 """
 

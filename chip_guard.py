@@ -4,9 +4,8 @@ The dispatch path is built to survive a broken device: a launch that
 raises is retried, the ladder demotes, and the host oracle answers, so
 verdicts stay right and the process exits 0 (`resilience/inflight.py`,
 `resilience/degrade.py`). That is the product's guarantee and it stays.
-Anything that *measures or proves* the chip path — `chip_smoke.py`,
-`bench.py`, `scripts/bench_configs.py`, `scripts/tpu_differential.py` —
-must therefore refuse to start below a TPU and must fail if any of that
+Anything that *proves* the chip path — `chip_smoke.py`,
+`scripts/tpu_differential.py` — must therefore refuse to start below a TPU and must fail if any of that
 machinery engaged. These helpers are that refusal, shared.
 """
 

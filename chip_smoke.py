@@ -41,7 +41,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import chip_guard
 
-# BASELINE.json configs 2-5 (config-5 shape: scripts/bench_configs.py).
+# BASELINE.json configs 2-5.
 BATCH_INPUTS = 10_000
 BLOCK_INPUTS = 3_200
 BLOCK_HEIGHT = 710_000

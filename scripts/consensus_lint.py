@@ -124,7 +124,7 @@ def main() -> int:
     host_ok = not findings
     print(f"  {'clean' if host_ok else f'{len(findings)} finding(s)'}")
 
-    print("\n== kernel region-annotation coverage (xprof attributability) ==")
+    print("\n== kernel region-annotation coverage (op names in a profiler trace) ==")
     region_findings = host_lint.lint_kernel_regions(
         include_heavy=not args.quick)
     for f in region_findings:

@@ -1,59 +1,51 @@
 """A/B harness for verify-kernel experiments on the live TPU.
 
-Builds a real mixed check batch (signed fixtures -> native prep_pack),
-then times the pallas kernel device-side (device-resident args, so the
-number is compute+readback without the host upload) and checks verdict
-equality against the XLA reference kernel. Usage:
+Builds a real mixed check batch (one signed spend a kind from
+`utils/blockgen` -> native prep_pack), then times the pallas kernel
+device-side (device-resident args, so the number is compute+readback
+without the host upload) and checks verdict equality against the XLA
+reference kernel. Usage:
 
     python scripts/kernel_ab.py [n_lanes] [tile ...]
 """
 
+import argparse
 import sys
 import time
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
-sys.path.insert(0, __file__.rsplit("/", 1)[0])
-
-import numpy as np
-import jax
-
-N = int(sys.argv[1]) if len(sys.argv) > 1 else 10240
-TILES = [int(t) for t in sys.argv[2:]] or [512]
 
 
 def build_checks(n):
-    from bench_configs import _make_batch_tx
     from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck
-    from bitcoinconsensus_tpu.core.tx import Tx
+    from bitcoinconsensus_tpu.core.script import push_data
+    from bitcoinconsensus_tpu.core.tx import TxOut
+    from bitcoinconsensus_tpu.utils.hashes import hash160
+    from bitcoinconsensus_tpu.utils.blockgen import (
+        build_spend_tx, make_funded_view,
+    )
     from bitcoinconsensus_tpu.core.sighash import (
         PrecomputedTxData, SIGHASH_ALL, bip143_sighash, SigVersion,
         bip341_sighash, SIGHASH_DEFAULT,
     )
 
-    # Mixed ECDSA + Schnorr checks from the signed bench fixtures; recover
+    # Mixed ECDSA + Schnorr checks from one signed spend a kind; recover
     # (pubkey, sig, sighash) triples by re-deriving the sighashes.
     checks = []
     for kind in ("p2wpkh", "p2tr"):
-        items = _make_batch_tx(kind, (n + 1) // 2, seed=f"bench-{kind}")
-        tx = Tx.deserialize(items[0].spending_tx)
+        _, funded = make_funded_view(
+            (n + 1) // 2, kinds=(kind,), seed=f"bench-{kind}")
+        tx = build_spend_tx(funded, fee=1000)
         if kind == "p2wpkh":
-            for i, item in enumerate(items):
+            for i, f in enumerate(funded):
                 sig, pub = tx.vin[i].witness
-                from bitcoinconsensus_tpu.utils.hashes import hash160
-                from bitcoinconsensus_tpu.core.script import push_data
-
                 code = b"\x76\xa9" + push_data(hash160(pub)) + b"\x88\xac"
-                sh = bip143_sighash(code, tx, i, SIGHASH_ALL, item.amount)
+                sh = bip143_sighash(code, tx, i, SIGHASH_ALL, f.amount)
                 checks.append(SigCheck("ecdsa", (pub, sig[:-1], sh)))
         else:
-            outs = [
-                __import__(
-                    "bitcoinconsensus_tpu.core.tx", fromlist=["TxOut"]
-                ).TxOut(a, s)
-                for a, s in items[0].spent_outputs
-            ]
+            outs = [TxOut(f.amount, f.wallet.spk) for f in funded]
             txd = PrecomputedTxData(tx, outs)
-            for i, _item in enumerate(items):
+            for i in range(len(funded)):
                 sig = tx.vin[i].witness[0]
                 sh = bip341_sighash(
                     tx, i, SIGHASH_DEFAULT, SigVersion.TAPROOT, txd, False, b""
@@ -73,6 +65,15 @@ def build_checks(n):
 
 
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("n_lanes", nargs="?", type=int, default=10240)
+    ap.add_argument("tile", nargs="*", type=int, default=[512])
+    a = ap.parse_args()
+    N, TILES = a.n_lanes, a.tile
+
+    import jax
+    import numpy as np
+
     from bitcoinconsensus_tpu import native_bridge
     from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
 

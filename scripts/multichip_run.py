@@ -20,7 +20,7 @@ compile cache is shared). On a TPU pod slice the same script measures
 the real thing — drop the forcing with --no-force.
 
 Usage:
-    python scripts/multichip_run.py --out MULTICHIP_r06.json
+    python scripts/multichip_run.py --out MULTICHIP.json
     python scripts/multichip_run.py --devices 8 --iters 5
     python scripts/multichip_run.py --no-force --devices 4 --lanes 2044
 
@@ -174,7 +174,7 @@ def main(argv=None) -> int:
         "continued_bit_identical": cont_ok,
     }
 
-    from bitcoinconsensus_tpu.obs import perf
+    from bitcoinconsensus_tpu.obs import flight
 
     doc = {
         "n_devices": args.devices,
@@ -184,7 +184,7 @@ def main(argv=None) -> int:
         "ok": True,
         "clean": clean,
         "eviction": eviction,
-        "provenance": perf.provenance(),
+        "provenance": flight.provenance(),
     }
     out = json.dumps(doc, indent=2)
     if args.out:

@@ -1,0 +1,162 @@
+"""The program's side of the benchmark's contract.
+
+`benchmarks/` reads the program through names: metrics of the registry
+(`consensus_*`) and phases of `verifier.phases`. A per-layer metric whose
+source was renamed reads `null` in the ledger, a chip guard that reads a
+renamed counter guards nothing, and neither fails a test of the program.
+This file makes such a rename fail tier-1 first: one small workload over
+the paths the cells drive (a native connect on fresh and on warm caches, a
+two-block stream that ends in a rollback, a wire-driver dispatch, a served
+request), then a case a name.
+
+It reads `benchmarks/` (the literal lists below must be what its files
+name) and imports nothing from it.
+"""
+
+import os
+import re
+
+import pytest
+
+from conftest import *  # noqa: F401,F403 (env setup)
+
+import __graft_entry__ as ge
+from bitcoinconsensus_tpu import native_bridge
+from bitcoinconsensus_tpu.core.flags import VERIFY_ALL_LIBCONSENSUS
+from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
+from bitcoinconsensus_tpu.models.batch import BatchItem
+from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
+from bitcoinconsensus_tpu.models.validate import connect_block, connect_block_stream
+from bitcoinconsensus_tpu.obs import get_registry
+from bitcoinconsensus_tpu.serving import VerifyServer
+from bitcoinconsensus_tpu.utils.blockgen import (
+    REGTEST_POW_LIMIT,
+    build_block,
+    build_spend_tx,
+    make_funded_view,
+)
+
+from test_batch import make_p2wpkh_spend
+from test_native_block import HEIGHT, to_native_view
+
+pytestmark = [
+    pytest.mark.skipif(
+        not native_bridge.available(), reason="native core unavailable"
+    ),
+    pytest.mark.usefixtures("warm_kernel"),  # conftest.py: first calls
+]
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# What the per-layer readers and the drivers take from the registry: each
+# must have a sample after the workload, except the shed counter, which a
+# run that sheds nothing never bumps.
+READ = (
+    "consensus_cache_hits_total",
+    "consensus_cache_lookups_total",
+    "consensus_dispatch_lanes_total",
+    "consensus_dispatch_new_shapes_total",
+    "consensus_dispatch_padded_lanes_total",
+    "consensus_dispatch_total",
+    "consensus_serving_admitted_total",
+    "consensus_serving_batch_fill",
+    "consensus_serving_batch_seconds",
+    "consensus_serving_batches_total",
+    "consensus_serving_queue_wait_seconds",
+    "consensus_serving_shed_total",
+    "consensus_stream_blocks_in_flight",
+    "consensus_stream_rollbacks_total",
+)
+# What the benchmark's chip guard holds at zero (`harness/chipguard.py`):
+# registered is all a sound run shows of them.
+ZERO = (
+    "consensus_backend_config_errors_total",
+    "consensus_host_fixup_total",
+    "consensus_inflight_deadline_expired_total",
+    "consensus_inflight_failures_total",
+    "consensus_resilience_contained_total",
+    "consensus_resilience_demotions_total",
+    "consensus_resilience_guard_anomalies_total",
+    "consensus_resilience_host_exact_lanes_total",
+    "consensus_resilience_retries_total",
+)
+NO_SAMPLE_NEEDED = ZERO + ("consensus_serving_shed_total",)
+# `PERF.md` section 3: the stretches of a native connect that
+# `verifier.phases` names, every one read through `detail.phase_ms_p50`.
+PHASES = (
+    "interpret", "host_prep", "pack", "dispatch", "sync", "parse",
+    "block_check", "accounting", "probe", "results", "apply", "undo",
+    "publish", "release",
+)
+
+
+def _block(seed: str, height: int, n: int = 6, corrupt=None):
+    """A raw block of one transaction that spends `n` fresh P2WPKH coins
+    (at most 7 curve checks: the 8-lane rung), and the coins that fund it."""
+    coins, funded = make_funded_view(n, kinds=("p2wpkh",), seed=seed)
+    tx = build_spend_tx(funded, fee=1000, corrupt_input=corrupt)
+    return build_block([tx], height, fees=1000).serialize(), coins
+
+
+@pytest.fixture(scope="module")
+def workload():
+    verifier = TpuSecpVerifier()
+    connect = dict(pow_limit=REGTEST_POW_LIMIT, verifier=verifier)
+
+    # a native connect on fresh caches, and the same block on warm ones
+    raw, coins = _block("contract/tip", HEIGHT)
+    sig, script = SigCache(), ScriptExecutionCache()
+    for _ in range(2):
+        res = connect_block(raw, to_native_view(coins), HEIGHT,
+                            sig_cache=sig, script_cache=script, **connect)
+        assert res.ok and len(res.input_results) == 6
+
+    # a stream whose second block has one flipped signature: it is begun
+    # on a speculative view, rejected where it is finished, and undone
+    good, coins_a = _block("contract/a", HEIGHT)
+    bad, coins_b = _block("contract/b", HEIGHT + 1, corrupt=2)
+    coins_a._map.update(coins_b._map)
+    results = list(connect_block_stream(
+        [good, bad], to_native_view(coins_a), HEIGHT, depth=2,
+        sig_cache=SigCache(), script_cache=ScriptExecutionCache(), **connect))
+    assert [r.ok for r in results] == [True, False]
+
+    # the wire driver's lane prep, on the interpreter that has no native
+    # `prep_pack`: the one place `pack` is a phase of its own
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(verifier, "_native", None)
+        assert verifier.verify_checks(ge._example_checks(7)).all()
+
+    # one request through a server
+    txb, spk, amount = make_p2wpkh_spend("contract/serve")
+    item = BatchItem(txb, 0, VERIFY_ALL_LIBCONSENSUS,
+                     spent_output_script=spk, amount=amount)
+    with VerifyServer(verifier=verifier, max_batch=4, flush_s=0.005,
+                      tenant_depth=8) as srv:
+        assert srv.verify(item, tenant="contract", timeout=120).ok
+
+    return verifier.phases.report(), get_registry().snapshot()
+
+
+@pytest.mark.parametrize("name", READ + ZERO + PHASES)
+def test_the_benchmark_finds(workload, name):
+    phases, snapshot = workload
+    if name in PHASES:
+        assert phases.get(name, {}).get("calls", 0) > 0, sorted(phases)
+        return
+    assert name in snapshot, f"{name} is not registered"
+    if name not in NO_SAMPLE_NEEDED:
+        assert snapshot[name]["samples"], f"{name} took no sample"
+
+
+def test_lists_are_what_benchmarks_names():
+    """Every `consensus_*` name in the benchmark's Python (its own tests
+    aside) is in a list above, and the lists hold nothing else."""
+    named = set()
+    for root, dirs, files in os.walk(os.path.join(REPO, "benchmarks")):
+        dirs[:] = [d for d in dirs if d not in ("tests", "__pycache__")]
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    named |= set(re.findall(r"\bconsensus_[a-z_]+", fh.read()))
+    assert named == set(READ + ZERO)
