@@ -100,6 +100,12 @@ _UNIQ_CHECKS = _obs_counter(
     "consensus_uniq_checks_total",
     "deduplicated curve checks discovered (uniq-list growth, index mode)",
 )
+_PREP_LANES = _obs_counter(
+    "consensus_prep_lanes_total",
+    "lanes prepped by nat_session_uniq_lanes, by whether the call sharded "
+    "them over worker threads or ran serial (too few lanes, or one thread)",
+    ("mode",),
+)
 # Shared with crypto/jax_backend.py: exceptional device lanes resolved
 # exactly on host, whichever driver flags them.
 _HOST_FIXUPS = _obs_counter(
@@ -370,9 +376,10 @@ def _prepare(
 
 
 def _idx_threads() -> int:
-    """Interpretation fan-out width for the native index-mode path (the
-    checkqueue.h:29-163 axis; the C call releases the GIL). Overridable
-    via BITCOINCONSENSUS_TPU_THREADS; single-core hosts stay serial."""
+    """Fan-out width of the native index-mode path: interpretation (the
+    checkqueue.h:29-163 axis) and the resolve round's lane prep and cache
+    digests (the C calls release the GIL). Overridable via
+    BITCOINCONSENSUS_TPU_THREADS; single-core hosts stay serial."""
     env = os.environ.get("BITCOINCONSENSUS_TPU_THREADS", "")
     if env:
         return max(1, int(env))
@@ -432,8 +439,9 @@ def _dispatch_uniq(nsess, verifier, sig_cache, state: _UniqState):
         return None
     _UNIQ_CHECKS.inc(U - lo)
     grow = np.arange(lo, U, dtype=np.int32)
+    n_threads = _idx_threads()
     with verifier.phases("host_prep"):
-        raw = nsess.uniq_digests(sig_cache._salt, grow).tobytes()
+        raw = nsess.uniq_digests(sig_cache._salt, grow, n_threads).tobytes()
     state.val = np.concatenate([state.val, np.zeros(U - lo, dtype=bool)])
 
     if len(sig_cache) == 0 and _faults.active() is None:
@@ -456,7 +464,9 @@ def _dispatch_uniq(nsess, verifier, sig_cache, state: _UniqState):
     for s in range(0, len(miss), cap):
         sub = miss[s : s + cap]
         with verifier.phases("host_prep"):
-            lanes = nsess.uniq_lanes(sub, verifier.pad(len(sub)))
+            lanes = nsess.uniq_lanes(sub, verifier.pad(len(sub)), n_threads)
+        sharded = native_bridge.prep_shards(len(sub), n_threads) > 1
+        _PREP_LANES.inc(len(sub), mode="sharded" if sharded else "serial")
         pending.append((verifier.dispatch_lanes(lanes, len(sub)), sub))
     return grow, raw, pending
 

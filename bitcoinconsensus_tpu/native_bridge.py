@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 7:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 7)")
+        if L.nat_version() < 8:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 8)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -220,12 +220,15 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_recidx_data.argtypes = [vp, i32p, ctypes.c_int64]
         L.nat_session_recidx_data.restype = ctypes.c_int64
         L.nat_session_uniq_lanes.argtypes = [
-            vp, i32p, ctypes.c_int32,
+            vp, i32p, ctypes.c_int32, ctypes.c_int32,
             u8p, i32p, i32p, i32p, i32p, i32p, i32p,
         ]
         L.nat_session_uniq_digests.argtypes = [
-            vp, u8p, ctypes.c_int64, i32p, ctypes.c_int32, u8p,
+            vp, u8p, ctypes.c_int64, i32p, ctypes.c_int32, ctypes.c_int32,
+            u8p,
         ]
+        L.nat_prep_shards.argtypes = [ctypes.c_int32, ctypes.c_int32]
+        L.nat_prep_shards.restype = ctypes.c_int32
         L.nat_session_publish_uniq.argtypes = [vp, i32p, ctypes.c_int32, i32p]
         L.nat_session_uniq_host_verify.argtypes = [vp, ctypes.c_int32]
         L.nat_session_uniq_host_verify.restype = ctypes.c_int32
@@ -395,6 +398,12 @@ def _pack_check_parts(checks: Sequence[Tuple[str, Tuple]]):
         else np.zeros(1, dtype=np.uint8)
     )
     return kinds, blob, offs
+
+
+def prep_shards(n: int, n_threads: int) -> int:
+    """Workers `NativeSession.uniq_lanes` / `uniq_digests` use for `n`
+    entries given `n_threads`: 1 is the serial path (no thread made)."""
+    return int(lib().nat_prep_shards(int(n), int(n_threads)))
 
 
 def digest_checks(salt: bytes, checks: Sequence[Tuple[str, Tuple]]) -> List[bytes]:
@@ -786,9 +795,11 @@ class NativeSession:
     def uniq_count(self) -> int:
         return int(lib().nat_session_uniq_count(self._ptr))
 
-    def uniq_lanes(self, idxs: np.ndarray, size: int):
+    def uniq_lanes(self, idxs: np.ndarray, size: int, n_threads: int = 1):
         """Packed kernel lanes for the uniq entries `idxs`, padded to
-        `size` — the session-resident twin of prep_pack."""
+        `size` — the session-resident twin of prep_pack. The native call
+        shards them over `prep_shards(len(idxs), n_threads)` workers; the
+        arrays are the same byte for byte at any width."""
         L = lib()
         n = len(idxs)
         assert size >= n
@@ -802,15 +813,17 @@ class NativeSession:
         valid_i = np.zeros(size, dtype=np.int32)
         if n:
             L.nat_session_uniq_lanes(
-                self._ptr, _i32p(idx_a), n, _u8p(fields), _i32p(want_odd),
-                _i32p(parity), _i32p(has_t2), _i32p(neg1), _i32p(neg2),
-                _i32p(valid_i),
+                self._ptr, _i32p(idx_a), n, int(n_threads), _u8p(fields),
+                _i32p(want_odd), _i32p(parity), _i32p(has_t2), _i32p(neg1),
+                _i32p(neg2), _i32p(valid_i),
             )
         return fields, want_odd, parity, has_t2, neg1, neg2, valid_i != 0
 
-    def uniq_digests(self, salt: bytes, idxs: np.ndarray) -> np.ndarray:
+    def uniq_digests(self, salt: bytes, idxs: np.ndarray,
+                     n_threads: int = 1) -> np.ndarray:
         """(n, 32) uint8 salted cache-key digests for uniq entries
-        `idxs`, computed in place (no check bytes cross the bridge)."""
+        `idxs`, computed in place (no check bytes cross the bridge);
+        sharded by count as `uniq_lanes` is."""
         L = lib()
         n = len(idxs)
         out = np.zeros((max(n, 1), 32), dtype=np.uint8)
@@ -822,7 +835,8 @@ class NativeSession:
                 else np.zeros(1, np.uint8)
             )
             L.nat_session_uniq_digests(
-                self._ptr, _u8p(salt_a), len(salt), _i32p(idx_a), n, _u8p(out)
+                self._ptr, _u8p(salt_a), len(salt), _i32p(idx_a), n,
+                int(n_threads), _u8p(out),
             )
         return out[:n]
 

@@ -67,9 +67,10 @@ void nat_verify_inputs_idx(void*, void**, const i32*, const i64*, const u8*,
                            const i64*, const i32*, i32, i32, i32*, i32*, i32*,
                            i64*);
 i32 nat_session_uniq_count(void*);
-void nat_session_uniq_lanes(void*, const i32*, i32, u8*, i32*, i32*, i32*, i32*,
-                            i32*, i32*);
-void nat_session_uniq_digests(void*, const u8*, i64, const i32*, i32, u8*);
+void nat_session_uniq_lanes(void*, const i32*, i32, i32, u8*, i32*, i32*, i32*,
+                            i32*, i32*, i32*);
+void nat_session_uniq_digests(void*, const u8*, i64, const i32*, i32, i32,
+                              u8*);
 void nat_session_publish_uniq(void*, const i32*, i32, const i32*);
 i32 nat_session_uniq_host_verify(void*, i32);
 int nat_verify_ecdsa(const u8*, i64, const u8*, i64, const u8*);
@@ -258,12 +259,12 @@ static void target_verify_differential(const uint8_t* d, size_t n) {
         }
         std::vector<u8> fields((size_t)fresh * 128), digests((size_t)fresh * 32);
         std::vector<i32> cols((size_t)fresh * 6);
-        nat_session_uniq_lanes(isess, idxs.data(), fresh, fields.data(),
+        nat_session_uniq_lanes(isess, idxs.data(), fresh, 1, fields.data(),
                                cols.data(), cols.data() + fresh,
                                cols.data() + 2 * fresh, cols.data() + 3 * fresh,
                                cols.data() + 4 * fresh, cols.data() + 5 * fresh);
         nat_session_uniq_digests(isess, spk, (i64)spk_len, idxs.data(), fresh,
-                                 digests.data());
+                                 1, digests.data());
         nat_session_publish_uniq(isess, idxs.data(), fresh, verdicts.data());
         published += fresh;
     }

@@ -21,7 +21,7 @@
 #include <cstdlib>
 #include <cstring>
 #include <memory>
-#include <thread>
+#include <pthread.h>
 
 using namespace nat;
 
@@ -142,6 +142,64 @@ i32 run_verify_input(Session* sess, NTx* tx, i32 n_in, i64 amount,
     return r.ok ? 1 : 0;
 }
 
+// The one fan-out of this file (spawn, join; no pool): fn(t, lo, hi) on
+// worker t of T over the contiguous shards [n*t/T, n*(t+1)/T) of [0, n).
+// Workers get WORKER_STACK bytes, not the 8 MB default: glibc keeps only
+// 40 MB of exited threads' stacks, so from the sixth worker on every
+// spawn mapped a fresh stack and every exit unmapped one (0.3 ms a thread
+// on the chip's host, more than a 600-lane shard's work). Nothing a
+// worker runs recurses or holds more than a few KB of locals. A worker
+// that cannot be made runs on the caller, and so does everything at T < 2.
+constexpr size_t WORKER_STACK = 1 << 20;
+
+template <class Fn>
+void fan_out(i32 n, i32 T, Fn fn) {
+    if (T < 2) {
+        fn(0, 0, n);
+        return;
+    }
+    struct Job {
+        Fn* fn;
+        i32 t, lo, hi;
+        pthread_t id;
+        bool spawned;
+    };
+    std::vector<Job> jobs((size_t)T);
+    pthread_attr_t attr;
+    pthread_attr_init(&attr);
+    pthread_attr_setstacksize(&attr, WORKER_STACK);
+    for (i32 t = 0; t < T; t++) {
+        Job& j = jobs[(size_t)t];
+        j = Job{&fn, t, (i32)((i64)n * t / T), (i32)((i64)n * (t + 1) / T),
+                pthread_t(), false};
+        auto body = [](void* p) -> void* {
+            Job* job = static_cast<Job*>(p);
+            (*job->fn)(job->t, job->lo, job->hi);
+            return nullptr;
+        };
+        j.spawned = pthread_create(&j.id, &attr, body, &j) == 0;
+        if (!j.spawned) fn(j.t, j.lo, j.hi);
+    }
+    pthread_attr_destroy(&attr);
+    for (Job& j : jobs)
+        if (j.spawned) pthread_join(j.id, nullptr);
+}
+
+// Width of the lane-prep fan-out (uniq_lanes, uniq_digests): one shard per
+// PREP_SHARD_MIN entries up to `n_threads`, and no thread at all under
+// PREP_SERIAL_BELOW, where spawn and join cost what the work does (a warm
+// connect's ~400 lanes, a served batch's ~13). Read on the chip's host
+// (PERF.md, PR 30): a worker costs ~0.1 ms to make and join, 512 lanes
+// take 0.7 ms to prep and 0.2 ms to digest, and 1,024 entries on four
+// workers took what they take on one.
+constexpr i32 PREP_SERIAL_BELOW = 1024;
+constexpr i32 PREP_SHARD_MIN = 512;
+
+inline i32 prep_shards(i32 n, i32 n_threads) {
+    if (n < PREP_SERIAL_BELOW) return 1;
+    return std::max(1, std::min(n_threads, n / PREP_SHARD_MIN));
+}
+
 // --- Reference-compatible libbitcoinconsensus ABI -------------------------
 // Drop-in twins of the reference's three exported symbols
 // (bitcoinconsensus.h:67-75): same signatures, same error enum
@@ -205,7 +263,9 @@ extern "C" {
 // 6: apply_block's undo record, nat_view_undo_block.
 // 7: the index-mode session keeps its checks in one arena and its verdicts
 //    by uniq index (same symbols; an older .so is a different structure).
-int nat_version() { return 7; }
+// 8: nat_session_uniq_lanes / nat_session_uniq_digests take n_threads;
+//    nat_prep_shards.
+int nat_version() { return 8; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -519,12 +579,12 @@ inline PartsView lanes_order(const PartsView& v) {
 // Outputs (caller-allocated, only the first n lanes are written):
 //   fields: n*128 bytes — per lane (a | b1 | b2 | px | t1) little-endian
 //   want_odd/parity/has_t2/neg1/neg2/valid: n x i32 each
-void prep_lanes_impl(const std::vector<PartsView>& parts, u8* fields,
-                     i32* want_odd, i32* parity, i32* has_t2, i32* neg1,
-                     i32* neg2, i32* valid) {
+// One shard: lanes parts[0..n) into the first n slots of the outputs.
+void prep_lanes_range(const PartsView* parts, i32 n, u8* fields,
+                      i32* want_odd, i32* parity, i32* has_t2, i32* neg1,
+                      i32* neg2, i32* valid) {
     // Pass 1: parse everything; collect ECDSA (r, s, m) for the batched
     // inversion (jax_backend._batch_inv_mod_n shape: one Fermat total).
-    const i32 n = (i32)parts.size();
     std::vector<Lane> lanes((size_t)n);
     std::vector<i32> ecdsa_idx((size_t)n);
     std::vector<Sc> ecdsa_r((size_t)n);
@@ -641,6 +701,22 @@ void prep_lanes_impl(const std::vector<PartsView>& parts, u8* fields,
     }
 }
 
+// All lanes, sharded over prep_shards(n, n_threads) workers: each runs
+// the three passes over its own contiguous shard (its own inversion
+// chain, exact mod n, so no lane differs from the one-shard run) into
+// its slice of the outputs. No shared mutable state, no merge.
+void prep_lanes_impl(const std::vector<PartsView>& parts, i32 n_threads,
+                     u8* fields, i32* want_odd, i32* parity, i32* has_t2,
+                     i32* neg1, i32* neg2, i32* valid) {
+    const i32 n = (i32)parts.size();
+    fan_out(n, prep_shards(n, n_threads), [&](i32, i32 lo, i32 hi) {
+        prep_lanes_range(parts.data() + lo, hi - lo,
+                         fields + (size_t)lo * 128, want_odd + lo,
+                         parity + lo, has_t2 + lo, neg1 + lo, neg2 + lo,
+                         valid + lo);
+    });
+}
+
 // Wire-shape entry (Python packs blob/offs/kinds; kinds[i]&0xff is the
 // kind, bit 8 the tweak parity).
 void nat_prep_lanes(const u8* blob, const i64* offs, const i32* kinds, i32 n,
@@ -650,7 +726,8 @@ void nat_prep_lanes(const u8* blob, const i64* offs, const i32* kinds, i32 n,
     parts.reserve((size_t)n);
     for (i32 i = 0; i < n; i++)
         parts.push_back(parts_from_wire(blob, offs, kinds, i));
-    prep_lanes_impl(parts, fields, want_odd, parity, has_t2, neg1, neg2,
+    // Serial: the Python packing loop around this entry dominates it.
+    prep_lanes_impl(parts, 1, fields, want_odd, parity, has_t2, neg1, neg2,
                     valid);
 }
 
@@ -924,23 +1001,17 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     i32 T = n_threads;
     std::vector<Session> scratch((size_t)T);
     std::vector<std::vector<i64>> bounds((size_t)T);
-    std::vector<std::thread> workers;
-    workers.reserve((size_t)T);
     for (i32 t = 0; t < T; t++) {
         scratch[t].index_mode = true;
         scratch[t].oracle = sess;
         bounds[t].assign((size_t)n + 1, 0);
-        i32 lo = (i32)((i64)n * t / T);
-        i32 hi = (i32)((i64)n * (t + 1) / T);
-        workers.emplace_back([&, t, lo, hi] {
-            // The scratch session's rec_idx is empty at entry, so the
-            // worker's bounds slots [lo+1, hi] are relative to 0.
-            run_idx_range(&scratch[t], txs, n_ins, amounts, spk_blob,
-                          spk_offs, flags, lo, hi, ok, err, unk,
-                          bounds[t].data());
-        });
     }
-    for (auto& w : workers) w.join();
+    fan_out(n, T, [&](i32 t, i32 lo, i32 hi) {
+        // The scratch session's rec_idx is empty at entry, so the
+        // worker's bounds slots [lo+1, hi] are relative to 0.
+        run_idx_range(&scratch[t], txs, n_ins, amounts, spk_blob, spk_offs,
+                      flags, lo, hi, ok, err, unk, bounds[t].data());
+    });
     // Serial merge in shard order: dedup each scratch's uniq into the
     // shared session (a new entry's bytes are copied once, its hash is
     // the scratch's), remap its rec_idx entries, and lay down global
@@ -996,28 +1067,38 @@ i64 nat_session_recidx_data(void* s, i32* out, i64 capacity) {
     return n;
 }
 
+// How many workers nat_session_uniq_lanes / _digests use for `n` entries
+// (1: the serial path, no thread made) — for the driver's counter.
+i32 nat_prep_shards(i32 n, i32 n_threads) { return prep_shards(n, n_threads); }
+
 // Kernel lanes for uniq[idxs[0..nidx)] — session-resident prep, no wire
-// blob. Output layout identical to nat_prep_lanes.
-void nat_session_uniq_lanes(void* s, const i32* idxs, i32 nidx, u8* fields,
-                            i32* want_odd, i32* parity, i32* has_t2,
-                            i32* neg1, i32* neg2, i32* valid) {
+// blob. Output layout identical to nat_prep_lanes. Sharded over up to
+// `n_threads` workers by lane count (prep_shards); the session is only
+// read.
+void nat_session_uniq_lanes(void* s, const i32* idxs, i32 nidx, i32 n_threads,
+                            u8* fields, i32* want_odd, i32* parity,
+                            i32* has_t2, i32* neg1, i32* neg2, i32* valid) {
     auto* sess = static_cast<Session*>(s);
     std::vector<PartsView> parts;
     parts.reserve((size_t)nidx);
     for (i32 j = 0; j < nidx; j++)
         parts.push_back(lanes_order(uniq_at(sess, idxs[j])));
-    prep_lanes_impl(parts, fields, want_odd, parity, has_t2, neg1, neg2,
-                    valid);
+    prep_lanes_impl(parts, n_threads, fields, want_odd, parity, has_t2, neg1,
+                    neg2, valid);
 }
 
 // Salted cache-key digests for uniq[idxs[0..nidx)] (models/sigcache.py
 // key stream — same bytes nat_digest_checks produces for the wire shape).
+// A digest an entry, independent: sharded as nat_session_uniq_lanes is.
 void nat_session_uniq_digests(void* s, const u8* salt, i64 salt_len,
-                              const i32* idxs, i32 nidx, u8* out) {
+                              const i32* idxs, i32 nidx, i32 n_threads,
+                              u8* out) {
     auto* sess = static_cast<Session*>(s);
-    for (i32 j = 0; j < nidx; j++)
-        digest_one(salt, salt_len, uniq_at(sess, idxs[j]),
-                   out + 32 * (size_t)j);
+    fan_out(nidx, prep_shards(nidx, n_threads), [&](i32, i32 lo, i32 hi) {
+        for (i32 j = lo; j < hi; j++)
+            digest_one(salt, salt_len, uniq_at(sess, idxs[j]),
+                       out + 32 * (size_t)j);
+    });
 }
 
 // Publish device/cache verdicts for uniq[idxs[0..nidx)] into the oracle:

@@ -51,6 +51,7 @@ REQUIRED_METRICS = [
     "consensus_batch_results_total",
     "consensus_fixpoint_rounds",
     "consensus_uniq_checks_total",
+    "consensus_prep_lanes_total",
     # caches
     "consensus_cache_lookups_total",
     "consensus_cache_hits_total",

@@ -197,3 +197,212 @@ def test_build_failure_keeps_the_compilers_stderr(tmp_path, monkeypatch):
     monkeypatch.setenv("CXX", str(tmp_path / "no-such-compiler"))
     assert "did not run" in NB._build()
     assert NB.why_absent() is None  # the loaded core is unaffected
+
+
+# --- Lane prep sharded over threads: the same lanes byte for byte ---------
+#
+# `NativeSession.uniq_lanes` / `uniq_digests` cut their entries into
+# contiguous shards, one worker a shard, from 1,024 entries up. The session
+# below holds 7,800 crafted checks, one an input in input order, so that
+# invalid lanes sit on both sides of every shard boundary any case below
+# cuts and two stretches hold no ECDSA lane (a shard without an inversion
+# chain). A check reaches a session only through the interpreter, which
+# always hands over a 32-byte sighash: a short message cannot be a uniq
+# entry (`test_prep_pack_bit_identical_to_python` covers it on the wire
+# twin, which stays serial).
+
+_PREP_N = 7800
+_PREP_SIZES = (0, 1, 255, 1023, 1024, _PREP_N)
+_PREP_THREADS = (1, 2, 3, 8, 13)
+_NO_ECDSA = (range(512, 1024), range(3000, 3600))  # shard 1 of 2, 5 of 13
+
+
+def _boundary_lanes():
+    """Both lanes at every cut any (n, n_threads) case makes, and each
+    case's first and last lane."""
+    out = set()
+    for n in _PREP_SIZES:
+        for t in _PREP_THREADS:
+            shards = NB.prep_shards(n, t)
+            for k in range(shards + 1):
+                cut = n * k // shards
+                out.update(i for i in (cut - 1, cut) if 0 <= i < n)
+    return out
+
+
+def _der(r: int, s: int) -> bytes:
+    body = H._der_encode_int(r) + H._der_encode_int(s)
+    return b"\x30" + bytes([len(body)]) + body
+
+
+@pytest.fixture(scope="module")
+def prep_session():
+    """(session, indices 0..7799, the kind of every lane)."""
+    import random
+
+    from bitcoinconsensus_tpu.core.flags import (
+        VERIFY_P2SH,
+        VERIFY_TAPROOT,
+        VERIFY_WITNESS,
+    )
+    from bitcoinconsensus_tpu.core.tx import OutPoint, Tx, TxIn, TxOut
+
+    rng = random.Random(30)
+    edge = _boundary_lanes()
+    scalar = lambda: rng.randrange(1, H.N)  # noqa: E731
+    x32 = lambda: rng.randrange(1, H.P).to_bytes(32, "big")  # noqa: E731
+    over = b"\xff" * 32  # >= p and >= n
+    bad = {"ecdsa": 0, "schnorr": 0, "tweak": 0}
+    ntxs, spks, kinds = [], [], []
+    for i in range(_PREP_N):
+        kind = (("ecdsa",) * 10 + ("schnorr", "schnorr", "tweak"))[i % 13]
+        if any(i in r for r in _NO_ECDSA):
+            kind = "schnorr" if i % 2 else "tweak"
+        which = -1
+        if i in edge:
+            which, bad[kind] = bad[kind], bad[kind] + 1
+        script_sig, witness = b"", []
+        if kind == "ecdsa":
+            pub, r, s = b"\x02" + x32(), scalar(), scalar() % (H.N // 2) + 1
+            if i % 97 == 5:
+                s = H.N - s  # high s: normalized, a valid lane
+            if i % 101 == 7:
+                r = rng.randrange(1, 1 << 120)  # r + n < p: has_t2
+            sig = _der(r, s)
+            if which >= 0:
+                pub, sig = [
+                    (b"\x03" + over, sig),  # x >= p
+                    (pub, _der(r, 0)),
+                    (pub, _der(0, s)),
+                    (b"\x04" + x32() + x32(), sig),  # not on the curve
+                    (pub, b"\x30\x00"),  # no DER integers
+                    (pub, _der(H.P - H.N + i, H.N - s)),  # r >= p - n, high s
+                ][which % 6]
+            spk = bytes([len(pub)]) + pub + b"\xac"  # <pub> CHECKSIG
+            sig += b"\x01"
+            script_sig = bytes([len(sig)]) + sig
+        elif kind == "schnorr":
+            q, sig = x32(), x32() + scalar().to_bytes(32, "big")
+            if which >= 0:
+                q, sig = [
+                    (over, sig),  # px >= p
+                    (q, over + sig[32:]),  # r >= p
+                    (q, sig[:32] + over),  # s >= n
+                ][which % 3]
+            spk, witness = b"\x51\x20" + q, [sig]
+        else:
+            internal = over if which >= 0 else x32()
+            spk = b"\x51\x20" + x32()
+            witness = [b"\x51", bytes([0xC0 | (i & 1)]) + internal]  # OP_1
+        prevout = OutPoint(i.to_bytes(32, "little"), 0)
+        tx = Tx(2, [TxIn(prevout, script_sig, witness=witness)],
+                [TxOut(1000, b"\x51")], 0)
+        ntx = NB.NativeTx(tx.serialize())
+        ntx.set_spent_outputs([(5000, spk)])
+        ntxs.append(ntx)
+        spks.append(spk)
+        kinds.append(kind)
+    assert (NB.prep_shards(1024, 8), NB.prep_shards(_PREP_N, 13)) == (2, 13)
+    # every invalid variant is on some edge
+    assert bad["ecdsa"] >= 6 and bad["schnorr"] >= 3 and bad["tweak"] >= 1
+    sess = NB.NativeSession()
+    flags = VERIFY_P2SH | VERIFY_WITNESS | VERIFY_TAPROOT  # lax DER, any s
+    ok, _err, unk, rec_idx, _ = sess.verify_inputs_idx(
+        ntxs, [0] * _PREP_N, [5000] * _PREP_N, spks, [flags] * _PREP_N,
+        n_threads=1,
+    )
+    # one deferred check an input, recorded in input order
+    assert ok.all() and (unk == 1).all() and sess.uniq_count() == _PREP_N
+    assert np.array_equal(rec_idx, np.arange(_PREP_N))
+    return sess, np.arange(_PREP_N, dtype=np.int32), kinds
+
+
+def _lane_bytes(lanes):
+    return [np.ascontiguousarray(a).tobytes() for a in lanes]
+
+
+@pytest.mark.parametrize("n", _PREP_SIZES)
+@pytest.mark.parametrize("n_threads", _PREP_THREADS)
+def test_sharded_prep_is_the_serial_prep_byte_for_byte(prep_session, n_threads, n):
+    sess, idx, kinds = prep_session
+    idx, size = idx[:n], n + 7  # the padding lanes stay as allocated
+    serial = sess.uniq_lanes(idx, size, 1)
+    digests = sess.uniq_digests(b"prep-salt", idx, 1)
+    if n_threads == 1:
+        # the reference itself, against every lane prepped alone (its own
+        # inversion, no batch)
+        alone = [sess.uniq_lanes(idx[j : j + 1], 1) for j in range(n)]
+        pad = sess.uniq_lanes(idx[:0], size - n)
+        for col, whole in enumerate(serial):
+            parts = [a[col] for a in alone] + [pad[col]]
+            assert np.concatenate(parts).tobytes() == np.asarray(whole).tobytes()
+        valid = serial[6][:n]
+        if n == _PREP_N:
+            ecdsa = np.asarray([k == "ecdsa" for k in kinds])
+            edge = sorted(_boundary_lanes())
+            ok_edges = int(valid[edge].sum())  # the "r >= p - n, high s" ones
+            assert 0 < ok_edges <= len(edge) // 6
+            assert valid.sum() == n - len(edge) + ok_edges
+            assert serial[3][:n][ecdsa & valid].any()  # has_t2 lanes exist
+        return
+    assert _lane_bytes(sess.uniq_lanes(idx, size, n_threads)) == _lane_bytes(serial)
+    sharded = sess.uniq_digests(b"prep-salt", idx, n_threads)
+    assert sharded.tobytes() == digests.tobytes() and sharded.shape == (n, 32)
+
+
+class _NoDevice:
+    """What `_dispatch_uniq` needs of a verifier, and no device."""
+
+    lane_capacity = 8192
+
+    def __init__(self):
+        from bitcoinconsensus_tpu.utils.profiling import Phases
+
+        self.phases = Phases()
+        self.launched = []
+
+    def pad(self, n):
+        return max(8, n)
+
+    def dispatch_lanes(self, lanes, n):
+        self.launched.append(n)
+
+
+class _FirstEntries:
+    """A session as the round sees it that discovered its first n checks."""
+
+    def __init__(self, sess, n):
+        self.sess, self.n = sess, n
+
+    def uniq_count(self):
+        return self.n
+
+    def __getattr__(self, name):
+        return getattr(self.sess, name)
+
+
+@pytest.mark.parametrize(
+    "n,threads,mode",
+    [(13, "13", "serial"), (393, "13", "serial"), (1023, "13", "serial"),
+     (1024, "13", "sharded"), (_PREP_N, "13", "sharded"),
+     (_PREP_N, "1", "serial")],
+)
+def test_prep_counter_says_which_path_ran(prep_session, monkeypatch, n, threads, mode):
+    """Under 1,024 lanes (a warm connect, a served batch) or on one thread
+    no worker is made: the counter rises under `serial` alone."""
+    from bitcoinconsensus_tpu.models import batch
+    from bitcoinconsensus_tpu.models.sigcache import SigCache
+
+    sess = _FirstEntries(prep_session[0], n)
+    monkeypatch.setenv("BITCOINCONSENSUS_TPU_THREADS", threads)
+    assert (NB.prep_shards(n, int(threads)) > 1) == (mode == "sharded")
+    before = {m: batch._PREP_LANES.value(mode=m) for m in ("serial", "sharded")}
+    verifier = _NoDevice()
+    grow, raw, pending = batch._dispatch_uniq(
+        sess, verifier, SigCache(), batch._UniqState()
+    )
+    assert len(grow) == n and len(raw) == 32 * n and verifier.launched == [n]
+    other = "serial" if mode == "sharded" else "sharded"
+    assert batch._PREP_LANES.value(mode=mode) - before[mode] == n
+    assert batch._PREP_LANES.value(mode=other) == before[other]
+    assert verifier.phases.report()["host_prep"]["calls"] == 2
