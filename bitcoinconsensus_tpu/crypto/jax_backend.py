@@ -470,7 +470,7 @@ class TpuSecpVerifier:
         # Padded shapes this instance has dispatched: first sight of a
         # shape means one jit compile (or persistent-cache load).
         self._seen_shapes: set = set()
-        self.phases = Phases()  # host_prep / pack / dispatch / sync
+        self.phases = Phases()  # host_prep / pack / backpressure / dispatch / sync
         # Fault containment (resilience/): retry budget + backend
         # quarantine ladder. `_dispatch_level` is the rung the in-flight
         # dispatch runs at (set around each _run_kernel call).
@@ -613,6 +613,7 @@ class TpuSecpVerifier:
                     sub = self._prep_lanes(sub_checks)
                 with self.phases("pack"):
                     args = self._pack_lanes(sub)
+            self._make_room()
             with self.phases("dispatch"):
                 pending.append(
                     (self._dispatch_guarded(args, len(sub_checks)), start,
@@ -678,6 +679,16 @@ class TpuSecpVerifier:
         cleanly settled ticket, so subclass verdict accounting can never
         double-count across retries."""
         self._note_device_verdict(all_ok, ok, needs, ticket.n)
+
+    def _make_room(self) -> None:
+        """The `backpressure` phase: with the in-flight queue at its depth
+        limit, the wait for its oldest ticket(s) to settle, taken before
+        the `dispatch` phase so that `dispatch` stays the launch alone. A
+        round of more chunks than the queue is deep stands here, not at
+        the settle seam (`sync`), for the kernels of its first chunks."""
+        if self._inflight.full:
+            with self.phases("backpressure"):
+                self._inflight.make_room()
 
     def _dispatch_guarded(self, args: Tuple, n: int) -> _inflight.Ticket:
         """Async-dispatch one packed chunk; returns its in-flight ticket
@@ -790,6 +801,7 @@ class TpuSecpVerifier:
         already padded); returns an opaque pending handle for sync_lanes.
         The index-mode driver's seam: lanes are prepped in the native
         session (uniq_lanes) so no SigCheck objects exist on this side."""
+        self._make_room()
         with self.phases("dispatch"):
             return self._dispatch_guarded(args, n)
 

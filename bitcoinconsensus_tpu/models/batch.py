@@ -92,6 +92,16 @@ _FIXPOINT_ROUNDS = _obs_histogram(
     "oracle re-interpretation rounds needed per batch fixpoint",
     buckets=(1, 2, 3, 4, 6, 8, 12, 24),
 )
+_REINTERPRETED = _obs_counter(
+    "consensus_fixpoint_reinterpreted_inputs_total",
+    "inputs interpreted again in a fixpoint round after the first (an "
+    "optimistic guess of theirs came back false)",
+)
+_SPEC_PAIRINGS = _obs_counter(
+    "consensus_multisig_spec_pairings_total",
+    "CHECKMULTISIG (signature, key) pairings pre-recorded ahead of the key "
+    "walk that became deduplicated checks of their own",
+)
 _EXACT_FALLBACK = _obs_counter(
     "consensus_exact_fallback_total",
     "inputs resolved by the exact host checker at the round cap",
@@ -559,6 +569,8 @@ class IdxFixpoint:
         if self._rounds >= self.max_rounds:
             return
         self._rounds += 1
+        if self._rounds > 1:
+            _REINTERPRETED.inc(len(self._pending))
         with _span("batch.interpret", n=len(self._pending)):
             interp = self.run_idx(self._pending)
         with _span("batch.resolve"):
@@ -619,6 +631,7 @@ class IdxFixpoint:
                 break
             self._settle_round()
         _FIXPOINT_ROUNDS.observe(self._rounds)
+        _SPEC_PAIRINGS.inc(self.nsess.spec_pairings())
         if len(self._pending):  # round cap hit: exact host fallback
             _EXACT_FALLBACK.inc(len(self._pending))
         for idx in self._pending.tolist():
@@ -999,7 +1012,9 @@ def _verify_batch_impl(
     def drain_spec() -> List[SigCheck]:
         if nsess is None:
             return []
-        return [SigCheck(k, d) for k, d in nsess.take_spec()]
+        spec = [SigCheck(k, d) for k, d in nsess.take_spec()]
+        _SPEC_PAIRINGS.inc(len(spec))
+        return spec
 
     # Phase 2: sig-cache probe, then one deduplicated device dispatch for
     # every remaining recorded check (sigcache.cpp:101-122 seam). Results
@@ -1094,6 +1109,7 @@ def _verify_batch_impl(
         if not pending:
             break
         rounds += 1
+        _REINTERPRETED.inc(len(pending))
         new_checks: List[SigCheck] = []
         still: List[int] = []
         nat_pending = [i for i in pending if preps[i].ntx is not None]

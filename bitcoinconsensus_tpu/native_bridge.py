@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 8:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 8)")
+        if L.nat_version() < 9:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 9)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -217,6 +217,8 @@ def lib() -> Optional[ctypes.CDLL]:
         ]
         L.nat_session_uniq_count.argtypes = [vp]
         L.nat_session_uniq_count.restype = ctypes.c_int32
+        L.nat_session_spec_pairings.argtypes = [vp]
+        L.nat_session_spec_pairings.restype = ctypes.c_int64
         L.nat_session_recidx_data.argtypes = [vp, i32p, ctypes.c_int64]
         L.nat_session_recidx_data.restype = ctypes.c_int64
         L.nat_session_uniq_lanes.argtypes = [
@@ -794,6 +796,12 @@ class NativeSession:
 
     def uniq_count(self) -> int:
         return int(lib().nat_session_uniq_count(self._ptr))
+
+    def spec_pairings(self) -> int:
+        """CHECKMULTISIG pairings pre-recorded into this session's uniq
+        list so far (index mode; entries a speculation made, not a key
+        walk): monotone over the session's life."""
+        return int(lib().nat_session_spec_pairings(self._ptr))
 
     def uniq_lanes(self, idxs: np.ndarray, size: int, n_threads: int = 1):
         """Packed kernel lanes for the uniq entries `idxs`, padded to

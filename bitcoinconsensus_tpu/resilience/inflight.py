@@ -197,11 +197,22 @@ class InflightQueue:
 
     # -- dispatch side -------------------------------------------------
 
-    def dispatch(self, args, n: int) -> Ticket:
-        """Launch one batch; return its ticket without synchronizing."""
-        while len(self._pending) >= self.max_depth:
+    @property
+    def full(self) -> bool:
+        """At `max_depth`: the next dispatch settles the oldest ticket first."""
+        return len(self._pending) >= self.max_depth
+
+    def make_room(self) -> None:
+        """Backpressure: settle the oldest tickets until one more fits. A
+        caller that times the wait calls this before `dispatch` (the
+        verifier's `backpressure` phase); `dispatch` then finds room."""
+        while self.full:
             _BACKPRESSURE.inc(site=self.site)
             self.settle(self._pending[0])
+
+    def dispatch(self, args, n: int) -> Ticket:
+        """Launch one batch; return its ticket without synchronizing."""
+        self.make_room()
         if self._prepare is not None:
             args, sset = self._prepare(args, n)
         else:

@@ -924,10 +924,12 @@ struct CheckStore {
         u64 hash;
         u32 l0, l1, l2;
         u8 kind, parity, verdict;
+        u8 spec;  // 1: entered by CHECKMULTISIG's pre-recording, not a walk
     };
     std::vector<u8> arena;
     std::vector<Entry> entries;
     std::vector<i32> slots;  // power-of-two table of indices, -1 empty
+    i64 spec_entries = 0;    // entries appended with `spec` set; monotone
 
     size_t size() const { return entries.size(); }
 
@@ -1013,16 +1015,18 @@ struct CheckStore {
         for (size_t i = 0; i < entries.size(); i++) place((i32)i);
     }
 
-    // find, or append `v` (its bytes copied once, verdict unknown).
+    // find, or append `v` (its bytes copied once, verdict unknown, marked
+    // `spec` when a pre-recording and not a key walk brings it).
     // `v` must not point into this store's own arena.
-    i32 intern(u64 h, const PartsView& v) {
+    i32 intern(u64 h, const PartsView& v, u8 spec = 0) {
         i32 at = find(h, v);
         if (at >= 0) return at;
         if ((entries.size() + 1) * 2 > slots.size()) grow();
         at = (i32)entries.size();
         entries.push_back(Entry{(u64)arena.size(), h, (u32)v.l0, (u32)v.l1,
                                 (u32)v.l2, (u8)v.kind, (u8)v.parity,
-                                V_UNKNOWN});
+                                V_UNKNOWN, spec});
+        spec_entries += spec;
         arena.insert(arena.end(), v.p0, v.p0 + v.l0);
         arena.insert(arena.end(), v.p1, v.p1 + v.l1);
         arena.insert(arena.end(), v.p2, v.p2 + v.l2);
@@ -1086,8 +1090,8 @@ struct Session {
 
     // Index mode: the uniq index of an oracle miss, deduped. `at` is what
     // `lookup` found (a recorded, still-unpublished check keeps its index).
-    i32 index_record(u64 h, const PartsView& v, i32 at) {
-        return (at >= 0 && !oracle) ? at : uniq.intern(h, v);
+    i32 index_record(u64 h, const PartsView& v, i32 at, u8 spec = 0) {
+        return (at >= 0 && !oracle) ? at : uniq.intern(h, v, spec);
     }
     // Speculative CHECKMULTISIG pairings: every (sig, key) pair the cursor
     // walk could reach (key-index minus sig-index in [0, nkeys-nsigs]) is
@@ -1216,7 +1220,9 @@ struct Checker {
             // Resolve-only: dedup into uniq WITHOUT emitting a rec_idx
             // entry, so a speculative pair can never affect an
             // optimistic verdict (same contract as the spec vector).
-            sess->index_record(h, v, at);
+            // Marked `spec` where it is new (uniq.spec_entries counts them;
+            // wire mode counts what it drains from `spec`).
+            sess->index_record(h, v, at, 1);
             return;
         }
         if (!sess->spec_seen.insert(Session::key(v)).second) return;

@@ -265,7 +265,8 @@ extern "C" {
 //    by uniq index (same symbols; an older .so is a different structure).
 // 8: nat_session_uniq_lanes / nat_session_uniq_digests take n_threads;
 //    nat_prep_shards.
-int nat_version() { return 8; }
+// 9: nat_session_spec_pairings.
+int nat_version() { return 9; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -1022,7 +1023,8 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
         std::vector<i32> remap(sc.uniq.size());
         for (size_t j = 0; j < sc.uniq.size(); j++)
             remap[j] = sess->uniq.intern(sc.uniq.entries[j].hash,
-                                         sc.uniq.view(j));
+                                         sc.uniq.view(j),
+                                         sc.uniq.entries[j].spec);
         i32 lo = (i32)((i64)n * t / T);
         i32 hi = (i32)((i64)n * (t + 1) / T);
         for (i32 i = lo; i < hi; i++) {
@@ -1036,6 +1038,12 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
 
 i32 nat_session_uniq_count(void* s) {
     return (i32)static_cast<Session*>(s)->uniq.size();
+}
+
+// Pre-recorded CHECKMULTISIG pairings that became uniq entries of this
+// session so far (CheckStore::spec_entries; index mode).
+i64 nat_session_spec_pairings(void* s) {
+    return static_cast<Session*>(s)->uniq.spec_entries;
 }
 
 // A stale or negative uniq index from the driver is an OOB read / heap

@@ -426,6 +426,9 @@ def test_fixpoint_round_cap_exact_fallback():
         def uniq_count(self):
             return 0  # no uniq growth: _resolve_uniq is a no-op
 
+        def spec_pairings(self):
+            return 0
+
     calls = {"rounds": 0, "fallback": []}
     live = [3, 5, 8, 13]
 

@@ -6,8 +6,9 @@ source was renamed reads `null` in the ledger, a chip guard that reads a
 renamed counter guards nothing, and neither fails a test of the program.
 This file makes such a rename fail tier-1 first: one small workload over
 the paths the cells drive (a native connect on fresh and on warm caches, a
-two-block stream that ends in a rollback, a wire-driver dispatch, a served
-request), then a case a name.
+two-block stream that ends in a rollback, a multisig connect of two chunks
+against a queue one deep, a wire-driver dispatch, a served request), then a
+case a name.
 
 It reads `benchmarks/` (the literal lists below must be what its files
 name) and imports nothing from it.
@@ -58,6 +59,8 @@ READ = (
     "consensus_dispatch_new_shapes_total",
     "consensus_dispatch_padded_lanes_total",
     "consensus_dispatch_total",
+    "consensus_fixpoint_reinterpreted_inputs_total",
+    "consensus_multisig_spec_pairings_total",
     "consensus_serving_admitted_total",
     "consensus_serving_batch_fill",
     "consensus_serving_batch_seconds",
@@ -71,6 +74,7 @@ READ = (
 # registered is all a sound run shows of them.
 ZERO = (
     "consensus_backend_config_errors_total",
+    "consensus_exact_fallback_total",
     "consensus_host_fixup_total",
     "consensus_inflight_deadline_expired_total",
     "consensus_inflight_failures_total",
@@ -86,14 +90,15 @@ NO_SAMPLE_NEEDED = ZERO + ("consensus_serving_shed_total",)
 PHASES = (
     "interpret", "host_prep", "pack", "dispatch", "sync", "parse",
     "block_check", "accounting", "probe", "results", "apply", "undo",
-    "publish", "release",
+    "publish", "release", "backpressure",
 )
 
 
-def _block(seed: str, height: int, n: int = 6, corrupt=None):
-    """A raw block of one transaction that spends `n` fresh P2WPKH coins
-    (at most 7 curve checks: the 8-lane rung), and the coins that fund it."""
-    coins, funded = make_funded_view(n, kinds=("p2wpkh",), seed=seed)
+def _block(seed: str, height: int, n: int = 6, corrupt=None, kind="p2wpkh"):
+    """A raw block of one transaction that spends `n` fresh coins of `kind`
+    (P2WPKH: at most 7 curve checks, the 8-lane rung), and the coins that
+    fund it."""
+    coins, funded = make_funded_view(n, kinds=(kind,), seed=seed)
     tx = build_spend_tx(funded, fee=1000, corrupt_input=corrupt)
     return build_block([tx], height, fees=1000).serialize(), coins
 
@@ -120,6 +125,18 @@ def workload():
         [good, bad], to_native_view(coins_a), HEIGHT, depth=2,
         sig_cache=SigCache(), script_cache=ScriptExecutionCache(), **connect))
     assert [r.ok for r in results] == [True, False]
+
+    # three 2-of-3 spends, 12 pre-recorded pairings in two chunks of the
+    # 8-lane rung against a queue one deep: the second chunk waits for the
+    # first (`backpressure`), and every input's first guess is wrong, so a
+    # second round interprets all three again and launches nothing
+    small = TpuSecpVerifier(chunk=8)
+    small.phases, small._inflight.max_depth = verifier.phases, 1
+    raw, coins = _block("contract/multisig", HEIGHT, n=3, kind="p2wsh_multisig")
+    res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        verifier=small, sig_cache=SigCache(),
+                        script_cache=ScriptExecutionCache())
+    assert res.ok and len(res.input_results) == 3
 
     # the wire driver's lane prep, on the interpreter that has no native
     # `prep_pack`: the one place `pack` is a phase of its own
