@@ -408,8 +408,10 @@ class _NativeConnect:
                 return
 
         with phase("accounting"):
+            # With a script cache to probe, the same call makes its keys.
+            salt = self.script_cache._salt if self.check_scripts else None
             (reason, fees, sigop_cost, tx_index, n_in, amounts, spk_offs,
-             spk_blob) = nblk.accounting(coins, self.height, flags)
+             spk_blob) = nblk.accounting(coins, self.height, flags, salt)
             if reason:
                 self.result = ConnectResult(False, reason)
                 return
@@ -419,7 +421,7 @@ class _NativeConnect:
             verifier, script_cache = self.verifier, self.script_cache
             n = self._n = len(tx_index)
             with phase("probe"):
-                raw_keys = nblk.script_keys(script_cache._salt, flags).tobytes()
+                raw_keys = nblk.script_keys().tobytes()
                 if len(script_cache) == 0:  # cold cache: every probe misses
                     hit = np.zeros(n, dtype=bool)
                 else:

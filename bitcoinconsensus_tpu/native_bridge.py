@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 9:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 9)")
+        if L.nat_version() < 10:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 10)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -248,20 +248,22 @@ def lib() -> Optional[ctypes.CDLL]:
             vp, ctypes.POINTER(ctypes.c_void_p), ctypes.c_int32,
         ]
         L.nat_block_tx_ptrs.restype = ctypes.c_int32
+        L.nat_block_nowit_sizes.argtypes = [vp, i64p]
         L.nat_block_txid.argtypes = [vp, ctypes.c_int32, u8p]
         L.nat_block_wtxid.argtypes = [vp, ctypes.c_int32, u8p]
         L.nat_block_check.argtypes = [vp, ctypes.c_int32, u8p, ctypes.c_int32]
         L.nat_block_check.restype = ctypes.c_int32
         L.nat_block_check_witness.argtypes = [vp]
         L.nat_block_check_witness.restype = ctypes.c_int32
-        L.nat_block_accounting.argtypes = [vp, vp, ctypes.c_int64, ctypes.c_int32]
+        L.nat_block_accounting.argtypes = [
+            vp, vp, ctypes.c_int64, ctypes.c_int32, u8p, ctypes.c_int64,
+        ]
         L.nat_block_accounting.restype = ctypes.c_int32
         L.nat_block_acct_meta.argtypes = [vp, i64p, i64p, i64p, i64p]
         L.nat_block_acct_data.argtypes = [vp, i32p, i32p, i64p, i64p, u8p]
         L.nat_block_spent_digests.argtypes = [vp, u8p]
-        L.nat_block_script_keys.argtypes = [
-            vp, u8p, ctypes.c_int64, ctypes.c_int32, u8p,
-        ]
+        L.nat_block_script_keys.argtypes = [vp, u8p]
+        L.nat_block_script_keys.restype = ctypes.c_int64
         L.nat_view_new.restype = vp
         L.nat_view_free.argtypes = [vp]
         L.nat_view_clone.argtypes = [vp]
@@ -1023,6 +1025,13 @@ class NativeBlock:
         lib().nat_block_wtxid(self._ptr, i, _u8p(out))
         return out.tobytes()
 
+    def nowit_sizes(self) -> np.ndarray:
+        """(n_tx,) serialized size of every tx without its witness."""
+        out = np.zeros(max(self.n_tx, 1), dtype=np.int64)
+        lib().nat_block_nowit_sizes(
+            self._ptr, out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        return out[: self.n_tx]
+
     def check(self, check_pow: bool, pow_limit: int, check_merkle: bool = True
               ) -> Optional[str]:
         """Context-free CheckBlock; returns a reject reason or None."""
@@ -1036,14 +1045,24 @@ class NativeBlock:
     def check_witness_commitment(self) -> Optional[str]:
         return BLOCK_REASONS[lib().nat_block_check_witness(self._ptr)]
 
-    def accounting(self, view: "NativeCoinsView", height: int, flags: int):
+    def accounting(self, view: "NativeCoinsView", height: int, flags: int,
+                   salt: Optional[bytes] = None):
         """ConnectBlock accounting (BIP30, existence/maturity/values, fees,
         sigop budget) + per-input script-phase data + per-tx hash
         precompute. Returns (reason|None, fees, sigop_cost, tx_index,
         n_in, amounts, spk_offs, spk_blob) — arrays one entry per
-        non-coinbase input, in block order."""
+        non-coinbase input, in block order. With `salt` (a script
+        execution cache's) it also makes every input's cache key, for
+        `script_keys()` to hand out."""
         L = lib()
-        code = L.nat_block_accounting(self._ptr, view._ptr, height, flags)
+        if salt is None:
+            salt_p, salt_len = None, 0
+        else:
+            salt_a = (np.frombuffer(salt, dtype=np.uint8) if salt
+                      else np.zeros(1, np.uint8))
+            salt_p, salt_len = _u8p(salt_a), len(salt)
+        code = L.nat_block_accounting(
+            self._ptr, view._ptr, height, flags, salt_p, salt_len)
         fees = np.zeros(1, np.int64)
         sigops = np.zeros(1, np.int64)
         n_in_total = np.zeros(1, np.int64)
@@ -1076,17 +1095,15 @@ class NativeBlock:
         lib().nat_block_spent_digests(self._ptr, _u8p(out))
         return out
 
-    def script_keys(self, salt: bytes, flags: int) -> np.ndarray:
+    def script_keys(self) -> np.ndarray:
         """(n_inputs, 32) script-execution-cache keys for every
-        non-coinbase input (byte-identical to ScriptExecutionCache
-        `_key(_parts(...))`; valid after a successful accounting())."""
+        non-coinbase input, under the salt and flags of the successful
+        `accounting(..., salt=...)` call that made them (byte-identical to
+        ScriptExecutionCache `_key(_parts(...))`)."""
         out = np.zeros((self.n_inputs, 32), dtype=np.uint8)
-        salt_a = (
-            np.frombuffer(salt, dtype=np.uint8) if salt else np.zeros(1, np.uint8)
-        )
-        lib().nat_block_script_keys(
-            self._ptr, _u8p(salt_a), len(salt), flags, _u8p(out)
-        )
+        got = lib().nat_block_script_keys(self._ptr, _u8p(out))
+        if got != out.size:
+            raise ValueError("no keys: accounting() was given no salt")
         return out
 
 

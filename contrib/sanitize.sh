@@ -54,6 +54,7 @@ python -m pytest \
     tests/test_native_batch.py \
     tests/test_native_idx.py \
     tests/test_native_block.py \
+    tests/test_native_front.py \
     tests/test_drop_in_abi.py \
     -q "$@"
 echo "sanitize: ASAN+UBSAN clean"

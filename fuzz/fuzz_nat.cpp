@@ -50,7 +50,7 @@ void nat_block_free(void*);
 i32 nat_block_n_tx(void*);
 void nat_block_txid(void*, i32, u8*);
 i32 nat_block_check(void*, i32, const u8*, i32);
-i32 nat_block_accounting(void*, void*, i64, i32);
+i32 nat_block_accounting(void*, void*, i64, i32, const u8*, i64);
 void* nat_view_new();
 void nat_view_free(void*);
 void* nat_session_new();
@@ -118,6 +118,11 @@ static void target_tx_codec(const uint8_t* d, size_t n) {
     }
     std::vector<u8> ser((size_t)sz);
     nat_tx_serialize(tx, 1, ser.data());
+    // ser_size is what the reader consumed: those bytes are the tx.
+    if ((size_t)sz > n || std::memcmp(ser.data(), d, (size_t)sz) != 0) {
+        std::fprintf(stderr, "FUZZ BUG: consumed bytes != serialization\n");
+        std::abort();
+    }
     void* tx2 = nat_tx_parse(ser.data(), sz);
     if (!tx2) {
         std::fprintf(stderr, "FUZZ BUG: reparse of own serialization failed\n");
@@ -151,7 +156,8 @@ static void target_block_codec(const uint8_t* d, size_t n) {
     std::memset(limit, 0xFF, 32);
     nat_block_check(blk, 1, limit, 1);  // must not crash on any shape
     void* view = nat_view_new();
-    nat_block_accounting(blk, view, 500000, (1 << 0) | (1 << 11));
+    const u8 salt[4] = {1, 2, 3, 4};
+    nat_block_accounting(blk, view, 500000, (1 << 0) | (1 << 11), salt, 4);
     nat_view_free(view);
     nat_block_free(blk);
 }

@@ -86,6 +86,7 @@ struct BlockAcct {
     std::vector<i64> spk_offs;   // n_inputs+1 offsets into spk_blob
     Bytes spk_blob;              // spent-output scriptPubKeys
     std::vector<Hash32> spent_digests;  // per tx (coinbase rows zero)
+    Bytes script_keys;  // 32 an input: script-execution-cache keys, if asked
 };
 
 struct NBlock {
@@ -102,9 +103,39 @@ struct NBlock {
     BlockAcct acct;
 };
 
+// The parse's per-transaction stage, from the block's own wire bytes
+// `data` (what the scan read; no tx is serialized again): wtxid = sha256d
+// of the tx's span; a tx read without the witness marker has that digest
+// for its txid too, any other hashes version || vin count .. last output
+// || locktime. Allocates nothing, cannot throw.
+inline void block_hash_txs(NBlock& blk, const u8* data) {
+    for (size_t t = 0; t < blk.vtx.size(); t++) {
+        const NTx& tx = *blk.vtx[t];
+        const u8* span = data + tx.span_lo;
+        size_t n = (size_t)tx.ser_size;
+        sha256d(span, n, blk.wtxids[t].data());
+        if (tx.body_lo == tx.span_lo + 4) {
+            blk.txids[t] = blk.wtxids[t];
+            blk.nowit_size[t] = tx.ser_size;
+            continue;
+        }
+        size_t body = tx.body_hi - tx.body_lo;
+        u8 once[32];
+        Sha256 h;
+        h.write(span, 4);
+        h.write(data + tx.body_lo, body);
+        h.write(span + n - 4, 4);
+        h.finalize(once);
+        sha256(once, 32, blk.txids[t].data());
+        blk.nowit_size[t] = (i64)(body + 8);
+    }
+}
+
 // Block wire parse (primitives/block.h:75-90 / core/block.py
 // Block.deserialize): 80-byte header + compact count + txs; trailing
-// bytes reject. Throws SerErr.
+// bytes reject. Throws SerErr. The scan builds the txs; block_hash_txs
+// then fills the per-tx rows (txids, wtxids, nowit_size) from the wire
+// bytes themselves.
 inline NBlock* block_parse(const u8* data, size_t len) {
     Reader r(data, len);
     auto blk = std::make_unique<NBlock>();
@@ -127,17 +158,7 @@ inline NBlock* block_parse(const u8* data, size_t len) {
     blk->txids.resize(blk->vtx.size());
     blk->wtxids.resize(blk->vtx.size());
     blk->nowit_size.resize(blk->vtx.size());
-    for (size_t i = 0; i < blk->vtx.size(); i++) {
-        Bytes nw = blk->vtx[i]->serialize(false);
-        blk->nowit_size[i] = (i64)nw.size();
-        sha256d(nw.data(), nw.size(), blk->txids[i].data());
-        if (blk->vtx[i]->has_witness()) {
-            Bytes w = blk->vtx[i]->serialize(true);
-            sha256d(w.data(), w.size(), blk->wtxids[i].data());
-        } else {
-            blk->wtxids[i] = blk->txids[i];
-        }
-    }
+    block_hash_txs(*blk, data);
     return blk.release();
 }
 
@@ -449,14 +470,24 @@ inline i64 blk_subsidy(i64 height) {
 }
 
 // ConnectBlock's accounting phases (validation.cpp:2155-2228 /
-// models/validate.py phase 2 + coinbase cap): BIP30 scan, input
-// existence/maturity/value rules, fees, sigop budget, per-input spent
-// outputs. Fills blk.acct (including each tx's hash precompute with its
-// spent outputs — the script phase needs them) and the per-tx
-// spent-output digests (models/sigcache.py spent_digest stream). Does
-// NOT mutate the view.
-inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
-                            u32 flags) {
+// models/validate.py phase 2 + coinbase cap), in two passes. Neither
+// mutates the view.
+//
+// Pass 1, block_acct_decide, holds everything that can fail, in block
+// order: the BIP30 scan, input existence, maturity and value rules, fees,
+// the sigop budget, the coinbase cap. It gathers each tx's spent outputs
+// once (`spent`, per tx, one an input) and sizes blk.acct's per-input
+// arrays. A block it refuses is left as it was but for a cleared blk.acct.
+//
+// Pass 2, block_acct_fill, only hashes and fills: the per-input records,
+// the spent-output digest (models/sigcache.py spent_digest stream), each
+// tx's hash precompute with its spent outputs moved in (the script phase
+// needs them) and, given a salt, the script-execution-cache key of every
+// input. Allocates nothing, cannot throw.
+using SpentOutputs = std::vector<std::vector<NTxOut>>;
+
+inline i32 block_acct_decide(NBlock& blk, const NView& view, i64 height,
+                             u32 flags, bool with_keys, SpentOutputs& all) {
     BlockAcct& A = blk.acct;
     A = BlockAcct();
     // The production driver runs check_block first (which rejects empty
@@ -464,23 +495,23 @@ inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
     // reachable through the C ABI — the coinbase-cap read below must not
     // index an empty vtx (found by fuzz/fuzz_nat.cpp on its seed corpus).
     if (blk.vtx.empty()) return BR_BAD_LENGTH;
+    size_t n_tx = blk.vtx.size();
     std::unordered_map<std::string, NCoin> overlay;
     std::unordered_set<std::string> spent_keys;
 
     // BIP30 against the start-of-block view.
-    for (size_t t = 0; t < blk.vtx.size(); t++)
+    for (size_t t = 0; t < n_tx; t++)
         for (u32 n = 0; n < blk.vtx[t]->vout.size(); n++)
             if (view.map.count(NView::key(blk.txids[t].data(), n)))
                 return BR_BIP30;
 
-    A.spk_offs.push_back(0);
-    A.spent_digests.resize(blk.vtx.size());
-    for (auto& d : A.spent_digests) d.fill(0);
-
-    for (size_t t = 0; t < blk.vtx.size(); t++) {
-        NTx& tx = *blk.vtx[t];
+    all.assign(n_tx, {});
+    size_t n_in = 0, spk_bytes = 0;
+    std::vector<const NTxOut*> sp;
+    for (size_t t = 0; t < n_tx; t++) {
+        const NTx& tx = *blk.vtx[t];
         bool cb = tx_is_coinbase(tx);
-        std::vector<NTxOut> spent;
+        std::vector<NTxOut>& spent = all[t];
         if (!cb) {
             spent.reserve(tx.vin.size());
             i64 value_in = 0;
@@ -504,6 +535,7 @@ inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
                 value_in += coin->value;
                 if (value_in > BLK_MAX_MONEY) return BR_INPUTVALUES_OUTOFRANGE;
                 spent.push_back(NTxOut{coin->value, coin->spk});
+                spk_bytes += coin->spk.size();
                 spent_keys.insert(std::move(k));
             }
             i64 value_out = 0;
@@ -512,37 +544,11 @@ inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
             A.fees += value_in - value_out;
             if (A.fees < 0 || A.fees > BLK_MAX_MONEY) return BR_FEE_OUTOFRANGE;
         }
-        {
-            std::vector<const NTxOut*> sp;
-            sp.reserve(spent.size());
-            for (const auto& s : spent) sp.push_back(&s);
-            A.sigop_cost += tx_sigop_cost(tx, sp, flags);
-        }
+        sp.clear();
+        for (const auto& s : spent) sp.push_back(&s);
+        A.sigop_cost += tx_sigop_cost(tx, sp, flags);
         if (A.sigop_cost > BLK_MAX_SIGOPS_COST) return BR_BLK_SIGOPS;
-        if (!cb) {
-            // Record the script phase's per-input data + the tx's hash
-            // precompute + the spent digest (sigcache.py spent_digest:
-            // per output amt 8LE || len(spk) 4LE || spk).
-            Sha256 h;
-            for (size_t i = 0; i < tx.vin.size(); i++) {
-                A.tx_index.push_back((i32)t);
-                A.n_in.push_back((i32)i);
-                A.amounts.push_back(spent[i].value);
-                A.spk_blob.insert(A.spk_blob.end(), spent[i].spk.begin(),
-                                  spent[i].spk.end());
-                A.spk_offs.push_back((i64)A.spk_blob.size());
-                u8 le[8];
-                u64 v = (u64)spent[i].value;
-                for (int j = 0; j < 8; j++) le[j] = u8(v >> (8 * j));
-                h.write(le, 8);
-                u32 sl = (u32)spent[i].spk.size();
-                u8 l4[4] = {u8(sl), u8(sl >> 8), u8(sl >> 16), u8(sl >> 24)};
-                h.write(l4, 4);
-                h.write(spent[i].spk.data(), spent[i].spk.size());
-            }
-            h.finalize(A.spent_digests[t].data());
-            precompute(tx, &spent);
-        }
+        n_in += spent.size();
         // Overlay this tx's outputs for later txs of the same block.
         for (u32 n = 0; n < tx.vout.size(); n++)
             overlay[NView::key(blk.txids[t].data(), n)] =
@@ -552,7 +558,75 @@ inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
     i64 cb_out = 0;
     for (const auto& out : blk.vtx[0]->vout) cb_out += out.value;
     if (cb_out > A.fees + blk_subsidy(height)) return BR_CB_AMOUNT;
-    A.ready = true;
+
+    A.tx_index.resize(n_in);
+    A.n_in.resize(n_in);
+    A.amounts.resize(n_in);
+    A.spk_offs.resize(n_in + 1);
+    A.spk_blob.resize(spk_bytes);
+    A.spent_digests.resize(n_tx);  // value-initialized: coinbase rows zero
+    if (with_keys) A.script_keys.resize(32 * n_in);
+    return BR_OK;
+}
+
+inline void block_acct_fill(NBlock& blk, SpentOutputs& all, u32 flags,
+                            const u8* salt, size_t salt_len) {
+    BlockAcct& A = blk.acct;
+    size_t j = 0;  // the input's row
+    i64 at = 0;    // its scriptPubKey's offset in spk_blob
+    for (size_t t = 0; t < blk.vtx.size(); t++) {
+        NTx& tx = *blk.vtx[t];
+        if (tx_is_coinbase(tx)) continue;
+        std::vector<NTxOut>& spent = all[t];
+        size_t first = j;
+        // sigcache.py spent_digest: per output amt 8LE || len(spk) 4LE || spk.
+        Sha256 h;
+        for (size_t i = 0; i < spent.size(); i++, j++) {
+            const Bytes& spk = spent[i].spk;
+            A.tx_index[j] = (i32)t;
+            A.n_in[j] = (i32)i;
+            A.amounts[j] = spent[i].value;
+            A.spk_offs[j] = at;
+            if (!spk.empty())
+                std::memcpy(A.spk_blob.data() + at, spk.data(), spk.size());
+            at += (i64)spk.size();
+            hash_i64(h, spent[i].value);
+            hash_part(h, spk.data(), (u32)spk.size());
+        }
+        u8* digest = A.spent_digests[t].data();
+        h.finalize(digest);
+        if (!A.script_keys.empty()) {
+            // sha256(salt || wtxid, n_in 4LE, flags 4LE, digest as parts):
+            // one midstate a tx, past the salt and the wtxid.
+            Sha256 head;
+            head.write(salt, salt_len);
+            hash_part(head, blk.wtxids[t].data(), 32);
+            for (u32 i = 0; i < spent.size(); i++) {
+                Sha256 k = head;
+                hash_u32(k, 4);  // a part goes in as len 4LE || bytes
+                hash_u32(k, i);
+                hash_u32(k, 4);
+                hash_u32(k, flags);
+                hash_part(k, digest, 32);
+                k.finalize(A.script_keys.data() + 32 * (first + i));
+            }
+        }
+        tx.precomp = Precomp();
+        tx.precomp.spent_outputs = std::move(spent);
+        tx.precomp.spent_ready = true;
+        precompute_hashes(tx);
+    }
+    A.spk_offs[j] = at;
+}
+
+// Both passes. `salt` (the script-execution cache's) NULL makes no key.
+inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
+                            u32 flags, const u8* salt, size_t salt_len) {
+    SpentOutputs spent;
+    i32 r = block_acct_decide(blk, view, height, flags, salt != nullptr, spent);
+    if (r != BR_OK) return r;
+    block_acct_fill(blk, spent, flags, salt, salt_len);
+    blk.acct.ready = true;
     return BR_OK;
 }
 

@@ -270,6 +270,29 @@ inline void put_string(Bytes& b, const Bytes& s) {
     put_bytes(b, s);
 }
 
+// The same encodings written straight into a hash.
+inline void hash_u32(Sha256& h, u32 v) {
+    u8 b[4] = {u8(v), u8(v >> 8), u8(v >> 16), u8(v >> 24)};
+    h.write(b, 4);
+}
+inline void hash_i64(Sha256& h, i64 v) {
+    u8 b[8];
+    for (int i = 0; i < 8; i++) b[i] = u8((u64)v >> (8 * i));
+    h.write(b, 8);
+}
+// A cache key's part (models/sigcache.py `_key`): len 4LE || bytes.
+inline void hash_part(Sha256& h, const u8* p, size_t len) {
+    hash_u32(h, (u32)len);
+    h.write(p, len);
+}
+inline void hash_compact_size(Sha256& h, u64 n) {
+    u8 b[9];
+    size_t k = n < 253 ? 0 : n <= 0xFFFF ? 2 : n <= 0xFFFFFFFFull ? 4 : 8;
+    b[0] = k == 0 ? u8(n) : k == 2 ? 0xFD : k == 4 ? 0xFE : 0xFF;
+    for (size_t i = 0; i < k; i++) b[1 + i] = u8(n >> (8 * i));
+    h.write(b, 1 + k);
+}
+
 // --------------------------------------------------------------------------
 // Transaction
 
@@ -293,6 +316,12 @@ struct NTxOut {
     }
 };
 
+inline void hash_txout(Sha256& h, const NTxOut& out) {
+    hash_i64(h, out.value);
+    hash_compact_size(h, out.spk.size());
+    h.write(out.spk.data(), out.spk.size());
+}
+
 struct Precomp {
     bool ready = false;
     bool spent_ready = false;
@@ -310,7 +339,13 @@ struct NTx {
     std::vector<NTxIn> vin;
     std::vector<NTxOut> vout;
     u32 locktime;
-    i64 ser_size;  // re-serialized size incl. witness (for the size check)
+    // The wire bytes this tx was read from, as offsets into the reader's
+    // data: [span_lo, span_lo + ser_size) is the whole tx and, being what
+    // a canonical reader consumed, equals serialize(true); [body_lo,
+    // body_hi) runs from the vin count to the last output, so version ||
+    // body || locktime equals serialize(false).
+    size_t span_lo, body_lo, body_hi;
+    i64 ser_size;  // serialized size incl. witness (for the size check)
     Precomp precomp;
 
     bool has_witness() const {
@@ -357,8 +392,10 @@ struct NTx {
 // tiny malformed tx cannot demand a multi-GB allocation.
 inline NTx* tx_parse_from(Reader& r) {
     auto tx = std::make_unique<NTx>();
+    tx->span_lo = r.pos;
     tx->version = r.read_i32();
     u8 flags = 0;
+    tx->body_lo = r.pos;
     u64 n_vin = r.read_compact_size();
     auto read_txin = [&](NTxIn& in) {
         const u8* h = r.read(32);
@@ -384,12 +421,14 @@ inline NTx* tx_parse_from(Reader& r) {
     if (tx->vin.empty()) {
         flags = r.read_u8();
         if (flags != 0) {
+            tx->body_lo = r.pos;  // past the marker and the flag byte
             read_vin(r.read_compact_size());
             read_vout(r.read_compact_size());
         }
     } else {
         read_vout(r.read_compact_size());
     }
+    tx->body_hi = r.pos;
     if (flags & 1) {
         flags ^= 1;
         bool any = false;
@@ -402,7 +441,9 @@ inline NTx* tx_parse_from(Reader& r) {
     }
     if (flags) throw SerErr("Unknown transaction optional data");
     tx->locktime = r.read_u32();
-    tx->ser_size = (i64)tx->serialize(true).size();
+    // The reader refuses a non-canonical CompactSize and a witness record
+    // with no witness in it, so what it consumed is serialize(true).
+    tx->ser_size = (i64)(r.pos - tx->span_lo);
     return tx.release();
 }
 
@@ -713,19 +754,13 @@ inline void legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
     sha256d(s.data(), s.size(), out);
 }
 
-// Compute the tx-wide single-SHA aggregates + BIP143 doubles; spent
-// aggregates when spent outputs are registered (interpreter.cpp:1422-1472).
-inline void precompute(NTx& tx, const std::vector<NTxOut>* spent) {
+// The tx-wide single-SHA aggregates + BIP143 doubles of a Precomp whose
+// spent outputs, if any, are already in place (spent_ready); spent
+// aggregates when they are (interpreter.cpp:1422-1472). Streams every
+// field into its hash: no buffer, no allocation, cannot throw.
+inline void precompute_hashes(NTx& tx) {
     Precomp& pc = tx.precomp;
-    pc = Precomp();
     pc.ready = true;
-    // A prevout list is only usable when it has exactly one entry per
-    // input (interpreter.cpp:1512 readiness contract); a wrong-length
-    // list is ignored rather than indexed out of bounds.
-    if (spent && spent->size() == tx.vin.size()) {
-        pc.spent_outputs = *spent;
-        pc.spent_ready = true;
-    }
     bool uses143 = false, uses341 = false;
     for (size_t i = 0; i < tx.vin.size(); i++) {
         if (uses143 && uses341) break;
@@ -737,21 +772,16 @@ inline void precompute(NTx& tx, const std::vector<NTxOut>* spent) {
         }
     }
     if (uses143 || uses341) {
-        Bytes b;
+        Sha256 prevouts, sequences, outputs;
         for (const auto& in : tx.vin) {
-            b.insert(b.end(), in.prevout_hash, in.prevout_hash + 32);
-            put_u32(b, in.prevout_n);
+            prevouts.write(in.prevout_hash, 32);
+            hash_u32(prevouts, in.prevout_n);
+            hash_u32(sequences, in.sequence);
         }
-        sha256(b.data(), b.size(), pc.prevouts_single);
-        b.clear();
-        for (const auto& in : tx.vin) put_u32(b, in.sequence);
-        sha256(b.data(), b.size(), pc.sequences_single);
-        b.clear();
-        for (const auto& out : tx.vout) {
-            put_i64(b, out.value);
-            put_string(b, out.spk);
-        }
-        sha256(b.data(), b.size(), pc.outputs_single);
+        for (const auto& out : tx.vout) hash_txout(outputs, out);
+        prevouts.finalize(pc.prevouts_single);
+        sequences.finalize(pc.sequences_single);
+        outputs.finalize(pc.outputs_single);
     }
     if (uses143) {
         sha256(pc.prevouts_single, 32, pc.hash_prevouts);
@@ -760,14 +790,30 @@ inline void precompute(NTx& tx, const std::vector<NTxOut>* spent) {
         pc.bip143_ready = true;
     }
     if (uses341 && pc.spent_ready) {
-        Bytes b;
-        for (const auto& out : pc.spent_outputs) put_i64(b, out.value);
-        sha256(b.data(), b.size(), pc.spent_amounts_single);
-        b.clear();
-        for (const auto& out : pc.spent_outputs) put_string(b, out.spk);
-        sha256(b.data(), b.size(), pc.spent_scripts_single);
+        Sha256 amounts, scripts;
+        for (const auto& out : pc.spent_outputs) {
+            hash_i64(amounts, out.value);
+            hash_compact_size(scripts, out.spk.size());
+            scripts.write(out.spk.data(), out.spk.size());
+        }
+        amounts.finalize(pc.spent_amounts_single);
+        scripts.finalize(pc.spent_scripts_single);
         pc.bip341_ready = true;
     }
+}
+
+// Precompute from scratch, registering a copy of `spent` when given.
+inline void precompute(NTx& tx, const std::vector<NTxOut>* spent) {
+    Precomp& pc = tx.precomp;
+    pc = Precomp();
+    // A prevout list is only usable when it has exactly one entry per
+    // input (interpreter.cpp:1512 readiness contract); a wrong-length
+    // list is ignored rather than indexed out of bounds.
+    if (spent && spent->size() == tx.vin.size()) {
+        pc.spent_outputs = *spent;
+        pc.spent_ready = true;
+    }
+    precompute_hashes(tx);
 }
 
 inline void bip143_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,

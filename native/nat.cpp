@@ -266,7 +266,9 @@ extern "C" {
 // 8: nat_session_uniq_lanes / nat_session_uniq_digests take n_threads;
 //    nat_prep_shards.
 // 9: nat_session_spec_pairings.
-int nat_version() { return 9; }
+// 10: nat_block_accounting takes the script cache's salt and makes the
+//     keys; nat_block_script_keys copies them out; nat_block_nowit_sizes.
+int nat_version() { return 10; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -321,6 +323,14 @@ void nat_block_wtxid(void* b, i32 i, u8* out32) {
     std::memcpy(out32, blk->wtxids[(size_t)i].data(), 32);
 }
 
+// Per-tx serialized sizes without witness (the weight rule's base size).
+// out: n_tx entries.
+void nat_block_nowit_sizes(void* b, i64* out) {
+    const std::vector<i64>& sizes = static_cast<NBlock*>(b)->nowit_size;
+    if (!sizes.empty())
+        std::memcpy(out, sizes.data(), sizes.size() * sizeof(i64));
+}
+
 // Context-free CheckBlock; returns a BlkReason code (0 = ok).
 i32 nat_block_check(void* b, i32 do_pow, const u8* pow_limit_be,
                     i32 do_merkle) {
@@ -332,9 +342,13 @@ i32 nat_block_check_witness(void* b) {
     return check_witness_commitment(*static_cast<NBlock*>(b));
 }
 
-i32 nat_block_accounting(void* b, void* v, i64 height, i32 flags) {
-    return block_accounting(*static_cast<NBlock*>(b),
-                            *static_cast<NView*>(v), height, (u32)flags);
+// ConnectBlock accounting. With a salt (the script-execution cache's) it
+// also makes every input's cache key, for nat_block_script_keys to hand
+// out; `salt` NULL makes none.
+i32 nat_block_accounting(void* b, void* v, i64 height, i32 flags,
+                         const u8* salt, i64 salt_len) {
+    return block_accounting(*static_cast<NBlock*>(b), *static_cast<NView*>(v),
+                            height, (u32)flags, salt, (size_t)salt_len);
 }
 
 void nat_block_acct_meta(void* b, i64* fees, i64* sigop_cost, i64* n_inputs,
@@ -368,33 +382,15 @@ void nat_block_spent_digests(void* b, u8* out) {
         std::memcpy(out + 32 * t, A.spent_digests[t].data(), 32);
 }
 
-// Script-execution-cache keys for every non-coinbase input (valid after
-// accounting): the models/sigcache.py `_key(_parts(wtxid, n_in, flags,
-// spent_digest))` stream — sha256(salt || [len(part) 4LE || part]...)
-// with parts (wtxid32, n_in 4LE, flags 4LE, digest32). out: n_inputs*32.
-void nat_block_script_keys(void* b, const u8* salt, i64 salt_len, i32 flags,
-                           u8* out) {
-    auto* blk = static_cast<NBlock*>(b);
-    const BlockAcct& A = blk->acct;
-    auto part = [](Sha256& h, const u8* p, u32 len) {
-        u8 lb[4] = {u8(len), u8(len >> 8), u8(len >> 16), u8(len >> 24)};
-        h.write(lb, 4);
-        h.write(p, len);
-    };
-    u8 f4[4] = {u8(flags), u8(flags >> 8), u8(flags >> 16), u8(flags >> 24)};
-    // One midstate per (salt); wtxid/digest swap per tx.
-    for (size_t j = 0; j < A.tx_index.size(); j++) {
-        i32 t = A.tx_index[j];
-        Sha256 h;
-        h.write(salt, (size_t)salt_len);
-        part(h, blk->wtxids[(size_t)t].data(), 32);
-        i32 n = A.n_in[j];
-        u8 n4[4] = {u8(n), u8(n >> 8), u8(n >> 16), u8(n >> 24)};
-        part(h, n4, 4);
-        part(h, f4, 4);
-        part(h, A.spent_digests[(size_t)t].data(), 32);
-        h.finalize(out + 32 * j);
-    }
+// Script-execution-cache keys for every non-coinbase input, as the
+// accounting call made them from its salt and flags: the models/sigcache.py
+// `_key(_parts(wtxid, n_in, flags, spent_digest))` stream. out: n_inputs*32.
+// Returns the bytes written (0: accounting ran without a salt, or not at
+// all).
+i64 nat_block_script_keys(void* b, u8* out) {
+    const Bytes& keys = static_cast<NBlock*>(b)->acct.script_keys;
+    if (!keys.empty()) std::memcpy(out, keys.data(), keys.size());
+    return (i64)keys.size();
 }
 
 void* nat_view_new() { return new NView(); }
@@ -814,11 +810,7 @@ void digest_one(const u8* salt, i64 salt_len, const PartsView& pv, u8* out32) {
         std::fprintf(stderr, "digest_one: bad kind %d\n", pv.kind);
         std::abort();
     }
-    auto part = [&h](const u8* p, size_t len) {
-        u8 lb[4] = {u8(len), u8(len >> 8), u8(len >> 16), u8(len >> 24)};
-        h.write(lb, 4);
-        h.write(p, len);
-    };
+    auto part = [&h](const u8* p, size_t len) { hash_part(h, p, len); };
     const char* name = NAMES[pv.kind];
     part(reinterpret_cast<const u8*>(name), std::strlen(name));
     part(pv.p0, (size_t)pv.l0);
@@ -850,10 +842,8 @@ void nat_digest_streams(const u8* salt, i64 salt_len, i32 n,
         Sha256 h;
         h.write(salt, (size_t)salt_len);
         for (i64 j = part_bounds[i]; j < part_bounds[i + 1]; j++) {
-            size_t len = (size_t)(part_offs[j + 1] - part_offs[j]);
-            u8 lb[4] = {u8(len), u8(len >> 8), u8(len >> 16), u8(len >> 24)};
-            h.write(lb, 4);
-            h.write(blob + part_offs[j], len);
+            hash_part(h, blob + part_offs[j],
+                      (size_t)(part_offs[j + 1] - part_offs[j]));
         }
         h.finalize(out + 32 * (size_t)i);
     }

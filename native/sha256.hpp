@@ -209,6 +209,7 @@ struct Sha256 {
     }
 
     Sha256& write(const u8* data, size_t len) {
+        if (!len) return *this;  // an empty vector's data() may be null
         size_t fill = bytes % 64;
         bytes += len;
         if (fill) {
@@ -231,13 +232,11 @@ struct Sha256 {
 
     void finalize(u8 out[32]) {
         u64 msgbits = bytes * 8;
-        u8 pad = 0x80;
-        write(&pad, 1);
-        u8 zero = 0;
-        while (bytes % 64 != 56) write(&zero, 1);
-        u8 lenb[8];
-        for (int i = 0; i < 8; i++) lenb[i] = u8(msgbits >> (56 - 8 * i));
-        write(lenb, 8);
+        size_t fill = bytes % 64;
+        size_t zeros = (fill < 56 ? 56 : 120) - fill;  // 0x80 included
+        u8 pad[72] = {0x80};
+        for (int i = 0; i < 8; i++) pad[zeros + i] = u8(msgbits >> (56 - 8 * i));
+        write(pad, zeros + 8);
         for (int i = 0; i < 8; i++) {
             out[4 * i] = u8(s[i] >> 24);
             out[4 * i + 1] = u8(s[i] >> 16);
