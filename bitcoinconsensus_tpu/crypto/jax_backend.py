@@ -470,7 +470,7 @@ class TpuSecpVerifier:
         # Padded shapes this instance has dispatched: first sight of a
         # shape means one jit compile (or persistent-cache load).
         self._seen_shapes: set = set()
-        self.phases = Phases()  # host_prep / pack / backpressure / dispatch / sync
+        self.phases = Phases()  # host_prep / pack / backpressure / dispatch / sync (mesh: + shard_layout / shard_check)
         # Fault containment (resilience/): retry budget + backend
         # quarantine ladder. `_dispatch_level` is the rung the in-flight
         # dispatch runs at (set around each _run_kernel call).

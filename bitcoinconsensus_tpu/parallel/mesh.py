@@ -31,6 +31,14 @@ for re-promotion. Per-shard stragglers have their own deadline
 (`BITCOINCONSENSUS_TPU_SHARD_DEADLINE_S`), distinct from the whole-ticket
 deadline of the in-flight queue.
 
+Telemetry of its own: phases `shard_layout` (the layout and sentinel install
+of `_prepare_ticket`, inside `dispatch`) and `shard_check` (what a settle
+does once the verdict buffer is on the host, inside `sync` or
+`backpressure`) on `verifier.phases`; spans `mesh.dispatch` and
+`mesh.settle` (shards, lanes, epoch); `consensus_mesh_dispatch_total` by
+the `kernel` every shard ran (`shard_kernel`); and the jitted step's stable
+program name, `jit_mesh_verify_tiles` on a TPU mesh.
+
 Multi-host: the same mesh spec over `jax.devices()` spanning hosts rides
 ICI/DCN transparently through pjit — no NCCL/MPI translation layer exists or
 is needed.
@@ -67,7 +75,7 @@ from ..resilience import guards as _guards
 from ..ops.regions import region_scope
 from ..resilience.inflight import settle_array
 
-__all__ = ["make_mesh", "ShardedSecpVerifier", "make_sharded_step"]
+__all__ = ["make_mesh", "ShardedSecpVerifier", "make_sharded_step", "shard_kernel"]
 
 # Mesh telemetry — host-side driver accounting only; `local_step` below is
 # traced and must stay instrumentation-free.
@@ -75,7 +83,10 @@ _MESH_DEVICES = _obs_gauge(
     "consensus_mesh_devices", "devices in the sharded verifier's mesh"
 )
 _MESH_DISPATCH = _obs_counter(
-    "consensus_mesh_dispatch_total", "sharded (multi-chip) dispatches"
+    "consensus_mesh_dispatch_total",
+    "sharded (multi-chip) dispatches, by the kernel every shard ran "
+    "(`shard_kernel`: pallas, or xla where the tile does not divide the shard)",
+    ("kernel",),
 )
 _MESH_SHARD_LANES = _obs_histogram(
     "consensus_mesh_shard_lanes",
@@ -97,6 +108,11 @@ _MESH_REPROMOTIONS = _obs_counter(
     "consensus_mesh_repromotions_total",
     "evicted devices re-promoted into the mesh after a clean probe",
     ("device",),
+)
+_MESH_VERDICT_MISMATCH = _obs_counter(
+    "consensus_mesh_verdict_mismatch_total",
+    "cleanly settled mesh dispatches whose replicated psum verdict differed "
+    "from the AND of their settled real lanes",
 )
 _MESH_REDISPATCH_LANES = _obs_counter(
     "consensus_mesh_redispatch_lanes_total",
@@ -132,21 +148,32 @@ def make_mesh(
     return Mesh(np.asarray(devices), (axis,))
 
 
+def shard_kernel(use_pallas: bool, shard_rows: int) -> str:
+    """The kernel a shard of `shard_rows` lanes runs, "pallas" or "xla":
+    the SAME backend selection as TpuSecpVerifier._run_kernel, applied to
+    the shard-local batch (so a multi-chip deployment dispatches the Pallas
+    production kernel on each chip; CPU meshes and tile-indivisible shards
+    fall back to XLA). The traced step and the dispatch counter's `kernel`
+    label both ask here, so the label cannot drift from what ran."""
+    if use_pallas:
+        from ..ops.pallas_kernel import LANE_TILE
+
+        if shard_rows % LANE_TILE == 0:
+            return "pallas"
+    return "xla"
+
+
 def _pick_backend(use_pallas: bool):
-    """Per-shard kernel: the SAME backend selection as
-    TpuSecpVerifier._run_kernel, applied to the shard-local batch (so a
-    multi-chip deployment dispatches the Pallas production kernel on each
-    chip; CPU meshes and tile-indivisible shards fall back to XLA)."""
+    """Per-shard kernel by `shard_kernel`."""
 
     def local_kernel(fields, want_odd, parity_req, has_t2, neg1, neg2, valid):
-        if use_pallas:
-            from ..ops.pallas_kernel import LANE_TILE, verify_tiles
+        # Shard-local shapes are static at trace time inside shard_map.
+        if shard_kernel(use_pallas, fields.shape[0]) == "pallas":
+            from ..ops.pallas_kernel import verify_tiles
 
-            # Shard-local shapes are static at trace time inside shard_map.
-            if fields.shape[0] % LANE_TILE == 0:
-                return verify_tiles(
-                    fields, want_odd, parity_req, has_t2, neg1, neg2, valid
-                )
+            return verify_tiles(
+                fields, want_odd, parity_req, has_t2, neg1, neg2, valid
+            )
         ok = _verify_kernel(
             fields, want_odd, parity_req, has_t2, neg1, neg2, valid
         )
@@ -216,8 +243,17 @@ def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
         out_specs=(P(axis), P(axis), P(), P(axis), P(axis)),
         check_vma=False,
     )
+
+    def step(*args):
+        return sharded(*args)
+
+    # The program's name in a profiler trace (`XLA Modules`: `jit_<name>`),
+    # after the kernel the shards run where the tile divides them.
+    step.__name__ = step.__qualname__ = (
+        "mesh_verify_tiles" if use_pallas else "mesh__verify_kernel"
+    )
     return jax.jit(
-        sharded,
+        step,
         in_shardings=(fields_sharding,) + (flat_sharding,) * 7,
         out_shardings=(
             flat_sharding, flat_sharding, replicated,
@@ -238,13 +274,21 @@ def _shard_positions(n: int, shard_size: int) -> np.ndarray:
     return (idx // cap) * shard_size + (idx % cap)
 
 
+def _shard_fill(n: int, shard_size: int, n_shards: int) -> list:
+    """Real lanes each shard holds under the scatter layout: full shards
+    of `shard_size - 1` first, then the remainder, then empty ones."""
+    cap = shard_size - 1
+    return [min(max(n - s * cap, 0), cap) for s in range(n_shards)]
+
+
 class _ShardLayout:
     """Settle context of one scattered mesh dispatch (rides ticket.sset).
 
     `positions` maps real-lane order to global rows; `ssets` holds one
     single-lane SentinelSet per shard (local position S-1) for per-shard
     checking, and `flat_sset` the same sentinels as one global set for
-    the quarantined single-device fallback path. `epoch` pins the mesh
+    the quarantined single-device fallback path; `live` marks the real
+    lanes' rows (the psum verdict counts no other). `epoch` pins the mesh
     generation the layout was built for: after an eviction rebuilds the
     mesh, stale layouts are no longer shard-aligned and relaunch on the
     single-device rung instead. `deadline_armed` is False for
@@ -253,17 +297,18 @@ class _ShardLayout:
     """
 
     __slots__ = (
-        "n", "padded", "n_shards", "shard_size", "positions", "ssets",
-        "flat_sset", "epoch", "deadline_armed",
+        "n", "padded", "n_shards", "shard_size", "positions", "live",
+        "ssets", "flat_sset", "epoch", "deadline_armed",
     )
 
-    def __init__(self, n, padded, n_shards, shard_size, positions, ssets,
-                 flat_sset, epoch, deadline_armed):
+    def __init__(self, n, padded, n_shards, shard_size, positions, live,
+                 ssets, flat_sset, epoch, deadline_armed):
         self.n = n
         self.padded = padded
         self.n_shards = n_shards
         self.shard_size = shard_size
         self.positions = positions
+        self.live = live
         self.ssets = ssets
         self.flat_sset = flat_sset
         self.epoch = epoch
@@ -312,10 +357,9 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         # up to a multiple of n (doubling in _pad preserves divisibility).
         self._min_batch = -(-self._base_min_batch // n) * n
         tpu_mesh = all(d.platform == "tpu" for d in mesh.devices.flat)
+        self._mesh_pallas = self._use_pallas and tpu_mesh
         with _obs_span("mesh.build", devices=n, epoch=self._mesh_epoch):
-            self._step = make_sharded_step(
-                mesh, use_pallas=self._use_pallas and tpu_mesh
-            )
+            self._step = make_sharded_step(mesh, use_pallas=self._mesh_pallas)
         _MESH_DEVICES.set(n)
 
     def _ladder_levels(self):
@@ -355,45 +399,61 @@ class ShardedSecpVerifier(TpuSecpVerifier):
             out.append(buf)
         return tuple(out)
 
-    def _build_layout(self, args, n: int) -> Optional[_ShardLayout]:
-        """Scatter real lanes across shards in place + install per-shard
-        sentinels; None when the buffer cannot carry the layout (caller
-        falls back to the contiguous single-sentinel prep)."""
+    def _build_layout(self, args, n: int, padded: Optional[int] = None):
+        """Lay `args`' first `n` lanes out shard-major in fresh buffers of
+        `padded` rows (default: as many as `args` has) and install the
+        per-shard sentinels: `(args, layout)`, or None when the size
+        cannot carry the layout (the caller falls back to the contiguous
+        single-sentinel prep). Shard s holds lanes [s*cap, (s+1)*cap) at
+        the head of its slice, so the scatter is one block copy a shard
+        and array, out of buffers that may be read-only (the native lane
+        prep's arena): no copy is made before this one."""
         d = int(self.mesh.devices.size)
-        padded = int(args[0].shape[0])
+        if padded is None:
+            padded = int(args[0].shape[0])
         if d < 2 or padded % d or padded < n + d:
             return None
         shard = padded // d
-        if shard < 2 or n > d * (shard - 1):
+        cap = shard - 1
+        if shard < 2 or n > d * cap:
             return None
-        positions = _shard_positions(n, shard)
+        fill = _shard_fill(n, shard, d)
+        out = []
         for a, pv in zip(args, _PAD_VALUES):
-            real = a[:n].copy()
-            a[...] = pv
-            a[positions] = real
-        sent_rows = [s * shard + shard - 1 for s in range(d)]
-        flat = _guards.install_sentinels_at(args, sent_rows)
+            buf = np.empty((padded,) + a.shape[1:], dtype=a.dtype)
+            for s, k in enumerate(fill):
+                row = s * shard
+                buf[row : row + k] = a[s * cap : s * cap + k]
+                buf[row + k : row + shard] = pv
+            out.append(buf)
+        out = tuple(out)
+        live = np.zeros(padded, dtype=bool)
+        for s, k in enumerate(fill):
+            live[s * shard : s * shard + k] = True
+        sent_rows = [s * shard + cap for s in range(d)]
+        flat = _guards.install_sentinels_at(out, sent_rows)
         if flat is None:
             return None
         ssets = [
-            _guards.SentinelSet([shard - 1], [bool(flat.expected[s])])
+            _guards.SentinelSet([cap], [bool(flat.expected[s])])
             for s in range(d)
         ]
-        return _ShardLayout(
-            n, padded, d, shard, positions, ssets, flat,
-            self._mesh_epoch, padded in self._seen_shapes,
+        return out, _ShardLayout(
+            n, padded, d, shard, _shard_positions(n, shard), live, ssets,
+            flat, self._mesh_epoch, padded in self._seen_shapes,
         )
 
     def _prepare_ticket(self, args, n: int):
-        """Dispatch-time prep (inflight queue callback): copy read-only
-        buffers, then lay the batch out shard-major with one rotating
-        known-answer sentinel per device shard. Falls back to the base
-        contiguous sentinel prep when the batch cannot shard."""
-        args, _copied = _guards.ensure_writable(args)
-        layout = self._build_layout(args, n)
-        if layout is None:
-            return args, _guards.install_sentinels(args, n)
-        return args, layout
+        """Dispatch-time prep (inflight queue callback): lay the batch out
+        shard-major with one rotating known-answer sentinel per device
+        shard, timed as the `shard_layout` phase (it lies inside
+        `dispatch`). Falls back to the base contiguous sentinel prep when
+        the batch cannot shard."""
+        with self.phases("shard_layout"):
+            laid = self._build_layout(args, n)
+        if laid is None:
+            return TpuSecpVerifier._prepare_ticket(self, args, n)
+        return laid
 
     # --- launch ---------------------------------------------------------
 
@@ -413,15 +473,29 @@ class ShardedSecpVerifier(TpuSecpVerifier):
                 level = "xla"
             return TpuSecpVerifier._launch_ticket(self, args, n, level, sset)
         _faults.maybe_raise("mesh.dispatch")
-        live = np.zeros(layout.padded, dtype=bool)
-        live[layout.positions] = True  # sentinel/pad lanes stay out of psum
-        self._note_dispatch(layout.padded, n, "mesh")
-        _MESH_DISPATCH.inc()
-        cap = layout.shard_size - 1
-        for s in range(layout.n_shards):
-            _MESH_SHARD_LANES.observe(min(max(n - s * cap, 0), cap))
-        # Per-shard checksums ride inside the 5-tuple result; no extra aux.
-        return self._step(*args, live), None
+        with _obs_span("mesh.dispatch", shards=layout.n_shards, lanes=n,
+                       epoch=layout.epoch):
+            self._note_mesh_dispatch(layout)
+            # Sentinel/pad lanes stay out of the psum (`layout.live`); the
+            # per-shard checksums ride inside the 5-tuple result: no aux.
+            result = self._step(*args, layout.live)
+            # Five results in four shards each: ask for their copies to
+            # the host now, behind the kernel, so that the settle finds
+            # them there and does not pay twenty round trips one by one.
+            for out in result:
+                start_copy = getattr(out, "copy_to_host_async", None)
+                if start_copy is not None:
+                    start_copy()
+            return result, None
+
+    def _note_mesh_dispatch(self, layout: _ShardLayout) -> None:
+        """Dispatch accounting of one sharded launch of `layout`."""
+        self._note_dispatch(layout.padded, layout.n, "mesh")
+        _MESH_DISPATCH.inc(
+            kernel=shard_kernel(self._mesh_pallas, layout.shard_size)
+        )
+        for k in _shard_fill(layout.n, layout.shard_size, layout.n_shards):
+            _MESH_SHARD_LANES.observe(k)
 
     # --- settle ---------------------------------------------------------
 
@@ -464,15 +538,34 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         return ok_r, needs_r, None
 
     def _materialize_sharded(self, ticket, layout: _ShardLayout):
-        """The per-shard settle seam: validate every device shard
-        independently (structural guards, per-shard checksum FIRST — the
-        single-flip detector — then the shard's sentinel), feed per-device
-        health, and re-dispatch only the failed shards' lanes."""
+        """The per-shard settle seam (span `mesh.settle`): validate every
+        device shard independently (structural guards, per-shard checksum
+        FIRST — the single-flip detector — then the shard's sentinel),
+        feed per-device health, and re-dispatch only the failed shards'
+        lanes."""
         per_lane, needs, all_ok, cnts, wsums = ticket.result
-        ok_np = settle_array(per_lane)
-        needs_np = settle_array(needs)
-        cnts_np = settle_array(cnts)
-        wsums_np = settle_array(wsums)
+        with _obs_span("mesh.settle", shards=layout.n_shards,
+                       lanes=layout.n, epoch=layout.epoch):
+            # The wait for the kernel is the caller's (`sync`,
+            # `backpressure`); what the mesh adds to a settle on the host
+            # is `shard_check`: the other four results fetched, every
+            # shard checked, the lanes gathered back to caller order.
+            ok_np = _faults.corrupt_verdict(
+                "jax_backend.verdict", settle_array(per_lane)
+            )
+            with self.phases("shard_check"):
+                ok_v, needs_v, bad = self._check_settled(
+                    ok_np, settle_array(needs), settle_array(cnts),
+                    settle_array(wsums), layout, _monotonic() - ticket.born,
+                )
+                if not bad:
+                    return self._settle_clean(layout, all_ok, ok_v, needs_v)
+            return self._settle_partial(ticket, layout, ok_v, needs_v, bad)
+
+    def _check_settled(self, ok_np, needs_np, cnts_np, wsums_np,
+                       layout: _ShardLayout, elapsed: float):
+        """`_check_shards` behind the whole-buffer shape guard, with the
+        per-device health report: `(ok, needs, bad)`."""
         if (
             ok_np.ndim != 1
             or ok_np.shape[0] != layout.padded
@@ -486,7 +579,6 @@ class ShardedSecpVerifier(TpuSecpVerifier):
                 f"got {ok_np.shape}/{cnts_np.shape}, "
                 f"want ({layout.padded},)/({layout.n_shards},)",
             )
-        elapsed = _monotonic() - ticket.born
         ok_v, needs_v, bad = self._check_shards(
             ok_np, needs_np, cnts_np, wsums_np, layout, elapsed
         )
@@ -512,19 +604,27 @@ class ShardedSecpVerifier(TpuSecpVerifier):
             raise _guards.VerdictAnomaly(
                 self._SITE, "all-shards", ",".join(sorted(set(bad.values())))
             )
-        if not bad:
-            probe_dev = self._shard_ladder.note_clean_dispatch()
-            if probe_dev is not None:
-                self._probe_evicted(probe_dev)
-            return (
-                ok_v[layout.positions],
-                needs_v[layout.positions],
-                bool(settle_array(all_ok)),
-            )
-        # Partial settlement: keep the good shards' verdicts, re-dispatch
-        # only the failed shards' real lanes. all_ok=None tells the
-        # verdict accounting to recompute from the assembled lanes (the
-        # psum scalar saw the faulted shards).
+        return ok_v, needs_v, bad
+
+    def _settle_clean(self, layout: _ShardLayout, all_ok, ok_v, needs_v):
+        """Every shard passed: the lanes in caller order and the psum
+        collective's replicated verdict (counted where it is not the AND
+        of the lanes it was reduced from)."""
+        probe_dev = self._shard_ladder.note_clean_dispatch()
+        if probe_dev is not None:
+            self._probe_evicted(probe_dev)
+        ok_r = ok_v[layout.positions]
+        needs_r = needs_v[layout.positions]
+        all_ok = bool(settle_array(all_ok))
+        if all_ok != bool(np.all(ok_r | needs_r)):
+            _MESH_VERDICT_MISMATCH.inc()
+        return ok_r, needs_r, all_ok
+
+    def _settle_partial(self, ticket, layout: _ShardLayout, ok_v, needs_v, bad):
+        """Partial settlement: keep the good shards' verdicts, re-dispatch
+        only the failed shards' real lanes. all_ok=None tells the verdict
+        accounting to recompute from the assembled lanes (the psum scalar
+        saw the faulted shards)."""
         cap = layout.shard_size - 1
         lane_shard = np.arange(layout.n, dtype=np.int64) // cap
         bad_keys = np.fromiter(bad.keys(), dtype=np.int64, count=len(bad))
@@ -626,17 +726,12 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         """One synchronous dispatch of the failed lanes over the current
         (possibly rebuilt) mesh, re-guarded shard-by-shard; None when the
         mesh cannot answer cleanly (caller falls to the next rung)."""
-        args = self._blank_args(sub, self._pad(k))
-        for a, r in zip(args, sub):
-            a[:k] = r
-        layout = self._build_layout(args, k)
-        if layout is None:
+        laid = self._build_layout(sub, k, self._pad(k))
+        if laid is None:
             return None
-        live = np.zeros(layout.padded, dtype=bool)
-        live[layout.positions] = True
-        self._note_dispatch(layout.padded, k, "mesh")
-        _MESH_DISPATCH.inc()
-        per_lane, needs, _all_ok, cnts, wsums = self._step(*args, live)
+        args, layout = laid
+        self._note_mesh_dispatch(layout)
+        per_lane, needs, _all_ok, cnts, wsums = self._step(*args, layout.live)
         ok_v, needs_v, bad = self._check_shards(
             settle_array(per_lane), settle_array(needs),
             settle_array(cnts), settle_array(wsums), layout, 0.0,

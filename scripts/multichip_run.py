@@ -26,8 +26,9 @@ Usage:
 
 On real chips give the clean run enough lanes for a 512-row tile per
 shard (`--lanes 2044` on four devices: 511 real lanes + 1 sentinel each),
-or every shard takes the XLA branch of `mesh._pick_backend`. The document
-then records how many Mosaic kernels the program that ran contains, and
+or `mesh.shard_kernel` answers `xla` for every shard. The document then
+records the dispatches by that answer (the counter's `kernel` label) beside
+how many Mosaic kernels the program that ran contains, and
 the run fails unless it stayed on the mesh rung with every fallback
 counter at zero (`chip_guard.assert_clean`).
 """
@@ -114,7 +115,10 @@ def main(argv=None) -> int:
         return step(*a)
 
     sv._step = recording_step
-    disp0 = M._MESH_DISPATCH.value()
+    def dispatched():
+        return {k: int(M._MESH_DISPATCH.value(kernel=k)) for k in ("pallas", "xla")}
+
+    disp0 = dispatched()
     res, verdict = sv.verify_checks_with_verdict(clean_checks)  # warm/compile
     assert np.array_equal(np.asarray(res, dtype=bool), clean_oracle) and verdict
     walls = []
@@ -138,7 +142,7 @@ def main(argv=None) -> int:
         "lanes_per_s": round(len(clean_checks) / best, 1),
         "bit_identical": True,
         "verdict": bool(verdict),
-        "mesh_dispatches": int(M._MESH_DISPATCH.value() - disp0),
+        "mesh_dispatches": {k: n - disp0[k] for k, n in dispatched().items()},
     }
     print(json.dumps({"clean": clean, "platform": devs[0].platform}),
           file=sys.stderr, flush=True)

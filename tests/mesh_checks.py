@@ -10,7 +10,7 @@ compile in a clean process always passes). test_parallel.py runs each
 check here in its own interpreter; the subprocess uses the persistent
 compile cache, so repeat runs are fast.
 
-Usage: python tests/mesh_checks.py {dryrun|sharded|np2|hostreject|faultdomains}...
+Usage: python tests/mesh_checks.py {dryrun|sharded|np2|hostreject|faultdomains|connect|connectflip}...
 Exit code 0 = every named check passed.
 """
 
@@ -191,12 +191,186 @@ def check_faultdomains() -> None:
           "eviction continued on 7 devices")
 
 
+# -- the mesh verifier on the normal path: `connect_block` ---------------------
+#
+# The block of the four-chip cell (`benchmarks/configs/worst-block-mesh4.json`)
+# at its rehearsal size, 15 inputs of a 1-of-20 CHECKMULTISIG = 300 pairings,
+# through a four-device mesh at the 16-lane shape: 12 real lanes a dispatch,
+# three and a sentinel a shard, 25 dispatches a round against a queue four deep.
+
+_WORST_SEED = 2**31 + 33
+
+
+def _worst_block():
+    """(configuration, traffic data) of the rehearsal-size block, with the
+    verifier's shapes cut to the 16-lane rung the suite keeps warm."""
+    from benchmarks import run
+    from benchmarks.generators import worstblock
+
+    spec = run.load_spec("worst-block-mesh4.sigops", rehearsal=True)
+    config = run.merge(spec["config"], {"verifier": {"min_batch": 16, "chunk": 16}})
+    return config, worstblock.build(config, spec["traffic"], _WORST_SEED, 0.0)
+
+
+def _connect_worst(raw, config, d, verifier):
+    """One connect on a fresh view and fresh caches: the result, the
+    signature cache's size and the view afterwards."""
+    from bitcoinconsensus_tpu import native_bridge
+    from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
+    from bitcoinconsensus_tpu.models.validate import connect_block
+
+    view = native_bridge.NativeCoinsView()
+    view.add_coins_batch(d["coins"])
+    sig_cache = SigCache()
+    res = connect_block(
+        raw, view, d["height"], pow_limit=int(config["block"]["pow_limit"], 16),
+        verifier=verifier, sig_cache=sig_cache, script_cache=ScriptExecutionCache(),
+    )
+    return res, len(sig_cache), view
+
+
+def _view_facts(view, d):
+    """What a connect leaves of the view, in plain data: its size, which of
+    the block's coins are still there, which of its outputs arrived."""
+    from bitcoinconsensus_tpu.core.tx import OutPoint, Tx
+
+    spent = [view.get(OutPoint(c[0], c[1])) is not None for c in d["coins"]]
+    made = [view.get(OutPoint(Tx.deserialize(t["raw"]).txid, 0)) is not None
+            for t in d["txs"]]
+    return len(view), spent, made
+
+
+def _mesh_rose(before=None):
+    from bitcoinconsensus_tpu.obs import get_registry
+
+    names = ("consensus_dispatch_total", "consensus_mesh_dispatch_total",
+             "consensus_inflight_backpressure_total",
+             "consensus_mesh_shard_failures_total",
+             "consensus_mesh_redispatch_lanes_total",
+             "consensus_mesh_verdict_mismatch_total",
+             "consensus_exact_fallback_total")
+    snap = get_registry().snapshot()
+    now = {n: sum(s["value"] for s in snap.get(n, {"samples": []})["samples"])
+           for n in names}
+    return now if before is None else {n: now[n] - before[n] for n in names}
+
+
+def check_connect() -> None:
+    """Mesh == base verifier == the plain reference == the host oracle, on
+    the same seeded block: verdicts, sigop cost, one signature-cache entry an
+    input, the view; the corrupted twin rejected for exactly its victim."""
+    from benchmarks.drivers.connect_mesh import make_verifier
+    from benchmarks.harness import oracle, sigopref
+    from bitcoinconsensus_tpu.core.flags import height_to_flags
+    from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
+
+    config, d = _worst_block()
+    n, lanes = d["n_inputs"], d["pairings"]
+    assert (n, lanes) == (15, 300)
+    mesh_v = make_verifier(config)
+    assert int(mesh_v.mesh.devices.size) == 4 and mesh_v.lane_capacity == 12
+    base_v = TpuSecpVerifier(min_batch=16, chunk=16)
+    dispatches = -(-lanes // mesh_v.lane_capacity)  # 25
+
+    before = _mesh_rose()
+    res, cached, view = _connect_worst(d["block"], config, d, mesh_v)
+    rose = _mesh_rose(before)
+    # every dispatch sharded (on CPU devices the shards run the XLA kernel),
+    # all but the queue's four waiting for the oldest ticket, none failing
+    assert rose["consensus_dispatch_total"] == dispatches
+    assert rose["consensus_mesh_dispatch_total"] == dispatches
+    assert rose["consensus_inflight_backpressure_total"] == dispatches - 4
+    assert rose["consensus_mesh_shard_failures_total"] == 0
+    assert rose["consensus_mesh_redispatch_lanes_total"] == 0
+    assert rose["consensus_mesh_verdict_mismatch_total"] == 0
+    assert rose["consensus_exact_fallback_total"] == 0
+    phases = mesh_v.phases.report()
+    assert phases["shard_layout"]["calls"] == dispatches
+    assert phases["shard_check"]["calls"] == dispatches
+    assert phases["dispatch"]["calls"] == dispatches
+    assert phases["backpressure"]["calls"] == dispatches - 4
+    assert mesh_v._inflight.depth == 0
+    assert mesh_v._resilience.ladder.current == "mesh"
+
+    base_res, base_cached, base_view = _connect_worst(d["block"], config, d, base_v)
+    triples = [oracle.as_triple(r) for r in res.input_results]
+    assert res.ok and base_res.ok and len(triples) == n
+    assert triples == [oracle.as_triple(r) for r in base_res.input_results]
+    assert res.sigop_cost == base_res.sigop_cost == lanes
+    assert cached == base_cached == n  # success-only: the pairing that verified
+    assert _view_facts(view, d) == _view_facts(base_view, d)
+    assert _view_facts(view, d)[1:] == ([False] * n, [True] * len(d["txs"]))
+
+    # the plain reference: its own count, its own walk over its own curve
+    # code; and the host oracle, input by input
+    parsed = [(sigopref.parse_tx(t["raw"]), t["outs"]) for t in d["txs"]]
+    assert sigopref.block_sigop_cost(sigopref.parse_tx(d["coinbase"]), parsed) == lanes
+    flags = height_to_flags(d["height"], extended=True)
+    at = 0
+    for (tx, outs), t in zip(parsed, d["txs"]):
+        for index in range(len(outs)):
+            tried, ok = sigopref.p2wsh_multisig_input(tx, index, outs[index])
+            assert ok and len(tried) == 20
+            assert triples[at] == oracle.oracle_verdict(t["raw"], index, outs, flags)
+            at += 1
+
+    # the corrupted twin, on both verifiers: rejected for its victim alone,
+    # the view untouched, and the victim's verdict the oracle's
+    bad = d["bad_tx"]
+    want = oracle.oracle_verdict(
+        bad["raw"], d["victim"] - d["tx_start"][bad["index"]], bad["outs"], flags)
+    assert not want[0]
+    for v in (mesh_v, base_v):
+        res, _cached, view = _connect_worst(d["bad_block"], config, d, v)
+        assert not res.ok and res.reason == "block-validation-failed"
+        assert res.script_failures == [d["victim"]]
+        assert oracle.as_triple(res.input_results[d["victim"]]) == want
+        assert len(view) == n
+    print(f"connect: {dispatches} mesh dispatches a round, mesh == base == reference == oracle")
+
+
+def check_connectflip() -> None:
+    """One lane flipped on shard 2 of one dispatch inside a connect: that
+    shard's checksum convicts it, only its three lanes re-dispatch (over the
+    mesh), and the block's verdicts, cost and cache are what they were."""
+    from benchmarks.drivers.connect_mesh import make_verifier
+    from benchmarks.harness import oracle
+    from bitcoinconsensus_tpu.parallel import mesh as M
+    from bitcoinconsensus_tpu.resilience.faults import FaultPlan, FaultSpec, inject
+
+    config, d = _worst_block()
+    v = make_verifier(config)
+    clean, cached, _view = _connect_worst(d["block"], config, d, v)
+    assert clean.ok and cached == d["n_inputs"]
+
+    dev = v._shard_device_ids[2]
+    flips0 = M._MESH_SHARD_FAILURES.value(device=dev, reason="checksum")
+    before = _mesh_rose()
+    with inject(FaultPlan([FaultSpec("mesh.shard.2", "flip")])) as inj:
+        res, cached, view = _connect_worst(d["block"], config, d, v)
+    rose = _mesh_rose(before)
+    assert inj.total_fired() == 1
+    assert M._MESH_SHARD_FAILURES.value(device=dev, reason="checksum") == flips0 + 1
+    assert rose["consensus_mesh_shard_failures_total"] == 1
+    assert rose["consensus_mesh_redispatch_lanes_total"] == 3  # shard 2's real lanes
+    assert M._MESH_REDISPATCH_LANES.value(level="mesh") >= 3
+    assert rose["consensus_mesh_dispatch_total"] == 25 + 1  # the re-dispatch is sharded too
+    assert res.ok and res.sigop_cost == clean.sigop_cost and cached == d["n_inputs"]
+    assert ([oracle.as_triple(r) for r in res.input_results]
+            == [oracle.as_triple(r) for r in clean.input_results])
+    assert len(view) == len(_view)
+    assert int(v.mesh.devices.size) == 4 and v._resilience.ladder.current == "mesh"
+    print("connectflip: shard 2 convicted by its checksum, 3 lanes re-dispatched")
+
+
 CHECKS = {
     "dryrun": check_dryrun,
     "sharded": check_sharded,
     "np2": check_np2,
     "hostreject": check_hostreject,
     "faultdomains": check_faultdomains,
+    "connect": check_connect,
+    "connectflip": check_connectflip,
 }
 
 if __name__ == "__main__":

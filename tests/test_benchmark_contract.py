@@ -7,8 +7,9 @@ renamed counter guards nothing, and neither fails a test of the program.
 This file makes such a rename fail tier-1 first: one small workload over
 the paths the cells drive (a native connect on fresh and on warm caches, a
 two-block stream that ends in a rollback, a multisig connect of two chunks
-against a queue one deep, a wire-driver dispatch, a served request), then a
-case a name.
+against a queue one deep, the same connect through the mesh verifier's
+layout and per-shard settle, a wire-driver dispatch, a served request), then
+a case a name.
 
 It reads `benchmarks/` (the literal lists below must be what its files
 name) and imports nothing from it.
@@ -17,6 +18,7 @@ name) and imports nothing from it.
 import os
 import re
 
+import numpy as np
 import pytest
 
 from conftest import *  # noqa: F401,F403 (env setup)
@@ -29,6 +31,8 @@ from bitcoinconsensus_tpu.models.batch import BatchItem
 from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
 from bitcoinconsensus_tpu.models.validate import connect_block, connect_block_stream
 from bitcoinconsensus_tpu.obs import get_registry
+from bitcoinconsensus_tpu.parallel.mesh import ShardedSecpVerifier, make_mesh
+from bitcoinconsensus_tpu.resilience.guards import verdict_checksum_host
 from bitcoinconsensus_tpu.serving import VerifyServer
 from bitcoinconsensus_tpu.utils.blockgen import (
     REGTEST_POW_LIMIT,
@@ -60,6 +64,8 @@ READ = (
     "consensus_dispatch_padded_lanes_total",
     "consensus_dispatch_total",
     "consensus_fixpoint_reinterpreted_inputs_total",
+    "consensus_mesh_dispatch_total",
+    "consensus_mesh_shard_lanes",
     "consensus_multisig_spec_pairings_total",
     "consensus_serving_admitted_total",
     "consensus_serving_batch_fill",
@@ -84,13 +90,23 @@ ZERO = (
     "consensus_resilience_host_exact_lanes_total",
     "consensus_resilience_retries_total",
 )
-NO_SAMPLE_NEEDED = ZERO + ("consensus_serving_shed_total",)
+# What the four-chip cell's driver holds still (`drivers/connect_mesh.py`).
+MESH_STILL = (
+    "consensus_mesh_evictions_total",
+    "consensus_mesh_redispatch_lanes_total",
+    "consensus_mesh_repromotions_total",
+    "consensus_mesh_shard_failures_total",
+    "consensus_mesh_verdict_mismatch_total",
+)
+NO_SAMPLE_NEEDED = ZERO + MESH_STILL + ("consensus_serving_shed_total",)
 # `PERF.md` section 3: the stretches of a native connect that
 # `verifier.phases` names, every one read through `detail.phase_ms_p50`.
 PHASES = (
     "interpret", "host_prep", "pack", "dispatch", "sync", "parse",
     "block_check", "accounting", "probe", "results", "apply", "undo",
     "publish", "release", "backpressure",
+    # under the mesh verifier alone, inside `dispatch` and `sync`
+    "shard_layout", "shard_check",
 )
 
 
@@ -101,6 +117,20 @@ def _block(seed: str, height: int, n: int = 6, corrupt=None, kind="p2wpkh"):
     coins, funded = make_funded_view(n, kinds=(kind,), seed=seed)
     tx = build_spend_tx(funded, fee=1000, corrupt_input=corrupt)
     return build_block([tx], height, fees=1000).serialize(), coins
+
+
+def _host_step(sharded):
+    """`make_sharded_step`'s answer from the verifier's own one-device kernel
+    (the 16-lane rung `warm_kernel` made) over the whole buffer, with the
+    psum and each shard's checksum pair worked out on the host."""
+
+    def step(fields, want_odd, parity, has_t2, neg1, neg2, valid, live):
+        ok = np.asarray(sharded._kernel(fields, want_odd, parity, has_t2, neg1, neg2, valid))
+        sums = [verdict_checksum_host(s) for s in np.split(ok, sharded.mesh.devices.size)]
+        cnts, wsums = (np.array(x, dtype=np.int64) for x in zip(*sums))
+        return ok, np.zeros_like(ok), not (live & ~ok).any(), cnts, wsums
+
+    return step
 
 
 @pytest.fixture(scope="module")
@@ -138,6 +168,18 @@ def workload():
                         script_cache=ScriptExecutionCache())
     assert res.ok and len(res.input_results) == 3
 
+    # the same block through the mesh verifier, four of the CPU devices wide:
+    # 12 lanes laid out three a shard beside a sentinel each, settled shard
+    # by shard. The sharded step is stood in for (its compile belongs to
+    # `tests/mesh_checks.py`'s children); what the benchmark reads of the
+    # mesh is all on the host side of it
+    sharded = ShardedSecpVerifier(mesh=make_mesh(4), min_batch=16, chunk=16)
+    sharded.phases, sharded._step = verifier.phases, _host_step(sharded)
+    res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        verifier=sharded, sig_cache=SigCache(),
+                        script_cache=ScriptExecutionCache())
+    assert res.ok and len(res.input_results) == 3
+
     # the wire driver's lane prep, on the interpreter that has no native
     # `prep_pack`: the one place `pack` is a phase of its own
     with pytest.MonkeyPatch.context() as mp:
@@ -155,7 +197,7 @@ def workload():
     return verifier.phases.report(), get_registry().snapshot()
 
 
-@pytest.mark.parametrize("name", READ + ZERO + PHASES)
+@pytest.mark.parametrize("name", READ + ZERO + MESH_STILL + PHASES)
 def test_the_benchmark_finds(workload, name):
     phases, snapshot = workload
     if name in PHASES:
@@ -176,4 +218,4 @@ def test_lists_are_what_benchmarks_names():
             if f.endswith(".py"):
                 with open(os.path.join(root, f)) as fh:
                     named |= set(re.findall(r"\bconsensus_[a-z_]+", fh.read()))
-    assert named == set(READ + ZERO)
+    assert named == set(READ + ZERO + MESH_STILL)

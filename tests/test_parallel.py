@@ -36,9 +36,15 @@ _HELPER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "mesh_checks.
 # hostreject, dryrun and sharded compile the same program (the 8-device
 # step at 16 lanes) and share a child; sharded comes last so that the
 # unsharded kernel it compares with is in the workers' cache by then.
+# connect and connectflip share the four-device step at 16 lanes: the
+# rehearsal-size block of the four-chip cell through `connect_block`.
 # Limits from the children's cold times under the tier-1 command
-# (CHANGES.md, PR 25).
-_CHILDREN = {("hostreject", "dryrun", "sharded"): 750, ("np2",): 600}
+# (CHANGES.md, PR 25; the connect child: PR 33).
+_CHILDREN = {
+    ("hostreject", "dryrun", "sharded"): 750,
+    ("np2",): 600,
+    ("connect", "connectflip"): 750,
+}
 
 
 @pytest.fixture(scope="module")
@@ -47,24 +53,25 @@ def children(tmp_path_factory):
         yield started
 
 
-@pytest.mark.limit(780)
-def test_dryrun_multichip(children):
-    children.expect("dryrun")
-
-
-@pytest.mark.limit(780)
-def test_sharded_matches_unsharded(children):
-    children.expect("sharded")
-
-
-@pytest.mark.limit(630)
-def test_sharded_non_power_of_two_mesh(children):
-    children.expect("np2")
-
-
-@pytest.mark.limit(780)
-def test_sharded_verdict_counts_host_rejected_lane(children):
-    children.expect("hostreject")
+@pytest.mark.parametrize("check", [
+    # jit + run of the sharded step and the API-facing verifier, 8 devices
+    pytest.param("dryrun", marks=pytest.mark.limit(780)),
+    # sharded == unsharded `verify_checks`, failing lanes and the psum verdict
+    pytest.param("sharded", marks=pytest.mark.limit(780)),
+    # a 6-device mesh must not hang and must agree
+    pytest.param("np2", marks=pytest.mark.limit(630)),
+    # a lane rejected on the host still flips the block verdict
+    pytest.param("hostreject", marks=pytest.mark.limit(780)),
+    # `connect_block` on a 4-device mesh == base verifier == reference == oracle
+    pytest.param("connect", marks=pytest.mark.limit(780)),
+    # a flipped lane inside a connect: one shard convicted, its lanes alone re-dispatched
+    pytest.param("connectflip", marks=pytest.mark.limit(780)),
+])
+def test_mesh_on_real_kernels(children, check):
+    """`tests/mesh_checks.py <check>` in its fresh process: the sharded step
+    compiled and run on forced host devices, through `verify_checks` and
+    through `connect_block`."""
+    children.expect(check)
 
 
 @pytest.mark.slow  # 12 minutes and 22 CPU-minutes of compiles, alone and cold
@@ -196,6 +203,57 @@ def test_mesh_stub_matches_oracle_and_verdict():
     v2, oracle2 = _mesh_stub_verifier(good)
     res2, verdict2 = v2.verify_checks_with_verdict(good)
     assert np.array_equal(np.asarray(res2, dtype=bool), oracle2) and verdict2
+
+
+# 8 devices at the 16- and 32-lane shapes: a shard holds 1 or 3 real lanes
+# beside its sentinel. Sizes that fill every shard, leave shards empty, and
+# end part-way through one.
+_LAYOUT_SIZES = {"one-lane": 1, "underfill": 3, "fill-16": 8, "straddle-32": 13,
+                 "fill-32": 24, "straddle-64": 30}
+
+
+@pytest.mark.parametrize("n", list(_LAYOUT_SIZES.values()), ids=list(_LAYOUT_SIZES))
+def test_layout_round_trips(n):
+    """Lane i's verdict comes back at i: every third check is bad, the
+    layout scatters them shard-major and the settle gathers them back."""
+    checks = []
+    for i, good in enumerate(_fd_checks(n, bad_last=False)):
+        checks.append(good if i % 3 != 1 else _fd_checks(0)[0])
+    v, oracle = _mesh_stub_verifier(checks)
+    assert list(oracle) == [i % 3 != 1 for i in range(n)]
+    res, verdict = v.verify_checks_with_verdict(checks)
+    assert np.array_equal(np.asarray(res, dtype=bool), oracle)
+    assert verdict == bool(oracle.all())
+    assert M._MESH_VERDICT_MISMATCH.value() == 0
+
+
+@pytest.mark.parametrize("n", [1, 3, 8, 13, 24])
+def test_build_layout_is_shard_major(n):
+    """Out of read-only buffers, into fresh ones: shard s holds lanes
+    [s*cap, (s+1)*cap) at the head of its slice, then pad rows, then its
+    sentinel; `positions` and `live` say where the real lanes went."""
+    v = M.ShardedSecpVerifier(mesh=M.make_mesh(8), min_batch=8)
+    checks = _fd_checks(n, bad_last=False)
+    packed = v._pack_lanes(v._prep_lanes(checks))
+    padded = int(packed[0].shape[0])
+    want_odd = np.arange(100, 100 + padded, dtype=np.int32)  # a tag a row
+    src = (packed[0], want_odd) + tuple(packed[2:])
+    for a in src:
+        a.flags.writeable = False
+    args, layout = v._build_layout(src, n)
+    shard, cap = padded // 8, padded // 8 - 1
+    assert (layout.n, layout.padded, layout.n_shards, layout.shard_size) == (n, padded, 8, shard)
+    assert all(a.flags.writeable and a.shape == b.shape for a, b in zip(args, src))
+    assert list(layout.positions) == [(i // cap) * shard + i % cap for i in range(n)]
+    assert list(np.nonzero(layout.live)[0]) == list(layout.positions)
+    assert list(args[1][layout.positions]) == list(range(100, 100 + n))
+    assert np.array_equal(args[0][layout.positions], src[0][:n])
+    sentinels = [s * shard + cap for s in range(8)]
+    assert list(layout.flat_sset.positions) == sentinels and all(args[6][sentinels])
+    pad = sorted(set(range(padded)) - set(layout.positions) - set(sentinels))
+    assert not args[6][pad].any() and not args[0][pad].any()
+    assert (args[2][pad] == -1).all()  # parity: don't-care
+    assert v._build_layout(src, padded) is None  # no room for the sentinels
 
 
 def test_single_shard_flip_convicted_by_checksum_and_contained():
