@@ -31,13 +31,24 @@ for re-promotion. Per-shard stragglers have their own deadline
 (`BITCOINCONSENSUS_TPU_SHARD_DEADLINE_S`), distinct from the whole-ticket
 deadline of the in-flight queue.
 
+A dispatch crosses the host-device seam in as few pieces as the mesh has
+shards, each way: its lanes travel as ONE packed byte buffer (`ROW_BYTES` a
+lane: the field bytes, the five flags, `valid`, `live`; `pack_lanes`,
+`unpack_lanes`), unpacked by the first ops of the sharded program, and its
+verdicts, deferral mask, checksum pairs and psum verdict come back as ONE
+int32 array (`unpack_result`). A launch costs by the piece, not by the byte
+(PERF.md section 6, PRs 33 and 35).
+
 Telemetry of its own: phases `shard_layout` (the layout and sentinel install
-of `_prepare_ticket`, inside `dispatch`) and `shard_check` (what a settle
-does once the verdict buffer is on the host, inside `sync` or
-`backpressure`) on `verifier.phases`; spans `mesh.dispatch` and
-`mesh.settle` (shards, lanes, epoch); `consensus_mesh_dispatch_total` by
-the `kernel` every shard ran (`shard_kernel`); and the jitted step's stable
-program name, `jit_mesh_verify_tiles` on a TPU mesh.
+of `_prepare_ticket`), `shard_put` (the packed buffer put to the shards) and
+`shard_exec` (the program started, its result's host copy asked for), all
+inside `dispatch`, and `shard_check` (what a settle does once the result is
+on the host, inside `sync` or `backpressure`) on `verifier.phases`; spans
+`mesh.dispatch` and `mesh.settle` (shards, lanes, epoch);
+`consensus_mesh_dispatch_total` by the `kernel` every shard ran
+(`shard_kernel`); `consensus_mesh_transfers_total` by direction, a count a
+piece; and the jitted step's stable program name, `jit_mesh_verify_tiles`
+on a TPU mesh.
 
 Multi-host: the same mesh spec over `jax.devices()` spanning hosts rides
 ICI/DCN transparently through pjit — no NCCL/MPI translation layer exists or
@@ -46,6 +57,7 @@ is needed.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Sequence
 
@@ -75,7 +87,10 @@ from ..resilience import guards as _guards
 from ..ops.regions import region_scope
 from ..resilience.inflight import settle_array
 
-__all__ = ["make_mesh", "ShardedSecpVerifier", "make_sharded_step", "shard_kernel"]
+__all__ = [
+    "make_mesh", "ShardedSecpVerifier", "make_sharded_step", "shard_kernel",
+    "ROW_BYTES", "pack_lanes", "unpack_lanes", "unpack_result",
+]
 
 # Mesh telemetry — host-side driver accounting only; `local_step` below is
 # traced and must stay instrumentation-free.
@@ -87,6 +102,13 @@ _MESH_DISPATCH = _obs_counter(
     "sharded (multi-chip) dispatches, by the kernel every shard ran "
     "(`shard_kernel`: pallas, or xla where the tile does not divide the shard)",
     ("kernel",),
+)
+_MESH_TRANSFERS = _obs_counter(
+    "consensus_mesh_transfers_total",
+    "host-device transfers of sharded dispatches, a count a piece (one array "
+    "on one shard), by direction: in = arguments put, out = results' host "
+    "copies asked for",
+    ("dir",),
 )
 _MESH_SHARD_LANES = _obs_histogram(
     "consensus_mesh_shard_lanes",
@@ -182,55 +204,141 @@ def _pick_backend(use_pallas: bool):
     return local_kernel
 
 
+# --- the packed wire format ---------------------------------------------
+#
+# A lane is one row of ROW_BYTES bytes: the 128 field bytes, then want_odd,
+# parity, has_t2, neg1, neg2 (int8: every flag is -1, 0 or 1), then valid
+# and live (0/1). Rows are widened value by value (`astype`) on both sides
+# of the seam, never reinterpreted across bytes, so the format has no byte
+# order. A dispatch's result is one int32 array, shard after shard: a
+# shard's `ok + 2 * needs_host` a row, then its checksum pair (count,
+# weighted sum) and the psum verdict every shard holds a copy of.
+
+_FIELD_BYTES = 4 * 32
+_N_FLAGS = 5
+_VALID_COL = _FIELD_BYTES + _N_FLAGS
+_LIVE_COL = _VALID_COL + 1
+ROW_BYTES = _LIVE_COL + 1
+_RESULT_TAIL = 3  # count, weighted sum, psum verdict
+
+
+def _lane_views(packed: np.ndarray):
+    """Writable views over a packed buffer, in the kernel's argument order
+    and then `live`: what is written through them is written to `packed`."""
+    fields = packed[:, :_FIELD_BYTES]
+    fields = np.lib.stride_tricks.as_strided(  # a reshape that cannot copy
+        fields, (packed.shape[0], 4, 32), (packed.strides[0], 32, 1)
+    )
+    flags = packed.view(np.int8)
+    return (
+        (fields,)
+        + tuple(flags[:, _FIELD_BYTES + i] for i in range(_N_FLAGS))
+        + (packed[:, _VALID_COL], packed[:, _LIVE_COL])
+    )
+
+
+def pack_lanes(args, live) -> np.ndarray:
+    """The kernel's seven arguments and the `live` mask, row for row, as one
+    buffer of `ROW_BYTES` a lane: what `make_sharded_step`'s step takes."""
+    packed = np.empty((int(args[0].shape[0]), ROW_BYTES), dtype=np.uint8)
+    for view, a in zip(_lane_views(packed), tuple(args) + (live,)):
+        view[...] = a
+    return packed
+
+
+def unpack_lanes(packed: np.ndarray):
+    """Host twin of the sharded step's first ops, on a host buffer:
+    `(fields, want_odd, parity, has_t2, neg1, neg2, valid, live)` as fresh
+    arrays of the dtypes the kernel takes, bit for bit what `pack_lanes`
+    was given."""
+    flags = np.ascontiguousarray(
+        packed[:, _FIELD_BYTES:_VALID_COL].view(np.int8).T
+    ).astype(np.int32)
+    fields = np.ascontiguousarray(packed[:, :_FIELD_BYTES]).reshape(-1, 4, 32)
+    return (
+        (fields,) + tuple(flags)
+        + (packed[:, _VALID_COL] != 0, packed[:, _LIVE_COL] != 0)
+    )
+
+
+def _unpack_lanes_traced(packed):
+    """`unpack_lanes` inside the traced program, on a shard's rows."""
+    fields = packed[:, :_FIELD_BYTES].reshape(packed.shape[0], 4, 32)
+    # uint8 -> int8 of the same width, then widened: parity's -1 is 0xff
+    flags = jax.lax.bitcast_convert_type(
+        packed[:, _FIELD_BYTES:_VALID_COL], jnp.int8
+    ).astype(jnp.int32)
+    return (
+        (fields,) + tuple(flags[:, i] for i in range(_N_FLAGS))
+        + (packed[:, _VALID_COL] != 0, packed[:, _LIVE_COL] != 0)
+    )
+
+
+def unpack_result(raw: np.ndarray, n_shards: int):
+    """A settled packed result, on the host (`settle_array`), as `(ok,
+    needs_host, all_ok, counts, wsums)`: the padded verdict buffer (bool),
+    the deferral mask (int32, so that a row outside {0..3} fails the
+    shard's domain guard and is not masked away), the psum verdict (False
+    unless every shard's copy says so) and each shard's checksum pair.
+    Raises ValueError on a buffer that does not split `n_shards` ways."""
+    if raw.ndim != 1 or raw.shape[0] % n_shards or (
+        raw.shape[0] // n_shards <= _RESULT_TAIL
+    ):
+        raise ValueError(f"packed result {raw.shape} over {n_shards} shards")
+    per_shard = raw.reshape(n_shards, -1)
+    rows = per_shard[:, :-_RESULT_TAIL].reshape(-1)
+    tail = per_shard[:, -_RESULT_TAIL:]
+    return (
+        (rows & 1) != 0, rows >> 1, bool((tail[:, 2] == 1).all()),
+        tail[:, 0], tail[:, 1],
+    )
+
+
 def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
     """The full multichip verify step, jitted over `mesh`.
 
-    Returns ``step(fields, want_odd, parity_req, has_t2, neg1, neg2,
-    valid, live) -> (per_lane, needs_host, all_ok, counts, wsums)`` where
-    inputs are batch-sharded, `per_lane`/`needs_host` come back
-    batch-sharded, `all_ok` is a replicated scalar produced by a psum
-    AND-reduction inside shard_map (the cross-chip collective — the
-    `CCheckQueueControl::Wait` analogue, checkqueue.h:139-142), and
-    `counts`/`wsums` are length-``n_devices`` arrays carrying each
-    shard's verdict checksum pair, computed on-device over the
+    Returns ``step(packed) -> result``: one argument in, one array out, so
+    that a launch makes one transfer a shard each way. `packed` is the
+    batch as `pack_lanes` lays it out (uint8, `ROW_BYTES` a lane),
+    batch-sharded; the first ops on every shard slice and widen it to the
+    kernel's seven arguments and the `live` mask (`unpack_lanes` is their
+    host twin). `result` is one int32 array, batch-sharded, `S + 3`
+    entries a shard of `S` rows (`unpack_result`): `ok + 2 * needs_host`
+    a row; the shard's verdict checksum pair, computed on-device over the
     shard-local verdict slice (`jax_backend._verdict_checksum`, so the
-    interval prover's coverage rides along). The settle seam recomputes
-    both sums host-side per shard; a mismatch convicts exactly that
-    shard. `live` marks real lanes: padding added to reach the batch
-    shape is not counted as a failure, while structurally-invalid real
-    lanes are. `needs_host` lanes (exceptional group-law deferrals of the
-    pallas fast adds) are excluded from the device verdict — the host
-    resolves them exactly and adjusts. Each shard runs the production
-    backend selection (Pallas on TPU when the local tile divides; XLA
-    otherwise).
+    interval prover's coverage rides along); and `all_ok`, produced by a
+    psum AND-reduction inside shard_map (the cross-chip collective — the
+    `CCheckQueueControl::Wait` analogue, checkqueue.h:139-142), of which
+    every shard carries a copy. The settle seam recomputes both sums
+    host-side per shard; a mismatch convicts exactly that shard. `live`
+    marks real lanes: padding added to reach the batch shape is not
+    counted as a failure, while structurally-invalid real lanes are.
+    `needs_host` lanes (exceptional group-law deferrals of the pallas fast
+    adds) are excluded from the device verdict — the host resolves them
+    exactly and adjusts. Each shard runs the production backend selection
+    (Pallas on TPU when the local tile divides; XLA otherwise).
     """
     axis = mesh.axis_names[0]
-    fields_sharding = NamedSharding(mesh, P(axis, None, None))
-    flat_sharding = NamedSharding(mesh, P(axis))
-    replicated = NamedSharding(mesh, P())
     if use_pallas is None:
         use_pallas = all(d.platform == "tpu" for d in mesh.devices.flat)
     local_kernel = _pick_backend(use_pallas)
 
-    def local_step(fields, want_odd, parity_req, has_t2, neg1, neg2, valid, live):
+    def local_step(packed):
         # region scope only — metadata: the op's name in a profiler
         # trace; the traced program is unchanged.
         with region_scope("shard_step"):
-            per_lane, needs = local_kernel(
-                fields, want_odd, parity_req, has_t2, neg1, neg2, valid
-            )
+            *lanes, live = _unpack_lanes_traced(packed)
+            per_lane, needs = local_kernel(*lanes)
             # all-valid <=> no live lane DEFINITELY failed, on any shard
             # (deferred lanes stay out; the host fixup ANDs their
             # verdicts in).
             failures = jnp.sum(jnp.where(live & ~per_lane & ~needs, 1, 0))
+            all_ok = jax.lax.psum(failures, axis) == 0
+            # the checksum pair over the pristine verdict slice
             cnt, wsum = _verdict_checksum(per_lane)
-            return (
-                per_lane,
-                needs,
-                jax.lax.psum(failures, axis) == 0,
-                jnp.reshape(cnt, (1,)),
-                jnp.reshape(wsum, (1,)),
-            )
+            rows = per_lane.astype(jnp.int32) + 2 * needs.astype(jnp.int32)
+            tail = jnp.stack([cnt, wsum, all_ok.astype(jnp.int32)])
+            return jnp.concatenate([rows, tail])
 
     # Varying-axes checking is off: the verify kernel's scan carries start
     # as mesh-wide constants (infinity masks, G-table selects) and become
@@ -239,13 +347,13 @@ def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
     sharded = shard_map(
         local_step,
         mesh=mesh,
-        in_specs=(P(axis, None, None),) + (P(axis),) * 7,
-        out_specs=(P(axis), P(axis), P(), P(axis), P(axis)),
+        in_specs=P(axis, None),
+        out_specs=P(axis),
         check_vma=False,
     )
 
-    def step(*args):
-        return sharded(*args)
+    def step(packed):
+        return sharded(packed)
 
     # The program's name in a profiler trace (`XLA Modules`: `jit_<name>`),
     # after the kernel the shards run where the tile divides them.
@@ -254,24 +362,26 @@ def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
     )
     return jax.jit(
         step,
-        in_shardings=(fields_sharding,) + (flat_sharding,) * 7,
-        out_shardings=(
-            flat_sharding, flat_sharding, replicated,
-            flat_sharding, flat_sharding,
-        ),
+        in_shardings=NamedSharding(mesh, P(axis, None)),
+        out_shardings=NamedSharding(mesh, P(axis)),
     )
 
 
+@functools.lru_cache(maxsize=16)
 def _shard_positions(n: int, shard_size: int) -> np.ndarray:
     """Global row index of real lane `i` under the scatter layout.
 
     Each shard of `shard_size` rows holds `shard_size - 1` real lanes
     followed by its reserved sentinel row, so lane i lands at
-    ``(i // (S-1)) * S + (i % (S-1))``.
+    ``(i // (S-1)) * S + (i % (S-1))``. A round's chunks are of one or
+    two sizes, so the array is kept (read-only: every layout of a size
+    shares it).
     """
     cap = shard_size - 1
     idx = np.arange(n, dtype=np.int64)
-    return (idx // cap) * shard_size + (idx % cap)
+    positions = (idx // cap) * shard_size + (idx % cap)
+    positions.flags.writeable = False
+    return positions
 
 
 def _shard_fill(n: int, shard_size: int, n_shards: int) -> list:
@@ -284,11 +394,11 @@ def _shard_fill(n: int, shard_size: int, n_shards: int) -> list:
 class _ShardLayout:
     """Settle context of one scattered mesh dispatch (rides ticket.sset).
 
-    `positions` maps real-lane order to global rows; `ssets` holds one
+    `positions` maps real-lane order to global rows (the rows whose `live`
+    byte is set: the psum verdict counts no other); `ssets` holds one
     single-lane SentinelSet per shard (local position S-1) for per-shard
     checking, and `flat_sset` the same sentinels as one global set for
-    the quarantined single-device fallback path; `live` marks the real
-    lanes' rows (the psum verdict counts no other). `epoch` pins the mesh
+    the quarantined single-device fallback path. `epoch` pins the mesh
     generation the layout was built for: after an eviction rebuilds the
     mesh, stale layouts are no longer shard-aligned and relaunch on the
     single-device rung instead. `deadline_armed` is False for
@@ -297,18 +407,17 @@ class _ShardLayout:
     """
 
     __slots__ = (
-        "n", "padded", "n_shards", "shard_size", "positions", "live",
+        "n", "padded", "n_shards", "shard_size", "positions",
         "ssets", "flat_sset", "epoch", "deadline_armed",
     )
 
-    def __init__(self, n, padded, n_shards, shard_size, positions, live,
+    def __init__(self, n, padded, n_shards, shard_size, positions,
                  ssets, flat_sset, epoch, deadline_armed):
         self.n = n
         self.padded = padded
         self.n_shards = n_shards
         self.shard_size = shard_size
         self.positions = positions
-        self.live = live
         self.ssets = ssets
         self.flat_sset = flat_sset
         self.epoch = epoch
@@ -318,6 +427,8 @@ class _ShardLayout:
 # Pad row values per packed array (mirrors _pack_lanes): fields 0,
 # want_odd 0, parity -1 (don't-care), has_t2/neg1/neg2 0, valid False.
 _PAD_VALUES = (0, 0, -1, 0, 0, 0, 0)
+# A pad row's bytes after the fields: the flags and `valid` as above, not live.
+_PAD_FLAGS = _PAD_VALUES[1:] + (0,)
 
 
 class ShardedSecpVerifier(TpuSecpVerifier):
@@ -358,6 +469,7 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         self._min_batch = -(-self._base_min_batch // n) * n
         tpu_mesh = all(d.platform == "tpu" for d in mesh.devices.flat)
         self._mesh_pallas = self._use_pallas and tpu_mesh
+        self._packed_sharding = NamedSharding(mesh, P(self._axis, None))
         with _obs_span("mesh.build", devices=n, epoch=self._mesh_epoch):
             self._step = make_sharded_step(mesh, use_pallas=self._mesh_pallas)
         _MESH_DEVICES.set(n)
@@ -400,14 +512,16 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         return tuple(out)
 
     def _build_layout(self, args, n: int, padded: Optional[int] = None):
-        """Lay `args`' first `n` lanes out shard-major in fresh buffers of
-        `padded` rows (default: as many as `args` has) and install the
-        per-shard sentinels: `(args, layout)`, or None when the size
-        cannot carry the layout (the caller falls back to the contiguous
-        single-sentinel prep). Shard s holds lanes [s*cap, (s+1)*cap) at
-        the head of its slice, so the scatter is one block copy a shard
-        and array, out of buffers that may be read-only (the native lane
-        prep's arena): no copy is made before this one."""
+        """Lay `args`' first `n` lanes out shard-major in ONE fresh packed
+        buffer of `padded` rows (default: as many as `args` has) and
+        install the per-shard sentinels: `((packed,), layout)`, or None
+        when the size cannot carry the layout (the caller falls back to
+        the contiguous single-sentinel prep). Shard s holds lanes
+        [s*cap, (s+1)*cap) at the head of its slice, so the scatter is two
+        block copies a shard (its field bytes, its flag bytes), out of
+        buffers that may be read-only (the native lane prep's arena): no
+        copy is made before this one. The flags narrow to a byte each on
+        the way (`pack_lanes`' format)."""
         d = int(self.mesh.devices.size)
         if padded is None:
             padded = int(args[0].shape[0])
@@ -417,29 +531,34 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         cap = shard - 1
         if shard < 2 or n > d * cap:
             return None
-        fill = _shard_fill(n, shard, d)
-        out = []
-        for a, pv in zip(args, _PAD_VALUES):
-            buf = np.empty((padded,) + a.shape[1:], dtype=a.dtype)
-            for s, k in enumerate(fill):
-                row = s * shard
-                buf[row : row + k] = a[s * cap : s * cap + k]
-                buf[row + k : row + shard] = pv
-            out.append(buf)
-        out = tuple(out)
-        live = np.zeros(padded, dtype=bool)
-        for s, k in enumerate(fill):
-            live[s * shard : s * shard + k] = True
+        packed = np.empty((padded, ROW_BYTES), dtype=np.uint8)
+        fields = packed[:, :_FIELD_BYTES]
+        flags = packed.view(np.int8)[:, _FIELD_BYTES:]
+        src_fields = args[0].reshape(-1, _FIELD_BYTES)
+        # A row's seven flag bytes go in as one block: a column at a time
+        # would walk the whole buffer seven times.
+        block = np.empty((cap, ROW_BYTES - _FIELD_BYTES), dtype=np.int8)
+        block[:, -1] = 1  # live
+        for s, k in enumerate(_shard_fill(n, shard, d)):
+            row, lane = s * shard, s * cap
+            fields[row : row + k] = src_fields[lane : lane + k]
+            fields[row + k : row + shard] = _PAD_VALUES[0]
+            for j, a in enumerate(args[1:]):
+                block[:k, j] = a[lane : lane + k]
+            flags[row : row + k] = block[:k]
+            flags[row + k : row + shard] = _PAD_FLAGS
         sent_rows = [s * shard + cap for s in range(d)]
-        flat = _guards.install_sentinels_at(out, sent_rows)
+        flat = _guards.install_sentinels_at(
+            _lane_views(packed)[:-1], sent_rows
+        )
         if flat is None:
             return None
         ssets = [
             _guards.SentinelSet([cap], [bool(flat.expected[s])])
             for s in range(d)
         ]
-        return out, _ShardLayout(
-            n, padded, d, shard, _shard_positions(n, shard), live, ssets,
+        return (packed,), _ShardLayout(
+            n, padded, d, shard, _shard_positions(n, shard), ssets,
             flat, self._mesh_epoch, padded in self._seen_shapes,
         )
 
@@ -471,22 +590,33 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         ):
             if level == "mesh":
                 level = "xla"
+            if layout is not None:  # a packed ticket on one device
+                args = unpack_lanes(args[0])[:-1]
             return TpuSecpVerifier._launch_ticket(self, args, n, level, sset)
         _faults.maybe_raise("mesh.dispatch")
         with _obs_span("mesh.dispatch", shards=layout.n_shards, lanes=n,
                        epoch=layout.epoch):
             self._note_mesh_dispatch(layout)
-            # Sentinel/pad lanes stay out of the psum (`layout.live`); the
-            # per-shard checksums ride inside the 5-tuple result: no aux.
-            result = self._step(*args, layout.live)
-            # Five results in four shards each: ask for their copies to
-            # the host now, behind the kernel, so that the settle finds
-            # them there and does not pay twenty round trips one by one.
-            for out in result:
-                start_copy = getattr(out, "copy_to_host_async", None)
-                if start_copy is not None:
-                    start_copy()
-            return result, None
+            # Sentinel/pad lanes stay out of the psum (their `live` byte);
+            # the per-shard checksums ride inside the one result: no aux.
+            # The 1-tuple is what tells a mesh result from the base rungs'.
+            return (self._run_step(args[0]),), None
+
+    def _run_step(self, packed: np.ndarray):
+        """Start the sharded program on one packed buffer: a piece a shard
+        to the devices (`shard_put`), then the execute call and the
+        request for the result's copy to the host, a piece a shard, behind
+        the kernel, so that the settle finds it there (`shard_exec`)."""
+        with self.phases("shard_put"):
+            on_mesh = jax.device_put(packed, self._packed_sharding)
+            _MESH_TRANSFERS.inc(on_mesh.sharding.num_devices, dir="in")
+        with self.phases("shard_exec"):
+            result = self._step(on_mesh)
+            start_copy = getattr(result, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
+                _MESH_TRANSFERS.inc(result.sharding.num_devices, dir="out")
+        return result
 
     def _note_mesh_dispatch(self, layout: _ShardLayout) -> None:
         """Dispatch accounting of one sharded launch of `layout`."""
@@ -505,7 +635,7 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         if layout is None:
             # Contiguous prep (unshardable batch): base settle seam.
             return TpuSecpVerifier._materialize_guarded(self, ticket)
-        if not (isinstance(result, tuple) and len(result) == 5):
+        if not (isinstance(result, tuple) and len(result) == 1):
             return self._materialize_flat(ticket, layout)
         return self._materialize_sharded(ticket, layout)
 
@@ -543,24 +673,36 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         FIRST — the single-flip detector — then the shard's sentinel),
         feed per-device health, and re-dispatch only the failed shards'
         lanes."""
-        per_lane, needs, all_ok, cnts, wsums = ticket.result
         with _obs_span("mesh.settle", shards=layout.n_shards,
                        lanes=layout.n, epoch=layout.epoch):
             # The wait for the kernel is the caller's (`sync`,
             # `backpressure`); what the mesh adds to a settle on the host
-            # is `shard_check`: the other four results fetched, every
-            # shard checked, the lanes gathered back to caller order.
-            ok_np = _faults.corrupt_verdict(
-                "jax_backend.verdict", settle_array(per_lane)
-            )
+            # is `shard_check`: the result unpacked, every shard checked,
+            # the lanes gathered back to caller order.
+            raw = settle_array(ticket.result[0])
             with self.phases("shard_check"):
+                ok_np, needs_np, all_ok, cnts, wsums = self._unpack_settled(
+                    raw, layout
+                )
                 ok_v, needs_v, bad = self._check_settled(
-                    ok_np, settle_array(needs), settle_array(cnts),
-                    settle_array(wsums), layout, _monotonic() - ticket.born,
+                    _faults.corrupt_verdict("jax_backend.verdict", ok_np),
+                    needs_np, cnts, wsums, layout,
+                    _monotonic() - ticket.born,
                 )
                 if not bad:
                     return self._settle_clean(layout, all_ok, ok_v, needs_v)
             return self._settle_partial(ticket, layout, ok_v, needs_v, bad)
+
+    def _unpack_settled(self, raw: np.ndarray, layout: _ShardLayout):
+        """`unpack_result` behind the whole-buffer shape guard."""
+        if raw.shape != (layout.padded + _RESULT_TAIL * layout.n_shards,):
+            _guards.GUARD_ANOMALIES.inc(site=self._SITE, reason="shape")
+            raise _guards.VerdictAnomaly(
+                self._SITE, "shape",
+                f"got {raw.shape}, want {layout.n_shards} shards of "
+                f"({layout.shard_size + _RESULT_TAIL},)",
+            )
+        return unpack_result(raw, layout.n_shards)
 
     def _check_settled(self, ok_np, needs_np, cnts_np, wsums_np,
                        layout: _ShardLayout, elapsed: float):
@@ -615,7 +757,6 @@ class ShardedSecpVerifier(TpuSecpVerifier):
             self._probe_evicted(probe_dev)
         ok_r = ok_v[layout.positions]
         needs_r = needs_v[layout.positions]
-        all_ok = bool(settle_array(all_ok))
         if all_ok != bool(np.all(ok_r | needs_r)):
             _MESH_VERDICT_MISMATCH.inc()
         return ok_r, needs_r, all_ok
@@ -637,7 +778,7 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         k = int(bad_mask.sum())
         if k:
             rows = layout.positions[bad_mask]
-            sub = tuple(a[rows] for a in ticket.args)
+            sub = unpack_lanes(ticket.args[0][rows])[:-1]
             ok_b, needs_b = self._redispatch_lanes(sub, k)
             ok_r[bad_mask] = ok_b
             needs_r[bad_mask] = needs_b
@@ -729,12 +870,13 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         laid = self._build_layout(sub, k, self._pad(k))
         if laid is None:
             return None
-        args, layout = laid
+        (packed,), layout = laid
         self._note_mesh_dispatch(layout)
-        per_lane, needs, _all_ok, cnts, wsums = self._step(*args, layout.live)
+        ok_np, needs_np, _all_ok, cnts, wsums = self._unpack_settled(
+            settle_array(self._run_step(packed)), layout
+        )
         ok_v, needs_v, bad = self._check_shards(
-            settle_array(per_lane), settle_array(needs),
-            settle_array(cnts), settle_array(wsums), layout, 0.0,
+            ok_np, needs_np, cnts, wsums, layout, 0.0
         )
         if bad:
             return None

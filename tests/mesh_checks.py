@@ -10,7 +10,7 @@ compile in a clean process always passes). test_parallel.py runs each
 check here in its own interpreter; the subprocess uses the persistent
 compile cache, so repeat runs are fast.
 
-Usage: python tests/mesh_checks.py {dryrun|sharded|np2|hostreject|faultdomains|connect|connectflip}...
+Usage: python tests/mesh_checks.py {dryrun|sharded|np2|hostreject|faultdomains|connect|connectflip|packing}...
 Exit code 0 = every named check passed.
 """
 
@@ -263,6 +263,7 @@ def check_connect() -> None:
     from benchmarks.harness import oracle, sigopref
     from bitcoinconsensus_tpu.core.flags import height_to_flags
     from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
+    from bitcoinconsensus_tpu.parallel import mesh as M
 
     config, d = _worst_block()
     n, lanes = d["n_inputs"], d["pairings"]
@@ -273,8 +274,12 @@ def check_connect() -> None:
     dispatches = -(-lanes // mesh_v.lane_capacity)  # 25
 
     before = _mesh_rose()
+    pieces = {way: M._MESH_TRANSFERS.value(dir=way) for way in ("in", "out")}
     res, cached, view = _connect_worst(d["block"], config, d, mesh_v)
     rose = _mesh_rose(before)
+    # one packed buffer in and one packed result out, a piece a shard
+    for way, was in pieces.items():
+        assert M._MESH_TRANSFERS.value(dir=way) == was + 4 * dispatches, way
     # every dispatch sharded (on CPU devices the shards run the XLA kernel),
     # all but the queue's four waiting for the oldest ticket, none failing
     assert rose["consensus_dispatch_total"] == dispatches
@@ -285,8 +290,8 @@ def check_connect() -> None:
     assert rose["consensus_mesh_verdict_mismatch_total"] == 0
     assert rose["consensus_exact_fallback_total"] == 0
     phases = mesh_v.phases.report()
-    assert phases["shard_layout"]["calls"] == dispatches
-    assert phases["shard_check"]["calls"] == dispatches
+    for name in ("shard_layout", "shard_put", "shard_exec", "shard_check"):
+        assert phases[name]["calls"] == dispatches, name
     assert phases["dispatch"]["calls"] == dispatches
     assert phases["backpressure"]["calls"] == dispatches - 4
     assert mesh_v._inflight.depth == 0
@@ -363,6 +368,57 @@ def check_connectflip() -> None:
     print("connectflip: shard 2 convicted by its checksum, 3 lanes re-dispatched")
 
 
+def check_packing() -> None:
+    """The compiled four-device program on one packed buffer: its unpack
+    ops under the mesh's sharding give what the host unpack gives, and its
+    one result unpacks to the five results of the step it replaced: the
+    one-device kernel's verdicts, no deferral, the AND of the live lanes, a
+    checksum pair a shard. Four pieces in, four out."""
+    import jax
+
+    import __graft_entry__ as ge
+    from benchmarks.drivers.connect_mesh import make_verifier
+    from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck
+    from bitcoinconsensus_tpu.parallel import mesh as M
+    from bitcoinconsensus_tpu.resilience.guards import verdict_checksum_host
+    from mesh_stub import traced_unpack
+
+    config, _d = _worst_block()
+    v = make_verifier(config)
+    unpack = traced_unpack(v.mesh)
+    # mixed kinds (ECDSA's parity is -1, don't-care); 12 lanes fill the four
+    # shards, 7 leave the last one empty; one bad signature, one not
+    for n, bad in ((12, None), (12, 4), (7, 1)):
+        checks = ge._example_checks(n)
+        if bad is not None:
+            pk32, sig64, msg = checks[bad].data
+            checks[bad] = SigCheck(
+                "schnorr", (pk32, sig64[:40] + bytes([sig64[40] ^ 1]) + sig64[41:], msg))
+        lanes = v._pack_lanes(v._prep_lanes(checks))
+        assert (lanes[2][:n] == -1).any() and (lanes[2][:n] != -1).any()
+        (packed,), layout = v._build_layout(lanes, n)
+        assert packed.shape == (16, M.ROW_BYTES) and layout.shard_size == 4
+        host = M.unpack_lanes(packed)
+        for got, want in zip(unpack(jax.device_put(packed, v._packed_sharding)), host):
+            assert got.dtype == want.dtype and np.array_equal(np.asarray(got), want)
+        assert list(np.nonzero(host[7])[0]) == list(layout.positions)
+
+        pieces = {way: M._MESH_TRANSFERS.value(dir=way) for way in ("in", "out")}
+        raw = np.asarray(v._run_step(packed))
+        for way, was in pieces.items():
+            assert M._MESH_TRANSFERS.value(dir=way) == was + 4, way
+        assert raw.dtype == np.int32 and raw.shape == (16 + 3 * 4,)
+        ok, needs, all_ok, cnts, wsums = M.unpack_result(raw, 4)
+        want_ok = np.asarray(v._kernel(*host[:7]))  # the one-device XLA kernel
+        assert np.array_equal(ok, want_ok) and not needs.any()
+        assert list(ok[layout.positions]) == [i != bad for i in range(n)]
+        assert all_ok is (bad is None) and all_ok == bool(ok[host[7]].all())
+        for s, part in enumerate(np.split(want_ok, 4)):
+            assert (int(cnts[s]), int(wsums[s])) == verdict_checksum_host(part), s
+        layout.flat_sset.check(ok, None, "packing")
+    print("packing: device unpack == host unpack, one result == the five, 4 + 4 pieces")
+
+
 CHECKS = {
     "dryrun": check_dryrun,
     "sharded": check_sharded,
@@ -371,6 +427,7 @@ CHECKS = {
     "faultdomains": check_faultdomains,
     "connect": check_connect,
     "connectflip": check_connectflip,
+    "packing": check_packing,
 }
 
 if __name__ == "__main__":

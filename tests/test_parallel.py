@@ -24,6 +24,7 @@ import pytest
 from conftest import *  # noqa: F401,F403 (env setup)
 
 from child_checks import Children
+from mesh_stub import host_step, pack_result
 
 from bitcoinconsensus_tpu.crypto import secp_host as H
 from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck
@@ -36,14 +37,15 @@ _HELPER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "mesh_checks.
 # hostreject, dryrun and sharded compile the same program (the 8-device
 # step at 16 lanes) and share a child; sharded comes last so that the
 # unsharded kernel it compares with is in the workers' cache by then.
-# connect and connectflip share the four-device step at 16 lanes: the
-# rehearsal-size block of the four-chip cell through `connect_block`.
+# connect, connectflip and packing share the four-device step at 16 lanes:
+# the rehearsal-size block of the four-chip cell through `connect_block`,
+# then the program alone on one packed buffer.
 # Limits from the children's cold times under the tier-1 command
 # (CHANGES.md, PR 25; the connect child: PR 33).
 _CHILDREN = {
     ("hostreject", "dryrun", "sharded"): 750,
     ("np2",): 600,
-    ("connect", "connectflip"): 750,
+    ("connect", "connectflip", "packing"): 750,
 }
 
 
@@ -66,6 +68,8 @@ def children(tmp_path_factory):
     pytest.param("connect", marks=pytest.mark.limit(780)),
     # a flipped lane inside a connect: one shard convicted, its lanes alone re-dispatched
     pytest.param("connectflip", marks=pytest.mark.limit(780)),
+    # the compiled program: its unpack == the host's, its one result == the five
+    pytest.param("packing", marks=pytest.mark.limit(780)),
 ])
 def test_mesh_on_real_kernels(children, check):
     """`tests/mesh_checks.py <check>` in its fresh process: the sharded step
@@ -125,7 +129,8 @@ def _mesh_stub_verifier(checks, n_devices=8, evict_after=None):
         {raw: exp for raw, *_rest, exp in G._sentinel_templates()}
     )
 
-    def lane_verdicts(fields, valid):
+    def lane_verdicts(fields, *flags_then_valid):
+        valid = flags_then_valid[-1]
         padded = int(fields.shape[0])
         ok = np.zeros(padded, dtype=bool)
         for pos in range(padded):
@@ -133,22 +138,10 @@ def _mesh_stub_verifier(checks, n_devices=8, evict_after=None):
                 ok[pos] = by_raw.get(np.asarray(fields[pos]).tobytes(), False)
         return ok
 
-    def step(fields, want_odd, parity, has_t2, neg1, neg2, valid, live):
-        padded = int(fields.shape[0])
-        d = int(v.mesh.devices.size)
-        shard = padded // d
-        ok = lane_verdicts(fields, valid)
-        needs = np.zeros(padded, dtype=bool)
-        failures = int((np.asarray(live) & ~ok).sum())
-        cnts = np.zeros(d, dtype=np.int64)
-        wsums = np.zeros(d, dtype=np.int64)
-        for s in range(d):
-            c, w = G.verdict_checksum_host(ok[s * shard: (s + 1) * shard])
-            cnts[s], wsums[s] = c, w
-        return ok, needs, failures == 0, cnts, wsums
+    step = host_step(v, lane_verdicts)
 
     def kernel(args, n):
-        ok = lane_verdicts(args[0], args[-1])
+        ok = lane_verdicts(*args)
         return ok, np.zeros(len(ok), dtype=bool)
 
     v._step = step
@@ -236,17 +229,19 @@ def test_build_layout_is_shard_major(n):
     checks = _fd_checks(n, bad_last=False)
     packed = v._pack_lanes(v._prep_lanes(checks))
     padded = int(packed[0].shape[0])
-    want_odd = np.arange(100, 100 + padded, dtype=np.int32)  # a tag a row
+    want_odd = np.arange(1, 1 + padded, dtype=np.int32)  # a tag a row, inside a byte
     src = (packed[0], want_odd) + tuple(packed[2:])
     for a in src:
         a.flags.writeable = False
-    args, layout = v._build_layout(src, n)
+    (packed,), layout = v._build_layout(src, n)
+    assert packed.shape == (padded, M.ROW_BYTES) and packed.dtype == np.uint8
+    *args, live = M.unpack_lanes(packed)
     shard, cap = padded // 8, padded // 8 - 1
     assert (layout.n, layout.padded, layout.n_shards, layout.shard_size) == (n, padded, 8, shard)
-    assert all(a.flags.writeable and a.shape == b.shape for a, b in zip(args, src))
+    assert all(a.dtype == b.dtype and a.shape == b.shape for a, b in zip(args, src))
     assert list(layout.positions) == [(i // cap) * shard + i % cap for i in range(n)]
-    assert list(np.nonzero(layout.live)[0]) == list(layout.positions)
-    assert list(args[1][layout.positions]) == list(range(100, 100 + n))
+    assert list(np.nonzero(live)[0]) == list(layout.positions)
+    assert list(args[1][layout.positions]) == list(range(1, 1 + n))
     assert np.array_equal(args[0][layout.positions], src[0][:n])
     sentinels = [s * shard + cap for s in range(8)]
     assert list(layout.flat_sset.positions) == sentinels and all(args[6][sentinels])
