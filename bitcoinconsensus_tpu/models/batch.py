@@ -56,6 +56,7 @@ from ..obs import span as _span
 from ..resilience import faults as _faults
 from ..resilience import guards as _guards
 from ..utils.gcpause import gc_paused
+from ..utils.profiling import phases_of
 from .sigcache import (
     ScriptExecutionCache,
     SigCache,
@@ -457,18 +458,19 @@ def _dispatch_uniq(nsess, verifier, sig_cache, state: _UniqState):
     if len(sig_cache) == 0 and _faults.active() is None:
         miss = grow  # cold cache: every probe misses
     else:
-        hit = sig_cache.contains_keys(raw, U - lo)
-        if _guards.audit_cache_hits():
-            # Audit mode (resilience): a hit certifies a past success,
-            # but a poisoned entry certifies nothing — re-verify on
-            # the exact oracle and evict entries proven wrong.
-            for j in np.nonzero(hit)[0].tolist():
-                if not nsess.uniq_host_verify(lo + j):
-                    _guards.CACHE_POISON_CAUGHT.inc(cache="sig")
-                    sig_cache.discard_key(raw[32 * j : 32 * j + 32])
-                    hit[j] = False
-        state.val[lo:] = hit
-        miss = grow[~hit]
+        with verifier.phases("sig_probe"):
+            hit = sig_cache.contains_keys(raw, U - lo)
+            if _guards.audit_cache_hits():
+                # Audit mode (resilience): a hit certifies a past success,
+                # but a poisoned entry certifies nothing — re-verify on
+                # the exact oracle and evict entries proven wrong.
+                for j in np.nonzero(hit)[0].tolist():
+                    if not nsess.uniq_host_verify(lo + j):
+                        _guards.CACHE_POISON_CAUGHT.inc(cache="sig")
+                        sig_cache.discard_key(raw[32 * j : 32 * j + 32])
+                        hit[j] = False
+            state.val[lo:] = hit
+            miss = grow[~hit]
     pending = []
     cap = verifier.lane_capacity
     for s in range(0, len(miss), cap):
@@ -494,16 +496,18 @@ def _settle_uniq(nsess, verifier, sig_cache, state: _UniqState,
         okv, needs = verifier.sync_lanes(pend, len(sub))
         okv = np.array(okv, dtype=bool, copy=True)
         if needs is not None and needs.any():
-            fix = np.nonzero(needs)[0]
-            _HOST_FIXUPS.inc(len(fix))
-            for t in fix:
-                r = nsess.uniq_host_verify(int(sub[t]))
-                okv[t] = r
-                if not r:
-                    verifier._fixup_failed = True
-        state.val[sub] = okv
-        # success-only, like the reference; raw's row j is entry grow[0]+j
-        sig_cache.add_keys(raw, (sub - grow[0])[okv])
+            with verifier.phases("host_fixup"):
+                fix = np.nonzero(needs)[0]
+                _HOST_FIXUPS.inc(len(fix))
+                for t in fix:
+                    r = nsess.uniq_host_verify(int(sub[t]))
+                    okv[t] = r
+                    if not r:
+                        verifier._fixup_failed = True
+        with verifier.phases("sig_insert"):
+            state.val[sub] = okv
+            # success-only, like the reference; raw's row j is entry grow[0]+j
+            sig_cache.add_keys(raw, (sub - grow[0])[okv])
 
     with verifier.phases("publish"):
         nsess.publish_uniq(grow, state.val[grow].astype(np.int32))
@@ -549,6 +553,7 @@ class IdxFixpoint:
     ):
         self.nsess = nsess
         self.verifier = verifier
+        self._phases = phases_of(verifier)
         self.sig_cache = sig_cache
         self.run_idx = run_idx
         self.exact_fallback = exact_fallback
@@ -571,27 +576,25 @@ class IdxFixpoint:
         self._rounds += 1
         if self._rounds > 1:
             _REINTERPRETED.inc(len(self._pending))
-        with _span("batch.interpret", n=len(self._pending)):
-            interp = self.run_idx(self._pending)
-        with _span("batch.resolve"):
-            rec = _dispatch_uniq(self.nsess, self.verifier, self.sig_cache,
-                                 self._state)
+        interp = self.run_idx(self._pending)  # the owner's `interpret` phase
+        rec = _dispatch_uniq(self.nsess, self.verifier, self.sig_cache,
+                             self._state)
         self._in_flight = (interp, rec)
 
     def _settle_round(self) -> None:
         interp, rec = self._in_flight
         self._in_flight = None
-        with _span("batch.resolve"):
-            _settle_uniq(self.nsess, self.verifier, self.sig_cache,
-                         self._state, rec)
-        ok, err, unk, rec_idx, bounds = interp
-        # exact verdict (unk == 0), or optimistic with every guess
-        # confirmed true — equivalent to an exact pass
-        accept = _accept_mask(self._state, rec_idx, bounds, unk)
-        done = self._pending[accept]
-        self.ok[done] = np.asarray(ok)[accept]
-        self.err[done] = np.asarray(err)[accept]
-        self._pending = self._pending[~accept]
+        _settle_uniq(self.nsess, self.verifier, self.sig_cache,
+                     self._state, rec)
+        with self._phases("accept"):
+            ok, err, unk, rec_idx, bounds = interp
+            # exact verdict (unk == 0), or optimistic with every guess
+            # confirmed true — equivalent to an exact pass
+            accept = _accept_mask(self._state, rec_idx, bounds, unk)
+            done = self._pending[accept]
+            self.ok[done] = np.asarray(ok)[accept]
+            self.err[done] = np.asarray(err)[accept]
+            self._pending = self._pending[~accept]
 
     def abandon(self) -> None:
         """Settle-and-discard the in-flight round without running the
@@ -617,7 +620,7 @@ class IdxFixpoint:
         """Free the native session now, timed as the `release` phase. The
         session's owner calls it after `finish()` (whose exact fallback is
         the session's last reader) or `abandon()`."""
-        with self.verifier.phases("release"):
+        with self._phases("release"):
             self.nsess.release()
 
     def finish(self) -> Tuple[np.ndarray, np.ndarray]:
@@ -810,12 +813,15 @@ def verify_batch_stream(
         script_cache = default_script_cache()
     depth = max(1, int(depth))
     window: List[tuple] = []
+    phases = phases_of(verifier)
 
     def _begin(items):
+        # The sweeps that end these two sections stay unphased: nothing reads
+        # a batch's tiling, and a serving worker pays for every seam.
         with gc_paused(), _span("batch.stream_begin", n=len(items)):
             if native_bridge.available() and _idx_mode_enabled():
                 nsess, preps, script_keys, _ = _prepare_and_probe(
-                    items, script_cache
+                    items, script_cache, phases
                 )
                 if all(p.result is not None or p.ntx is not None
                        for p in preps):
@@ -874,9 +880,11 @@ def _abandon_stream_window(window: List[tuple]) -> None:
 def _prepare_and_probe(
     items: Sequence[BatchItem],
     script_cache: ScriptExecutionCache,
+    phases,
 ):
     """Front half shared by the batch drivers: parse/prepare every item
-    (native session when available) and probe the script-execution cache.
+    (native session when available) and probe the script-execution cache,
+    as the verifier's `prepare` and `probe` phases.
     Returns (nsess, preps, script_keys, use_native)."""
     use_native = native_bridge.available()
     nsess = native_bridge.NativeSession() if use_native else None
@@ -884,7 +892,7 @@ def _prepare_and_probe(
     txdata_cache: Dict[Tuple, PrecomputedTxData] = {}
     spent_memo: Dict[int, Tuple] = {}
     ntx_cache: Optional[Dict] = {} if use_native else None
-    with _span("batch.prepare", n=len(items)):
+    with phases("prepare"):
         preps = [
             _prepare(item, tx_cache, txdata_cache, spent_memo, ntx_cache)
             for item in items
@@ -894,7 +902,7 @@ def _prepare_and_probe(
     # (wtxid, input, flags, prevouts) succeeded before — skip the
     # interpreter and the device outright (validation.cpp:1529-1536).
     script_keys: List[Optional[bytes]] = [None] * len(items)
-    with _span("batch.probe"):
+    with phases("probe"):
         probe_idx: List[int] = []
         probe_parts: List[Tuple[bytes, ...]] = []
         for idx, (item, prep) in enumerate(zip(items, preps, strict=True)):
@@ -938,8 +946,9 @@ def _verify_batch_impl(
     if script_cache is None:
         script_cache = default_script_cache()
 
+    phases = phases_of(verifier)
     nsess, preps, script_keys, use_native = _prepare_and_probe(
-        items, script_cache
+        items, script_cache, phases
     )
 
     # Fast path: with the native core on, every prep either failed
@@ -975,7 +984,7 @@ def _verify_batch_impl(
         return ok, err, checker.unknown, checker.recorded
 
     known: Dict[Tuple, bool] = {}
-    with _span("batch.interpret"):
+    with phases("interpret"):
         native_idx = [
             idx
             for idx, prep in enumerate(preps)
@@ -1037,52 +1046,51 @@ def _verify_batch_impl(
         """Fill `known` for every check: sig-cache probe (keys digested in
         one native call), then ONE deduplicated device dispatch; successes
         feed the cache."""
-        with _span("batch.resolve"):
-            todo: List[SigCheck] = []
-            for chk in checks:
-                key = (chk.kind, chk.data)
-                if key in known:
-                    continue
-                known[key] = False  # placeholder until probed/dispatched
-                todo.append(chk)
-            if todo:
-                # Same observable as the index-mode uniq-list growth: how
-                # many deduplicated checks this batch actually discovered.
-                _UNIQ_CHECKS.inc(len(todo))
-                cache_keys = sig_cache.keys_for_checks(todo)
-                audit = _guards.audit_cache_hits()
-                fresh: List[Tuple[SigCheck, bytes]] = []
-                for chk, ck in zip(todo, cache_keys, strict=True):
-                    if sig_cache.contains_key(ck):
-                        # Audit mode (resilience): re-verify the hit on
-                        # the exact oracle; evict entries proven wrong.
-                        if audit and not verifier._host_check(chk):
-                            _guards.CACHE_POISON_CAUGHT.inc(cache="sig")
-                            sig_cache.discard_key(ck)
-                            fresh.append((chk, ck))
-                        else:
-                            known[(chk.kind, chk.data)] = True
-                    else:
+        todo: List[SigCheck] = []
+        for chk in checks:
+            key = (chk.kind, chk.data)
+            if key in known:
+                continue
+            known[key] = False  # placeholder until probed/dispatched
+            todo.append(chk)
+        if todo:
+            # Same observable as the index-mode uniq-list growth: how
+            # many deduplicated checks this batch actually discovered.
+            _UNIQ_CHECKS.inc(len(todo))
+            cache_keys = sig_cache.keys_for_checks(todo)
+            audit = _guards.audit_cache_hits()
+            fresh: List[Tuple[SigCheck, bytes]] = []
+            for chk, ck in zip(todo, cache_keys, strict=True):
+                if sig_cache.contains_key(ck):
+                    # Audit mode (resilience): re-verify the hit on
+                    # the exact oracle; evict entries proven wrong.
+                    if audit and not verifier._host_check(chk):
+                        _guards.CACHE_POISON_CAUGHT.inc(cache="sig")
+                        sig_cache.discard_key(ck)
                         fresh.append((chk, ck))
-                if fresh:
-                    fresh_checks = [c for c, _ in fresh]
-                    try:
-                        _faults.maybe_raise("batch.dispatch")
-                        run_res = verifier.verify_checks(fresh_checks)
-                    except Exception:
-                        # Driver-level dispatch fault: contain by resolving
-                        # every check on the host-exact oracle (fail-closed
-                        # — latency, never correctness).
-                        _guards.CONTAINED.inc(site="batch.dispatch")
-                        _guards.HOST_EXACT_LANES.inc(len(fresh_checks))
-                        run_res = [
-                            verifier._host_check(c) for c in fresh_checks
-                        ]
-                    for (chk, ck), r in zip(fresh, run_res, strict=True):
-                        known[(chk.kind, chk.data)] = bool(r)
-                        if r:  # success-only insertion, like the reference
-                            sig_cache.add_key(ck)
-            publish_known()
+                    else:
+                        known[(chk.kind, chk.data)] = True
+                else:
+                    fresh.append((chk, ck))
+            if fresh:
+                fresh_checks = [c for c, _ in fresh]
+                try:
+                    _faults.maybe_raise("batch.dispatch")
+                    run_res = verifier.verify_checks(fresh_checks)
+                except Exception:
+                    # Driver-level dispatch fault: contain by resolving
+                    # every check on the host-exact oracle (fail-closed
+                    # — latency, never correctness).
+                    _guards.CONTAINED.inc(site="batch.dispatch")
+                    _guards.HOST_EXACT_LANES.inc(len(fresh_checks))
+                    run_res = [
+                        verifier._host_check(c) for c in fresh_checks
+                    ]
+                for (chk, ck), r in zip(fresh, run_res, strict=True):
+                    known[(chk.kind, chk.data)] = bool(r)
+                    if r:  # success-only insertion, like the reference
+                        sig_cache.add_key(ck)
+        publish_known()
 
     resolve([chk for prep in preps for chk in prep.checks] + drain_spec())
 

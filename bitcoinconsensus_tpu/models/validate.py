@@ -19,7 +19,6 @@ they sit above `CheckInputScripts` in the reference.
 
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import (
     Callable,
@@ -60,6 +59,7 @@ from ..obs import counter as _obs_counter
 from ..obs import histogram as _obs_histogram
 from ..obs import span as _span
 from ..utils.gcpause import gc_paused
+from ..utils.profiling import phases_of
 from .batch import BatchItem, BatchResult, verify_batch
 from .sigcache import ScriptExecutionCache, SigCache
 
@@ -264,7 +264,11 @@ def connect_block(
     """
     from .. import native_bridge
 
-    with gc_paused(), _span("block.connect", height=height):
+    if verifier is None and check_scripts:
+        from ..crypto.jax_backend import default_verifier
+
+        verifier = default_verifier()
+    with gc_paused(phases_of(verifier)), _span("block.connect", height=height):
         if (
             isinstance(coins, native_bridge.NativeCoinsView)
             and native_bridge.available()
@@ -280,7 +284,7 @@ def connect_block(
                 check_scripts, enforce_witness_commitment, pow_limit,
                 sig_cache, script_cache,
             )
-    _count_block(res)
+        _count_block(res)
     return res
 
 
@@ -358,11 +362,7 @@ class _NativeConnect:
         self._nblk = None
         self._run = None  # the script phase's IdxFixpoint, once begun
         self._undo = None  # the speculative apply's undo record, until commit
-
-    def _phase(self, name: str):
-        if self.verifier is None:
-            return nullcontext()
-        return self.verifier.phases(name)
+        self._phase = phases_of(verifier)  # times nothing without a verifier
 
     def _parse(self):
         from .. import native_bridge
@@ -428,14 +428,15 @@ class _NativeConnect:
                     hit = script_cache.contains_keys(raw_keys, n)
             self._raw_keys, self._hit = raw_keys, hit
 
-            nsess = native_bridge.NativeSession()
-            live = np.nonzero(~hit)[0]
-            n_threads = _idx_threads()
-            flags_a = np.full(n, flags, dtype=np.int32)
+            with phase("session_setup"):
+                nsess = native_bridge.NativeSession()
+                live = np.nonzero(~hit)[0]
+                n_threads = _idx_threads()
+                flags_a = np.full(n, flags, dtype=np.int32)
 
-            # Raw NTx pointers, one per input: the txs are owned by the
-            # (live) nblk, so the column outlasts every call below.
-            tx_ptrs = nblk.tx_ptrs()[tx_index]
+                # Raw NTx pointers, one per input: the txs are owned by the
+                # (live) nblk, so the column outlasts every call below.
+                tx_ptrs = nblk.tx_ptrs()[tx_index]
 
             def run_idx(pos):
                 if len(pos) == n:  # common path: whole block, zero-copy
@@ -487,14 +488,15 @@ class _NativeConnect:
 
             from ..core.script_error import ScriptError
 
-            ok, err = self._run.finish()
+            # The fixpoint keeps its verdict arrays: they end with it, in
+            # `_free_block`, not at this frame's return.
+            self._run.finish()
             self._run.release()
-            self._run = None
             with self._phase("results"):
                 # ok/err are written on the live rows only; a hit passed
                 # before.
                 hit = self._hit
-                passed = hit | (ok != 0)
+                passed = hit | (self._run.ok != 0)
                 self.script_cache.add_keys(self._raw_keys, passed & ~hit)
                 # Every passing input is the one frozen success instance;
                 # only a failing input gets a result object of its own.
@@ -502,25 +504,42 @@ class _NativeConnect:
                 failed = np.nonzero(~passed)[0].tolist()
                 for j in failed:
                     input_results[j] = BatchResult(
-                        False, Error.ERR_SCRIPT, ScriptError(int(err[j]))
+                        False, Error.ERR_SCRIPT,
+                        ScriptError(int(self._run.err[j])),
                     )
             if failed:
                 self.result = ConnectResult(
                     False, "block-validation-failed", self._fees,
                     self._sigop_cost, input_results,
                 )
+                if self._undo is None:
+                    self._free_block()
                 return self.result
         if self._undo is None:  # not applied speculatively in begin
             with self._phase("apply"):
                 self.coins.apply_block(self._nblk, self.height)
+            self._free_block()
         self.result = ConnectResult(
             True, None, self._fees, self._sigop_cost, input_results
         )
         return self.result
 
+    def _free_block(self) -> None:
+        """The `block_free` phase: what ends with the run, dropped after its
+        last reader (the apply, or the undo of a speculative one): the
+        parsed block, a speculative apply's undo record, and the fixpoint
+        with its verdict arrays and the block's columns. A block parsed
+        from raw bytes is freed here, `NativeBlock.__del__` (one cached on
+        a `Block` object lives on with it); the arrays go last, so that
+        the allocator's tidying after some 10^5 small frees (glibc
+        consolidates at the next large one) is paid inside the phase."""
+        with self._phase("block_free"):
+            self._nblk = self._undo = None
+            self._run = None
+
     def commit(self) -> None:
         """The speculative apply stands: drop its undo record."""
-        self._undo = None
+        self._free_block()
 
     def rollback(self) -> bool:
         """Take the speculative apply back; True when there was one."""
@@ -529,16 +548,16 @@ class _NativeConnect:
         undo, self._undo = self._undo, None
         with self._phase("undo"):
             self.coins.undo_block(self._nblk, undo)
+        self._free_block()
         return True
 
     def abandon(self) -> bool:
         """For a block whose verdicts nobody will read: settle and
         discard its tickets (`IdxFixpoint.abandon`), insert nothing into a
         cache, take its speculative apply back. Returns `rollback()`'s."""
-        run, self._run = self._run, None
-        if run is not None:
-            run.abandon()
-            run.release()
+        if self._run is not None and self.result is None:  # begun, not finished
+            self._run.abandon()
+            self._run.release()
         return self.rollback()
 
 
@@ -628,7 +647,7 @@ def connect_block_stream(
             True, None, pow_limit, sig_cache, script_cache,
         )
         _STREAM_IN_FLIGHT.observe(len(window) + 1)
-        with gc_paused(), _stream_span("block.stream_begin", height=height):
+        with gc_paused(run._phase), _span("block.stream_begin", height=height):
             try:
                 run.begin(speculate=True)
             except BaseException:
@@ -637,16 +656,16 @@ def connect_block_stream(
         return run
 
     def finish(run: _NativeConnect) -> ConnectResult:
-        with gc_paused(), _stream_span("block.stream_finish", height=run.height):
+        with gc_paused(run._phase), _span("block.stream_finish", height=run.height):
             res = run.finish()
             if res.ok:
                 run.commit()
-        _count_block(res)
-        _STREAM_BLOCKS.inc(result="ok" if res.ok else "reject")
+            _count_block(res)
+            _STREAM_BLOCKS.inc(result="ok" if res.ok else "reject")
         return res
 
     def undo(run: _NativeConnect, abandoned: bool) -> None:
-        with gc_paused():
+        with gc_paused(run._phase):
             if run.abandon():
                 _STREAM_ROLLBACKS.inc()
         if abandoned:
@@ -694,17 +713,6 @@ def _count_block(res: ConnectResult) -> None:
     _BLOCKS.inc(result="ok" if res.ok else "reject")
     if not res.ok and res.reason:
         _BLOCK_REJECTS.inc(reason=res.reason)
-
-
-@contextmanager
-def _stream_span(name: str, **attrs):
-    """A span that is also a `jax.profiler.TraceAnnotation`, so that a
-    device trace shows which block's host half ran under which block's
-    kernel."""
-    import jax
-
-    with _span(name, **attrs), jax.profiler.TraceAnnotation(name, **attrs):
-        yield
 
 
 def _connect_block_impl(

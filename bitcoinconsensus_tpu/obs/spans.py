@@ -5,13 +5,11 @@ timestamps (`time.perf_counter`). Spans nest per thread; each records its
 parent, so a JSONL sink reconstructs the call tree of a verify:
 
     block.connect
-      batch.verify_batch
-        batch.prepare
-        batch.interpret
-        batch.resolve
-          verifier.host_prep
-          verifier.dispatch
-          verifier.sync
+      verifier.parse
+      verifier.interpret
+      verifier.host_prep
+      verifier.dispatch
+      verifier.sync
 
 Every span aggregates into the process-global metrics registry:
 `consensus_span_duration_seconds{span=...}` (histogram — its `_count` is
@@ -20,6 +18,15 @@ raised. With no sink attached that aggregation is the ONLY exit-path work
 — no dict/JSON construction — so instrumentation stays on by default.
 Attach a `JsonlSink` (or anything with a `write(record: dict)` method) to
 additionally stream one JSON line per span.
+
+Every span is also a `jax.profiler.TraceAnnotation` of its own name, on the
+thread it runs on: a profiler session started anywhere in the process
+(`jax.profiler.start_trace`) shows the program's spans on the host lines
+of the same trace as the device's ops, on one clock. This is the package's
+one way to annotate a trace. Names only: attrs stay out of the annotation.
+The class is looked up once JAX is in the process (this package imports
+nothing from JAX itself; a process without JAX pays one `None` check a
+span), and with no session open an annotation is a flag test.
 
 Traces cross threads explicitly: every span carries a `trace` id (the
 root span's id, inherited down the per-thread stack), and
@@ -39,6 +46,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -85,6 +93,24 @@ def monotonic() -> float:
     code (`core/`, `models/`) still may not read it at all.
     """
     return time.perf_counter()
+
+# `jax.profiler.TraceAnnotation` once JAX is in the process; None until
+# then; False where that JAX has no profiler.
+_annotation = None
+
+
+def _find_annotation():
+    """The profiler's annotation class if something has imported JAX (only
+    then can a profiler session exist), else None. Never imports JAX."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation as found
+        except Exception:  # a jaxlib built without the profiler
+            found = False
+        _annotation = found
+    return _annotation or None
+
 
 _ids = itertools.count(1)  # next() is atomic under the GIL
 _tls = threading.local()
@@ -253,6 +279,10 @@ def span(name: str, **attrs):
         trace=parent.trace if parent is not None else None,
     )
     stack.append(sp)
+    ann = _annotation or _find_annotation()
+    if ann is not None:
+        ann = ann(name)
+        ann.__enter__()
     sp.t0 = time.perf_counter()
     try:
         yield sp
@@ -262,6 +292,8 @@ def span(name: str, **attrs):
     finally:
         dt = time.perf_counter() - sp.t0
         sp.duration_s = dt
+        if ann is not None:
+            ann.__exit__(None, None, None)
         if stack[-1] is sp:
             stack.pop()
         else:

@@ -39,21 +39,13 @@ def region_scope(name: str):
     """Inline form: ``with region_scope("point_decode"): ...``.
 
     Legal both under trace and eagerly, so host seams like settle can
-    use it unconditionally: under trace it extends the name stack; a
-    profiler ``TraceAnnotation`` additionally marks the region on the
-    host track of a capture (nanoseconds of overhead when no profiler
-    session is active), which is how eager seams stay attributable.
+    use it unconditionally: under trace it extends the name stack. A
+    host seam shows in a profiler capture through the `obs` span it runs
+    in (`verifier.sync` at the settle seam), not through this scope.
     """
     import jax
 
-    qual = region_name(name)
-    try:
-        ann = jax.profiler.TraceAnnotation(qual)
-    except Exception:  # pragma: no cover - profiler-less builds
-        with jax.named_scope(qual):
-            yield
-        return
-    with jax.named_scope(qual), ann:
+    with jax.named_scope(region_name(name)):
         yield
 
 

@@ -57,6 +57,7 @@ from ..core.script_error import ScriptError
 from ..models.batch import BatchItem, BatchResult
 from ..obs import counter as _obs_counter
 from ..obs import flight as _flight
+from ..obs import histogram as _obs_histogram
 from ..obs import monotonic as _monotonic
 from ..resilience import faults as _faults
 from .server import OverloadError, PendingVerify, VerifyServer
@@ -118,6 +119,15 @@ _I_PROTO_ERRS = _obs_counter(
     "consensus_ingress_protocol_errors_total",
     "malformed/oversized/truncated frames (session closed, typed ERR sent)",
 )
+_I_SECONDS = _obs_histogram(
+    "consensus_ingress_seconds",
+    "a request's time outside the verify server: `decode` from its "
+    "frame's last byte read to submit returned, `respond` from the "
+    "worker resolving it to its verdict frame written and drained",
+    ("stage",),
+)
+_I_DECODE = _I_SECONDS.labels(stage="decode")
+_I_RESPOND = _I_SECONDS.labels(stage="respond")
 
 
 def _note_proto_err(kind: str) -> None:
@@ -492,15 +502,17 @@ class IngressServer:
                 return
             except (_faults.InjectedFault, ConnectionError, OSError):
                 return
+            read_at = _monotonic()
             _I_FRAMES.inc(dir="in")
             _I_BYTES.inc(HEADER_LEN + ln, dir="in")
-            if not await self._dispatch(sess, ftype, payload):
+            if not await self._dispatch(sess, ftype, payload, read_at):
                 return
 
     async def _dispatch(
-        self, sess: _Session, ftype: int, payload: bytes
+        self, sess: _Session, ftype: int, payload: bytes, read_at: float
     ) -> bool:
-        """Handle one inbound frame; False closes the session."""
+        """Handle one inbound frame, whose last byte was read at
+        `read_at`; False closes the session."""
         if ftype != FRAME_REQ:
             _note_proto_err("bad_type")
             await self._send_err(
@@ -522,6 +534,8 @@ class IngressServer:
             return await self._send_err(
                 sess, rid, int(Error.ERR_OVERLOADED), e.reason
             )
+        # No await since the read: one synchronous stretch of the loop.
+        _I_DECODE.observe(_monotonic() - read_at)
         sess.pending[rid] = req
         req.add_done_callback(
             lambda _req, s=sess, r=rid: self._on_settled(s, r)
@@ -530,17 +544,22 @@ class IngressServer:
 
     def _on_settled(self, sess: _Session, rid: int) -> None:
         """Worker-thread → loop-thread hop for one settled request."""
+        resolved_at = _monotonic()  # on the settling (worker) thread
         loop = self._loop
         if loop is None or loop.is_closed():
             return
         try:
             loop.call_soon_threadsafe(
-                lambda: loop.create_task(self._respond(sess, rid))
+                lambda: loop.create_task(
+                    self._respond(sess, rid, resolved_at)
+                )
             )
         except RuntimeError:
             pass  # loop stopped between the check and the call
 
-    async def _respond(self, sess: _Session, rid: int) -> None:
+    async def _respond(
+        self, sess: _Session, rid: int, resolved_at: float
+    ) -> None:
         req = sess.pending.pop(rid, None)
         if req is None or not sess.alive:
             return
@@ -556,7 +575,10 @@ class IngressServer:
                 sess, rid, ERR_INTERNAL, f"{type(e).__name__}: {e}"
             )
             return
-        await self._send(sess, FRAME_RESP, encode_response(rid, res))
+        if await self._send(sess, FRAME_RESP, encode_response(rid, res)):
+            # The hop from the worker to this loop under one GIL, the
+            # encode, the write and the drain.
+            _I_RESPOND.observe(_monotonic() - resolved_at)
 
     async def _send_err(
         self, sess: _Session, rid: int, code: int, reason: str

@@ -360,9 +360,12 @@ class VerifyServer:
     def _worker(self) -> None:
         try:
             while True:
-                first = self._queue.take(
-                    self.max_batch, self.flush_s, block=True
-                )
+                # Between bursts: the wait for traffic, and then for the
+                # first batch's flush trigger.
+                with _span("serving.idle"):
+                    first = self._queue.take(
+                        self.max_batch, self.flush_s, block=True
+                    )
                 if first is None:  # closed and drained
                     return
                 self._run_burst(first)
@@ -404,9 +407,13 @@ class VerifyServer:
             while reqs is not None:
                 inflight.append((reqs, self._note_flush(reqs)))
                 yield [r.item for r in reqs]
-                reqs = self._queue.take(
-                    self.max_batch, self.flush_s, block=False
-                )
+                # Inside a burst, with begun batches in the worker's
+                # hands: returns at once on an empty queue, else waits out
+                # the oldest queued request's flush interval.
+                with _span("serving.take"):
+                    reqs = self._queue.take(
+                        self.max_batch, self.flush_s, block=False
+                    )
                 if reqs is not None:
                     self._inflight_reqs += len(reqs)
 

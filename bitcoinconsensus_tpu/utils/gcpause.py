@@ -23,7 +23,7 @@ from __future__ import annotations
 import gc
 import os
 import threading
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 __all__ = ["gc_paused"]
 
@@ -33,10 +33,12 @@ _reenable = False
 
 
 @contextmanager
-def gc_paused():
+def gc_paused(phases=None):
     """Depth-counted across threads: concurrent verify_batch calls are a
     supported pattern (models/sigcache.py mutex contract), so the
-    collector re-enables only when the LAST paused section exits."""
+    collector re-enables only when the LAST paused section exits.
+    `phases` (the caller's `utils.profiling.Phases`, if it has one) times
+    that exit's young-generation sweep as the `gc_sweep` phase."""
     global _depth, _reenable
     if os.environ.get("BITCOINCONSENSUS_TPU_GC_PAUSE", "") in ("0", "off"):
         yield
@@ -55,4 +57,5 @@ def gc_paused():
             if sweep:
                 gc.enable()
         if sweep:
-            gc.collect(0)  # sweep the sections' young garbage promptly
+            with nullcontext() if phases is None else phases("gc_sweep"):
+                gc.collect(0)  # sweep the sections' young garbage promptly

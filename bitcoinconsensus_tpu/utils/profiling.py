@@ -21,7 +21,13 @@ Usage is unchanged:
     ph = Phases()
     with ph("prep"):
         ...
-    ph.report()  # {"prep": {"secs": ..., "calls": ...}, ...}
+    ph.report()  # {"prep": {"secs": ..., "calls": ..., "outer_secs": ...}, ...}
+
+Phases nest (the mesh verifier's `shard_put` runs inside `dispatch`), so
+the `secs` of a report overlap. `outer_secs` is the part of `secs` a phase
+spent as the outermost open phase of this instance on its thread: over a
+call made on one thread the `outer_secs` tile it without overlap, and
+wall - sum(outer_secs) is the time no phase names.
 
 `Phases(enabled=False)` turns them into no-ops. `reset()` clears only the
 instance's dicts — the cumulative registry metrics are process-global by
@@ -36,7 +42,7 @@ from typing import Dict
 
 from ..obs import spans as _spans
 
-__all__ = ["Phases"]
+__all__ = ["Phases", "phases_of"]
 
 
 class Phases:
@@ -46,6 +52,8 @@ class Phases:
         self._lock = threading.Lock()
         self._secs: Dict[str, float] = {}
         self._calls: Dict[str, int] = {}
+        self._outer: Dict[str, float] = {}
+        self._open = threading.local()  # .depth: phases open on this thread
 
     @contextmanager
     def __call__(self, name: str):
@@ -53,27 +61,44 @@ class Phases:
             yield
             return
         sp = None
+        depth = getattr(self._open, "depth", 0)
+        self._open.depth = depth + 1
         try:
             with _spans.span(f"{self.scope}.{name}") as sp:
                 yield
         finally:
+            self._open.depth = depth
             if sp is not None and sp.duration_s is not None:
                 with self._lock:
                     self._secs[name] = self._secs.get(name, 0.0) + sp.duration_s
                     self._calls[name] = self._calls.get(name, 0) + 1
+                    if depth == 0:
+                        self._outer[name] = self._outer.get(name, 0.0) + sp.duration_s
 
     def reset(self) -> None:
         with self._lock:
             self._secs.clear()
             self._calls.clear()
+            self._outer.clear()
 
     def report(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
             return {
-                k: {"secs": round(self._secs[k], 6), "calls": self._calls[k]}
+                k: {"secs": round(self._secs[k], 6), "calls": self._calls[k],
+                    "outer_secs": round(self._outer.get(k, 0.0), 6)}
                 for k in self._secs
             }
 
     def total(self) -> float:
         with self._lock:
             return sum(self._secs.values())
+
+
+_OFF = Phases(enabled=False)
+
+
+def phases_of(verifier) -> Phases:
+    """`verifier.phases`, the one phase clock of the verify path; a clock
+    that times nothing for None or a stand-in verifier (anything with
+    `verify_checks`), so a driver never asks which it has."""
+    return getattr(verifier, "phases", _OFF)

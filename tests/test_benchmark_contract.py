@@ -31,8 +31,9 @@ from bitcoinconsensus_tpu.models.batch import BatchItem
 from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
 from bitcoinconsensus_tpu.models.validate import connect_block, connect_block_stream
 from bitcoinconsensus_tpu.obs import get_registry
+from bitcoinconsensus_tpu.obs import spans as S
 from bitcoinconsensus_tpu.parallel.mesh import ShardedSecpVerifier, make_mesh
-from bitcoinconsensus_tpu.serving import VerifyServer
+from bitcoinconsensus_tpu.serving import IngressClient, IngressServer, VerifyServer
 from bitcoinconsensus_tpu.utils.blockgen import (
     REGTEST_POW_LIMIT,
     build_block,
@@ -59,11 +60,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 READ = (
     "consensus_cache_hits_total",
     "consensus_cache_lookups_total",
+    "consensus_compile_seconds_total",
     "consensus_dispatch_lanes_total",
     "consensus_dispatch_new_shapes_total",
     "consensus_dispatch_padded_lanes_total",
     "consensus_dispatch_total",
     "consensus_fixpoint_reinterpreted_inputs_total",
+    "consensus_ingress_seconds",
     "consensus_mesh_dispatch_total",
     "consensus_mesh_shard_lanes",
     "consensus_multisig_spec_pairings_total",
@@ -73,6 +76,7 @@ READ = (
     "consensus_serving_batches_total",
     "consensus_serving_queue_wait_seconds",
     "consensus_serving_shed_total",
+    "consensus_span_duration_seconds",
     "consensus_stream_blocks_in_flight",
     "consensus_stream_rollbacks_total",
 )
@@ -102,14 +106,32 @@ NO_SAMPLE_NEEDED = ZERO + MESH_STILL + ("consensus_serving_shed_total",)
 # `PERF.md` section 3 names it with no reader under `benchmarks/` yet (PR 35):
 # the pieces a mesh dispatch crosses the host-device seam in, by direction.
 NO_READER_YET = ("consensus_mesh_transfers_total",)
+# `PERF.md` section 6 reads it beside `compile_s.setup`, not as a metric: the
+# persistent compile cache's hits and the misses it wrote an entry for.
+# Registered is all a process that found every program in the cache shows.
+PERF_MD_ONLY = ("consensus_compile_cache_total",)
 # `PERF.md` section 3: the stretches of a native connect that
 # `verifier.phases` names, every one read through `detail.phase_ms_p50`.
 PHASES = (
     "interpret", "host_prep", "pack", "dispatch", "sync", "parse",
     "block_check", "accounting", "probe", "results", "apply", "undo",
     "publish", "release", "backpressure",
+    # PR 36, siblings of the above: with them the phases tile a connect
+    # (`unphased_ms.connect`, `sig_cache_ms.connect`, `teardown_ms.connect`)
+    "sig_probe", "sig_insert", "block_free", "session_setup", "accept",
+    "gc_sweep",
+    # on the batch path (a served batch): every item parsed and prepared
+    "prepare",
+    # lanes the device would not answer for, resolved on the exact oracle
+    "host_fixup",
     # under the mesh verifier alone, inside `dispatch` and `sync`
     "shard_layout", "shard_put", "shard_exec", "shard_check",
+)
+# The spans whose seconds the served cell's readers take from
+# `consensus_span_duration_seconds{span}` (`layers/_spans.py`).
+SPANS = (
+    "serving.take", "serving.idle", "batch.stream_begin",
+    "batch.stream_finish", "verifier.sync",
 )
 
 
@@ -134,6 +156,26 @@ def workload():
         res = connect_block(raw, to_native_view(coins), HEIGHT,
                             sig_cache=sig, script_cache=script, **connect)
         assert res.ok and len(res.input_results) == 6
+    # and on a warm signature cache alone: interpreted again, every check
+    # found by the probe (`sig_probe`), nothing launched
+    res = connect_block(raw, to_native_view(coins), HEIGHT, sig_cache=sig,
+                        script_cache=ScriptExecutionCache(), **connect)
+    assert res.ok and len(res.input_results) == 6
+
+    # a verifier that will not answer for any lane: the driver resolves
+    # every one on the exact host oracle (`host_fixup`)
+    unsure = TpuSecpVerifier()
+    unsure.phases, settle = verifier.phases, unsure.sync_lanes
+
+    def no_answer(pending, n):
+        settle(pending, n)
+        return np.zeros(n, dtype=bool), np.ones(n, dtype=bool)
+
+    unsure.sync_lanes = no_answer
+    res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        verifier=unsure, sig_cache=SigCache(),
+                        script_cache=ScriptExecutionCache())
+    assert res.ok and len(res.input_results) == 6
 
     # a stream whose second block has one flipped signature: it is begun
     # on a speculative view, rejected where it is finished, and undone
@@ -177,26 +219,61 @@ def workload():
         mp.setattr(verifier, "_native", None)
         assert verifier.verify_checks(ge._example_checks(7)).all()
 
-    # one request through a server
+    # one request through a server, and one through the socket in front of it
     txb, spk, amount = make_p2wpkh_spend("contract/serve")
     item = BatchItem(txb, 0, VERIFY_ALL_LIBCONSENSUS,
                      spent_output_script=spk, amount=amount)
     with VerifyServer(verifier=verifier, max_batch=4, flush_s=0.005,
                       tenant_depth=8) as srv:
         assert srv.verify(item, tenant="contract", timeout=120).ok
+        with IngressServer(srv, idle_s=10.0) as ing:
+            with IngressClient(port=ing.port) as cli:
+                assert cli.verify(item, tenant="contract").ok
 
     return verifier.phases.report(), get_registry().snapshot()
 
 
-@pytest.mark.parametrize("name", READ + ZERO + MESH_STILL + NO_READER_YET + PHASES)
+@pytest.mark.parametrize(
+    "name", READ + ZERO + MESH_STILL + NO_READER_YET + PERF_MD_ONLY + PHASES + SPANS)
 def test_the_benchmark_finds(workload, name):
     phases, snapshot = workload
     if name in PHASES:
         assert phases.get(name, {}).get("calls", 0) > 0, sorted(phases)
+        assert 0.0 <= phases[name]["outer_secs"] <= phases[name]["secs"]
+        return
+    if name in SPANS:
+        timed = {s["labels"]["span"]: s["count"]
+                 for s in snapshot["consensus_span_duration_seconds"]["samples"]}
+        assert timed.get(name, 0) > 0, sorted(timed)
         return
     assert name in snapshot, f"{name} is not registered"
-    if name not in NO_SAMPLE_NEEDED:
+    if name not in NO_SAMPLE_NEEDED + PERF_MD_ONLY:
         assert snapshot[name]["samples"], f"{name} took no sample"
+
+
+def test_ingress_stages_and_compile_stages_are_the_ones_read(workload):
+    """The label values the readers ask for (`layers/ingress_ms.serve.py`,
+    `layers/_setup.py`): a renamed stage reads None on the chip, not here."""
+    _, snapshot = workload
+    stages = {s["labels"]["stage"]: s["count"]
+              for s in snapshot["consensus_ingress_seconds"]["samples"]}
+    assert stages.get("decode", 0) > 0 and stages.get("respond", 0) > 0, stages
+    compiled = {s["labels"]["stage"]: s["value"]
+                for s in snapshot["consensus_compile_seconds_total"]["samples"]}
+    # `warm_kernel`'s first calls traced, lowered and compiled or loaded
+    assert all(compiled.get(k, 0.0) > 0 for k in ("trace", "lower", "backend")), compiled
+
+
+def test_mesh_phases_nest_and_outer_secs_do_not_count_them_twice(workload):
+    """`shard_*` run inside `dispatch` and `sync` (or `backpressure`): their
+    seconds are in `secs` twice over and in `outer_secs` once, so the sum of
+    `outer_secs` stays under the sum of `secs` by at least the nested ones."""
+    phases, _ = workload
+    nested = ("shard_layout", "shard_put", "shard_exec", "shard_check")
+    assert all(phases[n]["outer_secs"] == 0.0 < phases[n]["secs"] for n in nested)
+    outer = sum(e["outer_secs"] for e in phases.values())
+    secs = sum(e["secs"] for e in phases.values())
+    assert outer <= secs - sum(phases[n]["secs"] for n in nested) + 1e-6 * len(phases)
 
 
 def test_lists_are_what_benchmarks_names():
@@ -210,3 +287,131 @@ def test_lists_are_what_benchmarks_names():
                 with open(os.path.join(root, f)) as fh:
                     named |= set(re.findall(r"\bconsensus_[a-z_]+", fh.read()))
     assert named == set(READ + ZERO + MESH_STILL)
+
+
+# -- where the time of a connect goes, by name -------------------------------
+
+
+def _open_phase():
+    """The innermost span open on this thread, as its name."""
+    stack = S._stack()
+    return stack[-1].name if stack else None
+
+
+@pytest.fixture
+def seams(monkeypatch):
+    """Every call of the three seams that used to run outside any phase,
+    each with the span that was open around it."""
+    seen = []
+
+    def recorded(owner, name, label):
+        real = getattr(owner, name)
+
+        def wrapper(self, *a, **k):
+            if isinstance(self, owner):
+                seen.append((label, _open_phase()))
+            return real(self, *a, **k)
+
+        monkeypatch.setattr(owner, name, wrapper)
+
+    recorded(SigCache, "add_keys", "add_keys")
+    recorded(SigCache, "contains_keys", "contains_keys")
+    recorded(native_bridge.NativeBlock, "__del__", "block_free")
+    return seen
+
+
+WHERE = {"add_keys": "verifier.sig_insert", "contains_keys": "verifier.sig_probe",
+         "block_free": "verifier.block_free"}
+
+
+def test_a_connect_runs_its_seams_inside_named_phases(seams):
+    verifier = TpuSecpVerifier()
+    raw, coins = _block("contract/tiling", HEIGHT)
+    sig = SigCache()
+    for script in (ScriptExecutionCache(), ScriptExecutionCache()):
+        res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                            verifier=verifier, sig_cache=sig, script_cache=script)
+        assert res.ok
+    # cold: one insert, no probe (an empty cache is not probed), the block
+    # freed; on the warm signature cache: one probe, an insert of nothing
+    assert [label for label, _ in seams] == [
+        "add_keys", "block_free", "contains_keys", "block_free"]
+    assert all(span == WHERE[label] for label, span in seams), seams
+    report = verifier.phases.report()
+    assert report["block_free"]["calls"] == report["session_setup"]["calls"] == 2
+    assert report["gc_sweep"]["calls"] == 2 and report["accept"]["calls"] == 2
+
+
+def test_a_stream_runs_its_seams_inside_named_phases(seams):
+    verifier = TpuSecpVerifier()
+    first, coins_a = _block("contract/tiling-a", HEIGHT)
+    second, coins_b = _block("contract/tiling-b", HEIGHT + 1)
+    coins_a._map.update(coins_b._map)
+    results = list(connect_block_stream(
+        [first, second], to_native_view(coins_a), HEIGHT, depth=2, verifier=verifier,
+        pow_limit=REGTEST_POW_LIMIT, sig_cache=SigCache(),
+        script_cache=ScriptExecutionCache()))
+    assert [r.ok for r in results] == [True, True]
+    # block 2 is begun before block 1's inserts, so it probes an empty
+    # cache (no call); each block inserts once and is freed at its commit
+    assert sorted(label for label, _ in seams) == ["add_keys"] * 2 + ["block_free"] * 2
+    assert all(span == WHERE[label] for label, span in seams), seams
+    report = verifier.phases.report()
+    # a begin and a finish a block, each a paused section of its own
+    assert report["block_free"]["calls"] == 2 and report["gc_sweep"]["calls"] == 4
+
+
+def test_a_connect_under_the_profiler_shares_its_clock():
+    """One mechanism, `obs.spans.span`, puts every span on the profiler's
+    clock: a connect traced on the CPU backend holds `block.connect` and the
+    `verifier.*` phases on the caller's host line, each inside its parent."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    verifier = TpuSecpVerifier()
+    raw, coins = _block("contract/clock", HEIGHT)
+    view = to_native_view(coins)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as directory:
+        jax.profiler.start_trace(directory, profiler_options=options)
+        try:
+            res = connect_block(raw, view, HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                                verifier=verifier, sig_cache=SigCache(),
+                                script_cache=ScriptExecutionCache())
+        finally:
+            jax.profiler.stop_trace()
+        assert res.ok
+        (path,) = glob.glob(os.path.join(
+            directory, "plugins", "profile", "*", "*.xplane.pb"))
+        data = ProfileData.from_file(path)
+    lines = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                events = [(e.name, int(e.start_ns), int(e.start_ns) + int(e.duration_ns))
+                          for e in line.events
+                          if e.name.startswith(("block.", "verifier."))]
+                if events:
+                    lines[line.name] = events
+    assert len(lines) == 1, sorted(lines)  # the caller's thread, no other
+    (events,) = lines.values()
+    by_name = {}
+    for name, start, end in events:
+        by_name.setdefault(name, []).append((start, end))
+    (c0, c1), = by_name["block.connect"]
+    want = {"verifier." + n for n in (
+        "parse", "block_check", "accounting", "probe", "session_setup", "interpret",
+        "host_prep", "dispatch", "sync", "sig_insert", "publish", "accept", "release",
+        "results", "apply", "block_free")}
+    assert want <= set(by_name), sorted(want - set(by_name))
+    for name in want:
+        assert all(c0 <= a <= b <= c1 for a, b in by_name[name]), name
+    # `gc_sweep` follows the span it closes: a sibling of `block.connect`
+    assert all(a >= c1 for a, _ in by_name["verifier.gc_sweep"])
+    # the phases of one thread do not overlap: they tile the connect
+    inner = sorted(iv for n in want for iv in by_name[n])
+    assert all(b0 <= a1 for (_, b0), (a1, _) in zip(inner, inner[1:]))

@@ -1,0 +1,252 @@
+"""The per-layer readers PR 36 added, each on a made-up `ctx`.
+
+`benchmarks/tests` is not part of tier-1, and a reader runs for real only
+in a `--trace 1` run on the chip. Here every new reader gets a context
+whose answer can be worked out by hand, and one of a program that lacks
+what it reads (the parent commit, under this PR's benchmark files), where
+it has to return None and not raise.
+"""
+
+import importlib.util
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+SPAN_SECONDS = "consensus_span_duration_seconds"
+
+
+def reader(metric: str):
+    path = os.path.join(REPO, "benchmarks", "layers", metric + ".py")
+    spec = importlib.util.spec_from_file_location(f"benchmarks.layers.{metric}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+def ms(x):
+    return pytest.approx(x, abs=1e-9)
+
+
+# -- connect cells ---------------------------------------------------------
+
+
+def phase(secs, outer=None, calls=1):
+    return {"secs": secs, "calls": calls, "outer_secs": secs if outer is None else outer}
+
+
+def connect_ctx(reports, walls):
+    return {"cell": "made-up.connect", "trace": None, "driver": {
+        "kind": "connect", "walls_s": walls, "phases": reports, "deltas": [],
+        "counters_before": {}, "counters_after": {}, "n_inputs": 6}}
+
+
+def _reports():
+    """Three connects; `shard_put` runs inside `dispatch` (mesh), so its
+    seconds are in `dispatch`'s and its `outer_secs` are 0."""
+    out = []
+    for k in range(3):
+        out.append({
+            "interpret": phase(0.010), "dispatch": phase(0.004), "shard_put": phase(0.001, 0.0),
+            "sync": phase(0.020 + 0.001 * k), "sig_probe": phase(0.0005), "sig_insert": phase(0.0025),
+            "block_free": phase(0.002), "release": phase(0.0001 * (k + 1)),
+        })
+    return out
+
+
+WALLS = [0.0500, 0.0520, 0.0560]
+
+
+def without(reports, *names, key=None):
+    return [{n: ({k: v for k, v in e.items() if k != key} if key else e)
+             for n, e in rep.items() if n not in names} for rep in reports]
+
+
+def test_unphased_connect_is_wall_less_outer_secs():
+    # sum of outer_secs: 0.0391, 0.0402, 0.0413 -> residues 10.9, 11.8, 14.7 ms
+    assert reader("unphased_ms.connect")(connect_ctx(_reports(), WALLS)) == ms(11.8)
+
+
+def test_sig_cache_connect_sums_probe_and_insert():
+    assert reader("sig_cache_ms.connect")(connect_ctx(_reports(), WALLS)) == ms(3.0)
+    cold = without(_reports(), "sig_probe")  # an empty cache is not probed
+    assert reader("sig_cache_ms.connect")(connect_ctx(cold, WALLS)) == ms(2.5)
+
+
+def test_teardown_connect_sums_block_free_and_release():
+    assert reader("teardown_ms.connect")(connect_ctx(_reports(), WALLS)) == ms(2.2)
+
+
+@pytest.mark.parametrize("metric,reports", [
+    ("unphased_ms.connect", without(_reports(), key="outer_secs")),  # the parent's reports
+    ("sig_cache_ms.connect", without(_reports(), "sig_probe", "sig_insert")),
+    ("teardown_ms.connect", without(_reports(), "block_free")),  # `release` alone means less
+    ("unphased_ms.connect", []), ("sig_cache_ms.connect", []), ("teardown_ms.connect", []),
+])
+def test_connect_readers_return_none_without_their_phases(metric, reports):
+    assert reader(metric)(connect_ctx(reports, WALLS[: len(reports)])) is None
+
+
+# -- the stream cell ---------------------------------------------------------
+
+
+def stream_ctx(gaps, reports, n_blocks=3):
+    return {"cell": "made-up.stream", "trace": None, "driver": {
+        "kind": "stream", "pass_walls_s": [], "block_gaps_s": gaps, "first_result_s": [],
+        "phases": reports, "counters_before": {}, "counters_after": {},
+        "n_inputs": 6, "n_blocks": n_blocks, "depth": 2}}
+
+
+def test_unphased_stream_skips_a_pass_first_record():
+    # two passes of three blocks: a record a result, a gap between two
+    first = {"parse": 9.0}  # the stretch before a pass's first result: no gap
+    reports = [first, {"parse": 0.010, "sync": 0.002}, {"parse": 0.011, "sync": 0.001},
+               first, {"parse": 0.010, "sync": 0.004}, {"parse": 0.012}]
+    gaps = [0.0130, 0.0125, 0.0160, 0.0125]  # residues 1.0, 0.5, 2.0, 0.5 ms
+    assert reader("unphased_ms.stream")(stream_ctx(gaps, reports)) == ms(0.75)
+
+
+@pytest.mark.parametrize("gaps,reports,n_blocks", [
+    ([0.01] * 4, [{"parse": 0.001}] * 5, 3),  # a record short
+    ([0.01] * 5, [{"parse": 0.001}] * 6, 3),  # a gap too many
+    ([], [{"parse": 0.001}] * 2, 1),  # one block a pass: no gap at all
+    ([0.01], [], 2),
+])
+def test_unphased_stream_returns_none_when_the_lists_do_not_line_up(gaps, reports, n_blocks):
+    assert reader("unphased_ms.stream")(stream_ctx(gaps, reports, n_blocks)) is None
+
+
+# -- the served cell ---------------------------------------------------------
+
+
+def hist(name, label, rows):
+    return {name: {"samples": [
+        {"labels": {label: value}, "sum": total, "count": count}
+        for value, (total, count) in rows.items()]}}
+
+
+def serve_ctx(before_spans, after_spans, batches=(10, 30), ingress=None):
+    before = {**hist(SPAN_SECONDS, "span", before_spans),
+              "consensus_serving_batches_total": {"samples": [{"labels": {}, "value": batches[0]}]}}
+    after = {**hist(SPAN_SECONDS, "span", after_spans),
+             "consensus_serving_batches_total": {"samples": [{"labels": {}, "value": batches[1]}]}}
+    if ingress:
+        before.update(hist("consensus_ingress_seconds", "stage", ingress[0]))
+        after.update(hist("consensus_ingress_seconds", "stage", ingress[1]))
+    return {"cell": "made-up.serve", "trace": None, "driver": {
+        "kind": "serve", "latency_ms": [], "lag_ms": [], "requests": 0,
+        "counters_before": before, "counters_after": after}}
+
+
+BEFORE = {"serving.take": (1.0, 50), "batch.stream_begin": (2.0, 10),
+          "batch.stream_finish": (1.0, 10), "verifier.sync": (0.5, 10), "serving.idle": (30.0, 5)}
+AFTER = {"serving.take": (1.1, 70), "batch.stream_begin": (2.16, 30),
+         "batch.stream_finish": (1.1, 30), "verifier.sync": (0.56, 30), "serving.idle": (37.0, 9)}
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("take_wait_ms.serve", 5.0),  # 0.1 s over 20 batches
+    ("host_ms.serve", 10.0),  # (0.16 + 0.1 - 0.06) s over 20 batches
+    ("settle_wait_ms.serve", 3.0),
+])
+def test_serve_span_readers_divide_by_batches(metric, want):
+    assert reader(metric)(serve_ctx(BEFORE, AFTER)) == ms(want)
+
+
+def test_a_span_first_seen_inside_the_window_counts_from_zero():
+    before = {k: v for k, v in BEFORE.items() if k != "serving.take"}
+    assert reader("take_wait_ms.serve")(serve_ctx(before, AFTER)) == ms(55.0)
+
+
+@pytest.mark.parametrize("metric,gone", [
+    ("take_wait_ms.serve", "serving.take"),  # the parent has no such span
+    ("host_ms.serve", "batch.stream_begin"),
+    ("settle_wait_ms.serve", "verifier.sync"),
+])
+def test_serve_span_readers_return_none_without_their_span(metric, gone):
+    after = {k: v for k, v in AFTER.items() if k != gone}
+    assert reader(metric)(serve_ctx(BEFORE, after)) is None
+    assert reader(metric)(serve_ctx(BEFORE, AFTER, batches=(10, 10))) is None  # no batch
+
+
+def test_ingress_reader_adds_the_two_stage_means():
+    ingress = ({"decode": (0.010, 100), "respond": (0.050, 100)},
+               {"decode": (0.030, 300), "respond": (0.250, 300)})
+    # means: decode 0.1 ms, respond 1.0 ms
+    assert reader("ingress_ms.serve")(serve_ctx(BEFORE, AFTER, ingress=ingress)) == ms(1.1)
+
+
+@pytest.mark.parametrize("ingress", [
+    None,  # the parent: no such histogram
+    ({"decode": (0.01, 100)}, {"decode": (0.03, 300)}),  # a stage missing
+    ({"decode": (0.01, 100), "respond": (0.05, 100)},) * 2,  # no request in the window
+])
+def test_ingress_reader_returns_none_without_both_stages(ingress):
+    assert reader("ingress_ms.serve")(serve_ctx(BEFORE, AFTER, ingress=ingress)) is None
+
+
+# -- set-up, every cell ------------------------------------------------------
+
+
+def setup_ctx(kind, stages):
+    before = {}
+    if stages is not None:
+        before["consensus_compile_seconds_total"] = {"samples": [
+            {"labels": {"stage": s}, "value": v} for s, v in stages.items()]}
+    return {"cell": "made-up", "trace": None,
+            "driver": {"kind": kind, "counters_before": before, "counters_after": {}}}
+
+
+STAGES = {"trace": 40.5, "lower": 4.25, "backend": 50.0, "cache_load": 1.5}
+
+
+@pytest.mark.parametrize("kind", ["connect", "stream", "serve"])
+@pytest.mark.parametrize("metric,want", [("trace_lower_s.setup", 44.75), ("compile_s.setup", 50.0)])
+def test_setup_readers_read_the_window_opening_snapshot(kind, metric, want):
+    assert reader(metric)(setup_ctx(kind, STAGES)) == ms(want)
+
+
+@pytest.mark.parametrize("metric", ["trace_lower_s.setup", "compile_s.setup"])
+def test_setup_readers_return_none_on_a_program_without_the_counter(metric):
+    assert reader(metric)(setup_ctx("connect", None)) is None
+    # registered and never raised (a process that compiled nothing) reads 0
+    assert reader(metric)(setup_ctx("connect", {})) == 0.0
+
+
+@pytest.mark.parametrize("metric,ctx", [
+    ("unphased_ms.connect", stream_ctx([0.01], [{"parse": 0.001}] * 2, 2)),
+    ("sig_cache_ms.connect", serve_ctx(BEFORE, AFTER)),
+    ("teardown_ms.connect", serve_ctx(BEFORE, AFTER)),
+    ("unphased_ms.stream", connect_ctx(_reports(), WALLS)),
+    ("take_wait_ms.serve", connect_ctx(_reports(), WALLS)),
+    ("host_ms.serve", stream_ctx([0.01], [{"parse": 0.001}] * 2, 2)),
+    ("settle_wait_ms.serve", connect_ctx(_reports(), WALLS)),
+    ("ingress_ms.serve", connect_ctx(_reports(), WALLS)),
+])
+def test_a_reader_in_another_kind_of_cell_returns_none(metric, ctx):
+    assert reader(metric)(ctx) is None
+
+
+def test_benchmark_json_lists_each_new_metric_with_its_cells():
+    import json
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    connect = ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "worst-block-mesh4.sigops"]
+    every = [w["name"] for w in bench["workloads"]]
+    want = {
+        "unphased_ms.connect": connect, "sig_cache_ms.connect": connect,
+        "teardown_ms.connect": connect, "unphased_ms.stream": ["ibd-stream.cold"],
+        "take_wait_ms.serve": ["mempool-serve.steady"], "host_ms.serve": ["mempool-serve.steady"],
+        "settle_wait_ms.serve": ["mempool-serve.steady"], "ingress_ms.serve": ["mempool-serve.steady"],
+        "trace_lower_s.setup": every, "compile_s.setup": every,
+    }
+    for name, cells in want.items():
+        assert by_name[name]["workloads"] == cells, name
+        assert os.path.exists(os.path.join(REPO, "benchmarks", "layers", name + ".py"))
+    assert [m["name"] for m in bench["per_layer"]][-len(want):] == list(want)

@@ -176,9 +176,29 @@ def test_malformed_payload_rejected():
 # -- end-to-end over the socket ----------------------------------------
 
 
+def _observations(name, label, value):
+    """(count, sum) of a labelled histogram of the global registry."""
+    from bitcoinconsensus_tpu.obs import get_registry
+
+    for s in get_registry().get(name)._samples():
+        if s["labels"] == {label: value}:
+            return s["count"], s["sum"]
+    return 0, 0.0
+
+
+def _stage(stage):
+    return _observations("consensus_ingress_seconds", "stage", stage)
+
+
+def _span_count(name):
+    return _observations("consensus_span_duration_seconds", "span", name)[0]
+
+
 def test_socket_verify_bit_identical_to_direct():
     items = _items(4)
     direct = verify_batch(items)
+    stages0 = {st: _stage(st) for st in ("decode", "respond")}
+    spans0 = {n: _span_count(n) for n in ("serving.idle", "serving.take")}
     with VerifyServer() as vs:
         with IngressServer(vs, idle_s=10.0) as ing:
             with IngressClient(port=ing.port) as cli:
@@ -188,6 +208,41 @@ def test_socket_verify_bit_identical_to_direct():
         assert (w.ok, w.error, w.script_error) == (
             d.ok, d.error, d.script_error,
         )
+    # Where a served request's time goes, outside the verify server: both
+    # ingress stages once a request (a refused input is a verdict frame
+    # too), each a positive stretch.
+    for st, (count0, sum0) in stages0.items():
+        count, total = _stage(st)
+        assert count - count0 == len(items), st
+        assert total > sum0, st
+    # The worker's waits: one blocking take a burst (and the one that the
+    # close ends), one non-blocking take a batch it handed the driver;
+    # four requests one by one are four bursts of one batch.
+    assert _span_count("serving.idle") - spans0["serving.idle"] == len(items) + 1
+    assert _span_count("serving.take") - spans0["serving.take"] == len(items)
+
+
+@pytest.mark.parametrize("delay_s", [0.0, 0.02])
+def test_ingress_stages_observed_once_a_verdict_frame(delay_s):
+    """`decode` when submit returned, `respond` when the verdict frame was
+    written; resolved inside submit (on the loop's thread) or later on
+    another thread, as the worker does. A shed request took neither."""
+    stub = _StubVerify(delay_s=delay_s)
+    item = BatchItem(b"tx", 0, 0)
+    before = {st: _stage(st) for st in ("decode", "respond")}
+    with IngressServer(stub, idle_s=10.0) as ing:
+        with IngressClient(port=ing.port) as cli:
+            for _ in range(3):
+                assert cli.verify(item).ok
+            stub.shed_reason = "slo"
+            with pytest.raises(OverloadError):
+                cli.verify(item)
+    for st, (count0, sum0) in before.items():
+        count, total = _stage(st)
+        assert count - count0 == 3, st
+        assert total > sum0
+    if delay_s:  # the hop from the settling thread is inside `respond`
+        assert _stage("respond")[1] - before["respond"][1] > 0
 
 
 def test_shed_arrives_as_overloaded_frame_session_survives():

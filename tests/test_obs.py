@@ -12,6 +12,7 @@ import json
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from conftest import *  # noqa: F401,F403 (env setup)
@@ -321,6 +322,209 @@ def test_phases_feed_registry_spans():
         pass
     assert count("obstest.phase1") == before + 1
     assert ph.report()["phase1"]["calls"] == 1
+
+
+# `outer_secs`: the part of a phase's `secs` it spent as the outermost open
+# phase of its `Phases` on its thread. Over a call made on one thread the
+# `outer_secs` tile it, so wall - sum(outer_secs) is what no phase names.
+
+
+def _nested(ph):
+    with ph("dispatch"):
+        with ph("shard_put"):
+            with ph("inmost"):
+                pass
+        with ph("shard_exec"):
+            pass
+    return {"dispatch"}
+
+
+def _siblings(ph):
+    for name in ("parse", "accounting", "parse"):
+        with ph(name):
+            pass
+    return {"parse", "accounting"}
+
+
+def _same_name_nested(ph):
+    with ph("sync"):
+        with ph("sync"):
+            time.sleep(0.002)  # so that the two levels differ after rounding
+    return {"sync"}
+
+
+def _other_instance_inside(ph):
+    other = Phases(scope="obstest-other")
+    with other("host"):  # another clock's phase does not make ours inner
+        with ph("lone"):
+            pass
+    assert other.report()["host"]["outer_secs"] == other.report()["host"]["secs"]
+    return {"lone"}
+
+
+def _after_an_exception(ph):
+    with pytest.raises(RuntimeError):
+        with ph("outer"):
+            with ph("raises"):
+                raise RuntimeError("boom")
+    with ph("next"):  # the depth came back down: outermost again
+        pass
+    return {"outer", "next"}
+
+
+@pytest.mark.parametrize(
+    "case", [_nested, _siblings, _same_name_nested, _other_instance_inside,
+             _after_an_exception],
+)
+def test_phases_outer_secs_tile_a_call(case):
+    ph = Phases(scope="obstest-outer")
+    t0 = time.perf_counter()
+    outermost = case(ph)
+    wall = time.perf_counter() - t0
+    rep = ph.report()
+    for name, entry in rep.items():
+        assert set(entry) == {"secs", "calls", "outer_secs"}
+        if name in outermost and name != "sync":
+            assert entry["outer_secs"] == entry["secs"]
+        elif name not in outermost:
+            assert entry["outer_secs"] == 0.0
+        assert 0.0 <= entry["outer_secs"] <= entry["secs"]
+    assert outermost <= set(rep)
+    # rounding: six digits an entry
+    assert sum(e["outer_secs"] for e in rep.values()) <= wall + 1e-6 * len(rep)
+    # `secs` and `calls` are what they were: nested phases still overlap
+    if case is _nested:
+        assert {n: e["calls"] for n, e in rep.items()} == {
+            "dispatch": 1, "shard_put": 1, "inmost": 1, "shard_exec": 1}
+        assert rep["dispatch"]["secs"] >= rep["shard_put"]["secs"] >= rep["inmost"]["secs"]
+        assert ph.total() == pytest.approx(sum(e["secs"] for e in rep.values()), abs=1e-5)
+    if case is _same_name_nested:
+        assert rep["sync"]["calls"] == 2 and 0 < rep["sync"]["outer_secs"] < rep["sync"]["secs"]
+    ph.reset()
+    assert ph.report() == {}
+
+
+def test_phases_outermost_is_per_thread():
+    """A phase open on another thread does not make this thread's phase
+    inner: each thread's `outer_secs` tile that thread's own wall."""
+    ph = Phases(scope="obstest-threads")
+    inside = threading.Event()
+    done = threading.Event()
+    walls = {}
+
+    def worker():
+        t0 = time.perf_counter()
+        with ph("worker"):
+            inside.set()
+            done.wait(30)
+        walls["worker"] = time.perf_counter() - t0
+
+    t = threading.Thread(target=worker)
+    t0 = time.perf_counter()
+    t.start()
+    assert inside.wait(30)
+    with ph("caller"):  # wholly inside the worker's phase, on this thread
+        with ph("caller_inner"):
+            pass
+    walls["caller"] = time.perf_counter() - t0
+    done.set()
+    t.join()
+    rep = ph.report()
+    assert rep["worker"]["outer_secs"] == rep["worker"]["secs"] <= walls["worker"] + 1e-6
+    assert rep["caller"]["outer_secs"] == rep["caller"]["secs"] <= walls["caller"] + 1e-6
+    assert rep["caller_inner"]["outer_secs"] == 0.0 < rep["caller_inner"]["calls"]
+
+
+# ---------------------------------------------------------------------------
+# The set-up split: JAX's own monitoring events feed the registry.
+
+
+def test_compile_listener_splits_a_first_call():
+    """A function jitted for the first time is traced, lowered and compiled:
+    each of the three stages rises, and the listeners are registered once
+    however often `configure()` runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from bitcoinconsensus_tpu.utils import compile_cache
+
+    compile_cache.configure()
+    compile_cache.configure()
+    seconds = get_registry().get("consensus_compile_seconds_total")
+    assert get_registry().get("consensus_compile_cache_total") is not None
+    x = jnp.arange(11, dtype=jnp.int32)
+    x.block_until_ready()
+    calls = []
+
+    def witness(event, secs, **kw):
+        calls.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(witness)
+    before = {s: seconds.value(stage=s) for s in ("trace", "lower", "backend")}
+    try:
+        fresh = jax.jit(lambda v: (v * 7 + 3) ^ 0x36)  # no other test's program
+        assert np.asarray(fresh(x)).tolist()[:2] == [3 ^ 0x36, 10 ^ 0x36]
+        rose = {s: seconds.value(stage=s) - before[s] for s in before}
+        assert all(v > 0 for v in rose.values()), rose
+        # one program lowered and compiled once (its ops are traced as
+        # jaxprs of their own, so tracing reports more than once)
+        assert calls.count("/jax/core/compile/jaxpr_trace_duration") >= 1
+        for event in ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                      "/jax/core/compile/backend_compile_duration"):
+            assert calls.count(event) == 1, (event, calls)
+        again = {s: seconds.value(stage=s) for s in before}
+        assert np.asarray(fresh(x)).tolist()[2] == 17 ^ 0x36  # a warm call: no event
+        assert {s: seconds.value(stage=s) for s in before} == again
+    finally:
+        jax.monitoring.unregister_event_duration_listener(witness)
+
+
+def test_compile_stages_tile_a_nested_trace():
+    """JAX reports a traced function's duration with, inside it, those of
+    the jitted functions it calls and of the eager ops it compiles on the
+    way. Each stage is credited its own time only, so the stages' sum stays
+    under the wall time where the raw durations' sum does not have to."""
+    import jax
+    import jax.numpy as jnp
+
+    from bitcoinconsensus_tpu.utils import compile_cache
+
+    compile_cache.configure()
+    seconds = get_registry().get("consensus_compile_seconds_total")
+    stages = ("trace", "lower", "backend")
+    raw = []
+
+    def witness(event, secs, **kw):
+        if event.startswith("/jax/core/compile/"):
+            raw.append(secs)
+
+    @jax.jit
+    def inner(v):
+        return v * 5 + 36
+
+    @jax.jit
+    def middle(v):
+        return inner(v) ^ inner(v + 1)
+
+    def outer(v):
+        eager = (jnp.arange(3, dtype=jnp.int32) * 36).sum()  # compiles mid-trace
+        return middle(v) + middle(v * 2) + eager
+
+    x = jnp.arange(9, dtype=jnp.int32)
+    x.block_until_ready()
+    jax.monitoring.register_event_duration_secs_listener(witness)
+    before = {s: seconds.value(stage=s) for s in stages}
+    t0 = time.perf_counter()
+    try:
+        jax.jit(outer)(x).block_until_ready()
+    finally:
+        wall = time.perf_counter() - t0
+        jax.monitoring.unregister_event_duration_listener(witness)
+    rose = {s: seconds.value(stage=s) - before[s] for s in stages}
+    assert all(v > 0 for v in rose.values()), rose
+    assert len(raw) > 3  # nested reports there were
+    assert sum(rose.values()) <= wall
+    assert sum(rose.values()) < sum(raw)  # the nested ones were not counted twice
 
 
 # ---------------------------------------------------------------------------
