@@ -103,6 +103,12 @@ _SPEC_PAIRINGS = _obs_counter(
     "CHECKMULTISIG (signature, key) pairings pre-recorded ahead of the key "
     "walk that became deduplicated checks of their own",
 )
+_SIGHASHES = _obs_counter(
+    "consensus_sighash_total",
+    "ECDSA message digests the native interpreter hashed (computed) or read "
+    "again from a CHECKMULTISIG's record of its signatures (reused)",
+    ("result",),
+)
 _EXACT_FALLBACK = _obs_counter(
     "consensus_exact_fallback_total",
     "inputs resolved by the exact host checker at the round cap",
@@ -635,6 +641,9 @@ class IdxFixpoint:
             self._settle_round()
         _FIXPOINT_ROUNDS.observe(self._rounds)
         _SPEC_PAIRINGS.inc(self.nsess.spec_pairings())
+        computed, reused = self.nsess.sighashes()
+        _SIGHASHES.inc(computed, result="computed")
+        _SIGHASHES.inc(reused, result="reused")
         if len(self._pending):  # round cap hit: exact host fallback
             _EXACT_FALLBACK.inc(len(self._pending))
         for idx in self._pending.tolist():

@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 10:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 10)")
+        if L.nat_version() < 11:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 11)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -219,6 +219,8 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_uniq_count.restype = ctypes.c_int32
         L.nat_session_spec_pairings.argtypes = [vp]
         L.nat_session_spec_pairings.restype = ctypes.c_int64
+        L.nat_session_sighashes.argtypes = [vp, i64p]
+        L.nat_session_sighashes.restype = None
         L.nat_session_recidx_data.argtypes = [vp, i32p, ctypes.c_int64]
         L.nat_session_recidx_data.restype = ctypes.c_int64
         L.nat_session_uniq_lanes.argtypes = [
@@ -804,6 +806,15 @@ class NativeSession:
         list so far (index mode; entries a speculation made, not a key
         walk): monotone over the session's life."""
         return int(lib().nat_session_spec_pairings(self._ptr))
+
+    def sighashes(self) -> Tuple[int, int]:
+        """ECDSA message digests this session's interpretations hashed, and
+        reads of one a CHECKMULTISIG had already made for the same
+        signature (or the same hash-type byte): (computed, reused),
+        monotone over the session's life."""
+        out = (ctypes.c_int64 * 2)()
+        lib().nat_session_sighashes(self._ptr, out)
+        return int(out[0]), int(out[1])
 
     def uniq_lanes(self, idxs: np.ndarray, size: int, n_threads: int = 1):
         """Packed kernel lanes for the uniq entries `idxs`, padded to

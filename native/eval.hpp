@@ -52,6 +52,59 @@ struct CondStack {
     }
 };
 
+// What one execution of OP_CHECKMULTISIG(VERIFY) knows of its signatures
+// apart from any key: the encoding verdict, the body (the signature without
+// its hash-type byte) and the message digest of the op's script code, which
+// is fixed once FindAndDelete has run over every signature. Each is made at
+// most once a signature, on first use, and shared by the speculative
+// pre-recording and the key walk; signatures with the same hash-type byte
+// share one digest. Indexed by the signature's position in the op (0 is the
+// one the walk tries first); lives on the op's stack frame and dies with it.
+struct MultisigSigs {
+    struct Sig {
+        Bytes body, msg;
+        int hash_type = -1;  // -1 until body and msg are made
+        i32 enc = -1;        // -1 until check_signature_encoding has run
+    };
+    Checker& checker;
+    const Bytes& script_code;
+    int sigversion;
+    u32 flags;
+    Sig sigs[MAX_PUBKEYS_PER_MULTISIG];
+
+    MultisigSigs(Checker& c, const Bytes& code, int sv, u32 f)
+        : checker(c), script_code(code), sigversion(sv), flags(f) {}
+
+    i32 encoding(size_t s, const Bytes& sig) {
+        Sig& m = sigs[s];
+        if (m.enc < 0) m.enc = check_signature_encoding(sig, flags);
+        return m.enc;
+    }
+
+    // Body and digest of the non-empty signature at position `s`.
+    const Sig& prepared(size_t s, const Bytes& sig) {
+        Sig& m = sigs[s];
+        if (m.hash_type < 0) {
+            int hash_type = sig.back();
+            m.body.assign(sig.begin(), sig.end() - 1);
+            for (const Sig& o : sigs)
+                if (o.hash_type == hash_type) {
+                    m.msg = o.msg;
+                    break;
+                }
+            m.hash_type = hash_type;
+            if (m.msg.empty()) {  // the first of its hash type: hashed, not reused
+                u8 sighash[32];
+                checker.ecdsa_sighash(hash_type, script_code, sigversion, sighash);
+                m.msg.assign(sighash, sighash + 32);
+                return m;
+            }
+        }
+        if (checker.sess) checker.sess->sighash_reused++;
+        return m;
+    }
+};
+
 // EvalChecksig (interpreter.cpp:345-429). Returns continue_ok; sets
 // *success / *err.
 inline bool eval_checksig(const Bytes& sig, const Bytes& pubkey,
@@ -538,6 +591,9 @@ inline EvalResult eval_script(Stack& stack, const Bytes& script, u32 flags,
                             }
                         }
 
+                        MultisigSigs sigs(checker, script_code, sigversion, flags);
+                        const size_t isig0 = isig;
+
                         // Deferring mode: pre-record every pairing the
                         // cursor walk below could reach (failure consumes a
                         // key, success consumes both, so key-idx - sig-idx
@@ -545,19 +601,17 @@ inline EvalResult eval_script(Stack& stack, const Bytes& script, u32 flags,
                         // answers any re-interpretation's oracle reads.
                         if (checker.mode == MODE_DEFER && checker.sess) {
                             i64 spare = n_keys - n_sigs;
-                            Bytes sig_body, msg;
                             for (i64 s = 0; s < n_sigs; s++) {
                                 const Bytes& vs =
                                     stack[stack.size() - isig - (size_t)s];
-                                if (!checker.speculate_ecdsa_prep(
-                                        vs, script_code, sigversion, &sig_body,
-                                        &msg))
-                                    continue;
+                                if (vs.empty()) continue;
+                                const MultisigSigs::Sig& m =
+                                    sigs.prepared((size_t)s, vs);
                                 for (i64 kk = s; kk <= s + spare; kk++) {
                                     const Bytes& vp =
                                         stack[stack.size() - ikey - (size_t)kk];
-                                    checker.speculate_ecdsa_record(vp, sig_body,
-                                                                   msg);
+                                    checker.speculate_ecdsa_record(vp, m.body,
+                                                                   m.msg);
                                 }
                             }
                         }
@@ -566,12 +620,16 @@ inline EvalResult eval_script(Stack& stack, const Bytes& script, u32 flags,
                         while (f_success && n_sigs > 0) {
                             const Bytes& vch_sig = stack[stack.size() - isig];
                             const Bytes& vch_pub = stack[stack.size() - ikey];
-                            i32 e = check_signature_encoding(vch_sig, flags);
+                            i32 e = sigs.encoding(isig - isig0, vch_sig);
                             if (e == SE_OK)
                                 e = check_pubkey_encoding(vch_pub, flags, sigversion);
                             if (e != SE_OK) return {false, e};
-                            bool f_ok = checker.check_ecdsa_signature(
-                                vch_sig, vch_pub, script_code, sigversion);
+                            bool f_ok = false;
+                            if (Checker::ec_check_plausible(vch_sig, vch_pub)) {
+                                const MultisigSigs::Sig& m =
+                                    sigs.prepared(isig - isig0, vch_sig);
+                                f_ok = checker.resolve(0, 0, vch_pub, m.body, m.msg);
+                            }
                             if (f_ok) {
                                 isig += 1;
                                 n_sigs -= 1;

@@ -1149,6 +1149,11 @@ struct Session {
     std::vector<Record> spec;
     std::set<std::string> spec_seen;
     int unknown = 0;
+    // ECDSA message digests hashed by this session's interpretations, and
+    // reads of one that an earlier pairing of the same CHECKMULTISIG had
+    // made (eval.hpp MultisigSigs); monotone, worker scratches summed in.
+    i64 sighash_computed = 0;
+    i64 sighash_reused = 0;
 
     static std::string key(const PartsView& v) {
         std::string k;
@@ -1223,38 +1228,30 @@ struct Checker {
         return !sig.empty() && pubkey_plausible(pubkey);
     }
 
-    void ecdsa_sighash(const Bytes& sig, const Bytes& script_code,
-                       int sigversion, Bytes* sig_body, Bytes* msg) {
-        int hash_type = sig.back();
-        sig_body->assign(sig.begin(), sig.end() - 1);
-        u8 sighash[32];
+    // The ECDSA message digest of `script_code` under `hash_type`: a
+    // function of the signature's hash-type byte and never of the key.
+    void ecdsa_sighash(int hash_type, const Bytes& script_code, int sigversion,
+                       u8 out[32]) {
         if (sigversion == SV_WITNESS_V0) {
-            bip143_sighash(script_code, *tx, n_in, hash_type, amount, sighash);
+            bip143_sighash(script_code, *tx, n_in, hash_type, amount, out);
         } else {
-            legacy_sighash(script_code, *tx, n_in, hash_type, sighash);
+            legacy_sighash(script_code, *tx, n_in, hash_type, out);
         }
-        msg->assign(sighash, sighash + 32);
+        if (sess) sess->sighash_computed++;
     }
 
+    // OP_CHECKSIG's check: one signature, one key, one digest.
     bool check_ecdsa_signature(const Bytes& sig, const Bytes& pubkey,
                                const Bytes& script_code, int sigversion) {
         if (!ec_check_plausible(sig, pubkey)) return false;
-        Bytes sig_body, msg;
-        ecdsa_sighash(sig, script_code, sigversion, &sig_body, &msg);
+        u8 sighash[32];
+        ecdsa_sighash(sig.back(), script_code, sigversion, sighash);
+        Bytes sig_body(sig.begin(), sig.end() - 1), msg(sighash, sighash + 32);
         return resolve(0, 0, pubkey, sig_body, msg);
     }
 
-    // Speculative CHECKMULTISIG pre-recording, split so the sighash (a
-    // function of the sig's hash_type only, not the key) is computed ONCE
-    // per sig: prep yields (sig_body, msg), then record per reachable key.
-    bool speculate_ecdsa_prep(const Bytes& sig, const Bytes& script_code,
-                              int sigversion, Bytes* sig_body, Bytes* msg) {
-        if (mode != MODE_DEFER || !sess) return false;
-        if (sig.empty()) return false;
-        ecdsa_sighash(sig, script_code, sigversion, sig_body, msg);
-        return true;
-    }
-
+    // Speculative CHECKMULTISIG pre-recording of one (signature, key)
+    // pairing; the op's MultisigSigs (eval.hpp) made `sig_body` and `msg`.
     void speculate_ecdsa_record(const Bytes& pubkey, const Bytes& sig_body,
                                 const Bytes& msg) {
         if (!pubkey_plausible(pubkey)) return;

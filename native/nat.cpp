@@ -268,7 +268,8 @@ extern "C" {
 // 9: nat_session_spec_pairings.
 // 10: nat_block_accounting takes the script cache's salt and makes the
 //     keys; nat_block_script_keys copies them out; nat_block_nowit_sizes.
-int nat_version() { return 10; }
+// 11: nat_session_sighashes.
+int nat_version() { return 11; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -1010,6 +1011,8 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     // over the same shard sequence.
     for (i32 t = 0; t < T; t++) {
         const Session& sc = scratch[t];
+        sess->sighash_computed += sc.sighash_computed;
+        sess->sighash_reused += sc.sighash_reused;
         std::vector<i32> remap(sc.uniq.size());
         for (size_t j = 0; j < sc.uniq.size(); j++)
             remap[j] = sess->uniq.intern(sc.uniq.entries[j].hash,
@@ -1034,6 +1037,15 @@ i32 nat_session_uniq_count(void* s) {
 // session so far (CheckStore::spec_entries; index mode).
 i64 nat_session_spec_pairings(void* s) {
     return static_cast<Session*>(s)->uniq.spec_entries;
+}
+
+// ECDSA message digests this session's interpretations have hashed
+// (out[0]) and read again from a CHECKMULTISIG's record of its signatures
+// (out[1]) so far.
+void nat_session_sighashes(void* s, i64* out) {
+    auto* sess = static_cast<Session*>(s);
+    out[0] = sess->sighash_computed;
+    out[1] = sess->sighash_reused;
 }
 
 // A stale or negative uniq index from the driver is an OOB read / heap

@@ -52,6 +52,7 @@ REQUIRED_METRICS = [
     "consensus_fixpoint_rounds",
     "consensus_uniq_checks_total",
     "consensus_prep_lanes_total",
+    "consensus_sighash_total",
     # caches
     "consensus_cache_lookups_total",
     "consensus_cache_hits_total",

@@ -429,6 +429,9 @@ def test_fixpoint_round_cap_exact_fallback():
         def spec_pairings(self):
             return 0
 
+        def sighashes(self):
+            return 0, 0
+
     calls = {"rounds": 0, "fallback": []}
     live = [3, 5, 8, 13]
 

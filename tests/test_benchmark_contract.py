@@ -76,6 +76,7 @@ READ = (
     "consensus_serving_batches_total",
     "consensus_serving_queue_wait_seconds",
     "consensus_serving_shed_total",
+    "consensus_sighash_total",
     "consensus_span_duration_seconds",
     "consensus_stream_blocks_in_flight",
     "consensus_stream_rollbacks_total",
@@ -262,6 +263,16 @@ def test_ingress_stages_and_compile_stages_are_the_ones_read(workload):
                 for s in snapshot["consensus_compile_seconds_total"]["samples"]}
     # `warm_kernel`'s first calls traced, lowered and compiled or loaded
     assert all(compiled.get(k, 0.0) > 0 for k in ("trace", "lower", "backend")), compiled
+
+
+def test_sighash_results_are_the_ones_read(workload):
+    """`layers/sighashes_per_input.connect.py` asks for `result="computed"`.
+    The workload's 2-of-3 connects make both: a digest a signature hashed
+    once a round, and read again by every pairing of the key walk."""
+    _, snapshot = workload
+    results = {s["labels"]["result"]: s["value"]
+               for s in snapshot["consensus_sighash_total"]["samples"]}
+    assert results.get("computed", 0) > 0 and results.get("reused", 0) > 0, results
 
 
 def test_mesh_phases_nest_and_outer_secs_do_not_count_them_twice(workload):

@@ -1,4 +1,4 @@
-"""The per-layer readers PR 36 added, each on a made-up `ctx`.
+"""The per-layer readers PRs 36 and 38 added, each on a made-up `ctx`.
 
 `benchmarks/tests` is not part of tier-1, and a reader runs for real only
 in a `--trace 1` run on the chip. Here every new reader gets a context
@@ -33,6 +33,37 @@ def ms(x):
 
 
 # -- connect cells ---------------------------------------------------------
+
+
+def sighash_ctx(kind, computed, reused, connects=3):
+    """A window of `connects` connects of 6 inputs over which the labeled
+    counter rose from (100, 1000) by (`computed`, `reused`)."""
+    def snap(c, r):
+        return {"consensus_sighash_total": {"samples": [
+            {"labels": {"result": "computed"}, "value": c},
+            {"labels": {"result": "reused"}, "value": r}]}}
+    return {"cell": "made-up", "trace": None, "driver": {
+        "kind": kind, "walls_s": [0.05] * connects, "n_inputs": 6,
+        "counters_before": snap(100, 1000), "counters_after": snap(100 + computed, 1000 + reused)}}
+
+
+@pytest.mark.parametrize("computed,want", [(36, 2.0), (414, 23.0), (0, 0.0)])
+def test_sighashes_per_input_divides_computed_digests_by_inputs_verified(computed, want):
+    # 18 inputs verified; the digests read again are not in it
+    assert reader("sighashes_per_input.connect")(sighash_ctx("connect", computed, 378)) == ms(want)
+
+
+@pytest.mark.parametrize("ctx", [
+    {"cell": "made-up", "trace": None, "driver": {  # the parent: no such counter
+        "kind": "connect", "walls_s": [0.05], "n_inputs": 6,
+        "counters_before": {"consensus_dispatch_total": {"samples": []}},
+        "counters_after": {"consensus_dispatch_total": {"samples": []}}}},
+    sighash_ctx("connect", 36, 378, connects=0),  # a window that timed nothing
+    sighash_ctx("stream", 36, 378),
+    sighash_ctx("serve", 36, 378),
+])
+def test_sighashes_per_input_returns_none_with_nothing_to_read(ctx):
+    assert reader("sighashes_per_input.connect")(ctx) is None
 
 
 def phase(secs, outer=None, calls=1):
@@ -245,6 +276,7 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "take_wait_ms.serve": ["mempool-serve.steady"], "host_ms.serve": ["mempool-serve.steady"],
         "settle_wait_ms.serve": ["mempool-serve.steady"], "ingress_ms.serve": ["mempool-serve.steady"],
         "trace_lower_s.setup": every, "compile_s.setup": every,
+        "sighashes_per_input.connect": ["worst-block.sigops", "worst-block-mesh4.sigops"],  # PR 38
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name

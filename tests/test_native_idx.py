@@ -16,7 +16,9 @@ digest_checks + add_known_batch):
   afterwards (index_mode resets — the ADVICE r4 protocol-mixing trap).
 """
 
+import functools
 import hashlib
+from dataclasses import dataclass
 
 import numpy as np
 import pytest
@@ -24,12 +26,34 @@ import pytest
 from conftest import *  # noqa: F401,F403 (env setup)
 
 from bitcoinconsensus_tpu import native_bridge
+from bitcoinconsensus_tpu.core import flags as F
 from bitcoinconsensus_tpu.core.flags import (
     VERIFY_ALL_EXTENDED,
     VERIFY_ALL_LIBCONSENSUS,
 )
+from bitcoinconsensus_tpu.core.interpreter import (
+    TransactionSignatureChecker,
+    verify_script,
+)
+from bitcoinconsensus_tpu.core.script import OP_CODESEPARATOR, OP_DROP, push_data
+from bitcoinconsensus_tpu.core.script_error import ScriptError
+from bitcoinconsensus_tpu.core.sighash import (
+    SIGHASH_ALL,
+    SIGHASH_ANYONECANPAY,
+    SIGHASH_NONE,
+    SIGHASH_SINGLE,
+    PrecomputedTxData,
+    bip143_sighash,
+    legacy_sighash,
+)
+from bitcoinconsensus_tpu.core.tx import OutPoint, Tx, TxIn, TxOut
+from bitcoinconsensus_tpu.crypto import secp_host as H
+from bitcoinconsensus_tpu.models.batch import DeferringSignatureChecker
 from bitcoinconsensus_tpu.models.sigcache import SigCache
 from bitcoinconsensus_tpu.utils.blockgen import build_spend_tx, make_funded_view
+from bitcoinconsensus_tpu.utils.hashes import hash160
+
+from test_worst_block import multisig_script
 
 pytestmark = [
     pytest.mark.skipif(
@@ -521,3 +545,275 @@ def test_random_protocol_interleavings_match_a_dict_oracle(seed):
             assert bool(okx) == bool(exact_ref[0][i])
             assert int(errx) == int(exact_ref[1][i])
     assert universe and sess.uniq_count()
+
+
+# -- one CHECKMULTISIG's record of its signatures ---------------------------
+# native/eval.hpp MultisigSigs: the digest, the body and the encoding verdict
+# of a signature are made once an op and shared by the speculation and the
+# key walk. Every case runs the native core (exact; deferring on one thread
+# and on four) against the executable spec (core/interpreter.py): same ok,
+# same ScriptError, the same checks in the same order.
+
+_MS_AMOUNT = 500_000
+_MS_FLAGS = F.VERIFY_P2SH | F.VERIFY_WITNESS | F.VERIFY_NULLDUMMY
+_MS_REPLICAS = 8  # verify_inputs_idx shards from 2 inputs a thread
+
+
+@dataclass
+class _MsCase:
+    tx: Tx
+    spk: bytes
+    flags: int
+    # Per CHECKMULTISIG executed, in order: (script code, is witness v0,
+    # signatures in walk order, keys in walk order).
+    ops: list
+    # (computed, reused) a round of one input, where the case pins them.
+    defer_counts: list = None
+    exact_counts: tuple = None
+
+    n_in = 1  # of two inputs and one output: SIGHASH_SINGLE has no output
+
+    def spent(self):
+        return [(_MS_AMOUNT, b"\x51"), (_MS_AMOUNT, self.spk)]
+
+    def digest(self, script_code, v0, hash_type):
+        if v0:
+            return bip143_sighash(script_code, self.tx, self.n_in, hash_type, _MS_AMOUNT)
+        return legacy_sighash(script_code, self.tx, self.n_in, hash_type)
+
+    def uniq(self):
+        """What the speculation pre-records: every signature against every
+        key its cursor can reach, op by op, each check once."""
+        out = []
+        for script_code, v0, sigs, keys in self.ops:
+            for s, sig in enumerate(sigs):
+                if not sig:
+                    continue
+                msg = self.digest(script_code, v0, sig[-1])
+                for key in keys[s : s + len(keys) - len(sigs) + 1]:
+                    chk = ("ecdsa", (key, sig[:-1], msg))
+                    if chk not in out:
+                        out.append(chk)
+        return out
+
+
+def _ms_keys(tag, n):
+    base = int.from_bytes(hashlib.sha256(tag.encode()).digest(), "big") % (H.N - n)
+    sks = [base + 1 + j for j in range(n)]
+    return sks, [H.pubkey_create(sk) for sk in sks]
+
+
+def _ms_tx():
+    ops = [OutPoint(hashlib.sha256(b"ms/op/%d" % i).digest(), i) for i in range(2)]
+    return Tx(version=2, vin=[TxIn(op) for op in ops],
+              vout=[TxOut(2 * _MS_AMOUNT - 1000, b"\x00\x14" + b"\x22" * 20)], locktime=0)
+
+
+def _ms_der(r, s):
+    def integer(v):
+        b = v.to_bytes(32, "big").lstrip(b"\x00") or b"\x00"
+        return b"\x02" + bytes([len(b) + (b[0] >> 7)]) + b"\x00" * (b[0] >> 7) + b
+    body = integer(r) + integer(s)
+    return b"\x30" + bytes([len(body)]) + body
+
+
+def _ms_high_s(sig):
+    r, s = H.parse_der_lax(sig)
+    return _ms_der(r, H.N - s)
+
+
+def _ms_non_der(sig):
+    """One zero byte more than DER allows in front of R: the lax parser
+    reads the same (r, s), the strict one refuses."""
+    r_len = sig[3]
+    return (b"\x30" + bytes([sig[1] + 1]) + b"\x02" + bytes([r_len + 1]) + b"\x00"
+            + sig[4:])
+
+
+def _ms_place(case_tx, kind, script, pushes):
+    """Put `script` behind `kind` and the pushes (bottom first) in front of
+    it; returns the scriptPubKey."""
+    txin = case_tx.vin[_MsCase.n_in]
+    if kind == "p2wsh":
+        txin.witness = list(pushes) + [script]
+        return b"\x00\x20" + hashlib.sha256(script).digest()
+    txin.script_sig = b"".join(push_data(p) if p else b"\x00" for p in pushes)
+    if kind == "p2sh":
+        txin.script_sig += push_data(script)
+        return b"\xa9\x14" + hash160(script) + b"\x87"
+    return script
+
+
+def _ms_hash_types(kind):
+    """3-of-5 by the keys pushed first, third and fifth, each signature
+    under another hash type: the walk pairs two of them wrongly first."""
+    sks, pubs = _ms_keys("ms/types/" + kind, 5)
+    script, tx, v0 = multisig_script(3, pubs), _ms_tx(), kind == "p2wsh"
+    case = _MsCase(tx, b"", _MS_FLAGS | F.VERIFY_DERSIG, [])
+    types = (SIGHASH_ALL, SIGHASH_NONE | SIGHASH_ANYONECANPAY, SIGHASH_SINGLE)
+    sigs = [H.sign_ecdsa(sks[k], case.digest(script, v0, t)) + bytes([t])
+            for k, t in zip((0, 2, 4), types)]
+    case.spk = _ms_place(tx, kind, script, [b""] + sigs)
+    case.ops = [(script, v0, sigs[::-1], pubs[::-1])]
+    return case
+
+
+def _ms_find_and_delete():
+    """Legacy 2-of-3 whose scriptPubKey also pushes one of the two
+    signatures: FindAndDelete takes that push out of what both sign."""
+    sks, pubs = _ms_keys("ms/fad", 3)
+    tx = _ms_tx()
+    code = bytes([OP_DROP]) + multisig_script(2, pubs)
+    case = _MsCase(tx, b"", _MS_FLAGS | F.VERIFY_DERSIG, [])
+    sig_a = H.sign_ecdsa(sks[0], case.digest(code, False, SIGHASH_ALL)) + bytes([SIGHASH_ALL])
+    sig_b = H.sign_ecdsa(sks[1], case.digest(code, False, SIGHASH_NONE)) + bytes([SIGHASH_NONE])
+    case.spk = _ms_place(tx, "bare", push_data(sig_a) + code, [b"", sig_a, sig_b])
+    case.ops = [(code, False, [sig_b, sig_a], pubs[::-1])]
+    return case
+
+
+def _ms_two_ops(kind):
+    """`1 k0 k1 2 CHECKMULTISIG DROP CODESEPARATOR 1 k0 k1 2 CHECKMULTISIG`
+    with one signature given to both: it signs the second op's script code,
+    so the first op fails and the second passes only on a digest of its own."""
+    sks, pubs = _ms_keys("ms/two/" + kind, 2)
+    second = multisig_script(1, pubs)
+    script = second + bytes([OP_DROP, OP_CODESEPARATOR]) + second
+    tx, v0 = _ms_tx(), kind == "p2wsh"
+    case = _MsCase(tx, b"", _MS_FLAGS | F.VERIFY_DERSIG, [])
+    sig = H.sign_ecdsa(sks[0], case.digest(second, v0, SIGHASH_ALL)) + bytes([SIGHASH_ALL])
+    case.spk = _ms_place(tx, kind, script, [b"", sig, b"", sig])
+    case.ops = [(script, v0, [sig], pubs[::-1]), (second, v0, [sig], pubs[::-1])]
+    return case
+
+
+def _ms_encoding(fault, at, flag):
+    """P2WSH 2-of-3 by the two keys pushed first. `at` 1: the faulty
+    signature is the one the walk tries first; `at` 2: the other one, which
+    the walk reaches once the first has taken a key."""
+    sks, pubs = _ms_keys(f"ms/enc/{fault}/{at}/{flag}", 3)
+    script, tx = multisig_script(2, pubs), _ms_tx()
+    case = _MsCase(tx, b"", _MS_FLAGS | getattr(F, "VERIFY_" + flag), [])
+    msg = case.digest(script, True, SIGHASH_ALL)
+    wrong = hashlib.sha256(msg).digest()
+    bodies = [H.sign_ecdsa(sk, msg) for sk in sks[:2]]
+    victim = 2 - at  # push position: the walk starts from the last pushed
+    if fault.startswith("wrong-"):
+        bodies[victim] = H.sign_ecdsa(sks[victim], wrong)
+    remake = _ms_high_s if fault.endswith("high-s") else _ms_non_der
+    bodies[victim] = remake(bodies[victim])
+    sigs = [b + bytes([SIGHASH_ALL]) for b in bodies]
+    case.spk = _ms_place(tx, "p2wsh", script, [b""] + sigs)
+    case.ops = [(script, True, sigs[::-1], pubs[::-1])]
+    return case
+
+
+def _ms_pin(position):
+    """1-of-20 behind P2WSH, the worst block's input: the walk starts at
+    the last-pushed key, so the first-pushed one costs all 20 pairings."""
+    k = {"first-pushed": 0, "middle": 9, "last-pushed": 19}[position]
+    sks, pubs = _ms_keys("ms/pin/" + position, 20)
+    script, tx = multisig_script(1, pubs), _ms_tx()
+    case = _MsCase(tx, b"", _MS_FLAGS | F.VERIFY_DERSIG, [])
+    sig = H.sign_ecdsa(sks[k], case.digest(script, True, SIGHASH_ALL)) + bytes([SIGHASH_ALL])
+    case.spk = _ms_place(tx, "p2wsh", script, [b"", sig])
+    case.ops = [(script, True, [sig], pubs[::-1])]
+    walk = 20 - k  # pairings of the exact walk
+    # One digest a round; one read of it a pairing the walk makes: the one
+    # optimistic pairing in round one, the whole walk in round two.
+    # The last-pushed key's one guess holds, and there is no round two.
+    case.defer_counts = [(1, 1)] + ([(1, walk)] if walk > 1 else [])
+    case.exact_counts = (1, walk - 1)
+    return case
+
+
+_MS_CASES = {
+    **{f"hash-types-{k}": functools.partial(_ms_hash_types, k)
+       for k in ("bare", "p2sh", "p2wsh")},
+    "find-and-delete": _ms_find_and_delete,
+    **{f"two-ops-codeseparator-{k}": functools.partial(_ms_two_ops, k)
+       for k in ("bare", "p2wsh")},
+    **{f"{fault}-at-pairing-{'1' if at == 1 else 'k'}-{flag}":
+       functools.partial(_ms_encoding, fault, at, flag)
+       for fault in ("high-s", "non-der") for at in (1, 2)
+       for flag in ("LOW_S", "DERSIG", "NULLFAIL")},
+    **{f"wrong-high-s-at-pairing-{'1' if at == 1 else 'k'}-NULLFAIL":
+       functools.partial(_ms_encoding, "wrong-high-s", at, "NULLFAIL") for at in (1, 2)},
+    **{f"count-{p}": functools.partial(_ms_pin, p)
+       for p in ("first-pushed", "middle", "last-pushed")},
+}
+
+_MS_ERRORS = {  # what the spec must say, so a case cannot pass by testing nothing
+    "LOW_S": {"high-s": ScriptError.SIG_HIGH_S, "non-der": ScriptError.SIG_DER},
+    "DERSIG": {"high-s": ScriptError.OK, "non-der": ScriptError.SIG_DER},
+    "NULLFAIL": {"high-s": ScriptError.OK, "non-der": ScriptError.OK,
+                 "wrong-high-s": ScriptError.SIG_NULLFAIL},
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _ms_case(name):
+    return _MS_CASES[name]()
+
+
+def _ms_spec(case, checker_type, **kw):
+    txin = case.tx.vin[case.n_in]
+    txdata = PrecomputedTxData(case.tx, [TxOut(a, s) for a, s in case.spent()])
+    checker = checker_type(case.tx, case.n_in, _MS_AMOUNT, txdata, **kw)
+    ok, err = verify_script(txin.script_sig, case.spk, txin.witness, case.flags, checker)
+    return bool(ok), int(err), checker
+
+
+@pytest.mark.parametrize("mode", ["exact", "defer-1-thread", "defer-4-threads"])
+@pytest.mark.parametrize("name", list(_MS_CASES))
+def test_multisig_signature_record_equals_the_spec(name, mode):
+    case = _ms_case(name)
+    want_ok, want_err, _ = _ms_spec(case, TransactionSignatureChecker)
+    if "-at-pairing-" in name:
+        fault, flag = name.split("-at-pairing-")[0], name.rsplit("-", 1)[1]
+        assert want_err == _MS_ERRORS[flag][fault]
+    else:
+        assert want_ok
+    ntx = native_bridge.NativeTx(case.tx.serialize())
+    ntx.set_spent_outputs(case.spent())
+    sess = native_bridge.NativeSession()
+
+    if mode == "exact":
+        ok, err, unk = sess.verify_input(ntx, case.n_in, _MS_AMOUNT, case.spk, case.flags,
+                                         mode=native_bridge.NativeSession.MODE_EXACT)
+        assert (bool(ok), err, unk) == (want_ok, want_err, 0)
+        if case.exact_counts:
+            assert sess.sighashes() == case.exact_counts
+        return
+
+    R, T = _MS_REPLICAS, int(mode.split("-")[1])
+    args = ([ntx] * R, [case.n_in] * R, [_MS_AMOUNT] * R, [case.spk] * R, [case.flags] * R)
+    expected = case.uniq()
+    expected_keys = native_bridge.digest_checks(_SALT, expected)
+    digests_a_round = sum(len({s[-1] for s in sigs if s}) for _c, _v, sigs, _k in case.ops)
+    known, counts = {}, []
+    for _round in range(4):
+        before = sess.sighashes()
+        ok, err, unk, rec_idx, bounds = sess.verify_inputs_idx(*args, n_threads=T)
+        after = sess.sighashes()
+        counts.append(tuple((a - b) // R for a, b in zip(after, before)))
+        assert (after[0] - before[0]) == R * digests_a_round
+        py_ok, py_err, chk = _ms_spec(case, DeferringSignatureChecker, known=known)
+        assert set(zip(ok.tolist(), err.tolist(), unk.tolist())) == \
+            {(int(py_ok), py_err, chk.unknown)}
+        uniq = _uniq_digests(sess)
+        assert uniq == expected_keys  # every reachable pairing, once, in order
+        walked = native_bridge.digest_checks(_SALT, [(c.kind, c.data) for c in chk.recorded])
+        for i in range(R):  # the walk's own misses: the spec's, in its order
+            assert [uniq[j] for j in rec_idx[int(bounds[i]) : int(bounds[i + 1])]] == walked
+        if chk.unknown == 0:
+            break
+        verdicts = np.array([sess.uniq_host_verify(i) for i in range(len(uniq))], dtype=bool)
+        assert verdicts.tolist() == [H.verify_ecdsa(*d) for _k, d in expected]
+        sess.publish_uniq(np.arange(len(uniq), dtype=np.int32), verdicts)
+        known = dict(zip(expected, verdicts.tolist()))
+        if all(known[(c.kind, c.data)] for c in chk.recorded):
+            break  # every guess held: the driver accepts, no further round
+    assert (py_ok, py_err) == (want_ok, want_err)
+    if case.defer_counts:
+        assert counts == case.defer_counts

@@ -95,9 +95,11 @@ def fund(wallets, seed: str):
     return coins, funded
 
 
-def _total(name: str) -> float:
+def _total(name: str, **labels) -> float:
+    """A metric summed over its label sets, or over those that have `labels`."""
     snap = get_registry().snapshot().get(name, {"samples": []})
-    return sum(s.get("value", s.get("sum", 0.0)) for s in snap["samples"])
+    return sum(s.get("value", s.get("sum", 0.0)) for s in snap["samples"]
+               if all(s["labels"].get(k) == v for k, v in labels.items()))
 
 
 class _Rose:
@@ -110,13 +112,20 @@ class _Rose:
         "consensus_exact_fallback_total", "consensus_fixpoint_rounds",
         "consensus_inflight_backpressure_total",
     )
+    SIGHASHES = ("computed", "reused")  # consensus_sighash_total{result}
+
+    def _read(self):
+        out = {n: _total(n) for n in self.NAMES}
+        out.update({"consensus_sighash_" + r: _total("consensus_sighash_total", result=r)
+                    for r in self.SIGHASHES})
+        return out
 
     def __enter__(self):
-        self.before = {n: _total(n) for n in self.NAMES}
+        self.before = self._read()
         return self
 
     def __exit__(self, *exc):
-        self.rose = {n: _total(n) - self.before[n] for n in self.NAMES}
+        self.rose = {n: v - self.before[n] for n, v in self._read().items()}
 
     def __getitem__(self, name):
         return self.rose["consensus_" + name]
@@ -214,6 +223,12 @@ def test_multichunk_connect_equals_the_spec(blocks, position, max_depth):
     assert rose["fixpoint_reinterpreted_inputs_total"] == (N_INPUTS if wrong_guess else 0)
     assert rose["fixpoint_rounds"] == (2 if wrong_guess else 1)
     assert rose["exact_fallback_total"] == 0
+    # An input's digest is hashed once a round it is interpreted in and read
+    # again by every pairing of the walk: the one optimistic pairing in round
+    # one, and in round two every key down to the one that signed.
+    walk = N_KEYS - POSITIONS[position]
+    assert rose["sighash_computed"] == N_INPUTS * (2 if wrong_guess else 1)
+    assert rose["sighash_reused"] == N_INPUTS * (1 + (walk if wrong_guess else 0))
     # The queue lets `max_depth` tickets out; every further chunk of the round
     # waits for the oldest, in the `backpressure` phase and not in `dispatch`.
     waits = DISPATCHES - max_depth
