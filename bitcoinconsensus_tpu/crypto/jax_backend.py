@@ -99,6 +99,8 @@ _compile_cache.configure()
 # Device-dispatch telemetry (README "Observability"). All host-side: these
 # run in the driver around `jit` calls, never inside a traced program, so
 # the analysis determinism gate sees identical kernel jaxprs.
+# Fed here by `verify_checks_begin` and, on the index path, by
+# models/batch.py `IdxFixpoint.finish` (the lanes a fixpoint sent).
 _CHECKS_TOTAL = _obs_counter(
     "consensus_checks_total", "deferred curve checks by kind", ("kind",)
 )

@@ -47,7 +47,12 @@ from ..core.script_error import ScriptError
 from ..core.serialize import SerializationError
 from ..core.sighash import PrecomputedTxData
 from ..core.tx import Tx, TxOut
-from ..crypto.jax_backend import SigCheck, TpuSecpVerifier, default_verifier
+from ..crypto.jax_backend import (
+    _CHECKS_TOTAL,
+    SigCheck,
+    TpuSecpVerifier,
+    default_verifier,
+)
 from .. import native_bridge
 from ..obs import counter as _obs_counter
 from ..obs import gauge as _obs_gauge
@@ -108,6 +113,12 @@ _SIGHASHES = _obs_counter(
     "ECDSA message digests the native interpreter hashed (computed) or read "
     "again from a CHECKMULTISIG's record of its signatures (reused)",
     ("result",),
+)
+_TAPROOT_HASHES = _obs_counter(
+    "consensus_taproot_hash_total",
+    "taproot hashes the native interpreter made: BIP 341 message digests "
+    "(sighash) and the commitment's TapLeaf, TapBranch and TapTweak hashes",
+    ("what",),
 )
 _EXACT_FALLBACK = _obs_counter(
     "consensus_exact_fallback_total",
@@ -572,6 +583,7 @@ class IdxFixpoint:
         self._state = _UniqState()
         self._rounds = 0
         self._in_flight = None  # (interp tuple, uniq round record)
+        self.lanes: Optional[Dict[str, int]] = None  # by kind, at finish
 
     def begin(self) -> None:
         """Start one round: interpret + dispatch, nothing synchronized."""
@@ -644,6 +656,11 @@ class IdxFixpoint:
         computed, reused = self.nsess.sighashes()
         _SIGHASHES.inc(computed, result="computed")
         _SIGHASHES.inc(reused, result="reused")
+        self.lanes = self.nsess.lane_kinds()
+        for kind, n in self.lanes.items():
+            _CHECKS_TOTAL.inc(n, kind=kind)
+        for what, n in self.nsess.taproot_hashes().items():
+            _TAPROOT_HASHES.inc(n, what=what)
         if len(self._pending):  # round cap hit: exact host fallback
             _EXACT_FALLBACK.inc(len(self._pending))
         for idx in self._pending.tolist():

@@ -268,7 +268,8 @@ def connect_block(
         from ..crypto.jax_backend import default_verifier
 
         verifier = default_verifier()
-    with gc_paused(phases_of(verifier)), _span("block.connect", height=height):
+    with gc_paused(phases_of(verifier)), \
+            _span("block.connect", height=height) as sp:
         if (
             isinstance(coins, native_bridge.NativeCoinsView)
             and native_bridge.available()
@@ -276,7 +277,7 @@ def connect_block(
             res = _connect_block_native(
                 block, coins, height, flags, verifier, check_pow,
                 check_scripts, enforce_witness_commitment, pow_limit,
-                sig_cache, script_cache,
+                sig_cache, script_cache, sp,
             )
         else:
             res = _connect_block_impl(
@@ -290,19 +291,23 @@ def connect_block(
 
 def _connect_block_native(
     block, coins, height, flags, verifier, check_pow, check_scripts,
-    enforce_witness_commitment, pow_limit, sig_cache, script_cache,
+    enforce_witness_commitment, pow_limit, sig_cache, script_cache, sp,
 ) -> ConnectResult:
     """`connect_block` with the block layer in C++ (native/block.hpp) and
     the script phase on the index-mode session protocol: `_NativeConnect`
     begun and finished back to back, with no speculation (the view is
     written after the verdicts). `connect_block_stream` drives the same
-    two halves with other blocks' halves in between."""
+    two halves with other blocks' halves in between. The lanes its
+    fixpoint sent, by kind, ride the `block.connect` span's record (`sp`)."""
     run = _NativeConnect(
         block, coins, height, flags, verifier, check_pow, check_scripts,
         enforce_witness_commitment, pow_limit, sig_cache, script_cache,
     )
     run.begin()
-    return run.finish()
+    res = run.finish()
+    if run.lanes is not None:
+        sp.attrs.update({f"lanes_{k}": n for k, n in run.lanes.items()})
+    return res
 
 
 class _NativeConnect:
@@ -361,6 +366,7 @@ class _NativeConnect:
         self.result: Optional[ConnectResult] = None
         self._nblk = None
         self._run = None  # the script phase's IdxFixpoint, once begun
+        self.lanes = None  # the lanes it sent, by kind, once finished
         self._undo = None  # the speculative apply's undo record, until commit
         self._phase = phases_of(verifier)  # times nothing without a verifier
 
@@ -491,6 +497,7 @@ class _NativeConnect:
             # The fixpoint keeps its verdict arrays: they end with it, in
             # `_free_block`, not at this frame's return.
             self._run.finish()
+            self.lanes = self._run.lanes
             self._run.release()
             with self._phase("results"):
                 # ok/err are written on the live rows only; a hit passed

@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 11:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 11)")
+        if L.nat_version() < 12:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 12)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -221,6 +221,10 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_spec_pairings.restype = ctypes.c_int64
         L.nat_session_sighashes.argtypes = [vp, i64p]
         L.nat_session_sighashes.restype = None
+        L.nat_session_lane_kinds.argtypes = [vp, i64p]
+        L.nat_session_lane_kinds.restype = None
+        L.nat_session_taproot_hashes.argtypes = [vp, i64p]
+        L.nat_session_taproot_hashes.restype = None
         L.nat_session_recidx_data.argtypes = [vp, i32p, ctypes.c_int64]
         L.nat_session_recidx_data.restype = ctypes.c_int64
         L.nat_session_uniq_lanes.argtypes = [
@@ -815,6 +819,26 @@ class NativeSession:
         out = (ctypes.c_int64 * 2)()
         lib().nat_session_sighashes(self._ptr, out)
         return int(out[0]), int(out[1])
+
+    LANE_KINDS = ("ecdsa", "schnorr", "tweak")
+    TAPROOT_HASHES = ("sighash", "leaf", "branch", "tweak")
+
+    def lane_kinds(self) -> Dict[str, int]:
+        """Lanes `uniq_lanes` has prepped out of this session so far, by
+        the kind of check (`LANE_KINDS`): what its fixpoint sent to the
+        device. Monotone over the session's life."""
+        out = (ctypes.c_int64 * 3)()
+        lib().nat_session_lane_kinds(self._ptr, out)
+        return dict(zip(self.LANE_KINDS, map(int, out)))
+
+    def taproot_hashes(self) -> Dict[str, int]:
+        """Taproot hashes this session's interpretations made so far
+        (`TAPROOT_HASHES`): BIP 341 message digests, key path and tapscript
+        alike, and the commitment's TapLeaf, TapBranch and TapTweak hashes.
+        Monotone over the session's life."""
+        out = (ctypes.c_int64 * 4)()
+        lib().nat_session_taproot_hashes(self._ptr, out)
+        return dict(zip(self.TAPROOT_HASHES, map(int, out)))
 
     def uniq_lanes(self, idxs: np.ndarray, size: int, n_threads: int = 1):
         """Packed kernel lanes for the uniq entries `idxs`, padded to

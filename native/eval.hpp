@@ -739,6 +739,11 @@ inline bool verify_taproot_commitment(const Bytes& control, const Bytes& program
     tweak_in.insert(tweak_in.end(), k, k + 32);
     u8 t[32];
     TAG_TAPTWEAK().hash(tweak_in.data(), tweak_in.size(), t);
+    if (checker.sess) {
+        checker.sess->taproot_hashes[Session::TH_LEAF]++;
+        checker.sess->taproot_hashes[Session::TH_BRANCH] += (i64)path_len;
+        checker.sess->taproot_hashes[Session::TH_TWEAK]++;
+    }
     Bytes q = program;
     Bytes tb(t, t + 32);
     if (!checker.verify_taproot_tweak(q, control[0] & 1, p, tb)) return false;

@@ -1154,6 +1154,14 @@ struct Session {
     // made (eval.hpp MultisigSigs); monotone, worker scratches summed in.
     i64 sighash_computed = 0;
     i64 sighash_reused = 0;
+    // Taproot's hashing by this session's interpretations, monotone and
+    // summed like the two above: BIP 341 digests (key path and tapscript),
+    // and the commitment's tagged hashes (TapLeaf, TapBranch, TapTweak).
+    enum : int { TH_SIGHASH = 0, TH_LEAF, TH_BRANCH, TH_TWEAK, TH_COUNT };
+    i64 taproot_hashes[TH_COUNT] = {0, 0, 0, 0};
+    // Lanes nat_session_uniq_lanes prepped out of this session's uniq
+    // list, by the check's kind (ecdsa, schnorr, tweak).
+    i64 lanes_by_kind[3] = {0, 0, 0};
 
     static std::string key(const PartsView& v) {
         std::string k;
@@ -1297,6 +1305,7 @@ struct Checker {
             *err = SE_SCHNORR_SIG_HASHTYPE;
             return false;
         }
+        if (sess) sess->taproot_hashes[Session::TH_SIGHASH]++;
         Bytes msg(sighash, sighash + 32);
         if (!resolve(1, 0, pubkey, sig, msg)) {
             *err = SE_SCHNORR_SIG;

@@ -269,7 +269,8 @@ extern "C" {
 // 10: nat_block_accounting takes the script cache's salt and makes the
 //     keys; nat_block_script_keys copies them out; nat_block_nowit_sizes.
 // 11: nat_session_sighashes.
-int nat_version() { return 11; }
+// 12: nat_session_lane_kinds, nat_session_taproot_hashes.
+int nat_version() { return 12; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -1013,6 +1014,8 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
         const Session& sc = scratch[t];
         sess->sighash_computed += sc.sighash_computed;
         sess->sighash_reused += sc.sighash_reused;
+        for (int k = 0; k < Session::TH_COUNT; k++)
+            sess->taproot_hashes[k] += sc.taproot_hashes[k];
         std::vector<i32> remap(sc.uniq.size());
         for (size_t j = 0; j < sc.uniq.size(); j++)
             remap[j] = sess->uniq.intern(sc.uniq.entries[j].hash,
@@ -1046,6 +1049,20 @@ void nat_session_sighashes(void* s, i64* out) {
     auto* sess = static_cast<Session*>(s);
     out[0] = sess->sighash_computed;
     out[1] = sess->sighash_reused;
+}
+
+// Lanes nat_session_uniq_lanes has prepped out of this session so far, by
+// kind: out[0] ecdsa, out[1] schnorr, out[2] tweak.
+void nat_session_lane_kinds(void* s, i64* out) {
+    auto* sess = static_cast<Session*>(s);
+    for (int k = 0; k < 3; k++) out[k] = sess->lanes_by_kind[k];
+}
+
+// Taproot hashes this session's interpretations have made so far: out[0]
+// BIP 341 digests, out[1] TapLeaf, out[2] TapBranch, out[3] TapTweak.
+void nat_session_taproot_hashes(void* s, i64* out) {
+    auto* sess = static_cast<Session*>(s);
+    for (int k = 0; k < Session::TH_COUNT; k++) out[k] = sess->taproot_hashes[k];
 }
 
 // A stale or negative uniq index from the driver is an OOB read / heap
@@ -1091,8 +1108,11 @@ void nat_session_uniq_lanes(void* s, const i32* idxs, i32 nidx, i32 n_threads,
     auto* sess = static_cast<Session*>(s);
     std::vector<PartsView> parts;
     parts.reserve((size_t)nidx);
-    for (i32 j = 0; j < nidx; j++)
+    for (i32 j = 0; j < nidx; j++) {
         parts.push_back(lanes_order(uniq_at(sess, idxs[j])));
+        int kind = parts.back().kind;
+        if (kind >= KIND_ECDSA && kind <= KIND_TWEAK) sess->lanes_by_kind[kind]++;
+    }
     prep_lanes_impl(parts, n_threads, fields, want_odd, parity, has_t2, neg1,
                     neg2, valid);
 }

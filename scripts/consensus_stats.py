@@ -53,6 +53,7 @@ REQUIRED_METRICS = [
     "consensus_uniq_checks_total",
     "consensus_prep_lanes_total",
     "consensus_sighash_total",
+    "consensus_taproot_hash_total",
     # caches
     "consensus_cache_lookups_total",
     "consensus_cache_hits_total",

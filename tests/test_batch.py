@@ -432,6 +432,12 @@ def test_fixpoint_round_cap_exact_fallback():
         def sighashes(self):
             return 0, 0
 
+        def lane_kinds(self):
+            return {"ecdsa": 0, "schnorr": 0, "tweak": 0}
+
+        def taproot_hashes(self):
+            return {"sighash": 0, "leaf": 0, "branch": 0, "tweak": 0}
+
     calls = {"rounds": 0, "fallback": []}
     live = [3, 5, 8, 13]
 

@@ -8,8 +8,8 @@ This file makes such a rename fail tier-1 first: one small workload over
 the paths the cells drive (a native connect on fresh and on warm caches, a
 two-block stream that ends in a rollback, a multisig connect of two chunks
 against a queue one deep, the same connect through the mesh verifier's
-layout and per-shard settle, a wire-driver dispatch, a served request), then
-a case a name.
+layout and per-shard settle, a script-path taproot spend, a wire-driver
+dispatch, a served request), then a case a name.
 
 It reads `benchmarks/` (the literal lists below must be what its files
 name) and imports nothing from it.
@@ -25,9 +25,9 @@ from conftest import *  # noqa: F401,F403 (env setup)
 
 import __graft_entry__ as ge
 from bitcoinconsensus_tpu import native_bridge
-from bitcoinconsensus_tpu.core.flags import VERIFY_ALL_LIBCONSENSUS
+from bitcoinconsensus_tpu.core.flags import VERIFY_ALL_EXTENDED, VERIFY_ALL_LIBCONSENSUS
 from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
-from bitcoinconsensus_tpu.models.batch import BatchItem
+from bitcoinconsensus_tpu.models.batch import BatchItem, verify_batch
 from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
 from bitcoinconsensus_tpu.models.validate import connect_block, connect_block_stream
 from bitcoinconsensus_tpu.obs import get_registry
@@ -42,7 +42,7 @@ from bitcoinconsensus_tpu.utils.blockgen import (
 )
 
 from mesh_stub import host_step
-from test_batch import make_p2wpkh_spend
+from test_batch import make_p2tr_scriptpath_spend, make_p2wpkh_spend
 from test_native_block import HEIGHT, to_native_view
 
 pytestmark = [
@@ -60,6 +60,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 READ = (
     "consensus_cache_hits_total",
     "consensus_cache_lookups_total",
+    "consensus_checks_total",
     "consensus_compile_seconds_total",
     "consensus_dispatch_lanes_total",
     "consensus_dispatch_new_shapes_total",
@@ -80,6 +81,7 @@ READ = (
     "consensus_span_duration_seconds",
     "consensus_stream_blocks_in_flight",
     "consensus_stream_rollbacks_total",
+    "consensus_taproot_hash_total",
 )
 # What the benchmark's chip guard holds at zero (`harness/chipguard.py`):
 # registered is all a sound run shows of them.
@@ -214,6 +216,12 @@ def workload():
                         script_cache=ScriptExecutionCache())
     assert res.ok and len(res.input_results) == 3
 
+    # a script-path taproot spend on the index path: a Schnorr lane and a
+    # tweak lane, a BIP 341 digest and the commitment's tagged hashes
+    txb, spk, amount = make_p2tr_scriptpath_spend("contract/leaf")
+    leaf = BatchItem(txb, 0, VERIFY_ALL_EXTENDED, spent_outputs=[(amount, spk)])
+    assert verify_batch([leaf], verifier, SigCache(), ScriptExecutionCache())[0].ok
+
     # the wire driver's lane prep, on the interpreter that has no native
     # `prep_pack`: the one place `pack` is a phase of its own
     with pytest.MonkeyPatch.context() as mp:
@@ -273,6 +281,23 @@ def test_sighash_results_are_the_ones_read(workload):
     results = {s["labels"]["result"]: s["value"]
                for s in snapshot["consensus_sighash_total"]["samples"]}
     assert results.get("computed", 0) > 0 and results.get("reused", 0) > 0, results
+
+
+def test_lane_kinds_and_taproot_hashes_are_the_ones_read(workload):
+    """`layers/_lanes.py` asks `consensus_checks_total` for `kind="schnorr"`
+    and `"tweak"` over all kinds, and `drivers/connect_taproot.py` reports
+    every kind; `layers/taphashes_per_input.connect.py` sums every `what`.
+    The workload's connects and its script-path spend feed both from the
+    index path (`IdxFixpoint.finish`)."""
+    _, snapshot = workload
+    kinds = {s["labels"]["kind"]: s["value"]
+             for s in snapshot["consensus_checks_total"]["samples"]}
+    assert all(kinds.get(k, 0) > 0 for k in ("ecdsa", "schnorr", "tweak")), kinds
+    hashes = {s["labels"]["what"]: s["value"]
+              for s in snapshot["consensus_taproot_hash_total"]["samples"]}
+    assert set(hashes) == {"sighash", "leaf", "branch", "tweak"}, hashes
+    # a lone leaf under the internal key: no sibling, so no branch hash
+    assert hashes["sighash"] > 0 and hashes["leaf"] > 0 and hashes["tweak"] > 0, hashes
 
 
 def test_mesh_phases_nest_and_outer_secs_do_not_count_them_twice(workload):

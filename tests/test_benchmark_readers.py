@@ -268,7 +268,8 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    connect = ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "worst-block-mesh4.sigops"]
+    connect = ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "worst-block-mesh4.sigops",
+               "taproot-block.cold"]  # PR 39: a new cell is appended to a list, nothing else changed
     every = [w["name"] for w in bench["workloads"]]
     want = {
         "unphased_ms.connect": connect, "sig_cache_ms.connect": connect,
@@ -276,7 +277,12 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "take_wait_ms.serve": ["mempool-serve.steady"], "host_ms.serve": ["mempool-serve.steady"],
         "settle_wait_ms.serve": ["mempool-serve.steady"], "ingress_ms.serve": ["mempool-serve.steady"],
         "trace_lower_s.setup": every, "compile_s.setup": every,
-        "sighashes_per_input.connect": ["worst-block.sigops", "worst-block-mesh4.sigops"],  # PR 38
+        "sighashes_per_input.connect":  # PR 38
+            ["worst-block.sigops", "worst-block-mesh4.sigops", "taproot-block.cold"],
+        # PR 39: the lanes by kind and the taproot hashes of the index path
+        "schnorr_lane_share.connect": ["taproot-block.cold", "tip-block.cold"],
+        "tweak_lane_share.connect": ["taproot-block.cold"],
+        "taphashes_per_input.connect": ["taproot-block.cold"],
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name
