@@ -101,6 +101,13 @@ _STREAM_ROLLBACKS = _obs_counter(
     "consensus_stream_rollbacks_total",
     "speculative block applies a connect_block_stream undid",
 )
+_COIN_PROBES = _obs_counter(
+    "consensus_coin_probes_total",
+    "hash-table probes (a find, an insert or an erase by outpoint) the "
+    "native accounting and apply of a connected block made, by table: the "
+    "view, and pass 1's table of the block's own coins",
+    ("table",),
+)
 _STREAM_IN_FLIGHT = _obs_histogram(
     "consensus_stream_blocks_in_flight",
     "blocks begun and not yet finished in a connect_block_stream, "
@@ -484,6 +491,7 @@ class _NativeConnect:
         if speculate:
             with phase("apply"):
                 self._undo = coins.apply_block(nblk, self.height, undo=True)
+                self._count_probes()
 
     def finish(self) -> ConnectResult:
         if self.result is not None:
@@ -525,11 +533,18 @@ class _NativeConnect:
         if self._undo is None:  # not applied speculatively in begin
             with self._phase("apply"):
                 self.coins.apply_block(self._nblk, self.height)
+                self._count_probes()
             self._free_block()
         self.result = ConnectResult(
             True, None, self._fees, self._sigop_cost, input_results
         )
         return self.result
+
+    def _count_probes(self) -> None:
+        """One read a block, after its apply: the probes its accounting and
+        that apply made (the parsed block counted them as it went)."""
+        for table, n in self._nblk.coin_probes().items():
+            _COIN_PROBES.inc(n, table=table)
 
     def _free_block(self) -> None:
         """The `block_free` phase: what ends with the run, dropped after its

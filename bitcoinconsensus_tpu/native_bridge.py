@@ -138,8 +138,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 12:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 12)")
+        if L.nat_version() < 13:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 13)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -270,6 +270,8 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_block_spent_digests.argtypes = [vp, u8p]
         L.nat_block_script_keys.argtypes = [vp, u8p]
         L.nat_block_script_keys.restype = ctypes.c_int64
+        L.nat_block_coin_probes.argtypes = [vp, i64p]
+        L.nat_block_coin_probes.restype = None
         L.nat_view_new.restype = vp
         L.nat_view_free.argtypes = [vp]
         L.nat_view_clone.argtypes = [vp]
@@ -1122,6 +1124,16 @@ class NativeBlock:
         )
         return (None, int(fees[0]), int(sigops[0]), tx_index[:n], n_in[:n],
                 amounts[:n], spk_offs, spk_blob)
+
+    def coin_probes(self) -> Dict[str, int]:
+        """Hash-table probes (a find, an insert or an erase by key) the last
+        `accounting()` of this block and every `apply_block` of it since
+        made, by table: `view`, and `block` (pass 1's table of the block's
+        own coins). An accounting starts both at zero."""
+        out = np.zeros(2, dtype=np.int64)
+        lib().nat_block_coin_probes(
+            self._ptr, out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        return {"view": int(out[0]), "block": int(out[1])}
 
     def spent_digests(self) -> np.ndarray:
         """(n_tx, 32) per-tx spent-output digests (coinbase rows zero);

@@ -14,10 +14,11 @@
 
 #include "interp.hpp"
 
+#include <algorithm>
 #include <array>
 #include <memory>
+#include <type_traits>
 #include <unordered_map>
-#include <unordered_set>
 
 namespace nat {
 
@@ -65,6 +66,80 @@ enum BlkReason : i32 {
 
 using Hash32 = std::array<u8, 32>;
 
+// A coin's key, everywhere a coin is keyed (the view, a block's own table,
+// the undo record, the duplicate-input check): the 36 bytes txid[32] || n
+// little-endian, held inline. view_digest hashes exactly these bytes.
+struct NOutPoint {
+    u8 b[36];
+
+    NOutPoint(const u8 txid[32], u32 n) {
+        std::memcpy(b, txid, 32);
+        for (int j = 0; j < 4; j++) b[32 + j] = u8(n >> (8 * j));
+    }
+    bool operator==(const NOutPoint& o) const {
+        return std::memcmp(b, o.b, sizeof b) == 0;
+    }
+    bool operator<(const NOutPoint& o) const {
+        return std::memcmp(b, o.b, sizeof b) < 0;
+    }
+};
+static_assert(std::is_trivially_copyable<NOutPoint>::value &&
+                  sizeof(NOutPoint) == 36,
+              "a coin's key is 36 plain bytes");
+
+// The hash of every coin table (coins.h SaltedOutpointHasher): SipHash-2-4
+// of the 36 outpoint bytes under two 64-bit keys drawn once a process, so
+// that whoever picks the txids cannot pick the buckets. Never a slice of
+// the txid: a miner grinds those.
+struct OutpointHasher {
+    u64 k0, k1;
+
+    OutpointHasher() {
+        static const std::array<u64, 2> salt = [] {
+            std::random_device rd;
+            auto word = [&rd] { return ((u64)rd() << 32) | (u64)rd(); };
+            return std::array<u64, 2>{word(), word()};
+        }();
+        k0 = salt[0];
+        k1 = salt[1];
+    }
+
+    static u64 rotl(u64 x, int r) { return (x << r) | (x >> (64 - r)); }
+
+    u64 operator()(const u8 txid[32], u32 n) const {
+        u64 v0 = 0x736f6d6570736575ULL ^ k0, v1 = 0x646f72616e646f6dULL ^ k1;
+        u64 v2 = 0x6c7967656e657261ULL ^ k0, v3 = 0x7465646279746573ULL ^ k1;
+        auto round = [&] {
+            v0 += v1; v1 = rotl(v1, 13); v1 ^= v0; v0 = rotl(v0, 32);
+            v2 += v3; v3 = rotl(v3, 16); v3 ^= v2;
+            v0 += v3; v3 = rotl(v3, 21); v3 ^= v0;
+            v2 += v1; v1 = rotl(v1, 17); v1 ^= v2; v2 = rotl(v2, 32);
+        };
+        auto absorb = [&](u64 m) {
+            v3 ^= m;
+            round();
+            round();
+            v0 ^= m;
+        };
+        for (int w = 0; w < 4; w++) {
+            u64 m = 0;
+            for (int j = 0; j < 8; j++) m |= (u64)txid[8 * w + j] << (8 * j);
+            absorb(m);
+        }
+        absorb(((u64)36 << 56) | n);  // the last 4 bytes under the length
+        v2 ^= 0xff;
+        for (int i = 0; i < 4; i++) round();
+        return v0 ^ v1 ^ v2 ^ v3;
+    }
+    // Not noexcept, on purpose: libstdc++ then keeps each node's hash code
+    // beside it, and a probe walks a bucket without hashing its nodes again.
+    size_t operator()(const NOutPoint& k) const {
+        u32 n = 0;
+        for (int j = 0; j < 4; j++) n |= (u32)k.b[32 + j] << (8 * j);
+        return (size_t)(*this)(k.b, n);
+    }
+};
+
 inline bool tx_is_coinbase(const NTx& tx) {
     if (tx.vin.size() != 1) return false;
     const NTxIn& in = tx.vin[0];
@@ -101,6 +176,11 @@ struct NBlock {
     std::vector<i64> nowit_size;  // per-tx no-witness serialized size
     i64 ser_size = 0;
     BlockAcct acct;
+    // Hash-table probes (a find, an insert or an erase by key) the last
+    // accounting of this block and the applies since made: of the view, and
+    // of the block's own coin table. Pass 1 starts both at zero; `mutable`
+    // because an apply reads the block and counts on it.
+    mutable i64 view_probes = 0, block_probes = 0;
 };
 
 // The parse's per-transaction stage, from the block's own wire bytes
@@ -327,24 +407,37 @@ inline i64 tx_sigop_cost(const NTx& tx, const std::vector<const NTxOut*>& spent,
     return cost;
 }
 
+// A transaction's outputs summed as CheckTransaction sums them: every term
+// and every partial sum inside MoneyRange, so the i64 never overflows.
+// Returns BR_OK with the sum in `total`, else the reject.
+inline i32 sum_outputs(const NTx& tx, i64& total) {
+    total = 0;
+    for (const auto& out : tx.vout) {
+        if (out.value < 0) return BR_VOUT_NEGATIVE;
+        if (out.value > BLK_MAX_MONEY) return BR_VOUT_TOOLARGE;
+        total += out.value;
+        if (total > BLK_MAX_MONEY) return BR_TXOUTTOTAL_TOOLARGE;
+    }
+    return BR_OK;
+}
+
 // CheckTransaction (consensus/tx_verify.cpp:157-196 / core/tx_check.py).
 inline i32 check_transaction(const NTx& tx, i64 nowit_size) {
     if (tx.vin.empty()) return BR_VIN_EMPTY;
     if (tx.vout.empty()) return BR_VOUT_EMPTY;
     if (nowit_size * BLK_WITNESS_SCALE > BLK_MAX_WEIGHT) return BR_OVERSIZE;
-    i64 value_out = 0;
-    for (const auto& out : tx.vout) {
-        if (out.value < 0) return BR_VOUT_NEGATIVE;
-        if (out.value > BLK_MAX_MONEY) return BR_VOUT_TOOLARGE;
-        value_out += out.value;
-        if (value_out < 0 || value_out > BLK_MAX_MONEY)
-            return BR_TXOUTTOTAL_TOOLARGE;
-    }
-    std::unordered_set<std::string> seen;
-    for (const auto& in : tx.vin) {
-        std::string key(reinterpret_cast<const char*>(in.prevout_hash), 32);
-        key.append(reinterpret_cast<const char*>(&in.prevout_n), 4);
-        if (!seen.insert(std::move(key)).second) return BR_INPUTS_DUPLICATE;
+    i64 value_out;
+    if (i32 r = sum_outputs(tx, value_out)) return r;
+    if (tx.vin.size() > 1) {
+        // Duplicate inputs: the outpoints sorted, equal neighbours looked
+        // for (the reference's std::set<COutPoint>, in one allocation).
+        std::vector<NOutPoint> seen;
+        seen.reserve(tx.vin.size());
+        for (const auto& in : tx.vin)
+            seen.emplace_back(in.prevout_hash, in.prevout_n);
+        std::sort(seen.begin(), seen.end());
+        if (std::adjacent_find(seen.begin(), seen.end()) != seen.end())
+            return BR_INPUTS_DUPLICATE;
     }
     if (tx_is_coinbase(tx)) {
         size_t n = tx.vin[0].script_sig.size();
@@ -454,13 +547,9 @@ struct NCoin {
 };
 
 struct NView {
-    std::unordered_map<std::string, NCoin> map;
+    std::unordered_map<NOutPoint, NCoin, OutpointHasher> map;
 
-    static std::string key(const u8 txid[32], u32 n) {
-        std::string k(reinterpret_cast<const char*>(txid), 32);
-        k.append(reinterpret_cast<const char*>(&n), 4);
-        return k;
-    }
+    static NOutPoint key(const u8 txid[32], u32 n) { return NOutPoint(txid, n); }
 };
 
 inline i64 blk_subsidy(i64 height) {
@@ -486,25 +575,75 @@ inline i64 blk_subsidy(i64 height) {
 // input. Allocates nothing, cannot throw.
 using SpentOutputs = std::vector<std::vector<NTxOut>>;
 
+// Pass 1's one table of the block's own coins: every outpoint an input of
+// the block has named (`spent`) and every output the block has created so
+// far, which it points at in the parsed block (`out`; null for a coin of
+// the view). Open addressing over slots that borrow their key's txid from
+// the block, sized once for the block's inputs and outputs at a load of a
+// half at most: one allocation a block, none a coin.
+struct BlockCoins {
+    struct Slot {
+        const u8* txid = nullptr;  // null: empty
+        u32 n = 0;
+        u32 tag = 0;  // the hash's upper half: most strangers differ here
+        const NTxOut* out = nullptr;
+        bool coinbase = false;
+        bool spent = false;
+    };
+    std::vector<Slot> slots;
+    OutpointHasher hasher;
+
+    explicit BlockCoins(size_t n_keys) {
+        size_t cap = 16;
+        while (cap < 2 * n_keys) cap *= 2;
+        slots.resize(cap);
+    }
+
+    // The key's slot: its own, or the empty one it now takes (nothing
+    // spent, nothing made).
+    Slot& probe(const u8 txid[32], u32 n) {
+        u64 h = hasher(txid, n);
+        u32 tag = (u32)(h >> 32);
+        size_t mask = slots.size() - 1;
+        for (size_t i = (size_t)h & mask;; i = (i + 1) & mask) {
+            Slot& s = slots[i];
+            if (!s.txid) {
+                s.txid = txid;
+                s.n = n;
+                s.tag = tag;
+                return s;
+            }
+            if (s.tag == tag && s.n == n && std::memcmp(s.txid, txid, 32) == 0)
+                return s;
+        }
+    }
+};
+
 inline i32 block_acct_decide(NBlock& blk, const NView& view, i64 height,
                              u32 flags, bool with_keys, SpentOutputs& all) {
     BlockAcct& A = blk.acct;
     A = BlockAcct();
+    blk.view_probes = blk.block_probes = 0;
     // The production driver runs check_block first (which rejects empty
     // blocks with bad-blk-length), but this entry is independently
     // reachable through the C ABI — the coinbase-cap read below must not
     // index an empty vtx (found by fuzz/fuzz_nat.cpp on its seed corpus).
     if (blk.vtx.empty()) return BR_BAD_LENGTH;
     size_t n_tx = blk.vtx.size();
-    std::unordered_map<std::string, NCoin> overlay;
-    std::unordered_set<std::string> spent_keys;
 
     // BIP30 against the start-of-block view.
-    for (size_t t = 0; t < n_tx; t++)
-        for (u32 n = 0; n < blk.vtx[t]->vout.size(); n++)
+    size_t n_keys = 0;
+    for (size_t t = 0; t < n_tx; t++) {
+        const NTx& tx = *blk.vtx[t];
+        n_keys += tx.vout.size() + (tx_is_coinbase(tx) ? 0 : tx.vin.size());
+        for (u32 n = 0; n < tx.vout.size(); n++) {
+            blk.view_probes++;
             if (view.map.count(NView::key(blk.txids[t].data(), n)))
                 return BR_BIP30;
+        }
+    }
 
+    BlockCoins coins(n_keys);
     all.assign(n_tx, {});
     size_t n_in = 0, spk_bytes = 0;
     std::vector<const NTxOut*> sp;
@@ -516,30 +655,43 @@ inline i32 block_acct_decide(NBlock& blk, const NView& view, i64 height,
             spent.reserve(tx.vin.size());
             i64 value_in = 0;
             for (const auto& in : tx.vin) {
-                std::string k = NView::key(in.prevout_hash, in.prevout_n);
-                if (spent_keys.count(k)) return BR_INPUTS_MISSINGORSPENT;
-                const NCoin* coin = nullptr;
-                auto ito = overlay.find(k);
-                if (ito != overlay.end()) {
-                    coin = &ito->second;
-                } else {
-                    auto itv = view.map.find(k);
+                // One probe says whether the block spent this coin before
+                // (a reject, before any look-up) and whether it made it.
+                blk.block_probes++;
+                BlockCoins::Slot& own =
+                    coins.probe(in.prevout_hash, in.prevout_n);
+                if (own.spent) return BR_INPUTS_MISSINGORSPENT;
+                own.spent = true;
+                const NTxOut* out = own.out;
+                i32 coin_height = (i32)height;
+                bool coin_cb = own.coinbase;
+                if (!out) {  // not of this block: the view's, or nobody's
+                    blk.view_probes++;
+                    auto itv = view.map.find(
+                        NView::key(in.prevout_hash, in.prevout_n));
                     if (itv == view.map.end())
                         return BR_INPUTS_MISSINGORSPENT;
-                    coin = &itv->second;
+                    const NCoin& coin = itv->second;
+                    coin_height = coin.height;
+                    coin_cb = coin.coinbase;
+                    spent.push_back(NTxOut{coin.value, coin.spk});
+                } else {
+                    spent.push_back(*out);
                 }
-                if (coin->coinbase && height - coin->height < BLK_COINBASE_MATURITY)
+                const NTxOut& got = spent.back();
+                if (coin_cb && height - coin_height < BLK_COINBASE_MATURITY)
                     return BR_PREMATURE_COINBASE;
-                if (coin->value < 0 || coin->value > BLK_MAX_MONEY)
+                if (got.value < 0 || got.value > BLK_MAX_MONEY)
                     return BR_INPUTVALUES_OUTOFRANGE;
-                value_in += coin->value;
+                value_in += got.value;
                 if (value_in > BLK_MAX_MONEY) return BR_INPUTVALUES_OUTOFRANGE;
-                spent.push_back(NTxOut{coin->value, coin->spk});
-                spk_bytes += coin->spk.size();
-                spent_keys.insert(std::move(k));
+                spk_bytes += got.spk.size();
             }
-            i64 value_out = 0;
-            for (const auto& out : tx.vout) value_out += out.value;
+            // check_transaction holds every block of the production path
+            // to the same ranges first; this entry stands alone behind the
+            // C ABI, and an i64 sum must not overflow there either.
+            i64 value_out;
+            if (i32 r = sum_outputs(tx, value_out)) return r;
             if (value_in < value_out) return BR_IN_BELOWOUT;
             A.fees += value_in - value_out;
             if (A.fees < 0 || A.fees > BLK_MAX_MONEY) return BR_FEE_OUTOFRANGE;
@@ -549,14 +701,18 @@ inline i32 block_acct_decide(NBlock& blk, const NView& view, i64 height,
         A.sigop_cost += tx_sigop_cost(tx, sp, flags);
         if (A.sigop_cost > BLK_MAX_SIGOPS_COST) return BR_BLK_SIGOPS;
         n_in += spent.size();
-        // Overlay this tx's outputs for later txs of the same block.
-        for (u32 n = 0; n < tx.vout.size(); n++)
-            overlay[NView::key(blk.txids[t].data(), n)] =
-                NCoin{tx.vout[n].value, tx.vout[n].spk, (i32)height, cb};
+        // This tx's outputs, for later txs of the same block: read in
+        // place. An outpoint the block already spent stays spent.
+        for (u32 n = 0; n < tx.vout.size(); n++) {
+            blk.block_probes++;
+            BlockCoins::Slot& own = coins.probe(blk.txids[t].data(), n);
+            own.out = &tx.vout[n];
+            own.coinbase = cb;
+        }
     }
 
-    i64 cb_out = 0;
-    for (const auto& out : blk.vtx[0]->vout) cb_out += out.value;
+    i64 cb_out;
+    if (i32 r = sum_outputs(*blk.vtx[0], cb_out)) return r;
     if (cb_out > A.fees + blk_subsidy(height)) return BR_CB_AMOUNT;
 
     A.tx_index.resize(n_in);
@@ -639,7 +795,7 @@ inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
 // backwards one transaction at a time.
 struct NBlockUndo {
     struct Entry {
-        std::string key;
+        NOutPoint key;
         NCoin coin;
     };
     std::vector<Entry> spent, replaced;
@@ -655,32 +811,30 @@ inline void view_apply_block(NView& view, const NBlock& blk, i64 height,
     for (size_t t = 0; t < blk.vtx.size(); t++) {
         const NTx& tx = *blk.vtx[t];
         bool cb = tx_is_coinbase(tx);
+        blk.view_probes += (i64)tx.vout.size() + (cb ? 0 : (i64)tx.vin.size());
         if (!cb)
             for (const auto& in : tx.vin) {
-                std::string k = NView::key(in.prevout_hash, in.prevout_n);
+                NOutPoint k = NView::key(in.prevout_hash, in.prevout_n);
                 if (!undo) {
                     view.map.erase(k);
                     continue;
                 }
                 auto it = view.map.find(k);
                 if (it == view.map.end()) continue;
-                undo->spent.push_back({std::move(k), std::move(it->second)});
+                undo->spent.push_back({k, std::move(it->second)});
                 view.map.erase(it);
             }
         for (u32 n = 0; n < tx.vout.size(); n++) {
             NCoin coin{tx.vout[n].value, tx.vout[n].spk, (i32)height, cb};
-            std::string k = NView::key(blk.txids[t].data(), n);
+            NOutPoint k = NView::key(blk.txids[t].data(), n);
             if (!undo) {
-                view.map[std::move(k)] = std::move(coin);
+                view.map.insert_or_assign(k, std::move(coin));
                 continue;
             }
-            auto it = view.map.find(k);
-            if (it == view.map.end()) {
-                view.map.emplace(std::move(k), std::move(coin));
-            } else {
-                undo->replaced.push_back({std::move(k), std::move(it->second)});
-                it->second = std::move(coin);
-            }
+            auto at = view.map.try_emplace(k);
+            if (!at.second)
+                undo->replaced.push_back({k, std::move(at.first->second)});
+            at.first->second = std::move(coin);
         }
         if (undo) {
             undo->spent_end.push_back(undo->spent.size());
@@ -709,10 +863,11 @@ inline bool view_undo_block(NView& view, const NBlock& blk,
             view.map.erase(NView::key(blk.txids[t].data(), n));
         size_t lo = t ? undo.replaced_end[t - 1] : 0;
         for (size_t i = undo.replaced_end[t]; i-- > lo;)
-            view.map[undo.replaced[i].key] = undo.replaced[i].coin;
+            view.map.insert_or_assign(undo.replaced[i].key,
+                                      undo.replaced[i].coin);
         lo = t ? undo.spent_end[t - 1] : 0;
         for (size_t i = undo.spent_end[t]; i-- > lo;)
-            view.map[undo.spent[i].key] = undo.spent[i].coin;
+            view.map.insert_or_assign(undo.spent[i].key, undo.spent[i].coin);
     }
     return true;
 }
@@ -725,7 +880,7 @@ inline void view_digest(const NView& view, u8 out[32]) {
     std::memset(out, 0, 32);
     for (const auto& kv : view.map) {
         Sha256 h;
-        h.write(reinterpret_cast<const u8*>(kv.first.data()), kv.first.size());
+        h.write(kv.first.b, sizeof kv.first.b);
         u8 meta[13];
         u64 v = (u64)kv.second.value;
         for (int j = 0; j < 8; j++) meta[j] = u8(v >> (8 * j));

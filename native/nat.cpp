@@ -270,7 +270,9 @@ extern "C" {
 //     keys; nat_block_script_keys copies them out; nat_block_nowit_sizes.
 // 11: nat_session_sighashes.
 // 12: nat_session_lane_kinds, nat_session_taproot_hashes.
-int nat_version() { return 12; }
+// 13: nat_block_coin_probes; the coin tables key on a fixed 36-byte outpoint
+//     under a salted hash (same symbols, another NView).
+int nat_version() { return 13; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -395,6 +397,14 @@ i64 nat_block_script_keys(void* b, u8* out) {
     return (i64)keys.size();
 }
 
+// Hash-table probes the block's last accounting and the applies since made:
+// out[0] of the view, out[1] of the block's own coin table.
+void nat_block_coin_probes(void* b, i64* out) {
+    auto* blk = static_cast<NBlock*>(b);
+    out[0] = blk->view_probes;
+    out[1] = blk->block_probes;
+}
+
 void* nat_view_new() { return new NView(); }
 
 void nat_view_free(void* v) { delete static_cast<NView*>(v); }
@@ -420,8 +430,8 @@ void nat_view_add_coins(void* v, i32 n, const u8* txids, const i32* ns,
         c.height = heights[i];
         c.coinbase = coinbases[i] != 0;
         c.spk.assign(spk_blob + spk_offs[i], spk_blob + spk_offs[i + 1]);
-        view->map[NView::key(txids + 32 * (size_t)i, (u32)ns[i])] =
-            std::move(c);
+        view->map.insert_or_assign(
+            NView::key(txids + 32 * (size_t)i, (u32)ns[i]), std::move(c));
     }
 }
 

@@ -83,6 +83,8 @@ REQUIRED_METRICS = [
     "consensus_stream_blocks_total",
     "consensus_stream_rollbacks_total",
     "consensus_stream_blocks_in_flight",
+    # native coin tables (the stream leg's native connects: both tables)
+    "consensus_coin_probes_total",
     # resilience (clean-path samples: ladder gauge set at verifier
     # construction, sentinel lanes ride every padded dispatch; the fault
     # counters only light up under scripts/consensus_chaos.py)
@@ -383,6 +385,11 @@ def run_mini_workload() -> None:
         ]
         streamed = list(connect_block_stream(chain, nview, 200, check_pow=False))
         assert [r.ok for r in streamed] == [True, False]
+        # both began, so both were accounted and applied: the counter holds
+        # the two label values its readers sum
+        from bitcoinconsensus_tpu.models.validate import _COIN_PROBES
+
+        assert all(_COIN_PROBES.value(table=t) > 0 for t in ("view", "block"))
 
     # --- mesh: a sharded dispatch over the (virtual) device mesh ---
     sv = ShardedSecpVerifier(mesh=make_mesh())

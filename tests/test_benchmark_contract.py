@@ -61,6 +61,7 @@ READ = (
     "consensus_cache_hits_total",
     "consensus_cache_lookups_total",
     "consensus_checks_total",
+    "consensus_coin_probes_total",
     "consensus_compile_seconds_total",
     "consensus_dispatch_lanes_total",
     "consensus_dispatch_new_shapes_total",
@@ -281,6 +282,18 @@ def test_sighash_results_are_the_ones_read(workload):
     results = {s["labels"]["result"]: s["value"]
                for s in snapshot["consensus_sighash_total"]["samples"]}
     assert results.get("computed", 0) > 0 and results.get("reused", 0) > 0, results
+
+
+def test_coin_probe_tables_are_the_ones_counted(workload):
+    """`layers/_probes.py` sums `consensus_coin_probes_total` over its
+    `table` label: the view, and pass 1's table of the block's own coins.
+    Every native connect of the workload raises both, once, after its
+    apply."""
+    _, snapshot = workload
+    tables = {s["labels"]["table"]: s["value"]
+              for s in snapshot["consensus_coin_probes_total"]["samples"]}
+    assert set(tables) == {"view", "block"}, tables
+    assert tables["view"] > tables["block"] > 0, tables
 
 
 def test_lane_kinds_and_taproot_hashes_are_the_ones_read(workload):

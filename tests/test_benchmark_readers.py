@@ -262,6 +262,60 @@ def test_a_reader_in_another_kind_of_cell_returns_none(metric, ctx):
     assert reader(metric)(ctx) is None
 
 
+# -- the coin tables' probes (PR 40) -----------------------------------------
+
+
+def probes_ctx(kind, view, block, calls=3, n_blocks=None):
+    """A window of `calls` timed calls (connects, or passes of `n_blocks`
+    blocks) of 6-input blocks over which the counter rose from (500, 70) by
+    (`view`, `block`)."""
+    def snap(v, b):
+        return {"consensus_coin_probes_total": {"samples": [
+            {"labels": {"table": "view"}, "value": v},
+            {"labels": {"table": "block"}, "value": b}]}}
+    walls = "walls_s" if kind == "connect" else "pass_walls_s"
+    d = {"kind": kind, walls: [0.05] * calls, "n_inputs": 6,
+         "counters_before": snap(500, 70), "counters_after": snap(500 + view, 70 + block)}
+    if n_blocks is not None:
+        d["n_blocks"] = n_blocks
+    return {"cell": "made-up", "trace": None, "driver": d}
+
+
+@pytest.mark.parametrize("view,block,want", [(54, 27, 4.5), (72, 27, 5.5), (0, 0, 0.0)])
+def test_coin_probes_per_input_connect_sums_both_tables_over_inputs_connected(view, block, want):
+    # 3 connects x 6 inputs; 3 outputs a block: (3 x 6 + 3 x 3) / 6 = 4.5
+    assert reader("coin_probes_per_input.connect")(probes_ctx("connect", view, block)) == ms(want)
+
+
+def test_coin_probes_per_input_stream_divides_by_the_blocks_of_every_pass():
+    # 2 passes x 4 blocks x 6 inputs = 48 inputs connected
+    ctx = probes_ctx("stream", 160, 80, calls=2, n_blocks=4)
+    assert reader("coin_probes_per_input.stream")(ctx) == ms(5.0)
+
+
+_NO_COUNTER = {"consensus_dispatch_total": {"samples": []}}
+
+
+@pytest.mark.parametrize("metric,ctx", [
+    # the parent: no such counter, in a cell of the reader's own kind
+    ("coin_probes_per_input.connect", {"cell": "made-up", "trace": None, "driver": {
+        "kind": "connect", "walls_s": [0.05], "n_inputs": 6,
+        "counters_before": _NO_COUNTER, "counters_after": _NO_COUNTER}}),
+    ("coin_probes_per_input.stream", {"cell": "made-up", "trace": None, "driver": {
+        "kind": "stream", "pass_walls_s": [0.05], "n_inputs": 6, "n_blocks": 4,
+        "counters_before": _NO_COUNTER, "counters_after": _NO_COUNTER}}),
+    # a window that timed nothing
+    ("coin_probes_per_input.connect", probes_ctx("connect", 54, 27, calls=0)),
+    ("coin_probes_per_input.stream", probes_ctx("stream", 54, 27, calls=0, n_blocks=4)),
+    # another kind of cell
+    ("coin_probes_per_input.connect", probes_ctx("stream", 54, 27, n_blocks=4)),
+    ("coin_probes_per_input.connect", probes_ctx("serve", 54, 27)),
+    ("coin_probes_per_input.stream", probes_ctx("connect", 54, 27)),
+])
+def test_coin_probes_per_input_returns_none_with_nothing_to_read(metric, ctx):
+    assert reader(metric)(ctx) is None
+
+
 def test_benchmark_json_lists_each_new_metric_with_its_cells():
     import json
 
@@ -283,6 +337,10 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "schnorr_lane_share.connect": ["taproot-block.cold", "tip-block.cold"],
         "tweak_lane_share.connect": ["taproot-block.cold"],
         "taphashes_per_input.connect": ["taproot-block.cold"],
+        # PR 40: the coin tables' probes
+        "coin_probes_per_input.connect":
+            ["tip-block.cold", "tip-block.warm", "taproot-block.cold", "worst-block.sigops"],
+        "coin_probes_per_input.stream": ["ibd-stream.cold"],
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name

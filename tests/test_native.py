@@ -406,3 +406,134 @@ def test_prep_counter_says_which_path_ran(prep_session, monkeypatch, n, threads,
     assert batch._PREP_LANES.value(mode=mode) - before[mode] == n
     assert batch._PREP_LANES.value(mode=other) == before[other]
     assert verifier.phases.report()["host_prep"]["calls"] == 2
+
+
+# -- the coin tables' key and hash (native/block.hpp NOutPoint, PR 40) ------
+
+
+def _golden_coins():
+    ns = (0, 1, 2, 3, 255, 256, 0x01020304, 0x7FFFFFFE)
+    spks = (b"", b"\x51", b"\x00\x14" + bytes(range(20)),
+            b"\x76\xa9\x14" + bytes(20) + b"\x88\xac", b"\x51\x20" + bytes(range(32)),
+            b"\x6a", b"\xa9\x14" + b"\x07" * 20 + b"\x87", bytes(range(200)))
+    return [(hashlib.sha256(b"golden-coin-%d" % i).digest(), ns[i], 1001 * (i + 1) - 1,
+             100 + i, i % 3 == 0, spks[i]) for i in range(8)]
+
+
+def test_view_digest_of_a_fixed_view_is_pinned():
+    """The key's bytes are txid || n little-endian, whatever holds them: the
+    digest below was read off the `std::string`-keyed view (PR 39's tree),
+    and the same bytes hashed here by hand give it too."""
+    view = NB.NativeCoinsView()
+    view.add_coins_batch(_golden_coins())
+    want = bytearray(32)
+    for txid, n, value, height, cb, spk in _golden_coins():
+        d = hashlib.sha256(
+            txid + n.to_bytes(4, "little") + value.to_bytes(8, "little")
+            + height.to_bytes(4, "little") + bytes([cb]) + spk).digest()
+        want = bytearray(a ^ b for a, b in zip(want, d))
+    assert len(view) == 8
+    assert view.digest() == bytes(want)
+    assert view.digest().hex() == (
+        "0c7a84524d75b2fd582b55105919d320fd97bb0ba9f549d046d09835e685d6d8")
+    assert view.clone().digest() == view.digest()
+
+
+def _insert_and_probe_s(txids: np.ndarray) -> float:
+    """Seconds to insert the coins (txids[i], i & 3) in bulk, clone the view
+    (an insert a coin again) and look every fourth one up."""
+    import time
+
+    from bitcoinconsensus_tpu.core.tx import OutPoint
+
+    n = len(txids)
+    view = NB.NativeCoinsView()
+    t0 = time.perf_counter()
+    view.add_coins_arrays(
+        txids=txids.reshape(-1), ns=np.arange(n, dtype=np.int32) & 3,
+        values=np.full(n, 5000, np.int64), heights=np.ones(n, np.int32),
+        coinbases=np.zeros(n, np.int32), spk_blob=np.full(n, 0x51, np.uint8),
+        spk_offs=np.arange(n + 1, dtype=np.int64))
+    clone = view.clone()
+    rows = [r.tobytes() for r in txids[::4]]
+    found = sum(clone.get(OutPoint(row, (4 * j) & 3)) is not None
+                for j, row in enumerate(rows))
+    took = time.perf_counter() - t0
+    assert len(view) == len(clone) == n and found == len(rows)
+    return took
+
+
+def test_chosen_outpoints_cannot_flood_the_coin_table():
+    """50,000 outpoints whose txids share their first 16 bytes, and 50,000
+    whose txids are a counter (all but 3 bytes equal), go in and are found in
+    about the time 50,000 random ones take. A hash that sliced the txid, or
+    one whoever mines the txids could run ahead of time, would put a set of
+    them in one bucket: 1.25e9 key compares, hundreds of times slower. (A set
+    that collides under libstdc++'s unsalted hash of the old string key is
+    not cheap to make: its murmur mix yields to differentials pairwise, 8
+    strings of 36 bytes, not 50,000.)"""
+    n = 50_000
+    rng = np.random.Generator(np.random.PCG64(40))
+    random_ids = np.frombuffer(rng.bytes(32 * n), dtype=np.uint8).reshape(n, 32)
+    shared_head = random_ids.copy()
+    shared_head[:, :16] = shared_head[0, :16]
+    counter = np.zeros((n, 32), dtype=np.uint8)
+    counter[:] = random_ids[1]
+    counter[:, 29:] = np.arange(n, dtype=">u4").view(np.uint8).reshape(n, 4)[:, 1:]
+    _insert_and_probe_s(random_ids[:2000])  # first touch of the code paths
+    base = min(_insert_and_probe_s(random_ids) for _ in range(2))
+    for ids in (shared_head, counter):
+        assert _insert_and_probe_s(ids) < 5 * base
+
+
+def test_views_filled_in_any_order_are_equal_by_length_and_digest():
+    """Nothing reads the order a table holds its coins in (it changes with
+    the process's salt): views are compared by `len` and `digest`."""
+    a, b = NB.NativeCoinsView(), NB.NativeCoinsView()
+    coins = _golden_coins()
+    a.add_coins_batch(coins)
+    b.add_coins_batch(coins[::-1])
+    assert (len(a), a.digest()) == (len(b), b.digest())
+
+
+def _accounting_reason(vouts, cb_vouts=None):
+    """`nat_block_accounting` alone (no `check_block` in front of it, as a
+    caller of the C ABI or the fuzzer's target may do) on a block whose one
+    transaction spends a funded coin to `vouts`."""
+    from bitcoinconsensus_tpu.core.tx import OutPoint, Tx, TxIn, TxOut
+    from bitcoinconsensus_tpu.utils.blockgen import build_block
+
+    funding = hashlib.sha256(b"money-range").digest()
+    view = NB.NativeCoinsView()
+    view.add_coins_batch([(funding, 0, 50_000, 100, False, b"\x51")])
+    tx = Tx(2, [TxIn(OutPoint(funding, 0))], [TxOut(v, b"\x51") for v in vouts], 0)
+    block = build_block([tx], 710_000, witness_commitment=False)
+    if cb_vouts is not None:
+        block.vtx[0].vout = [TxOut(v, b"\x51") for v in cb_vouts]
+        block.vtx[0].invalidate_caches()
+    nblk = NB.NativeBlock(block.serialize())
+    before = (len(view), view.digest())
+    reason = nblk.accounting(view, 710_000, 0)[0]
+    assert (len(view), view.digest()) == before
+    return reason
+
+
+_MAX_MONEY = 21_000_000 * 100_000_000
+
+
+@pytest.mark.parametrize("vouts,cb_vouts,reason", [
+    ([40_000], None, None),
+    # two terms in range, a sum out of it
+    ([_MAX_MONEY, 1], None, "bad-txns-txouttotal-toolarge"),
+    # terms whose i64 sum would wrap (signed overflow: UB the sanitizer build
+    # aborts on): refused at the first term, nothing added
+    ([2**62, 2**62], None, "bad-txns-vout-toolarge"),
+    ([2**63 - 1, 2**63 - 1, 2], None, "bad-txns-vout-toolarge"),
+    ([-1], None, "bad-txns-vout-negative"),
+    # the coinbase's own sum, for the reward cap
+    ([40_000], [2**62, 2**62], "bad-txns-vout-toolarge"),
+    ([40_000], [_MAX_MONEY, _MAX_MONEY], "bad-txns-txouttotal-toolarge"),
+    ([40_000], [_MAX_MONEY], "bad-cb-amount"),
+])
+def test_accounting_alone_sums_outputs_inside_money_range(vouts, cb_vouts, reason):
+    assert _accounting_reason(vouts, cb_vouts) == reason

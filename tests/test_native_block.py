@@ -425,3 +425,134 @@ def test_native_connect_touches_no_input_one_at_a_time(monkeypatch):
     # the session's verdicts go in by index once (round 2 discovers nothing
     # new) and its owner frees it once, before the connect returns
     assert report["publish"]["calls"] == report["release"]["calls"] == 1
+
+
+# -- pass 1's one table of the block's own coins (PR 40) --------------------
+#
+# Each case builds (block, coins): what an input may find in the block's own
+# table (an output made earlier, one made later, an outpoint spent before,
+# the block's coinbase) and what the view alone answers (BIP30). Scripts are
+# off: the table is what is under test, and `b"\x51"` outputs need no key.
+
+
+def _spend(outpoints, value, n_out=1):
+    return Tx(2, [TxIn(op) for op in outpoints],
+              [TxOut(value // n_out, b"\x51")] * n_out, 0)
+
+
+def _case_spends_earlier_output():
+    coins, funded = make_funded_view(2, amount=COIN, seed="tbl1")
+    t1 = _spend([funded[0].outpoint], COIN - 1000, n_out=2)
+    t2 = _spend([OutPoint(t1.txid, 1), funded[1].outpoint], COIN, n_out=1)
+    return build_block([t1, t2], HEIGHT), coins
+
+
+def _case_spends_later_output():
+    coins, funded = make_funded_view(1, amount=COIN, seed="tbl2")
+    t2 = _spend([funded[0].outpoint], COIN - 1000)
+    t1 = _spend([OutPoint(t2.txid, 0)], COIN - 2000)
+    return build_block([t1, t2], HEIGHT), coins
+
+
+def _case_twice_in_one_tx():
+    coins, funded = make_funded_view(1, amount=COIN, seed="tbl3")
+    tx = _spend([funded[0].outpoint, funded[0].outpoint], COIN)
+    return build_block([tx], HEIGHT), coins
+
+
+def _case_twice_in_two_txs():
+    coins, funded = make_funded_view(2, amount=COIN, seed="tbl4")
+    t1 = _spend([funded[0].outpoint], COIN - 1000)
+    t2 = _spend([funded[1].outpoint, funded[0].outpoint], COIN)
+    return build_block([t1, t2], HEIGHT), coins
+
+
+def _case_in_block_output_twice():
+    coins, funded = make_funded_view(1, amount=COIN, seed="tbl5")
+    t1 = _spend([funded[0].outpoint], COIN - 1000)
+    t2 = _spend([OutPoint(t1.txid, 0)], COIN - 2000)
+    t3 = _spend([OutPoint(t1.txid, 0)], COIN - 3000)
+    return build_block([t1, t2, t3], HEIGHT), coins
+
+
+def _case_own_coinbase():
+    coins, _ = make_funded_view(1, seed="tbl6")
+    # Without a commitment the coinbase depends on the height and the
+    # reward alone, so its txid is known before the block that spends it.
+    reward_of = build_block([], HEIGHT, fees=1000, witness_commitment=False)
+    tx = _spend([OutPoint(reward_of.vtx[0].txid, 0)], 5000)
+    block = build_block([tx], HEIGHT, fees=1000, witness_commitment=False)
+    assert block.vtx[0].txid == reward_of.vtx[0].txid
+    return block, coins
+
+
+def _case_bip30():
+    coins, funded = make_funded_view(2, amount=COIN, seed="tbl7")
+    t1 = _spend([funded[0].outpoint], COIN - 1000)
+    t2 = _spend([funded[1].outpoint], COIN - 1000, n_out=3)
+    coins.add(OutPoint(t2.txid, 2), Coin(TxOut(7, b"\x51"), HEIGHT - 9, False))
+    return build_block([t1, t2], HEIGHT), coins
+
+
+@pytest.mark.parametrize("case,reason", [
+    (_case_spends_earlier_output, None),
+    (_case_spends_later_output, "bad-txns-inputs-missingorspent"),
+    (_case_twice_in_one_tx, "bad-txns-inputs-duplicate"),
+    (_case_twice_in_two_txs, "bad-txns-inputs-missingorspent"),
+    (_case_in_block_output_twice, "bad-txns-inputs-missingorspent"),
+    (_case_own_coinbase, "bad-txns-premature-spend-of-coinbase"),
+    (_case_bip30, "bad-txns-BIP30"),
+], ids=lambda x: x.__name__[len("_case_"):] if callable(x) else None)
+def test_block_table_parity(case, reason):
+    block, coins = case()
+    nview, replay = to_native_view(coins), to_native_view(coins)
+    before = (len(nview), nview.digest())
+    kw = dict(pow_limit=REGTEST_POW_LIMIT, check_scripts=False)
+    res_py = connect_block(block, coins, HEIGHT, **kw)
+    res_nat = connect_block(block, nview, HEIGHT, **kw)
+    assert _result_tuple(res_nat) == _result_tuple(res_py)
+    assert res_py.reason == reason and res_py.ok == (reason is None)
+    assert len(nview) == len(coins)
+    if reason is not None:  # a refused block leaves the view as it was
+        assert (len(nview), nview.digest()) == before
+        return
+    # apply with an undo record gives the view the connect gave, and the
+    # undo puts back the view it started from, coin for coin
+    nblk = native_bridge.NativeBlock(block.serialize())
+    undo = replay.apply_block(nblk, HEIGHT, undo=True)
+    assert (len(replay), replay.digest()) == (len(nview), nview.digest())
+    assert (len(replay), replay.digest()) != before
+    replay.undo_block(nblk, undo)
+    assert (len(replay), replay.digest()) == before
+
+
+def test_coin_probes_count_one_block_table_probe_an_input():
+    """`consensus_coin_probes_total`, read off the parsed block: an input
+    probes the block's table once and, unless the block made the coin, the
+    view once; an output probes the view once (BIP30) and the table once;
+    the apply probes the view once an input and once an output."""
+    block, coins = _case_spends_earlier_output()
+    n_in, n_out, in_block = 3, 3 + len(block.vtx[0].vout), 1
+    nview = to_native_view(coins)
+    nblk = native_bridge.NativeBlock(block.serialize())
+    flags = 0
+    assert nblk.accounting(nview, HEIGHT, flags)[0] is None
+    assert nblk.coin_probes() == {
+        "view": n_out + n_in - in_block, "block": n_in + n_out}
+    undo = nview.apply_block(nblk, HEIGHT, undo=True)
+    assert nblk.coin_probes() == {
+        "view": n_out + n_in - in_block + n_in + n_out, "block": n_in + n_out}
+    nview.undo_block(nblk, undo)
+    # the next accounting of the same parsed block starts from zero
+    assert nblk.accounting(nview, HEIGHT, flags)[0] is None
+    assert nblk.coin_probes()["block"] == n_in + n_out
+
+    from bitcoinconsensus_tpu.models.validate import _COIN_PROBES
+
+    was = {t: _COIN_PROBES.value(table=t) for t in ("view", "block")}
+    res = connect_block(block, nview, HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        check_scripts=False)
+    assert res.ok
+    assert _COIN_PROBES.value(table="block") - was["block"] == n_in + n_out
+    assert _COIN_PROBES.value(table="view") - was["view"] == (
+        2 * (n_in + n_out) - in_block)
