@@ -108,6 +108,11 @@ _SPEC_PAIRINGS = _obs_counter(
     "CHECKMULTISIG (signature, key) pairings pre-recorded ahead of the key "
     "walk that became deduplicated checks of their own",
 )
+_WALK_PAIRINGS = _obs_counter(
+    "consensus_multisig_walk_pairings_total",
+    "CHECKMULTISIG (signature, key) pairings the cursor walk tried in the "
+    "interpretation whose verdict was returned: what Core's own walk verifies",
+)
 _SIGHASHES = _obs_counter(
     "consensus_sighash_total",
     "ECDSA message digests the native interpreter hashed (computed) or read "
@@ -584,6 +589,12 @@ class IdxFixpoint:
         self._rounds = 0
         self._in_flight = None  # (interp tuple, uniq round record)
         self.lanes: Optional[Dict[str, int]] = None  # by kind, at finish
+        # CHECKMULTISIG pairings, at finish: `spec_pairings` pre-recorded
+        # ahead of the walk, `walk_pairings` tried by the walk of the
+        # interpretation each input's verdict was taken from
+        self.multisig: Optional[Dict[str, int]] = None
+        self._walk_pairings = 0
+        self._round_walks = None  # the in-flight round's, by pending position
 
     def begin(self) -> None:
         """Start one round: interpret + dispatch, nothing synchronized."""
@@ -595,6 +606,9 @@ class IdxFixpoint:
         if self._rounds > 1:
             _REINTERPRETED.inc(len(self._pending))
         interp = self.run_idx(self._pending)  # the owner's `interpret` phase
+        # what each input's CHECKMULTISIG walks tried, before the session's
+        # next call overwrites it
+        self._round_walks = self.nsess.call_walks(len(self._pending))
         rec = _dispatch_uniq(self.nsess, self.verifier, self.sig_cache,
                              self._state)
         self._in_flight = (interp, rec)
@@ -612,6 +626,7 @@ class IdxFixpoint:
             done = self._pending[accept]
             self.ok[done] = np.asarray(ok)[accept]
             self.err[done] = np.asarray(err)[accept]
+            self._walk_pairings += int(self._round_walks[accept].sum())
             self._pending = self._pending[~accept]
 
     def abandon(self) -> None:
@@ -652,7 +667,8 @@ class IdxFixpoint:
                 break
             self._settle_round()
         _FIXPOINT_ROUNDS.observe(self._rounds)
-        _SPEC_PAIRINGS.inc(self.nsess.spec_pairings())
+        spec_pairings = self.nsess.spec_pairings()
+        _SPEC_PAIRINGS.inc(spec_pairings)
         computed, reused = self.nsess.sighashes()
         _SIGHASHES.inc(computed, result="computed")
         _SIGHASHES.inc(reused, result="reused")
@@ -665,6 +681,11 @@ class IdxFixpoint:
             _EXACT_FALLBACK.inc(len(self._pending))
         for idx in self._pending.tolist():
             self.ok[idx], self.err[idx] = self.exact_fallback(idx)
+            # the fallback's walk is the one this verdict came from
+            self._walk_pairings += int(self.nsess.call_walks(1).sum())
+        _WALK_PAIRINGS.inc(self._walk_pairings)
+        self.multisig = {"spec_pairings": spec_pairings,
+                         "walk_pairings": self._walk_pairings}
         return self.ok, self.err
 
 

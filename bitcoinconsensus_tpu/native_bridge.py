@@ -97,6 +97,14 @@ def _absent(reason: str) -> None:
     )
 
 
+def store_pool_bytes() -> int:
+    """Bytes of retired index-mode check stores the native core keeps for
+    the next session (native/interp.hpp `StorePool`: a released session's
+    list of a megabyte or more is emptied and parked, not freed, so that
+    the next connect does not fault its pages in again; 256 MB at most)."""
+    return int(lib().nat_store_pool_bytes())
+
+
 def why_absent() -> Optional[str]:
     """Why `available()` is False (None while the core is loaded)."""
     if os.environ.get("BITCOINCONSENSUS_TPU_NATIVE", "") in ("0", "off"):
@@ -138,8 +146,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 13:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 13)")
+        if L.nat_version() < 14:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 14)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -219,6 +227,10 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_uniq_count.restype = ctypes.c_int32
         L.nat_session_spec_pairings.argtypes = [vp]
         L.nat_session_spec_pairings.restype = ctypes.c_int64
+        L.nat_store_pool_bytes.argtypes = []
+        L.nat_store_pool_bytes.restype = ctypes.c_int64
+        L.nat_session_call_walks.argtypes = [vp, i64p, ctypes.c_int64]
+        L.nat_session_call_walks.restype = ctypes.c_int64
         L.nat_session_sighashes.argtypes = [vp, i64p]
         L.nat_session_sighashes.restype = None
         L.nat_session_lane_kinds.argtypes = [vp, i64p]
@@ -812,6 +824,19 @@ class NativeSession:
         list so far (index mode; entries a speculation made, not a key
         walk): monotone over the session's life."""
         return int(lib().nat_session_spec_pairings(self._ptr))
+
+    def call_walks(self, n: int) -> np.ndarray:
+        """(signature, key) pairings CHECKMULTISIG's cursor walk tried in
+        each interpretation of this session's newest verify call, by the
+        input's position in that call (`n`: how many it held; one after
+        `verify_input`): what Core's own walk verifies for that
+        interpretation's verdict. The next call overwrites it, so a
+        fixpoint reads it a round and adds an input's count when it accepts
+        the verdict."""
+        out = np.zeros(max(n, 1), dtype=np.int64)
+        got = lib().nat_session_call_walks(
+            self._ptr, out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), n)
+        return out[:int(got)]
 
     def sighashes(self) -> Tuple[int, int]:
         """ECDSA message digests this session's interpretations hashed, and

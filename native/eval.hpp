@@ -624,6 +624,9 @@ inline EvalResult eval_script(Stack& stack, const Bytes& script, u32 flags,
                             if (e == SE_OK)
                                 e = check_pubkey_encoding(vch_pub, flags, sigversion);
                             if (e != SE_OK) return {false, e};
+                            // One pairing of the walk: what Core hands its
+                            // checker here, whatever the curve then says.
+                            checker.walk_pairings++;
                             bool f_ok = false;
                             if (Checker::ec_check_plausible(vch_sig, vch_pub)) {
                                 const MultisigSigs::Sig& m =

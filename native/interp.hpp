@@ -19,6 +19,7 @@
 #include <cstring>
 #include <map>
 #include <memory>
+#include <mutex>
 #include <random>
 #include <set>
 #include <stdexcept>
@@ -1079,6 +1080,67 @@ struct CheckStore {
         place(at);
         return at;
     }
+
+    size_t capacity_bytes() const {
+        return arena.capacity() + entries.capacity() * sizeof(Entry) +
+               slots.capacity() * sizeof(i32);
+    }
+    // Empty as a new store is, with the buffers it grew kept.
+    void reset() {
+        arena.clear();
+        entries.clear();
+        slots.clear();
+        spec_entries = 0;
+    }
+};
+
+// The buffers of retired check stores, kept for the next session. A block
+// of 286,000 checks fills a 39 MB arena, an 11 MB entry list and a 4 MB
+// table, and about as much again over the workers' scratch stores. Whether
+// the allocator gave those pages back to the system at every release, so
+// that the next connect faulted them in one by one, depended on the state
+// of its heap, process by process: round one of the same block took 50 ms
+// more in some processes than in others (PR 41). A retired store is
+// emptied, its capacity kept, and parked here; stores under KEEP_FROM bytes
+// are left to the allocator, and the pool holds MAX_BYTES at most.
+struct StorePool {
+    static constexpr size_t KEEP_FROM = 1u << 20;
+    static constexpr size_t MAX_BYTES = 256u << 20;
+    std::mutex mu;
+    std::vector<CheckStore> idle;
+    size_t bytes = 0;
+
+    static StorePool& get() {
+        static StorePool pool;
+        return pool;
+    }
+
+    void give(CheckStore&& store) {
+        size_t b = store.capacity_bytes();
+        if (b < KEEP_FROM) return;
+        store.reset();
+        std::lock_guard<std::mutex> lock(mu);
+        if (bytes + b > MAX_BYTES) return;
+        bytes += b;
+        idle.push_back(std::move(store));
+    }
+
+    // Hand `into`, a store that has allocated nothing yet, the largest
+    // parked store (a session's own list) or the smallest (a worker's
+    // scratch); leaves it as it is when nothing is parked.
+    void take(CheckStore& into, bool largest) {
+        if (into.capacity_bytes()) return;
+        std::lock_guard<std::mutex> lock(mu);
+        if (idle.empty()) return;
+        size_t pick = 0;
+        for (size_t i = 1; i < idle.size(); i++) {
+            bool larger = idle[i].capacity_bytes() > idle[pick].capacity_bytes();
+            if (larger == largest) pick = i;
+        }
+        bytes -= idle[pick].capacity_bytes();
+        into = std::move(idle[pick]);
+        idle.erase(idle.begin() + (std::ptrdiff_t)pick);
+    }
 };
 
 struct Session {
@@ -1162,6 +1224,12 @@ struct Session {
     // Lanes nat_session_uniq_lanes prepped out of this session's uniq
     // list, by the check's kind (ecdsa, schnorr, tweak).
     i64 lanes_by_kind[3] = {0, 0, 0};
+    // (signature, key) pairings CHECKMULTISIG's cursor walk tried in each
+    // interpretation of the newest verify call, by the input's position in
+    // that call (workers write their own slots): the number Core's own
+    // walk verifies. The next call overwrites it; the driver adds an
+    // input's count when it accepts that interpretation's verdict.
+    std::vector<i64> call_walk;
 
     static std::string key(const PartsView& v) {
         std::string k;
@@ -1197,6 +1265,9 @@ struct Checker {
     i64 amount;
     int mode;
     Session* sess;  // used in MODE_DEFER
+    // Pairings the CHECKMULTISIG walks of this interpretation tried
+    // (eval.hpp counts; run_verify_input hands the total to its caller).
+    i64 walk_pairings = 0;
 
     // raw curve resolution: oracle -> record-optimistic (defer) or native
     // verify (exact)

@@ -16,6 +16,7 @@
 #include "eval.hpp"
 #include "secp.hpp"
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -117,10 +118,12 @@ void fill_records_data(const std::vector<Record>& v, u8* blob) {
 
 // One input through verify_script with a (possibly deferring) checker;
 // bounds-checks n_in. Does NOT touch the session's records/unknown state —
-// callers own the clear/boundary bookkeeping.
+// callers own the clear/boundary bookkeeping. *walk, where asked for, gets
+// the pairings the script's CHECKMULTISIG walks tried.
 i32 run_verify_input(Session* sess, NTx* tx, i32 n_in, i64 amount,
                      const u8* spk, i64 spk_len, i32 flags, i32 mode,
-                     i32* script_err, i32* unknown) {
+                     i32* script_err, i32* unknown, i64* walk = nullptr) {
+    if (walk) *walk = 0;
     if (n_in < 0 || (size_t)n_in >= tx->vin.size()) {
         *script_err = SE_UNKNOWN_ERROR;
         *unknown = 0;
@@ -139,6 +142,7 @@ i32 run_verify_input(Session* sess, NTx* tx, i32 n_in, i64 amount,
                                  checker);
     *script_err = r.err;
     *unknown = sess ? sess->unknown : 0;
+    if (walk) *walk = checker.walk_pairings;
     return r.ok ? 1 : 0;
 }
 
@@ -272,7 +276,8 @@ extern "C" {
 // 12: nat_session_lane_kinds, nat_session_taproot_hashes.
 // 13: nat_block_coin_probes; the coin tables key on a fixed 36-byte outpoint
 //     under a salted hash (same symbols, another NView).
-int nat_version() { return 13; }
+// 14: nat_session_call_walks, nat_store_pool_bytes.
+int nat_version() { return 14; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -747,7 +752,11 @@ void nat_prep_lanes(const u8* blob, const i64* offs, const i32* kinds, i32 n,
 
 void* nat_session_new() { return new Session(); }
 
-void nat_session_free(void* s) { delete static_cast<Session*>(s); }
+void nat_session_free(void* s) {
+    auto* sess = static_cast<Session*>(s);
+    if (sess) StorePool::get().give(std::move(sess->uniq));
+    delete sess;
+}
 
 void nat_session_add_known(void* s, i32 kind, i32 parity, const u8* p0, i64 l0,
                            const u8* p1, i64 l1, const u8* p2, i64 l2,
@@ -927,9 +936,11 @@ i32 nat_verify_input(void* s, void* txp, i32 n_in, i64 amount, const u8* spk,
         // publish optimistic verdicts with the misses unresolved).
         sess->index_mode = false;
         sess->records.clear();
+        sess->call_walk.assign(1, 0);
     }
     return run_verify_input(sess, static_cast<NTx*>(txp), n_in, amount, spk,
-                            spk_len, flags, mode, script_err, unknown);
+                            spk_len, flags, mode, script_err, unknown,
+                            sess ? sess->call_walk.data() : nullptr);
 }
 
 // Batched verify: n inputs in one call (the per-call ctypes cost of the
@@ -975,13 +986,13 @@ void nat_verify_inputs(void* s, void** txs, const i32* n_ins,
 static void run_idx_range(Session* sess, void** txs, const i32* n_ins,
                           const i64* amounts, const u8* spk_blob,
                           const i64* spk_offs, const i32* flags, i32 lo,
-                          i32 hi, i32* ok, i32* err, i32* unk,
+                          i32 hi, i32* ok, i32* err, i32* unk, i64* walk,
                           i64* local_bounds) {
     for (i32 i = lo; i < hi; i++) {
         ok[i] = run_verify_input(sess, static_cast<NTx*>(txs[i]), n_ins[i],
                                  amounts[i], spk_blob + spk_offs[i],
                                  spk_offs[i + 1] - spk_offs[i], flags[i],
-                                 MODE_DEFER, &err[i], &unk[i]);
+                                 MODE_DEFER, &err[i], &unk[i], &walk[i]);
         local_bounds[i + 1] = (i64)sess->rec_idx.size();
     }
 }
@@ -994,11 +1005,15 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     auto* sess = static_cast<Session*>(s);
     sess->index_mode = true;
     sess->rec_idx.clear();
+    sess->call_walk.assign((size_t)n, 0);
+    i64* walk = sess->call_walk.data();
+    StorePool& pool = StorePool::get();
+    pool.take(sess->uniq, true);
     rec_bounds[0] = 0;
     if (n_threads < 2 || n < 2 * n_threads) {
         // rec_idx was just cleared, so per-input bounds are global bounds.
         run_idx_range(sess, txs, n_ins, amounts, spk_blob, spk_offs, flags, 0,
-                      n, ok, err, unk, rec_bounds);
+                      n, ok, err, unk, walk, rec_bounds);
         return;
     }
     i32 T = n_threads;
@@ -1007,13 +1022,14 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     for (i32 t = 0; t < T; t++) {
         scratch[t].index_mode = true;
         scratch[t].oracle = sess;
+        pool.take(scratch[t].uniq, false);
         bounds[t].assign((size_t)n + 1, 0);
     }
     fan_out(n, T, [&](i32 t, i32 lo, i32 hi) {
         // The scratch session's rec_idx is empty at entry, so the
         // worker's bounds slots [lo+1, hi] are relative to 0.
         run_idx_range(&scratch[t], txs, n_ins, amounts, spk_blob, spk_offs,
-                      flags, lo, hi, ok, err, unk, bounds[t].data());
+                      flags, lo, hi, ok, err, unk, walk, bounds[t].data());
     });
     // Serial merge in shard order: dedup each scratch's uniq into the
     // shared session (a new entry's bytes are copied once, its hash is
@@ -1039,7 +1055,15 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
                 sess->rec_idx.push_back(remap[(size_t)sc.rec_idx[(size_t)j]]);
             rec_bounds[i + 1] = (i64)sess->rec_idx.size();
         }
+        pool.give(std::move(scratch[t].uniq));
     }
+}
+
+// Bytes of retired check stores parked for the next session (StorePool).
+i64 nat_store_pool_bytes() {
+    StorePool& pool = StorePool::get();
+    std::lock_guard<std::mutex> lock(pool.mu);
+    return (i64)pool.bytes;
 }
 
 i32 nat_session_uniq_count(void* s) {
@@ -1050,6 +1074,17 @@ i32 nat_session_uniq_count(void* s) {
 // session so far (CheckStore::spec_entries; index mode).
 i64 nat_session_spec_pairings(void* s) {
     return static_cast<Session*>(s)->uniq.spec_entries;
+}
+
+// (signature, key) pairings the CHECKMULTISIG cursor walks tried in each
+// interpretation of the session's newest verify call, by the input's
+// position in that call (one entry after nat_verify_input). Copies at most
+// `capacity` entries; returns the count copied.
+i64 nat_session_call_walks(void* s, i64* out, i64 capacity) {
+    const auto& w = static_cast<Session*>(s)->call_walk;
+    i64 n = std::min((i64)w.size(), capacity);
+    if (n > 0) std::memcpy(out, w.data(), (size_t)n * sizeof(i64));
+    return n;
 }
 
 // ECDSA message digests this session's interpretations have hashed

@@ -54,6 +54,10 @@ REQUIRED_METRICS = [
     "consensus_prep_lanes_total",
     "consensus_sighash_total",
     "consensus_taproot_hash_total",
+    # CHECKMULTISIG on the index path: the pairings pre-recorded ahead of
+    # the key walk, and those the walk behind a returned verdict tried
+    "consensus_multisig_spec_pairings_total",
+    "consensus_multisig_walk_pairings_total",
     # caches
     "consensus_cache_lookups_total",
     "consensus_cache_hits_total",

@@ -429,6 +429,9 @@ def test_fixpoint_round_cap_exact_fallback():
         def spec_pairings(self):
             return 0
 
+        def call_walks(self, n):
+            return np.zeros(n, dtype=np.int64)
+
         def sighashes(self):
             return 0, 0
 

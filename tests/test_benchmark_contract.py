@@ -72,6 +72,7 @@ READ = (
     "consensus_mesh_dispatch_total",
     "consensus_mesh_shard_lanes",
     "consensus_multisig_spec_pairings_total",
+    "consensus_multisig_walk_pairings_total",
     "consensus_serving_admitted_total",
     "consensus_serving_batch_fill",
     "consensus_serving_batch_seconds",

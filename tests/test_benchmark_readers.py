@@ -1,4 +1,4 @@
-"""The per-layer readers PRs 36 and 38 added, each on a made-up `ctx`.
+"""The per-layer readers PRs 36 to 41 added, each on a made-up `ctx`.
 
 `benchmarks/tests` is not part of tier-1, and a reader runs for real only
 in a `--trace 1` run on the chip. Here every new reader gets a context
@@ -316,6 +316,43 @@ def test_coin_probes_per_input_returns_none_with_nothing_to_read(metric, ctx):
     assert reader(metric)(ctx) is None
 
 
+# -- the walk's share of the pre-recorded pairings (PR 41) ---------------------
+
+_WALK = "consensus_multisig_walk_pairings_total"
+_SPEC = "consensus_multisig_spec_pairings_total"
+
+
+def walk_ctx(kind, walk, spec, connects=3, names=(_WALK, _SPEC)):
+    """A window of `connects` connects over which the two counters rose
+    from (700, 1100) by (`walk`, `spec`); `names`: what the program has."""
+    def snap(w, s):
+        both = {_WALK: w, _SPEC: s}
+        return {n: {"samples": [{"labels": {}, "value": both[n]}]} for n in names}
+    return {"cell": "made-up", "trace": None, "driver": {
+        "kind": kind, "walls_s": [0.8] * connects, "n_inputs": 5,
+        "counters_before": snap(700, 1100), "counters_after": snap(700 + walk, 1100 + spec)}}
+
+
+@pytest.mark.parametrize("walk,spec,want", [
+    (300, 1560, 100 * 20 / 104),  # 8-of-20 by the eight first-pushed keys: 20 of 104 an input
+    (300, 300, 100.0),            # 1-of-20 by the key tried last: every lane is the walk's
+    (0, 12, 0.0),
+])
+def test_walk_share_of_spec_divides_the_walk_by_the_band(walk, spec, want):
+    assert reader("walk_share_of_spec.connect")(walk_ctx("connect", walk, spec)) == ms(want)
+
+
+@pytest.mark.parametrize("ctx", [
+    walk_ctx("connect", 0, 1560, names=(_SPEC,)),  # the parent: no walk counter
+    walk_ctx("connect", 300, 0),                   # a window that pre-recorded nothing
+    walk_ctx("stream", 300, 1560),                 # another kind of cell
+    walk_ctx("serve", 300, 1560),
+    {"cell": "made-up", "trace": None, "driver": {"kind": "connect", "walls_s": [0.8], "n_inputs": 5}},
+])
+def test_walk_share_of_spec_returns_none_with_nothing_to_read(ctx):
+    assert reader("walk_share_of_spec.connect")(ctx) is None
+
+
 def test_benchmark_json_lists_each_new_metric_with_its_cells():
     import json
 
@@ -323,7 +360,8 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         bench = json.load(f)
     by_name = {m["name"]: m for m in bench["per_layer"]}
     connect = ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "worst-block-mesh4.sigops",
-               "taproot-block.cold"]  # PR 39: a new cell is appended to a list, nothing else changed
+               "taproot-block.cold",  # PR 39: a new cell is appended to a list, nothing else changed
+               "worst-block-multisig20.fanout"]  # PR 41
     every = [w["name"] for w in bench["workloads"]]
     want = {
         "unphased_ms.connect": connect, "sig_cache_ms.connect": connect,
@@ -332,15 +370,19 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "settle_wait_ms.serve": ["mempool-serve.steady"], "ingress_ms.serve": ["mempool-serve.steady"],
         "trace_lower_s.setup": every, "compile_s.setup": every,
         "sighashes_per_input.connect":  # PR 38
-            ["worst-block.sigops", "worst-block-mesh4.sigops", "taproot-block.cold"],
+            ["worst-block.sigops", "worst-block-mesh4.sigops", "taproot-block.cold",
+             "worst-block-multisig20.fanout"],
         # PR 39: the lanes by kind and the taproot hashes of the index path
         "schnorr_lane_share.connect": ["taproot-block.cold", "tip-block.cold"],
         "tweak_lane_share.connect": ["taproot-block.cold"],
         "taphashes_per_input.connect": ["taproot-block.cold"],
         # PR 40: the coin tables' probes
         "coin_probes_per_input.connect":
-            ["tip-block.cold", "tip-block.warm", "taproot-block.cold", "worst-block.sigops"],
+            ["tip-block.cold", "tip-block.warm", "taproot-block.cold", "worst-block.sigops",
+             "worst-block-multisig20.fanout"],
         "coin_probes_per_input.stream": ["ibd-stream.cold"],
+        # PR 41: the walk's share of the pre-recorded CHECKMULTISIG pairings
+        "walk_share_of_spec.connect": ["worst-block-multisig20.fanout", "worst-block.sigops"],
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name

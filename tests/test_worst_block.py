@@ -5,7 +5,11 @@ CHECKMULTISIG behind P2WSH, at the sigop-cost limit.
 (80,000 curve checks, ten 8,192-lane dispatches a connect). Here the same
 shape runs small on the CPU: a connect of 1-of-20 spends that takes several
 chunks a round against the executable spec, the budget at exactly its
-limit in both accountings, and the number of lanes an m-of-20 input costs.
+limit in both accountings, and the number of lanes an m-of-20 input costs
+(one input, all signatures sound: the band's formula). What m > 1 does
+through `connect_block`, twins and counters and all, is
+`tests/test_multisig_block.py`, and at size on the chip the cell
+`worst-block-multisig20.fanout`.
 """
 
 import hashlib
