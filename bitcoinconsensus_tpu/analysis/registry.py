@@ -229,8 +229,8 @@ def _verdict_checksum_fn():
 
 # verify_tiles requires B % LANE_TILE == 0 and a multi-step grid is the
 # interesting case, so the Pallas spec ignores the requested batch and
-# proves two full lane tiles.
-_PALLAS_B = 1024  # == 2 * ops.pallas_kernel.LANE_TILE
+# proves two grid steps of the dense tile (8 sublane rows of 128 lanes).
+_PALLAS_B = 2048
 
 
 def _pallas_flag_bounds():
@@ -242,7 +242,7 @@ def _pallas_verify_build():
     from . import pallas_check  # noqa: F401  registers the Ref rules
     from ..ops import pallas_kernel as PK
 
-    assert _PALLAS_B == 2 * PK.LANE_TILE
+    assert PK.tile_grid(_PALLAS_B) == (8, PK.VREG_LANES, 2)
     B = _PALLAS_B
 
     def fn(fields, want_odd, parity_req, has_t2, neg1, neg2, valid):

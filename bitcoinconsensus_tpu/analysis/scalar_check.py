@@ -1453,11 +1453,13 @@ def _target_double_scalar_mult_glv(quick: bool = False) -> CertResult:
 
 def _pallas_source_checks(facts: Dict[str, Any],
                           failures: List[str]) -> None:
-    """AST facts about _kernel_body that the eager walk cannot see:
+    """AST facts about _kernel_body (and the `_g_select` it calls a
+    window) that the eager walk cannot see:
     the one-hot comparands are iota+1 (table row k holds (k+1)·P /
     (j+1)·256^w·G — off-by-one here selects the wrong multiple), and the
     digit signs are XORed with the GLV half signs before negating y."""
-    src = textwrap.dedent(inspect.getsource(pk_mod._kernel_body))
+    src = "\n".join(textwrap.dedent(inspect.getsource(fn))
+                    for fn in (pk_mod._kernel_body, pk_mod._g_select))
     tree = ast.parse(src)
     iota_plus_one = []
     for node in ast.walk(tree):
@@ -1470,9 +1472,9 @@ def _pallas_source_checks(facts: Dict[str, Any],
             dims = [a.value for a in node.left.args[1].elts
                     if isinstance(a, ast.Constant)]
             iota_plus_one.append(tuple(dims))
-    if (16, 1, 1) not in iota_plus_one:
+    if (16, 1, 1, 1) not in iota_plus_one:
         failures.append("pallas: k16 one-hot comparand is not "
-                        "broadcasted_iota((16,1,1)) + 1 — P-table row k "
+                        "broadcasted_iota((16,1,1,1)) + 1 — P-table row k "
                         "holds (k+1)·P, the +1 is load-bearing")
     if (255, 1) not in iota_plus_one:
         failures.append("pallas: k255 comparand is not "
@@ -1494,36 +1496,40 @@ def _target_pallas_schedule(quick: bool = False) -> CertResult:
     _pallas_source_checks(facts, failures)
 
     rec = _Recorder()
-    T = 1
+    tile = (1, 1)  # one lane: (S, L) behind the rows of every operand
     k_int = 0xBADC0DE0DDF00D0D15EA5E0BEEFFACE0CADFACE0DEAD0FAB0FEED0ACE
     a1, neg1, a2, neg2 = glv_mod.split_lambda(k_int)
     ab1, sb1 = (np.asarray(v) for v in
                 pk_mod._signed_digits128(_limb_col(a1, 10)))
     ab2, sb2 = (np.asarray(v) for v in
                 pk_mod._signed_digits128(_limb_col(a2, 10)))
-    px = _limb_col(host.G_X)
-    flags = np.zeros((6, T), np.int32)
-    flags[0, :] = host.G_Y & 1         # want_odd
-    flags[1, :] = -1                   # no parity requirement
-    flags[3, :] = 1                    # valid
-    flags[4, :] = 1 if neg1 else 0
-    flags[5, :] = 1 if neg2 else 0
+    flags = np.zeros((6,) + tile, np.int32)
+    flags[0] = host.G_Y & 1         # want_odd
+    flags[1] = -1                   # no parity requirement
+    flags[3] = 1                    # valid
+    flags[4] = 1 if neg1 else 0
+    flags[5] = 1 if neg2 else 0
     gx, gy = curve_mod._g_table()
+
+    def lanes(rows, arr=None):
+        arr = jnp.zeros((rows, 1), jnp.int32) if arr is None else arr
+        return jnp.asarray(arr).reshape((rows,) + tile)
+
     refs = {
-        "px": _FakeRef(px, "px", rec),
-        "t1": _FakeRef(jnp.zeros((NLIMB, T), jnp.int32), "t1", rec),
-        "t1n": _FakeRef(jnp.zeros((NLIMB, T), jnp.int32), "t1n", rec),
-        "da": _FakeRef(jnp.zeros((32, T), jnp.int32), "da", rec),
-        "db1": _FakeRef(jnp.asarray(ab1), "db1", rec),
-        "ds1": _FakeRef(jnp.asarray(sb1), "ds1", rec),
-        "db2": _FakeRef(jnp.asarray(ab2), "db2", rec),
-        "ds2": _FakeRef(jnp.asarray(sb2), "ds2", rec),
+        "px": _FakeRef(lanes(NLIMB, _limb_col(host.G_X)), "px", rec),
+        "t1": _FakeRef(lanes(NLIMB), "t1", rec),
+        "t1n": _FakeRef(lanes(NLIMB), "t1n", rec),
+        "da": _FakeRef(lanes(32), "da", rec),
+        "db1": _FakeRef(lanes(pk_mod.SGLV_WINDOWS, ab1), "db1", rec),
+        "ds1": _FakeRef(lanes(pk_mod.SGLV_WINDOWS, sb1), "ds1", rec),
+        "db2": _FakeRef(lanes(pk_mod.SGLV_WINDOWS, ab2), "db2", rec),
+        "ds2": _FakeRef(lanes(pk_mod.SGLV_WINDOWS, sb2), "ds2", rec),
         "flags": _FakeRef(jnp.asarray(flags), "flags", rec),
         "gx": _FakeRef(gx.astype(jnp.float32), "gx", rec),
         "gy": _FakeRef(gy.astype(jnp.float32), "gy", rec),
-        "ok": _FakeRef(jnp.zeros((2, T), jnp.int32), "ok", rec),
-        "tx": _FakeRef(jnp.zeros((16, NLIMB, T), jnp.int32), "tx", rec),
-        "ty": _FakeRef(jnp.zeros((16, NLIMB, T), jnp.int32), "ty", rec),
+        "ok": _FakeRef(lanes(2), "ok", rec),
+        "tx": _FakeRef(jnp.zeros((16, NLIMB) + tile, jnp.int32), "tx", rec),
+        "ty": _FakeRef(jnp.zeros((16, NLIMB) + tile, jnp.int32), "ty", rec),
     }
     patches = _jacobian_spies(rec, pk_mod)
     patches[(jax.lax, "fori_loop")] = _fake_fori(rec)

@@ -107,6 +107,13 @@ _CHECKS_TOTAL = _obs_counter(
 _DISPATCH_TOTAL = _obs_counter(
     "consensus_dispatch_total", "device dispatches by backend", ("backend",)
 )
+_DISPATCH_TILES = _obs_counter(
+    "consensus_dispatch_tiles_total",
+    "grid steps of the Pallas programs dispatched, by the sublane rows a "
+    "step's tile fills: 8 is the dense tile, 4 the half-filled one of the "
+    "512-lane shape",
+    ("rows",),
+)
 _DISPATCH_LANES = _obs_counter(
     "consensus_dispatch_lanes_total", "real (unpadded) lanes dispatched"
 )
@@ -884,6 +891,16 @@ class TpuSecpVerifier:
             _NEW_SHAPES.inc()
         return first
 
+    @staticmethod
+    def _note_tiles(rows_a_program: int, programs: int = 1) -> None:
+        """Count the grid steps `programs` Pallas programs of
+        `rows_a_program` lanes each will run (a mesh dispatch: one program
+        a shard), by the tile `verify_tiles` chooses for that size."""
+        from ..ops.pallas_kernel import tile_grid
+
+        sublanes, _, steps = tile_grid(rows_a_program)
+        _DISPATCH_TILES.inc(steps * programs, rows=str(sublanes))
+
     def _launch_timed(self, kernel, args: Tuple, n: int, backend: str):
         """Account for and launch one kernel call, timing the call itself:
         a shape's first launch traces and compiles before it enqueues."""
@@ -912,6 +929,7 @@ class TpuSecpVerifier:
             from ..ops.pallas_kernel import LANE_TILE, verify_tiles
 
             if padded % LANE_TILE == 0:
+                self._note_tiles(padded)
                 return self._launch_timed(verify_tiles, args, n, "pallas")
         return self._launch_timed(self._kernel, args, n, "xla")
 

@@ -95,13 +95,13 @@ G_WINDOW_BITS = 8
 
 
 def _col(vec: np.ndarray, like):
-    """Constant limb vector -> (20, 1, ..., 1) broadcastable column.
+    """Constant limb vector -> a column that broadcasts against `like`.
 
-    Routed through `limb_const` so pallas kernels resolve it to a
+    Routed through `limb_col` so pallas kernels resolve it to a
     constant-table input instead of a captured jnp constant."""
-    from .limbs import limb_const
+    from .limbs import limb_col
 
-    return limb_const(vec).reshape((NLIMB,) + (1,) * (like.ndim - 1))
+    return limb_col(vec, like)
 
 
 @named_region("jacobian_double")

@@ -5,10 +5,15 @@ in radix 2^13, dtype int32, **limb axis first**: shape ``(20, ...)`` with
 the batch in the trailing (lane) axes. Two hardware facts drive the layout
 and the carry scheme:
 
-- The VPU operates on (8, 128) tiles with the *last* dimension mapped to
-  128 lanes. Batch-last means every elementwise op runs at full lane
-  occupancy; the tiny 20-limb axis lives in the sublane dimension. (The
-  transposed layout — limbs last — wastes 108/128 lanes on every op.)
+- The VPU operates on (8, 128) registers: the *last* dimension maps to
+  128 lanes, the one before it to 8 sublanes. Batch-last means every
+  elementwise op runs at full lane occupancy. Every routine here takes any
+  trailing shape: on a 2-D ``(20, B)`` batch (the XLA path) the 20-limb
+  axis lies on the sublanes; the Pallas kernel folds its batch to
+  ``(20, S, 128)``, where the limb axis is an untiled leading axis, a limb
+  is a whole register, and the shifts, pads and slices along the limbs
+  below are plain indexing (`ops/pallas_kernel.py`). (The transposed
+  layout — limbs last — wastes 108/128 lanes on every op.)
 - There is no 64-bit multiplier. A 13x13-bit product is < 2^26 and a
   20-term schoolbook column sums to < 2^31, so every intermediate of a
   256-bit multiply fits a signed int32 lane. The reference proves the
@@ -156,6 +161,20 @@ def limb_const(arr: np.ndarray):
         if out is not None:
             return out
     return jnp.asarray(arr)
+
+
+def limb_col(arr: np.ndarray, like):
+    """A well-known limb vector as a column that broadcasts against the
+    element `like`: (20, 1, ..., 1) — or the provider's own array where
+    that already has `like`'s rank (the Pallas kernel's constant table
+    holds every limb spread over a whole tile, so a use costs a load and
+    no broadcast; `fe_is_zero_many` widens the lanes k-fold, and the tile
+    repeats k times beside itself)."""
+    c = limb_const(arr)
+    if c.ndim != like.ndim:
+        return c.reshape((NLIMB,) + (1,) * (like.ndim - 1))
+    k = like.shape[-1] // c.shape[-1]
+    return c if k == 1 else jnp.concatenate([c] * k, axis=-1)
 
 
 def set_const_provider(fn):
@@ -323,7 +342,7 @@ _SUB_BOUNDS = [int(d) + w for d, w in zip(_SUB_BIAS, W2, strict=True)]
 @named_region("fe_sub")
 def fe_sub(a, b):
     """a - b mod p (weak in/out): a + 32p(in >=W2-limb form) - b >= 0."""
-    bias = limb_const(_SUB_BIAS).reshape((NLIMB,) + (1,) * (a.ndim - 1))
+    bias = limb_col(_SUB_BIAS, a)
     return _settle(a + bias - b, list(_SUB_BOUNDS))
 
 
@@ -551,7 +570,7 @@ def fe_canon(a, bounds: Bounds = None):
     # One conditional subtract-p via borrow lookahead: d = e - p limbwise;
     # borrow-in b satisfies the same prefix recurrence with
     # g = (d < 0), pr = (d == 0) on the negated difference domain.
-    p = limb_const(_P_LIMBS).reshape((NLIMB,) + (1,) * (a.ndim - 1))
+    p = limb_col(_P_LIMBS, a)
     d = e - p
     g = (d < 0).astype(jnp.int32)
     pr = (d == 0).astype(jnp.int32)  # zero diff propagates an incoming borrow
@@ -573,7 +592,7 @@ def fe_canon(a, bounds: Bounds = None):
 def fe_is_zero(a, bounds: Bounds = None):
     """a ≡ 0 mod p? Returns (...,) bool (batch shape without limb axis)."""
     e = _exact_lt_2p(a, list(W2) if bounds is None else list(bounds))
-    p = limb_const(_P_LIMBS).reshape((NLIMB,) + (1,) * (a.ndim - 1))
+    p = limb_col(_P_LIMBS, a)
     return jnp.all(e == 0, axis=0) | jnp.all(e == p, axis=0)
 
 

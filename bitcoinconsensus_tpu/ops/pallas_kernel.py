@@ -2,29 +2,43 @@
 
 Why this exists: the XLA lowering of the limb-arithmetic graph
 (`ops/limbs.py` + `ops/curve.py`) leaves the ~4k field operations per lane
-as many small HBM-roundtripping fused kernels — profiling attributes ~65%
-of verify wall time to device compute that should be VPU-bound by two
-orders of magnitude less. This kernel runs the ENTIRE scalar-mult +
-accept-logic pipeline for a tile of lanes inside one `pallas_call`:
-every intermediate lives in VMEM (a (20, TILE) field element is 40 KB;
-the live set is a few MB against ~16 MB of VMEM), HBM traffic is exactly
-the kernel inputs/outputs, and Mosaic compiles the loops without
-unrolling (the 315 s XLA warmup problem).
+as many small fused kernels, each a round trip through HBM. This kernel
+runs the ENTIRE scalar-mult + accept-logic pipeline for a tile of lanes
+inside one `pallas_call`: every intermediate lives in VMEM, HBM traffic is
+exactly the kernel inputs/outputs, and Mosaic compiles the loops without
+unrolling.
+
+Layout. The batch axis of a dispatch splits into (grid step, sublane row,
+lane) behind the rows of every per-lane operand, so inside a grid step a
+field element is ``(20, S, L)`` with L = 128 lanes: the limb axis is an
+untiled leading axis, and **a limb is one (8, 128) vector register**
+(S = 8: 1,024 lanes a step, the tile of every dispatch 1,024 divides;
+S = 4: the 512-lane dispatch, whose one tile fills half of each register
+and costs what a full one does; `tile_grid`). A product ``a[i] * b``, a
+shift along the limbs (`_pad_rows`, the slices of `_pass`, `fe_canon`'s
+Kogge-Stone steps) and a reduction over limbs are then whole-register
+operations or plain indexing, and every (S, L) mask, digit and flag row
+fills a register too. A field element is 80 KB at S = 8; the live set is
+the two 2.6 MB P tables, the G window tables, the 0.6 MB table of limb
+constants spread over a tile and the double-buffered operands
+(`analysis/pallas_check.py` holds their sum to its VMEM budget).
 
 The math is literally the same code — `fe_mul`, `jacobian_double`,
-`jacobian_add_complete`, ... are pure jnp functions over (20, B) int32
+`jacobian_add_complete`, ... are pure jnp functions over (20, ...) int32
 arrays and are called here on VMEM-resident values. Differences from the
 XLA path (`curve.double_scalar_mult` + `jax_backend._verify_kernel`):
 
 - The final x-compare uses the reference's z²-scaled trick where
-  possible, but lanes may also need R.y parity (Schnorr/taproot), so a
-  per-lane Fermat inverse (all-lanes SPMD, ~10% of the scalar-mult cost)
-  produces true affine coordinates — replacing the XLA path's
-  cross-lane `fe_batch_inv` scan, which does not belong inside a tiled
-  kernel.
+  possible, but lanes may also need R.y parity (Schnorr/taproot), so the
+  kernel produces true affine coordinates through a Montgomery batch
+  inverse along the lanes of each sublane row (`_tile_batch_inv`, one
+  Fermat chain a tile) — replacing the XLA path's cross-lane
+  `fe_batch_inv` scan, which does not lower in Mosaic.
 - Window digits and the r+n secondary target are precomputed in the XLA
   preamble (`verify_tiles` below) — cheap fused gathers there, scalar
   noise here.
+- The a·G table select runs its one-hot product a sublane row at a time
+  (`_g_select`) and stacks the rows behind the limbs.
 
 Spec: `secp256k1_ecmult` (`secp256k1/src/ecmult_impl.h:446-580`),
 `secp256k1_ecdsa_sig_verify` x-compare (`ecdsa_impl.h:207-275`), BIP340
@@ -77,9 +91,11 @@ from .limbs import (
     set_const_provider,
 )
 
-__all__ = ["verify_tiles", "LANE_TILE", "FLAG_BOUNDS", "OK_BOUNDS"]
+__all__ = ["verify_tiles", "tile_grid", "LANE_TILE", "FLAG_BOUNDS", "OK_BOUNDS"]
 
-LANE_TILE = 512  # lanes per kernel instance (4 VPU lane groups)
+LANE_TILE = 512  # the smallest Pallas dispatch: callers test `padded % LANE_TILE`
+VREG_LANES = 128  # lanes of one vector register (8 sublanes x 128 lanes)
+FULL_TILE = 8 * VREG_LANES  # the dense tile: a limb of 1,024 lanes is one register
 
 # Input/output contract of `verify_tiles`, single-sourced here and
 # consumed by analysis/registry (the prover assumes exactly this much of
@@ -138,49 +154,74 @@ _CONST_ROWS = {
 }
 
 def _const_col(vec, like):
-    from .limbs import limb_const
+    from .limbs import limb_col
 
-    return jnp.broadcast_to(
-        limb_const(vec).reshape((NLIMB,) + (1,) * (like.ndim - 1)), like.shape
-    ).astype(like.dtype)
+    return jnp.broadcast_to(limb_col(vec, like), like.shape).astype(like.dtype)
 
 
-def _tile_batch_inv(Z, inf_mask, ones):
-    """Montgomery batch inverse across the tile's lane axis.
+def _tile_batch_inv(Z, skip, ones):
+    """Montgomery batch inverse of a (20, S, L) tile, a sublane row at a
+    time: every row of L lanes inverts its own product.
 
-    Hillis-Steele prefix/suffix fe_mul trees (log2(tile) whole-tile muls
-    each, lanes shifted with jnp.roll) + ONE Fermat chain on the (20, 1)
-    grand product + 2 muls per lane — replaces a 255-step per-lane chain
-    with ~21 tile-wide muls. The in-kernel analogue of `fe_batch_inv`
-    (whose lax.associative_scan does not lower in Mosaic). Infinity lanes
-    contribute 1 and return garbage, masked by the caller.
+    Hillis-Steele prefix and suffix `fe_mul` trees along the lanes
+    (log2(L) tile-wide muls each, lanes shifted with `jnp.roll`), ONE
+    Fermat chain on the prefix tree (the last lane of a row holds that
+    row's product; the other lanes ride along) and two muls a lane. The
+    in-kernel analogue of `fe_batch_inv` (whose lax.associative_scan does
+    not lower in Mosaic). The rows are not multiplied into one product:
+    a limb of the grand product would fill one sublane of a register, and
+    the chain on it would issue the same instructions as the chain on all
+    S rows, after log2(S) more tree levels each way.
+
+    `skip` (S, L) lanes (infinity, deferred) contribute 1 and return
+    garbage, masked by the caller.
     """
-    T = Z.shape[-1]
-    zz = jnp.where(inf_mask[None], ones, Z)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    L = Z.shape[-1]
+    zz = jnp.where(skip[None], ones, Z)
+    lane = jax.lax.broadcasted_iota(jnp.int32, Z.shape[1:], Z.ndim - 2)
     pre = zz
     d = 1
-    while d < T:
+    while d < L:
         pre = jnp.where(
-            lane >= d, fe_mul(pre, jnp.roll(pre, d, axis=1)), pre
+            lane >= d, fe_mul(pre, jnp.roll(pre, d, axis=-1)), pre
         )
         d *= 2
     suf = zz
     d = 1
-    while d < T:
+    while d < L:
         suf = jnp.where(
-            lane < T - d, fe_mul(suf, jnp.roll(suf, -d, axis=1)), suf
+            lane < L - d, fe_mul(suf, jnp.roll(suf, -d, axis=-1)), suf
         )
         d *= 2
-    # Fermat chain (addition-chain fe_inv) on the grand product at width
-    # 128 (Mosaic mis-lowers field ops on width-1 vectors); only the last
-    # lane is the real total.
-    w = min(128, T)
-    tinv_w = fe_inv_chain(pre[:, T - w :])
-    tinv = tinv_w[:, w - 1 :]  # (20, 1)
-    left = jnp.where(lane == 0, ones, jnp.roll(pre, 1, axis=1))
-    right = jnp.where(lane == T - 1, ones, jnp.roll(suf, -1, axis=1))
-    return fe_mul(fe_mul(left, right), jnp.broadcast_to(tinv, Z.shape))
+    row_inv = jnp.broadcast_to(fe_inv_chain(pre)[..., L - 1 :], Z.shape)
+    left = jnp.where(lane == 0, ones, jnp.roll(pre, 1, axis=-1))
+    right = jnp.where(lane == L - 1, ones, jnp.roll(suf, -1, axis=-1))
+    return fe_mul(fe_mul(left, right), row_inv)
+
+
+def _g_select(da, gxw, gyw):
+    """One window of the a·G table select for an (S, L) tile of digits:
+    the exact f32 one-hot product against the window's (255, 20) rows, a
+    sublane row of the tile at a time (the 2-D product of the XLA path),
+    stacked behind the limb axis. A row's (20, L) result has its limbs on
+    the sublanes; the stack puts limb m of every row into one (S, L)
+    register. 13-bit limbs are exact in f32 and a one-hot column sums one
+    term, so the result is the table row, bit for bit."""
+    k255 = jax.lax.broadcasted_iota(jnp.int32, (255, 1), 0) + 1
+
+    def pick(table, oh):
+        return jax.lax.dot_general(
+            table, oh, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=lax.Precision.HIGHEST,
+        ).astype(jnp.int32)  # (20, L)
+
+    xs, ys = [], []
+    for s in range(da.shape[0]):
+        oh = (da[s : s + 1] == k255).astype(jnp.float32)  # (255, L)
+        xs.append(pick(gxw, oh))
+        ys.append(pick(gyw, oh))
+    return jnp.stack(xs, axis=1), jnp.stack(ys, axis=1)  # (20, S, L)
 
 
 def _kernel(
@@ -200,11 +241,13 @@ def _kernel(
     tx_ref,
     ty_ref,
 ):
-    """One LANE_TILE-wide verify tile, entirely in VMEM.
+    """One verify tile of S x L lanes, entirely in VMEM: every per-lane
+    operand is (rows, S, L), a field element (20, S, L).
 
     flags rows: 0=want_odd, 1=parity_req, 2=has_t2, 3=valid, 4=neg1,
-    5=neg2. db/ds: signed-window digit magnitudes/signs (26, tile).
-    tx/ty: (16, 20, tile) VMEM scratch for the global-Z-affine
+    5=neg2. db/ds: signed-window digit magnitudes/signs (26, S, L).
+    consts: (7, 20, S, L), every limb constant spread over a tile.
+    tx/ty: (16, 20, S, L) VMEM scratch for the global-Z-affine
     {1..16}·P table.
     """
 
@@ -242,12 +285,12 @@ def _kernel_body(
     ty_ref,
 ):
     px = px_ref[:]
-    want_odd = flags_ref[0, :]
-    parity_req = flags_ref[1, :]
-    has_t2 = flags_ref[2, :]
-    valid = flags_ref[3, :] != 0
-    neg1i = flags_ref[4, :]
-    neg2i = flags_ref[5, :]
+    want_odd = flags_ref[0]
+    parity_req = flags_ref[1]
+    has_t2 = flags_ref[2]
+    valid = flags_ref[3] != 0
+    neg1i = flags_ref[4]
+    neg2i = flags_ref[5]
 
     # -- lift P's y from (x, parity): y = sqrt(x^3 + 7), flip to parity --
     seven = _const_col(_SEVEN, px)
@@ -262,10 +305,8 @@ def _kernel_body(
     # Sanitize invalid (off-curve) lanes to the generator: keeps the
     # explicitly-tracked infinity masks sound for every lane (see the
     # XLA kernel's matching comment); verdicts stay masked by `valid`.
-    gxb = jnp.broadcast_to(_const_col(_GX_LIMBS, px), px.shape).astype(px.dtype)
-    gyb = jnp.broadcast_to(_const_col(_GY_LIMBS, px), px.shape).astype(px.dtype)
-    px = jnp.where(valid[None], px, gxb)
-    py = jnp.where(valid[None], py, gyb)
+    px = jnp.where(valid[None], px, _const_col(_GX_LIMBS, px))
+    py = jnp.where(valid[None], py, _const_col(_GY_LIMBS, px))
 
     # -- per-lane table {1..16}·P, renormalized to a GLOBAL Z -----------
     # Row r holds (r+1)·P. Build is Jacobian (row 1 = explicit doubling,
@@ -314,10 +355,8 @@ def _kernel_body(
     # (beta*x, y); digit signs xor the GLV half signs and negate the
     # selected y; zero digits keep R via the same select pattern as the
     # G loop).
-    k16 = jax.lax.broadcasted_iota(jnp.int32, (16, 1, 1), 0) + 1
-    beta = jnp.broadcast_to(
-        _const_col(_BETA_LIMBS, px)[:, :1], px.shape
-    ).astype(px.dtype)
+    k16 = jax.lax.broadcasted_iota(jnp.int32, (16, 1, 1, 1), 0) + 1
+    beta = _const_col(_BETA_LIMBS, px)
 
     # Infinity and needs-host masks ride the fori_loop carries as int32
     # 0/1 — Mosaic cannot lower i1 vectors through loop boundaries.
@@ -343,15 +382,15 @@ def _kernel_body(
         R = jacobian_double(*R)
         R = jacobian_double(*R)
         R = jacobian_double(*R)
-        d1 = db1_ref[w]  # ref-indexed dynamic VMEM load, (tile,)
+        d1 = db1_ref[w]  # ref-indexed dynamic VMEM load, (S, L)
         s1 = (ds1_ref[w] ^ neg1i)[None]
-        oh = (d1[None, None, :] == k16).astype(jnp.int32)  # (16, 1, T)
+        oh = (d1[None, None] == k16).astype(jnp.int32)  # (16, 1, S, L)
         selx = jnp.sum(TX * oh, axis=0)
         sely = jnp.sum(TY * oh, axis=0)
         R, r_inf32, nh = madd_step(R, r_inf32, nh, d1, s1, selx, sely)
         d2 = db2_ref[w]
         s2 = (ds2_ref[w] ^ neg2i)[None]
-        oh = (d2[None, None, :] == k16).astype(jnp.int32)
+        oh = (d2[None, None] == k16).astype(jnp.int32)
         selx = fe_mul(jnp.sum(TX * oh, axis=0), beta)
         sely = jnp.sum(TY * oh, axis=0)
         R, r_inf32, nh = madd_step(R, r_inf32, nh, d2, s2, selx, sely)
@@ -368,26 +407,12 @@ def _kernel_body(
     R = (X, Y, Z)
 
     # -- a·G: 32 windows, MXU one-hot row select against the VMEM table -
-    # Table row j holds (j+1)·256^w·G: the one-hot compares against 1..255.
-    k255 = jax.lax.broadcasted_iota(jnp.int32, (255, 1), 0) + 1
-
+    # Table row j holds (j+1)·256^w·G: `_g_select` compares against 1..255.
     def gbody(i, carry):
         Xg, Yg, Zg, rg_inf32, nh = carry
         rg_inf = rg_inf32 == 1
-        da = da_ref[i]  # ref-indexed dynamic VMEM load, (tile,)
-        oh = (da[None, :] == k255).astype(jnp.float32)  # (255, T)
-        gxw = gx_ref[i]  # (255, 20) f32
-        gyw = gy_ref[i]
-        selx = jax.lax.dot_general(
-            gxw, oh, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        ).astype(jnp.int32)  # (20, T); 13-bit limbs are exact in f32
-        sely = jax.lax.dot_general(
-            gyw, oh, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        ).astype(jnp.int32)
+        da = da_ref[i]  # ref-indexed dynamic VMEM load, (S, L)
+        selx, sely = _g_select(da, gx_ref[i], gy_ref[i])  # (255, 20) f32 each
         Xa, Ya, Za, inf_a, nd = jacobian_madd_flagged(
             Xg, Yg, Zg, selx, sely, inf1=rg_inf
         )
@@ -424,28 +449,44 @@ def _kernel_body(
     y_odd = (y[0] & 1) == 1
     par_ok = (parity_req < 0) | (y_odd == (parity_req == 1))
     ok = valid & ~inf_mask & ok_x & par_ok & ~needs
-    ok_ref[0, :] = ok.astype(jnp.int32)
-    ok_ref[1, :] = needs.astype(jnp.int32)
+    ok_ref[0] = ok.astype(jnp.int32)
+    ok_ref[1] = needs.astype(jnp.int32)
+
+
+def tile_grid(B: int, tile: int = None):
+    """(S, L, steps) of a `verify_tiles` call over B lanes: a grid step
+    runs S sublane rows of L lanes. The tile follows from B alone: the
+    dense 8 x 128 (a limb is one full register) where B divides by 1,024,
+    else 4 x 128, the half-filled tile of the 512-lane dispatch. `tile`
+    (lanes a step) overrides it for tests and experiments; one that is not
+    a multiple of 128 runs rows of 8 lanes, so that interpret mode on a
+    CPU walks S > 1 at a small size."""
+    if tile is None:
+        tile = FULL_TILE if B % FULL_TILE == 0 else LANE_TILE
+    L = VREG_LANES if tile % VREG_LANES == 0 else 8
+    assert tile % L == 0 and B % tile == 0, (B, tile)
+    return tile // L, L, B // tile
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 @named_region("verify_tiles")
 def verify_tiles(
     fields, want_odd, parity_req, has_t2, neg1, neg2, valid,
-    tile=LANE_TILE, interpret=False,
+    tile=None, interpret=False,
 ):
     """Replacement for `jax_backend._verify_kernel` running the heavy math
     as a Pallas grid over lane tiles.
 
     fields: (B, 4, 32) uint8 LE (a, |b1|‖|b2|, px, t1); flag vectors (B,)
-    int32 / bool. B must be a multiple of `tile`. Returns
+    int32 / bool. B must be a multiple of `LANE_TILE` (of `tile`, where
+    given: see `tile_grid`). Returns
     ``(ok, needs_host)`` — both (B,) bool. ``needs_host`` marks lanes that
     hit an exceptional group-law case the fast adds defer (crafted scalar
     collisions only; such lanes report ok=False and MUST be re-checked by
     the exact host path, which TpuSecpVerifier.verify_checks does).
     """
     B = fields.shape[0]
-    assert B % tile == 0, (B, tile)
+    S, L, steps = tile_grid(B, tile)
 
     # XLA preamble: byte unpack, window digits (signed 5-bit for the GLV
     # halves), r+n secondary target.
@@ -478,18 +519,24 @@ def verify_tiles(
     gx = gx.astype(jnp.float32)
     gy = gy.astype(jnp.float32)
 
+    # The batch axis splits into (step, sublane row, lane) behind the rows
+    # of every per-lane operand; a grid step takes one (rows, S, L) block.
+    tiled = lambda x: x.reshape(x.shape[0], steps, S, L)  # noqa: E731
     lane_block = lambda rows: pl.BlockSpec(  # noqa: E731
-        (rows, tile), lambda i: (0, i), memory_space=pltpu.VMEM
+        (rows, None, S, L), lambda i: (0, i, 0, 0), memory_space=pltpu.VMEM
     )
     shared = lambda shape: pl.BlockSpec(  # noqa: E731
         shape, lambda i: (0,) * len(shape), memory_space=pltpu.VMEM
     )
 
-    consts = jnp.asarray(_CONST_TABLE)
+    consts = jnp.broadcast_to(
+        jnp.asarray(_CONST_TABLE)[:, :, None, None],
+        _CONST_TABLE.shape + (S, L),
+    )
 
     ok = pl.pallas_call(
         _kernel,
-        grid=(B // tile,),
+        grid=(steps,),
         in_specs=[
             lane_block(NLIMB),  # px
             lane_block(NLIMB),  # t1 (raw)
@@ -504,12 +551,13 @@ def verify_tiles(
             shared(gx.shape),  # G window table x
             shared(gy.shape),  # G window table y
         ],
-        out_specs=pl.BlockSpec((2, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((2, B), jnp.int32),
+        out_specs=lane_block(2),
+        out_shape=jax.ShapeDtypeStruct((2, steps, S, L), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((16, NLIMB, tile), jnp.int32),  # P-table x
-            pltpu.VMEM((16, NLIMB, tile), jnp.int32),  # P-table y
+            pltpu.VMEM((16, NLIMB, S, L), jnp.int32),  # P-table x
+            pltpu.VMEM((16, NLIMB, S, L), jnp.int32),  # P-table y
         ],
         interpret=interpret,
-    )(px, t1, t1n, da, db1, ds1, db2, ds2, flags, consts, gx, gy)
+    )(*map(tiled, (px, t1, t1n, da, db1, ds1, db2, ds2, flags)), consts, gx, gy)
+    ok = ok.reshape(2, B)
     return ok[0] != 0, ok[1] != 0

@@ -621,9 +621,10 @@ class ShardedSecpVerifier(TpuSecpVerifier):
     def _note_mesh_dispatch(self, layout: _ShardLayout) -> None:
         """Dispatch accounting of one sharded launch of `layout`."""
         self._note_dispatch(layout.padded, layout.n, "mesh")
-        _MESH_DISPATCH.inc(
-            kernel=shard_kernel(self._mesh_pallas, layout.shard_size)
-        )
+        kernel = shard_kernel(self._mesh_pallas, layout.shard_size)
+        _MESH_DISPATCH.inc(kernel=kernel)
+        if kernel == "pallas":
+            self._note_tiles(layout.shard_size, layout.n_shards)
         for k in _shard_fill(layout.n, layout.shard_size, layout.n_shards):
             _MESH_SHARD_LANES.observe(k)
 

@@ -1,117 +1,145 @@
 """A/B harness for verify-kernel experiments on the live TPU.
 
-Builds a real mixed check batch (one signed spend a kind from
-`utils/blockgen` -> native prep_pack), then times the pallas kernel
-device-side (device-resident args, so the number is compute+readback
-without the host upload) and checks verdict equality against the XLA
-reference kernel. Usage:
+Builds one adversarial mixed batch (ECDSA, Schnorr and taproot tweak
+lanes, valid and corrupted, from `tpu_differential.build_adversarial_checks`,
+with the crafted equal-points tweak the fast adds must defer in lane 0),
+packs it at each dispatch shape through the native prep, and times the
+Pallas kernel device-side (device-resident args, so the number is
+compute + readback without the host upload). Every shape's verdicts and
+`needs_host` flags are compared with the XLA reference kernel, which runs
+in 512-lane pieces so that it compiles once. Usage:
 
-    python scripts/kernel_ab.py [n_lanes] [tile ...]
+    python scripts/kernel_ab.py [--lanes 512 2048 8192] [--tile T ...]
+                                [--against CHECKOUT]
+
+`--tile` times explicit tiles (lanes a grid step) beside the kernel's own
+choice; `--against` also times the `verify_tiles` of another checkout of
+this repository (a `git archive` of the commit to beat), same arguments,
+same process, same chip. One line a shape and kernel: ms a dispatch, ns a
+lane, and whether the verdicts matched.
 """
 
 import argparse
+import importlib
+import os
 import sys
 import time
+import types
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
+sys.path.insert(0, __file__.rsplit("/", 1)[0])
+
+_REF_LANES = 512  # the XLA reference's one compiled shape
 
 
 def build_checks(n):
+    """n mixed checks: lane 0 the crafted collision (2G = G + 1·G, which
+    the flagged adds defer to the host), the rest adversarial."""
+    from tpu_differential import build_adversarial_checks
+
+    from bitcoinconsensus_tpu.crypto import secp_host as H
     from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck
-    from bitcoinconsensus_tpu.core.script import push_data
-    from bitcoinconsensus_tpu.core.tx import TxOut
-    from bitcoinconsensus_tpu.utils.hashes import hash160
-    from bitcoinconsensus_tpu.utils.blockgen import (
-        build_spend_tx, make_funded_view,
+
+    qx, qy = H.G.mul(2).to_affine()
+    collision = SigCheck(
+        "tweak",
+        (qx.to_bytes(32, "big"), qy & 1, H.G_X.to_bytes(32, "big"),
+         (1).to_bytes(32, "big")),
     )
-    from bitcoinconsensus_tpu.core.sighash import (
-        PrecomputedTxData, SIGHASH_ALL, bip143_sighash, SigVersion,
-        bip341_sighash, SIGHASH_DEFAULT,
-    )
-
-    # Mixed ECDSA + Schnorr checks from one signed spend a kind; recover
-    # (pubkey, sig, sighash) triples by re-deriving the sighashes.
-    checks = []
-    for kind in ("p2wpkh", "p2tr"):
-        _, funded = make_funded_view(
-            (n + 1) // 2, kinds=(kind,), seed=f"bench-{kind}")
-        tx = build_spend_tx(funded, fee=1000)
-        if kind == "p2wpkh":
-            for i, f in enumerate(funded):
-                sig, pub = tx.vin[i].witness
-                code = b"\x76\xa9" + push_data(hash160(pub)) + b"\x88\xac"
-                sh = bip143_sighash(code, tx, i, SIGHASH_ALL, f.amount)
-                checks.append(SigCheck("ecdsa", (pub, sig[:-1], sh)))
-        else:
-            outs = [TxOut(f.amount, f.wallet.spk) for f in funded]
-            txd = PrecomputedTxData(tx, outs)
-            for i in range(len(funded)):
-                sig = tx.vin[i].witness[0]
-                sh = bip341_sighash(
-                    tx, i, SIGHASH_DEFAULT, SigVersion.TAPROOT, txd, False, b""
-                )
-                pk = outs[i].script_pubkey[2:]
-                checks.append(SigCheck("schnorr", (pk, sig, sh)))
-    # interleave + corrupt a few so both verdicts appear
-    mixed = []
-    for a, b in zip(checks[: n // 2], checks[n // 2 :], strict=False):
-        mixed.extend((a, b))
-    mixed = mixed[:n]
-    for j in range(0, n, 97):
-        k, d = mixed[j].kind, mixed[j].data
-        bad = d[2][:5] + bytes([d[2][5] ^ 1]) + d[2][6:]
-        mixed[j] = SigCheck(k, (d[0], d[1], bad))
-    return mixed
+    return [collision] + build_adversarial_checks(n - 1, seed=7)
 
 
-def main():
+def other_kernel(checkout):
+    """`verify_tiles` of another checkout, imported under an alias package
+    (its modules import each other relatively, so nothing of it lands on
+    this checkout's names)."""
+    alias = "kernel_ab_other"
+    root = types.ModuleType(alias)
+    root.__path__ = [os.path.join(os.path.abspath(checkout), "bitcoinconsensus_tpu")]
+    sys.modules[alias] = root
+    return importlib.import_module(alias + ".ops.pallas_kernel").verify_tiles
+
+
+def reference(dargs, lanes):
+    """(lanes,) verdicts of the XLA complete-add kernel, a piece at a time."""
+    import jax
+    import numpy as np
+
+    from bitcoinconsensus_tpu.crypto.jax_backend import _verify_kernel
+
+    kernel = jax.jit(_verify_kernel)
+    step = min(_REF_LANES, lanes)
+    return np.concatenate([
+        np.asarray(kernel(*(a[i : i + step] for a in dargs)))
+        for i in range(0, lanes, step)
+    ])
+
+
+def timed(kernel, dargs, **kw):
+    """(first call s, best s, median s, ok, needs) of `kernel` on `dargs`."""
+    import numpy as np
+
+    t0 = time.perf_counter()
+    ok, needs = kernel(*dargs, **kw)
+    np.asarray(ok)
+    first = time.perf_counter() - t0
+    times = []
+    for _ in range(7):
+        t0 = time.perf_counter()
+        ok, needs = kernel(*dargs, **kw)
+        ok.block_until_ready()
+        needs.block_until_ready()
+        times.append(time.perf_counter() - t0)
+    times.sort()
+    return first, times[0], times[len(times) // 2], np.asarray(ok), np.asarray(needs)
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("n_lanes", nargs="?", type=int, default=10240)
-    ap.add_argument("tile", nargs="*", type=int, default=[512])
-    a = ap.parse_args()
-    N, TILES = a.n_lanes, a.tile
+    ap.add_argument("--lanes", nargs="+", type=int, default=[512, 2048, 8192])
+    ap.add_argument("--tile", nargs="*", type=int, default=[])
+    ap.add_argument("--against", help="another checkout whose kernel to time too")
+    a = ap.parse_args(argv)
 
     import jax
     import numpy as np
 
     from bitcoinconsensus_tpu import native_bridge
-    from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
-
-    checks = build_checks(N)
-    args = native_bridge.prep_pack(checks, N)
-    dargs = [jax.device_put(np.asarray(a)) for a in args]
-    for x in dargs:
-        x.block_until_ready()
-
-    # XLA reference verdicts (once)
-    v = TpuSecpVerifier()
-    ref = np.asarray(v._kernel(*dargs))
-    print(f"lanes={N} valid={int(np.asarray(args[6]).sum())} "
-          f"ref_ok={int(ref.sum())}")
-
     from bitcoinconsensus_tpu.ops.pallas_kernel import verify_tiles
 
-    for tile in TILES:
-        t0 = time.perf_counter()
-        ok, needs = verify_tiles(*dargs, tile=tile)
-        np.asarray(ok)
-        compile_s = time.perf_counter() - t0
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            ok, needs = verify_tiles(*dargs, tile=tile)
-            ok.block_until_ready(); needs.block_until_ready()
-            times.append(time.perf_counter() - t0)
-        ok_np, needs_np = np.asarray(ok), np.asarray(needs)
-        match = np.array_equal(ok_np | needs_np, ref | needs_np)
-        best = min(times)
-        print(
-            f"tile={tile:5d} compile={compile_s:6.1f}s best={best*1000:8.2f}ms "
-            f"median={sorted(times)[2]*1000:8.2f}ms "
-            f"{N/best:9.0f} lanes/s needs_host={int(needs_np.sum())} "
-            f"match={match}"
-        )
-        assert match, "verdict mismatch vs XLA kernel"
+    kernels = [("this", verify_tiles, {})]
+    kernels += [(f"this tile={t}", verify_tiles, {"tile": t}) for t in a.tile]
+    if a.against:
+        kernels.append((a.against, other_kernel(a.against), {}))
+
+    checks = build_checks(max(a.lanes))
+    failed = False
+    for lanes in a.lanes:
+        args = native_bridge.prep_pack(checks[:lanes], lanes)
+        dargs = [jax.device_put(np.asarray(x)) for x in args]
+        ref = reference(dargs, lanes)
+        print(f"lanes={lanes} valid={int(np.asarray(args[6]).sum())} "
+              f"ref_ok={int(ref.sum())}", flush=True)
+        flags = None
+        for name, kernel, kw in kernels:
+            if kw.get("tile") and lanes % kw["tile"]:
+                continue
+            first, best, median, ok, needs = timed(kernel, dargs, **kw)
+            # A deferred lane reports ok=False and is the host's to answer:
+            # everywhere else the verdict is the XLA kernel's, bit for bit,
+            # and every kernel defers the same lanes (the crafted one).
+            match = (np.array_equal(ok | needs, ref | needs)
+                     and not (ok & needs).any() and bool(needs[0])
+                     and (flags is None or np.array_equal(needs, flags)))
+            flags = needs if flags is None else flags
+            failed |= not match
+            print(
+                f"  {name:24s} first={first:6.1f}s best={best * 1e3:8.3f}ms "
+                f"median={median * 1e3:8.3f}ms {best / lanes * 1e9:7.1f}ns/lane "
+                f"needs_host={int(needs.sum())} match={match}", flush=True,
+            )
+    if failed:
+        sys.exit("verdict mismatch vs XLA kernel")
 
 
 if __name__ == "__main__":

@@ -9,10 +9,11 @@ reproducibly NOT when the same compile runs in a clean process (the crash
 is inside jaxlib, with the native core disabled too).
 
 Checks that compile the same programs share a process: `small` and
-`collision` both run the 8-lane `_verify_kernel` and the `tile=8`
-interpret-mode `verify_tiles`, so `test_pallas_kernel.py` starts them as
-one child (`production`, the 512-lane tile, is a `slow` test with a child
-of its own). Each check is reported by name on a line of its own, so each
+`collision` both run the 16-lane `_verify_kernel` and the `tile=16`
+interpret-mode `verify_tiles` (two sublane rows of 8 lanes, so the CPU
+walks a tile with S > 1), so `test_pallas_kernel.py` starts them as one
+child (`production`, the 512-lane tile of four rows of 128 lanes, is a
+`slow` test with a child of its own). Each check is reported by name on a line of its own, so each
 stays a test of its own.
 
 Usage: python tests/pallas_equality_check.py {small|production|collision}...
@@ -32,8 +33,8 @@ import numpy as np  # noqa: E402
 
 def _xla_kernel(*args):
     """The XLA side as the verifier dispatches it: `_verify_kernel` under
-    jit. At 8 lanes that is the program the suite's workers compile for
-    themselves, so called after the Pallas side (minutes into the run) it
+    jit. At 16 lanes that is a program the suite's workers compile for
+    themselves (`warm_kernel`'s second rung), so called after the Pallas side (minutes into the run) it
     loads from the persistent cache; run op by op it would compile a
     dozen scans of its own."""
     import jax
@@ -43,11 +44,12 @@ def _xla_kernel(*args):
 
 
 def check_small() -> None:
-    """tile=8 adversarial mix: bit-equality with the XLA kernel."""
+    """tile=16 (two rows of 8 lanes) adversarial mix: bit-equality with
+    the XLA kernel, a corruption of every flavor in each row."""
     import __graft_entry__ as ge
     from bitcoinconsensus_tpu.ops.pallas_kernel import verify_tiles
 
-    fields, want_odd, parity, has_t2, neg1, neg2, valid = ge._example_arrays(8)
+    fields, want_odd, parity, has_t2, neg1, neg2, valid = ge._example_arrays(16)
     fields = np.array(fields)
     want_odd = np.array(want_odd)
     valid = np.array(valid)
@@ -58,10 +60,13 @@ def check_small() -> None:
     fields[7, 2, 0] ^= 1  # perturb lane 7's pubkey x (likely non-residue)
     want_odd[2] ^= 1  # wrong y parity for lane 2's pubkey -> wrong R
     neg1[4] ^= 1  # flip a GLV half sign -> wrong R for lane 4
+    fields[11, 3, 0] ^= 1  # the second row: a target,
+    valid[13] = False  # an invalid lane
+    want_odd[10] ^= 1  # and a wrong lift
 
     got_ok, got_needs = verify_tiles(
         fields, want_odd, parity, has_t2, neg1, neg2, valid,
-        tile=8, interpret=True,
+        tile=16, interpret=True,
     )
     got = np.asarray(got_ok)
     want = np.asarray(
@@ -69,15 +74,16 @@ def check_small() -> None:
     )
     assert not np.asarray(got_needs).any()  # no group-law deferrals here
     assert (got == want).all(), (got, want)
-    assert not want[3] and not want[5] and not want[2] and not want[4]
-    assert want[0] and want[1]
+    bad = [2, 3, 4, 5, 10, 11, 13]
+    assert not want[bad].any(), want[bad]
+    assert want[[0, 1, 8, 9, 15]].all(), want
 
 
 def check_production() -> None:
     """Equality at the PRODUCTION tile (LANE_TILE=512): multi-kind lanes
     (ECDSA/Schnorr/tweak), adversarial corruptions of every flavor, and —
-    crucially — the w=128 Fermat narrowing in _tile_batch_inv, which the
-    tile=8 check can never reach (w=min(128, T))."""
+    crucially — the 128-lane rows of `_tile_batch_inv` (seven tree levels
+    each way where the tile=16 check walks three) and the (4, 128) tile."""
     import __graft_entry__ as ge
     from bitcoinconsensus_tpu.crypto.jax_backend import (
         SigCheck,
@@ -151,18 +157,18 @@ def check_collision() -> None:
             (1).to_bytes(32, "big"),
         ),
     )
-    checks = ge._example_checks(7)
+    checks = ge._example_checks(15)  # both rows of the tile hold live lanes
     checks[0] = collision
-    v = TpuSecpVerifier(min_batch=8)
+    v = TpuSecpVerifier(min_batch=16)
     args = v._pack_lanes(v._prep_lanes(checks))
 
-    ok, needs = verify_tiles(*args, tile=8, interpret=True)
+    ok, needs = verify_tiles(*args, tile=16, interpret=True)
     ok, needs = np.asarray(ok), np.asarray(needs)
     assert needs[0] and not ok[0], "collision lane must defer"
-    assert not needs[1:7].any() and ok[1:7].all(), "others unaffected"
+    assert not needs[1:15].any() and ok[1:15].all(), "others unaffected"
 
     want = np.asarray(_xla_kernel(*args))
-    assert want[:7].all()  # XLA complete kernel: collision resolves TRUE
+    assert want[:15].all()  # XLA complete kernel: collision resolves TRUE
 
 
 CHECKS = {

@@ -107,7 +107,11 @@ MESH_STILL = (
     "consensus_mesh_shard_failures_total",
     "consensus_mesh_verdict_mismatch_total",
 )
-NO_SAMPLE_NEEDED = ZERO + MESH_STILL + ("consensus_serving_shed_total",)
+# What only a Pallas dispatch raises (`layers/full_tile_share.connect.py`): a
+# CPU run registers it and, launching no Pallas program, never bumps it;
+# `test_tile_rows_are_the_ones_read` stands in for the launch.
+CHIP_ONLY = ("consensus_dispatch_tiles_total",)
+NO_SAMPLE_NEEDED = ZERO + MESH_STILL + CHIP_ONLY + ("consensus_serving_shed_total",)
 # `PERF.md` section 3 names it with no reader under `benchmarks/` yet (PR 35):
 # the pieces a mesh dispatch crosses the host-device seam in, by direction.
 NO_READER_YET = ("consensus_mesh_transfers_total",)
@@ -245,7 +249,7 @@ def workload():
 
 
 @pytest.mark.parametrize(
-    "name", READ + ZERO + MESH_STILL + NO_READER_YET + PERF_MD_ONLY + PHASES + SPANS)
+    "name", READ + ZERO + MESH_STILL + CHIP_ONLY + NO_READER_YET + PERF_MD_ONLY + PHASES + SPANS)
 def test_the_benchmark_finds(workload, name):
     phases, snapshot = workload
     if name in PHASES:
@@ -314,6 +318,53 @@ def test_lane_kinds_and_taproot_hashes_are_the_ones_read(workload):
     assert hashes["sighash"] > 0 and hashes["leaf"] > 0 and hashes["tweak"] > 0, hashes
 
 
+def test_tile_rows_are_the_ones_read(monkeypatch):
+    """`layers/full_tile_share.connect.py` asks `consensus_dispatch_tiles_total`
+    for `rows="8"` over every `rows`: a grid step of the Pallas program a
+    dispatch launches, by the sublane rows its tile fills. The 512-lane
+    shape is one half-filled tile, a multiple of 1,024 lanes runs dense
+    ones, a mesh dispatch counts every shard's steps, and an XLA rung
+    counts nothing."""
+    from types import SimpleNamespace
+
+    from bitcoinconsensus_tpu.ops import pallas_kernel
+
+    def rows():
+        samples = get_registry().snapshot()["consensus_dispatch_tiles_total"]["samples"]
+        return {s["labels"]["rows"]: s["value"] for s in samples}
+
+    def rose(before):
+        return {k: v - before.get(k, 0) for k, v in rows().items() if v != before.get(k, 0)}
+
+    def launch(verifier, lanes):
+        flags = (np.zeros(lanes, np.int32),) * 5
+        verifier._run_kernel(
+            (np.zeros((lanes, 4, 32), np.uint8),) + flags + (np.zeros(lanes, bool),), lanes - 1)
+
+    monkeypatch.setattr(pallas_kernel, "verify_tiles",
+                        lambda fields, *flags: (np.zeros(len(fields), bool),) * 2)
+    verifier = TpuSecpVerifier()
+    verifier._kernel = lambda fields, *flags: np.zeros(len(fields), bool)
+    before = rows()
+    launch(verifier, 512)  # a CPU verifier: the XLA rung, whatever the shape
+    assert rose(before) == {}
+    verifier._use_pallas = True
+    launch(verifier, 8)    # under the Pallas tile: the XLA rung
+    assert rose(before) == {}
+    launch(verifier, 512)
+    assert rose(before) == {"4": 1}
+    launch(verifier, 8192)
+    launch(verifier, 1024)
+    assert rose(before) == {"4": 1, "8": 9}
+    sharded = ShardedSecpVerifier(mesh=make_mesh(4), min_batch=16, chunk=16)
+    layout = SimpleNamespace(padded=8192, n=8188, n_shards=4, shard_size=2048)
+    sharded._note_mesh_dispatch(layout)  # a CPU mesh: its shards run the XLA kernel
+    assert rose(before) == {"4": 1, "8": 9}
+    sharded._mesh_pallas = True
+    sharded._note_mesh_dispatch(layout)  # four shards of two dense steps
+    assert rose(before) == {"4": 1, "8": 17}
+
+
 def test_mesh_phases_nest_and_outer_secs_do_not_count_them_twice(workload):
     """`shard_*` run inside `dispatch` and `sync` (or `backpressure`): their
     seconds are in `secs` twice over and in `outer_secs` once, so the sum of
@@ -336,7 +387,7 @@ def test_lists_are_what_benchmarks_names():
             if f.endswith(".py"):
                 with open(os.path.join(root, f)) as fh:
                     named |= set(re.findall(r"\bconsensus_[a-z_]+", fh.read()))
-    assert named == set(READ + ZERO + MESH_STILL)
+    assert named == set(READ + ZERO + MESH_STILL + CHIP_ONLY)
 
 
 # -- where the time of a connect goes, by name -------------------------------

@@ -1,4 +1,4 @@
-"""The per-layer readers PRs 36 to 41 added, each on a made-up `ctx`.
+"""The per-layer readers PRs 36 to 42 added, each on a made-up `ctx`.
 
 `benchmarks/tests` is not part of tier-1, and a reader runs for real only
 in a `--trace 1` run on the chip. Here every new reader gets a context
@@ -353,6 +353,60 @@ def test_walk_share_of_spec_returns_none_with_nothing_to_read(ctx):
     assert reader("walk_share_of_spec.connect")(ctx) is None
 
 
+_TILES = "consensus_dispatch_tiles_total"
+
+
+def tile_ctx(window, deltas, kind="connect", has_counter=True):
+    """A window over which the registry rose by `window` = (full steps,
+    half steps, dispatches, padded lanes) from made-up levels, whose timed
+    connects dispatched `deltas` = [(dispatches, padded lanes), ...]."""
+    def snap(full, half, dispatches, padded):
+        out = {"consensus_dispatch_total": {"samples": [
+                   {"labels": {"backend": "pallas"}, "value": dispatches}]},
+               "consensus_dispatch_padded_lanes_total": {"samples": [
+                   {"labels": {}, "value": padded}]}}
+        if has_counter:
+            out[_TILES] = {"samples": [{"labels": {"rows": "8"}, "value": full},
+                                       {"labels": {"rows": "4"}, "value": half}]}
+        return out
+    base = (40, 7, 12, 44544)
+    return {"cell": "made-up", "trace": None, "driver": {
+        "kind": kind, "walls_s": [0.05] * len(deltas), "n_inputs": 6,
+        "deltas": [{"consensus_dispatch_total": d, "consensus_dispatch_padded_lanes_total": p}
+                   for d, p in deltas],
+        "counters_before": snap(*base),
+        "counters_after": snap(*(b + w for b, w in zip(base, window)))}}
+
+
+@pytest.mark.parametrize("window,deltas,want", [
+    # three connects of ten 8,192-lane chunks: 240 dense steps, nothing else
+    ((240, 0, 30, 245760), [(10, 81920)] * 3, 100.0),
+    # the four-chip cell: a dispatch is four shards of two dense steps
+    ((16, 0, 2, 16384), [(1, 8192)] * 2, 100.0),
+    # the warm cell: an untimed 8,192-lane precharge and a timed 512-lane
+    # connect an iteration; the connects' steps are the half-filled ones
+    ((24, 3, 6, 26112), [(1, 512)] * 3, 0.0),
+    # a connect of one chunk and a 512-lane remainder: 8 dense steps of 9
+    ((16, 2, 4, 17408), [(2, 8704)] * 2, 100 * 8 / 9),
+])
+def test_full_tile_share_is_the_dense_steps_of_the_timed_connects(window, deltas, want):
+    assert reader("full_tile_share.connect")(tile_ctx(window, deltas)) == ms(want)
+
+
+@pytest.mark.parametrize("ctx", [
+    tile_ctx((0, 0, 3, 24576), [(1, 8192)] * 3, has_counter=False),  # the parent: no such counter
+    tile_ctx((0, 0, 3, 24), [(1, 8)] * 3),           # XLA rungs only: no Pallas step ran
+    tile_ctx((24, 3, 6, 26112), []),                 # a window that timed nothing
+    # untimed dispatches of shapes that leave two splits open
+    tile_ctx((9, 2, 4, 10240), [(2, 5120)]),
+    tile_ctx((240, 0, 30, 245760), [(10, 81920)] * 3, kind="stream"),
+    tile_ctx((240, 0, 30, 245760), [(10, 81920)] * 3, kind="serve"),
+    {"cell": "made-up", "trace": None, "driver": {"kind": "connect", "walls_s": [0.8], "n_inputs": 5}},
+])
+def test_full_tile_share_returns_none_with_nothing_to_read(ctx):
+    assert reader("full_tile_share.connect")(ctx) is None
+
+
 def test_benchmark_json_lists_each_new_metric_with_its_cells():
     import json
 
@@ -383,6 +437,8 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "coin_probes_per_input.stream": ["ibd-stream.cold"],
         # PR 41: the walk's share of the pre-recorded CHECKMULTISIG pairings
         "walk_share_of_spec.connect": ["worst-block-multisig20.fanout", "worst-block.sigops"],
+        # PR 42: the dense tile's share of the kernel's grid steps
+        "full_tile_share.connect": connect,
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name

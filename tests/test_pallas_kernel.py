@@ -23,10 +23,97 @@ pytestmark = pytest.mark.skipif(
     not RUN, reason="pallas interpreter equality disabled (PALLAS_INTERPRET_TESTS=0)"
 )
 
+# -- the two pieces of the kernel body that know a tile is (S, L), as plain
+# jnp functions on the CPU, and the choice of the tile ----------------------
+
+
+@pytest.mark.parametrize("lanes,tile,want", [
+    (512, None, (4, 128, 1)),    # the warm and the served cell: one half-filled tile
+    (1024, None, (8, 128, 1)),
+    (2048, None, (8, 128, 2)),   # a shard of the four-chip mesh
+    (8192, None, (8, 128, 8)),   # the chunk
+    (1536, None, (4, 128, 3)),   # a `pad_step` shape 1,024 does not divide
+    (16, 16, (2, 8, 1)),         # the interpret-mode checks: S > 1 at 8 lanes a row
+    (8, 8, (1, 8, 1)),
+    (2048, 512, (4, 128, 4)),    # an explicit tile (scripts/kernel_ab.py --tile)
+])
+def test_tile_follows_from_the_lanes_dispatched(lanes, tile, want):
+    from bitcoinconsensus_tpu.ops.pallas_kernel import LANE_TILE, tile_grid
+
+    assert tile_grid(lanes, tile) == want
+    assert LANE_TILE == 512  # what jax_backend and mesh test `padded` against
+
+
+def _random_elements(rng, shape):
+    """Weak field elements (20,) + shape and their values as Python ints."""
+    from bitcoinconsensus_tpu.ops import limbs as L
+
+    n = int(np.prod(shape))
+    vals = [int.from_bytes(rng.bytes(32), "big") % L.P_INT or 1 for _ in range(n)]
+    arr = L.ints_to_limbs_batch(vals).T.reshape((L.NLIMB,) + shape)
+    return arr, vals
+
+
+@pytest.mark.parametrize("shape", [(1, 8), (2, 8), (4, 128)])
+def test_tile_batch_inverse_matches_the_per_lane_inverse(shape):
+    """Every live lane's output is that lane's own inverse, whatever its
+    row's infinity and deferred lanes hold (a zero among them would zero
+    the row's product, were it not replaced by one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bitcoinconsensus_tpu.ops import limbs as L
+    from bitcoinconsensus_tpu.ops.pallas_kernel import _tile_batch_inv
+
+    rng = np.random.default_rng(42 + shape[0] * shape[1])
+    z, vals = _random_elements(rng, shape)
+    skip = rng.random(shape) < 0.25
+    skip[0, 0], skip[-1, -1] = True, False  # a row's first lane out, its last in
+    z = np.where(skip[None] & (rng.random(shape) < 0.5)[None], 0, z)  # Z = 0: infinity
+    ones = np.zeros_like(z)
+    ones[0] = 1
+    got = jax.jit(lambda a, m, o: L.fe_canon(_tile_batch_inv(a, m, o)))(
+        jnp.asarray(z), jnp.asarray(skip), jnp.asarray(ones))
+    got = np.asarray(got).reshape(L.NLIMB, -1)
+    live = np.flatnonzero(~skip.ravel())
+    assert live.size > skip.size // 2
+    for i in live:
+        assert L.limbs_to_int(got[:, i]) == pow(vals[i], -1, L.P_INT), i
+
+
+@pytest.mark.parametrize("shape", [(1, 8), (2, 8), (8, 128)])
+def test_g_select_relayout_matches_the_two_dimensional_product(shape):
+    """The a·G select of an (S, L) tile, a row at a time and stacked
+    behind the limbs, is the XLA path's (255, B) one-hot product over the
+    flattened tile, and the table's own rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from bitcoinconsensus_tpu.ops.curve import _g_table
+    from bitcoinconsensus_tpu.ops.pallas_kernel import _g_select
+
+    rng = np.random.default_rng(7)
+    da = rng.integers(0, 256, size=shape).astype(np.int32)
+    da[0, :3] = (0, 1, 255)  # no row, the first, the last
+    gx, gy = (np.asarray(t[5]) for t in _g_table())  # window 5: (255, 20)
+    selx, sely = jax.jit(_g_select)(
+        jnp.asarray(da), jnp.asarray(gx, jnp.float32), jnp.asarray(gy, jnp.float32))
+    flat = da.reshape(-1)
+    oh = (flat[None, :] == np.arange(1, 256)[:, None]).astype(np.float32)
+    for sel, table in ((selx, gx), (sely, gy)):
+        assert sel.shape == (20,) + shape and sel.dtype == jnp.int32
+        flat_product = jnp.dot(jnp.asarray(table, jnp.float32).T, oh,
+                               precision=lax.Precision.HIGHEST).astype(jnp.int32)
+        assert np.array_equal(np.asarray(sel).reshape(20, -1), np.asarray(flat_product))
+        rows = np.where(flat[:, None] > 0, table[np.maximum(flat, 1) - 1], 0)
+        assert np.array_equal(np.asarray(sel).reshape(20, -1), rows.T)
+
+
 _HELPER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "pallas_equality_check.py")
-# `small` and `collision` compile the same interpret-mode program (tile=8)
-# and share a child; its limit is from its cold time under the tier-1
+# `small` and `collision` compile the same interpret-mode program (tile=16:
+# two sublane rows of 8 lanes) and share a child; its limit is from its cold time under the tier-1
 # command (CHANGES.md, PR 25).
 _CHILDREN = {("small", "collision"): 900}
 
@@ -39,15 +126,15 @@ def children(tmp_path_factory):
 
 @pytest.mark.limit(930)
 def test_pallas_matches_xla_kernel(children):
-    """tile=8 adversarial mix, bit-equality (fresh process)."""
+    """tile=16 adversarial mix, bit-equality (fresh process)."""
     children.expect("small")
 
 
 @pytest.mark.slow  # a second 10-minute compile the tier-1 run has no room for
 @pytest.mark.limit(1530)
 def test_pallas_production_shape_matches_xla(tmp_path):
-    """PRODUCTION tile (LANE_TILE=512) equality incl. the w=128 Fermat
-    narrowing in _tile_batch_inv (fresh process)."""
+    """PRODUCTION tile (LANE_TILE=512, four rows of 128 lanes) equality
+    incl. the 128-lane trees of _tile_batch_inv (fresh process)."""
     with Children(_HELPER, {("production",): 1500}, tmp_path) as child:
         child.expect("production")
 
