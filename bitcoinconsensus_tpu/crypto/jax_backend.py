@@ -13,13 +13,18 @@ kinds into ONE device program over `double_scalar_mult`:
     Schnorr   s        n-e    lift_x(pk)   R.x == r and even(R.y)
     tweak     t        1      lift_x(pki)  R.x == out_x and parity matches
 
-Two choices shape the host/device boundary:
+Three choices shape the host/device boundary:
 
 - **Byte-packed transfers**: each check ships as 4 x 32-byte fields
-  (a, GLV-split |b1|‖|b2|, pubkey-x, target) + 6 flag ints — ~150 B/lane
+  (a, GLV-split |b1|‖|b2|, pubkey-x, target) + 6 flag bytes — 135 B/lane
   instead of ~500 B of pre-split limbs. Limb splitting, window-digit
   extraction, y-lifting (fe_sqrt), and the r+n secondary target all
   happen on device.
+- **One piece each way**: a launch costs the host by the piece, not by
+  the byte, so a dispatch is ONE packed buffer put (`crypto/lane_wire.py`,
+  the mesh verifier's format too), ONE device program (unpack, the
+  kernel, the verdict checksum) and ONE int32 result whose host copy is
+  asked for at launch, so that the settle finds the bytes on the host.
 - **Pipelined chunk dispatch**: large batches go out in chunks whose
   transfers/compute overlap the host-side prep of the next chunk (JAX
   async dispatch); the per-roundtrip sync cost is paid once.
@@ -36,6 +41,7 @@ consensus vectors.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import List, Optional, Sequence, Tuple
 
@@ -81,6 +87,7 @@ from ..resilience import degrade as _degrade
 from ..resilience import faults as _faults
 from ..resilience import guards as _guards
 from ..resilience import inflight as _inflight
+from . import lane_wire as _wire
 
 __all__ = ["SigCheck", "TpuSecpVerifier", "default_verifier"]
 
@@ -129,6 +136,13 @@ _NEW_SHAPES = _obs_counter(
     "consensus_dispatch_new_shapes_total",
     "distinct padded dispatch shapes this process (each is one jit "
     "compile or persistent-cache load)",
+)
+_TRANSFERS = _obs_counter(
+    "consensus_dispatch_transfers_total",
+    "host-device transfers of one-device dispatches, a count a piece, by "
+    "direction: in = arguments put, out = results' host copies asked for "
+    "(a packed dispatch makes one each way)",
+    ("dir",),
 )
 _HOST_FIXUPS = _obs_counter(
     "consensus_host_fixup_total",
@@ -389,8 +403,9 @@ def _verify_kernel(fields, want_odd, parity_req, has_t2, neg1, neg2, valid):
 def _verdict_checksum(ok):
     """Device-side verdict checksum: (count, position-weighted) int32 sums.
 
-    Chained onto the still-async ok buffer as a *separate* tiny jitted
-    program, so the proven verify kernels are untouched; the settle seam
+    Computed over the kernel's pristine `ok` inside the dispatch's one
+    program (`_packed_program`; a shard at a time in the mesh's), behind
+    the proven verify kernels, which are untouched; the settle seam
     recomputes both sums host-side from the materialized buffer and any
     mismatch (a single-lane flip anywhere, a replayed buffer) demotes the
     ticket to the host oracle. Weights are i % 251 + 1, keeping the
@@ -404,7 +419,37 @@ def _verdict_checksum(ok):
     return jnp.sum(v), jnp.sum(v * w)
 
 
-_checksum_jit = jax.jit(_verdict_checksum)
+@functools.lru_cache(maxsize=None)
+def _packed_program(backend: str):
+    """The ONE device program of a one-device dispatch on `backend`
+    ("pallas" or "xla"): `packed uint8[padded, ROW_BYTES] ->
+    int32[padded + 2]`. Its first ops slice and widen the packed rows to
+    the kernel's seven arguments (`lane_wire`), then the kernel, then
+    `_verdict_checksum` over its pristine `ok`; the result is `ok + 2 *
+    needs_host` a row and the checksum pair as the tail (the complete-add
+    XLA kernel defers no lane)."""
+
+    def program(packed):
+        *lanes, _live = _wire._unpack_lanes_traced(packed)
+        if backend == "pallas":
+            # Deferred import keeps CPU-only paths light. The kernel's own
+            # function, not its jit: a jit nested in this one read 80-90 s
+            # more tracing and lowering a shape on the chip's host
+            # (PERF.md section 6, PR 43).
+            from ..ops.pallas_kernel import verify_tiles
+
+            ok, needs = verify_tiles.__wrapped__(*lanes)
+        else:
+            ok = _verify_kernel(*lanes)
+            needs = jnp.zeros_like(ok)
+        return _wire.pack_result_traced(ok, needs, list(_verdict_checksum(ok)))
+
+    # The program's name in a profiler trace (`XLA Modules`: `jit_<name>`),
+    # after the kernel inside it, as the mesh's `mesh_verify_tiles` is.
+    program.__name__ = program.__qualname__ = (
+        "packed_verify_tiles" if backend == "pallas" else "packed__verify_kernel"
+    )
+    return jax.jit(program)
 
 
 class TpuSecpVerifier:
@@ -450,7 +495,6 @@ class TpuSecpVerifier:
                 "BITCOINCONSENSUS_TPU_DEVICE_SHA", ""
             ) in ("1", "on")
         self._device_challenge = bool(device_challenge)
-        self._kernel = jax.jit(_verify_kernel)
         self._min_batch = min_batch
         self._chunk = chunk
         self._pad_step = pad_step
@@ -482,7 +526,7 @@ class TpuSecpVerifier:
         self.phases = Phases()  # host_prep / pack / backpressure / dispatch / sync (mesh: + shard_layout / shard_check)
         # Fault containment (resilience/): retry budget + backend
         # quarantine ladder. `_dispatch_level` is the rung the in-flight
-        # dispatch runs at (set around each _run_kernel call).
+        # dispatch runs at (set around each _run_packed call).
         self._resilience = _degrade.DispatchResilience(
             self._ladder_levels(), name=type(self).__name__
         )
@@ -491,11 +535,7 @@ class TpuSecpVerifier:
         # returns tickets, settlement applies the guards/retry/ladder
         # policy. Depth bounds unsettled host state (backpressure);
         # deadline bounds how long a wedged ticket may retry before the
-        # host oracle takes the lanes. The device-side verdict checksum
-        # rides every dispatch unless explicitly disabled.
-        self._checksum = os.environ.get(
-            "BITCOINCONSENSUS_TPU_CHECKSUM", ""
-        ) not in ("0", "off")
+        # host oracle takes the lanes.
         self._inflight = _inflight.InflightQueue(
             self._resilience,
             self._SITE,
@@ -656,32 +696,37 @@ class TpuSecpVerifier:
             return ("pallas", "xla", _degrade.HOST_LEVEL)
         return ("xla", _degrade.HOST_LEVEL)
 
-    def _run_level(self, args: Tuple, n: int, level: str):
+    def _run_level(self, packed: np.ndarray, n: int, level: str):
         self._dispatch_level = level
         try:
-            return self._run_kernel(args, n)
+            return self._run_packed(packed, n)
         finally:
             self._dispatch_level = None
 
+    def _pack_ticket(self, args: Tuple, n: int, padded: Optional[int] = None):
+        """The kernel's seven arguments as one fresh packed buffer of
+        `padded` rows (default: as many as `args` hold), the rotating
+        known-answer lanes seeded into its pad region through the buffer's
+        own views: `(packed, sentinel set)`. ONE pass out of buffers that
+        may be read-only (the native arena's): no copy before this one."""
+        packed = _wire.pack_lanes(args, n, padded)
+        return packed, _guards.install_sentinels(
+            _wire._lane_views(packed)[:-1], n
+        )
+
     def _prepare_ticket(self, args: Tuple, n: int):
-        """Dispatch-time prep (inflight queue callback): copy read-only
-        native buffers, then seed the rotating known-answer lanes into
-        the reserved pad region — every dispatch carries sentinels."""
-        args, _copied = _guards.ensure_writable(args)
-        return args, _guards.install_sentinels(args, n)
+        """Dispatch-time prep (inflight queue callback): the chunk as
+        `((packed,), sentinel set)` — every dispatch carries sentinels."""
+        packed, sset = self._pack_ticket(args, n)
+        return (packed,), sset
 
     def _launch_ticket(self, args: Tuple, n: int, level: str, sset=None):
-        """Launch one chunk at `level` (inflight queue callback); chains
-        the device-side verdict checksum onto the still-async ok buffer.
+        """Launch one packed chunk at `level` (inflight queue callback).
         `sset` is the prepare output (sentinel set; the sharded subclass
         passes its shard layout and routes on it). Returns (result, aux)
-        with nothing synchronized."""
-        result = self._run_level(args, n, level)
-        aux = None
-        if self._checksum:
-            aux = _checksum_jit(result[0] if isinstance(result, tuple)
-                                else result)
-        return result, aux
+        with nothing synchronized; the checksum pair rides inside the one
+        result, so there is no aux."""
+        return self._run_level(args[0], n, level), None
 
     def _on_device_settle(self, ticket, ok, needs, all_ok) -> None:
         """Success hook (inflight queue callback): exactly once per
@@ -711,39 +756,40 @@ class TpuSecpVerifier:
         Returns (ok, needs, all_ok) — padded bool arrays and the sharded
         step's replicated verdict scalar (None off-mesh). Raises
         VerdictAnomaly on a buffer the guards reject."""
-        with region_scope("settle"):
-            result = ticket.result
-            padded = int(ticket.args[0].shape[0])
-            all_ok = None
-            needs_raw = None
-            if isinstance(result, tuple):
-                if len(result) == 3:
-                    ok_raw, needs_raw, all_ok = result
-                else:
-                    ok_raw, needs_raw = result
-            else:
-                ok_raw = result
-            ok_np = _faults.corrupt_verdict(
-                "jax_backend.verdict", np.asarray(ok_raw)
+        padded = int(ticket.args[0].shape[0])
+        ok, needs = self._settle_packed(ticket.result, padded, ticket.sset,
+                                        seam=True)
+        return ok, needs, None
+
+    def _settle_packed(self, result, padded: int, sset, seam: bool = False):
+        """One packed program's result through every guard: `(ok, needs)`
+        padded bool arrays. ONE pull (the host copy was asked for at
+        launch), the whole-buffer shape guard, then the guards in the order
+        they have always had: the verdict domain of `ok` and of the
+        deferral mask, the sentinels, the checksum. At the settle seam
+        (`seam`) the `jax_backend.verdict` fault site sits on the unpacked
+        `ok`, before any guard sees it."""
+        raw = _inflight.settle_array(result)
+        if raw.shape != (padded + _wire.CHECKSUM_TAIL,):
+            _guards.GUARD_ANOMALIES.inc(site=self._SITE, reason="shape")
+            raise _guards.VerdictAnomaly(
+                self._SITE, "shape",
+                f"got {raw.shape}, want ({padded + _wire.CHECKSUM_TAIL},)",
             )
-            ok = _guards.validate_verdict(ok_np, padded, self._SITE)
-            needs = None
-            if needs_raw is not None:
-                needs = _guards.validate_verdict(
-                    np.asarray(needs_raw), padded, self._SITE
-                )
-            _guards.check_sentinels(ticket.sset, ok, needs, self._SITE)
-            if ticket.aux is not None:
-                # Device sums were computed over the pristine in-flight
-                # buffer; recomputing from the materialized (possibly
-                # corrupted-in-transit) copy catches any single-lane flip —
-                # real-lane region included.
-                dev_sums = (int(np.asarray(ticket.aux[0])),
-                            int(np.asarray(ticket.aux[1])))
-                _guards.check_checksum(dev_sums, ok, self._SITE)
-            if all_ok is not None:
-                all_ok = bool(np.asarray(all_ok))
-            return ok, needs, all_ok
+        ok_np, needs_np, tail = _wire.split_result(raw, 1, _wire.CHECKSUM_TAIL)
+        if seam:
+            ok_np = _faults.corrupt_verdict("jax_backend.verdict", ok_np)
+        ok = _guards.validate_verdict(ok_np, padded, self._SITE)
+        needs = _guards.validate_verdict(needs_np, padded, self._SITE)
+        _guards.check_sentinels(sset, ok, needs, self._SITE)
+        # The device sums were computed over the pristine verdicts inside
+        # the program; recomputing from the materialized (possibly
+        # corrupted-in-transit) copy catches any single-lane flip —
+        # real-lane region included.
+        _guards.check_checksum(
+            (int(tail[0, 0]), int(tail[0, 1])), ok, self._SITE
+        )
+        return ok, needs
 
     def _settle_device(self, ticket: _inflight.Ticket, count: int):
         """Settle one ticket through the in-flight queue's retry/
@@ -806,8 +852,8 @@ class TpuSecpVerifier:
         return self._chunk - 1
 
     def dispatch_lanes(self, args: Tuple, n: int):
-        """Async-dispatch one packed lane batch (the prep_pack 7-tuple,
-        already padded); returns an opaque pending handle for sync_lanes.
+        """Async-dispatch one lane batch (the prep_pack 7-tuple, already
+        padded); returns an opaque pending handle for sync_lanes.
         The index-mode driver's seam: lanes are prepped in the native
         session (uniq_lanes) so no SigCheck objects exist on this side."""
         self._make_room()
@@ -901,37 +947,46 @@ class TpuSecpVerifier:
         sublanes, _, steps = tile_grid(rows_a_program)
         _DISPATCH_TILES.inc(steps * programs, rows=str(sublanes))
 
-    def _launch_timed(self, kernel, args: Tuple, n: int, backend: str):
-        """Account for and launch one kernel call, timing the call itself:
-        a shape's first launch traces and compiles before it enqueues."""
-        padded = int(args[0].shape[0])
+    def _launch_timed(self, packed: np.ndarray, n: int, backend: str):
+        """Account for and launch one packed dispatch on `backend`, timing
+        the launch itself (a shape's first launch traces and compiles
+        before it enqueues): the program called on the host buffer, its
+        one put made inside the call (0.29 ms on the chip's host against
+        0.27 + 0.16 for a `device_put` and the call on its result: my chip
+        run, PR 43), and the request for the result's copy to the host,
+        behind the kernel, so that the settle finds it there."""
+        padded = int(packed.shape[0])
         first = self._note_dispatch(padded, n, backend)
         t0 = _monotonic()
-        result = kernel(*args)
+        result = _packed_program(backend)(packed)
+        _TRANSFERS.inc(dir="in")
+        result.copy_to_host_async()
+        _TRANSFERS.inc(dir="out")
         _LAUNCH_SECONDS.set(
             _monotonic() - t0, backend=backend, padded=str(padded),
             which="first" if first else "warm",
         )
         return result
 
-    def _run_kernel(self, args: Tuple, n: int):
-        """Dispatch seam: subclasses (mesh sharding) override to add a live
-        mask / collective verdict. `n` is the count of real (unpadded)
-        lanes. Returns the (async) device result — a plain ok array (XLA
-        complete-add kernel) or an (ok, needs_host) tuple (pallas fast-add
-        kernel; flagged lanes are resolved host-side in verify_checks)."""
-        padded = int(args[0].shape[0])
+    def _run_packed(self, packed: np.ndarray, n: int):
+        """Dispatch seam: start the device program of the current rung on
+        one packed buffer (`lane_wire`). `n` is the count of real
+        (unpadded) lanes. Returns the (async) device result,
+        `int32[padded + 2]`: `ok + 2 * needs_host` a row (the pallas
+        fast-add kernel defers lanes, resolved host-side; the XLA
+        complete-add kernel none) and the checksum pair."""
+        padded = int(packed.shape[0])
         _faults.maybe_raise("jax_backend.dispatch")
         if self._use_pallas and self._dispatch_level != "xla":
-            # Deferred import keeps CPU-only paths light; LANE_TILE is the
-            # kernel's own tile so the guard cannot drift from its assert.
-            # A ladder-quarantined pallas rung skips straight to XLA.
-            from ..ops.pallas_kernel import LANE_TILE, verify_tiles
+            # LANE_TILE is the kernel's own tile so the guard cannot drift
+            # from its assert. A ladder-quarantined pallas rung skips
+            # straight to XLA.
+            from ..ops.pallas_kernel import LANE_TILE
 
             if padded % LANE_TILE == 0:
                 self._note_tiles(padded)
-                return self._launch_timed(verify_tiles, args, n, "pallas")
-        return self._launch_timed(self._kernel, args, n, "xla")
+                return self._launch_timed(packed, n, "pallas")
+        return self._launch_timed(packed, n, "xla")
 
     # Convenience single-check wrappers (used by tests/differential fuzzing).
     def verify_ecdsa(self, pubkey: bytes, sig_der: bytes, msg32: bytes) -> bool:

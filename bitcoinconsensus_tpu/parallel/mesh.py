@@ -34,7 +34,8 @@ deadline of the in-flight queue.
 A dispatch crosses the host-device seam in as few pieces as the mesh has
 shards, each way: its lanes travel as ONE packed byte buffer (`ROW_BYTES` a
 lane: the field bytes, the five flags, `valid`, `live`; `pack_lanes`,
-`unpack_lanes`), unpacked by the first ops of the sharded program, and its
+`unpack_lanes`: `crypto/lane_wire.py`, the one-chip verifier's format
+too), unpacked by the first ops of the sharded program, and its
 verdicts, deferral mask, checksum pairs and psum verdict come back as ONE
 int32 array (`unpack_result`). A launch costs by the piece, not by the byte
 (PERF.md section 6, PRs 33 and 35).
@@ -69,12 +70,26 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..crypto import jax_backend as _jb
 from ..crypto.jax_backend import (
     SigCheck,
     TpuSecpVerifier,
+    _packed_program,
     _verdict_checksum,
     _verify_kernel,
+)
+from ..crypto.lane_wire import (  # re-exported: the format's names live on here
+    CHECKSUM_TAIL,
+    ROW_BYTES,
+    _FIELD_BYTES,
+    _PAD_FLAGS,
+    _PAD_VALUES,
+    _lane_views,
+    _unpack_lanes_traced,
+    pack_lanes,
+    pack_result_traced,
+    pad_rows,
+    split_result,
+    unpack_lanes,
 )
 from ..obs import counter as _obs_counter
 from ..obs import gauge as _obs_gauge
@@ -172,7 +187,7 @@ def make_mesh(
 
 def shard_kernel(use_pallas: bool, shard_rows: int) -> str:
     """The kernel a shard of `shard_rows` lanes runs, "pallas" or "xla":
-    the SAME backend selection as TpuSecpVerifier._run_kernel, applied to
+    the SAME backend selection as TpuSecpVerifier._run_packed, applied to
     the shard-local batch (so a multi-chip deployment dispatches the Pallas
     production kernel on each chip; CPU meshes and tile-indivisible shards
     fall back to XLA). The traced step and the dispatch counter's `kernel`
@@ -206,72 +221,14 @@ def _pick_backend(use_pallas: bool):
 
 # --- the packed wire format ---------------------------------------------
 #
-# A lane is one row of ROW_BYTES bytes: the 128 field bytes, then want_odd,
-# parity, has_t2, neg1, neg2 (int8: every flag is -1, 0 or 1), then valid
-# and live (0/1). Rows are widened value by value (`astype`) on both sides
-# of the seam, never reinterpreted across bytes, so the format has no byte
-# order. A dispatch's result is one int32 array, shard after shard: a
-# shard's `ok + 2 * needs_host` a row, then its checksum pair (count,
+# `crypto/lane_wire.py` holds the format both verifiers send: `ROW_BYTES` a
+# lane in, `ok + 2 * needs_host` a row and a tail out. What the mesh adds:
+# the rows lie shard-major (`_build_layout`), a row's `live` byte says
+# whether the psum verdict counts it, and a dispatch's result is one int32
+# array, shard after shard, each shard's tail its checksum pair (count,
 # weighted sum) and the psum verdict every shard holds a copy of.
 
-_FIELD_BYTES = 4 * 32
-_N_FLAGS = 5
-_VALID_COL = _FIELD_BYTES + _N_FLAGS
-_LIVE_COL = _VALID_COL + 1
-ROW_BYTES = _LIVE_COL + 1
-_RESULT_TAIL = 3  # count, weighted sum, psum verdict
-
-
-def _lane_views(packed: np.ndarray):
-    """Writable views over a packed buffer, in the kernel's argument order
-    and then `live`: what is written through them is written to `packed`."""
-    fields = packed[:, :_FIELD_BYTES]
-    fields = np.lib.stride_tricks.as_strided(  # a reshape that cannot copy
-        fields, (packed.shape[0], 4, 32), (packed.strides[0], 32, 1)
-    )
-    flags = packed.view(np.int8)
-    return (
-        (fields,)
-        + tuple(flags[:, _FIELD_BYTES + i] for i in range(_N_FLAGS))
-        + (packed[:, _VALID_COL], packed[:, _LIVE_COL])
-    )
-
-
-def pack_lanes(args, live) -> np.ndarray:
-    """The kernel's seven arguments and the `live` mask, row for row, as one
-    buffer of `ROW_BYTES` a lane: what `make_sharded_step`'s step takes."""
-    packed = np.empty((int(args[0].shape[0]), ROW_BYTES), dtype=np.uint8)
-    for view, a in zip(_lane_views(packed), tuple(args) + (live,)):
-        view[...] = a
-    return packed
-
-
-def unpack_lanes(packed: np.ndarray):
-    """Host twin of the sharded step's first ops, on a host buffer:
-    `(fields, want_odd, parity, has_t2, neg1, neg2, valid, live)` as fresh
-    arrays of the dtypes the kernel takes, bit for bit what `pack_lanes`
-    was given."""
-    flags = np.ascontiguousarray(
-        packed[:, _FIELD_BYTES:_VALID_COL].view(np.int8).T
-    ).astype(np.int32)
-    fields = np.ascontiguousarray(packed[:, :_FIELD_BYTES]).reshape(-1, 4, 32)
-    return (
-        (fields,) + tuple(flags)
-        + (packed[:, _VALID_COL] != 0, packed[:, _LIVE_COL] != 0)
-    )
-
-
-def _unpack_lanes_traced(packed):
-    """`unpack_lanes` inside the traced program, on a shard's rows."""
-    fields = packed[:, :_FIELD_BYTES].reshape(packed.shape[0], 4, 32)
-    # uint8 -> int8 of the same width, then widened: parity's -1 is 0xff
-    flags = jax.lax.bitcast_convert_type(
-        packed[:, _FIELD_BYTES:_VALID_COL], jnp.int8
-    ).astype(jnp.int32)
-    return (
-        (fields,) + tuple(flags[:, i] for i in range(_N_FLAGS))
-        + (packed[:, _VALID_COL] != 0, packed[:, _LIVE_COL] != 0)
-    )
+_RESULT_TAIL = CHECKSUM_TAIL + 1  # count, weighted sum, psum verdict
 
 
 def unpack_result(raw: np.ndarray, n_shards: int):
@@ -281,17 +238,8 @@ def unpack_result(raw: np.ndarray, n_shards: int):
     shard's domain guard and is not masked away), the psum verdict (False
     unless every shard's copy says so) and each shard's checksum pair.
     Raises ValueError on a buffer that does not split `n_shards` ways."""
-    if raw.ndim != 1 or raw.shape[0] % n_shards or (
-        raw.shape[0] // n_shards <= _RESULT_TAIL
-    ):
-        raise ValueError(f"packed result {raw.shape} over {n_shards} shards")
-    per_shard = raw.reshape(n_shards, -1)
-    rows = per_shard[:, :-_RESULT_TAIL].reshape(-1)
-    tail = per_shard[:, -_RESULT_TAIL:]
-    return (
-        (rows & 1) != 0, rows >> 1, bool((tail[:, 2] == 1).all()),
-        tail[:, 0], tail[:, 1],
-    )
+    ok, needs, tail = split_result(raw, n_shards, _RESULT_TAIL)
+    return ok, needs, bool((tail[:, 2] == 1).all()), tail[:, 0], tail[:, 1]
 
 
 def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
@@ -336,9 +284,9 @@ def make_sharded_step(mesh: Mesh, use_pallas: Optional[bool] = None):
             all_ok = jax.lax.psum(failures, axis) == 0
             # the checksum pair over the pristine verdict slice
             cnt, wsum = _verdict_checksum(per_lane)
-            rows = per_lane.astype(jnp.int32) + 2 * needs.astype(jnp.int32)
-            tail = jnp.stack([cnt, wsum, all_ok.astype(jnp.int32)])
-            return jnp.concatenate([rows, tail])
+            return pack_result_traced(
+                per_lane, needs, [cnt, wsum, all_ok.astype(jnp.int32)]
+            )
 
     # Varying-axes checking is off: the verify kernel's scan carries start
     # as mesh-wide constants (infinity masks, G-table selects) and become
@@ -424,13 +372,6 @@ class _ShardLayout:
         self.deadline_armed = deadline_armed
 
 
-# Pad row values per packed array (mirrors _pack_lanes): fields 0,
-# want_odd 0, parity -1 (don't-care), has_t2/neg1/neg2 0, valid False.
-_PAD_VALUES = (0, 0, -1, 0, 0, 0, 0)
-# A pad row's bytes after the fields: the flags and `valid` as above, not live.
-_PAD_FLAGS = _PAD_VALUES[1:] + (0,)
-
-
 class ShardedSecpVerifier(TpuSecpVerifier):
     """Drop-in TpuSecpVerifier that spreads each dispatch over a mesh,
     with per-device fault domains: per-shard sentinels + checksums at
@@ -501,16 +442,6 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         rung."""
         return self._chunk - int(self.mesh.devices.size)
 
-    def _blank_args(self, like, padded: int):
-        """Fresh all-pad packed buffers shaped like `like` at `padded`."""
-        out = []
-        for a, pv in zip(like, _PAD_VALUES):
-            buf = np.zeros((padded,) + a.shape[1:], dtype=a.dtype)
-            if pv:
-                buf[...] = pv
-            out.append(buf)
-        return tuple(out)
-
     def _build_layout(self, args, n: int, padded: Optional[int] = None):
         """Lay `args`' first `n` lanes out shard-major in ONE fresh packed
         buffer of `padded` rows (default: as many as `args` has) and
@@ -580,8 +511,9 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         """Launch one chunk (inflight queue callback). Mesh-level launches
         need a current-epoch shard layout; anything else (quarantined
         rung, stale layout after an eviction rebuild, unshardable batch)
-        runs the single-device base dispatch, whose settle is guarded by
-        the flat sentinel set + global checksum."""
+        runs the single-device base dispatch on the same packed buffer
+        (the base program does not read `live`), whose settle is guarded
+        by the flat sentinel set + global checksum."""
         layout = sset if isinstance(sset, _ShardLayout) else None
         if (
             level != "mesh"
@@ -590,8 +522,6 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         ):
             if level == "mesh":
                 level = "xla"
-            if layout is not None:  # a packed ticket on one device
-                args = unpack_lanes(args[0])[:-1]
             return TpuSecpVerifier._launch_ticket(self, args, n, level, sset)
         _faults.maybe_raise("mesh.dispatch")
         with _obs_span("mesh.dispatch", shards=layout.n_shards, lanes=n,
@@ -644,29 +574,10 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         """Settle a scattered buffer answered by the single-device rung:
         whole-buffer guards (flat sentinels + global checksum), then
         gather real lanes back to caller order."""
-        result = ticket.result
-        needs_raw = None
-        if isinstance(result, tuple):
-            ok_raw, needs_raw = result[0], result[1]
-        else:
-            ok_raw = result
-        ok_np = _faults.corrupt_verdict(
-            "jax_backend.verdict", settle_array(ok_raw)
+        ok, needs = self._settle_packed(
+            ticket.result, layout.padded, layout.flat_sset, seam=True
         )
-        ok = _guards.validate_verdict(ok_np, layout.padded, self._SITE)
-        needs = None
-        if needs_raw is not None:
-            needs = _guards.validate_verdict(
-                settle_array(needs_raw), layout.padded, self._SITE
-            )
-        _guards.check_sentinels(layout.flat_sset, ok, needs, self._SITE)
-        if ticket.aux is not None:
-            dev_sums = (int(settle_array(ticket.aux[0])),
-                        int(settle_array(ticket.aux[1])))
-            _guards.check_checksum(dev_sums, ok, self._SITE)
-        ok_r = ok[layout.positions]
-        needs_r = None if needs is None else needs[layout.positions]
-        return ok_r, needs_r, None
+        return ok[layout.positions], needs[layout.positions], None
 
     def _materialize_sharded(self, ticket, layout: _ShardLayout):
         """The per-shard settle seam (span `mesh.settle`): validate every
@@ -886,22 +797,11 @@ class ShardedSecpVerifier(TpuSecpVerifier):
     def _redispatch_xla(self, sub, k: int):
         """Single-device re-answer of the failed lanes, guarded by a
         fresh contiguous sentinel set + the global verdict checksum."""
-        args = self._blank_args(sub, self._pad(k))
-        for a, r in zip(args, sub):
-            a[:k] = r
-        sset = _guards.install_sentinels(args, k)
-        padded = int(args[0].shape[0])
-        result = self._run_level(args, k, "xla")
-        ok_raw = result[0] if isinstance(result, tuple) else result
-        aux = _jb._checksum_jit(ok_raw) if self._checksum else None
-        ok = _guards.validate_verdict(
-            settle_array(ok_raw), padded, self._SITE
+        padded = self._pad(k)
+        packed, sset = self._pack_ticket(sub, k, padded)
+        ok, _needs = self._settle_packed(
+            self._run_level(packed, k, "xla"), padded, sset
         )
-        _guards.check_sentinels(sset, ok, None, self._SITE)
-        if aux is not None:
-            dev_sums = (int(settle_array(aux[0])),
-                        int(settle_array(aux[1])))
-            _guards.check_checksum(dev_sums, ok, self._SITE)
         return ok[:k], np.zeros(k, dtype=bool)
 
     # --- elastic mesh: eviction + re-promotion -------------------------
@@ -944,19 +844,16 @@ class ShardedSecpVerifier(TpuSecpVerifier):
         if dev is None:
             return False
         size = 8
-        args = self._blank_args(
-            (np.zeros((1, 4, 32), dtype=np.uint8),) + tuple(
-                np.zeros(1, dtype=np.int32) for _ in range(5)
-            ) + (np.zeros(1, dtype=bool),),
-            size,
+        packed = pad_rows(size)
+        sset = _guards.install_sentinels_at(
+            _lane_views(packed)[:-1], [0, 1, 2, 3], rotation=0
         )
-        sset = _guards.install_sentinels_at(args, [0, 1, 2, 3], rotation=0)
         if sset is None:
             return False
-        put = tuple(jax.device_put(a, dev) for a in args)
-        ok = _guards.validate_verdict(
-            settle_array(self._kernel(*put)), size, "mesh.probe"
-        )
+        program = _packed_program("xla")  # runs where its argument lies
+        raw = settle_array(program(jax.device_put(packed, dev)))
+        ok_np, _needs, _tail = split_result(raw, 1, CHECKSUM_TAIL)
+        ok = _guards.validate_verdict(ok_np, size, "mesh.probe")
         try:
             sset.check(ok, None, "mesh.probe")
         except _guards.VerdictAnomaly:

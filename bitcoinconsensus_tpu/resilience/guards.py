@@ -24,16 +24,16 @@ catch whole-buffer corruption classes (inversion, garbage, encoding
 faults, dead kernels), structural validation catches anything
 non-boolean, and the **verdict checksum** (`check_checksum`) closes the
 remaining gap: a device-side (count, position-weighted) sum over the
-verdict buffer, dispatched with the batch and compared at settle against
-the same sums recomputed from the materialized buffer. Any single-lane
+verdict buffer, computed inside the dispatch's one program and compared at
+settle against the same sums recomputed from the materialized buffer. Any single-lane
 flip — sentinel region or real-lane region — changes the count by ±1
 and mismatches; `flip` is a hard pass criterion in the chaos sweep.
 Sentinel templates additionally *rotate* across dispatches
 (`install_sentinels`), so a replayed/stuck verdict buffer that answers
 the previous dispatch's pattern is caught; the dispatch layer pads every
-shape with at least one spare lane (`TpuSecpVerifier._pad`) and copies
-read-only native buffers (`ensure_writable`) so no dispatch goes out
-sentinel-less.
+shape with at least one spare lane (`TpuSecpVerifier._pad`) and packs the
+(read-only) native buffers into one fresh buffer (`crypto/lane_wire.py`),
+whose views take the sentinels, so no dispatch goes out sentinel-less.
 
 Cache audit mode (`set_cache_audit`): when armed, the batch driver
 re-verifies cache hits against the host oracle and evicts proven-wrong
@@ -61,7 +61,6 @@ __all__ = [
     "audit_cache_hits",
     "check_checksum",
     "check_sentinels",
-    "ensure_writable",
     "install_sentinels",
     "install_sentinels_at",
     "set_cache_audit",
@@ -97,11 +96,6 @@ CACHE_POISON_CAUGHT = _obs_counter(
     "consensus_resilience_cache_poison_caught_total",
     "cache hits whose audit re-verification disagreed (entry evicted)",
     ("cache",),
-)
-_WRITABLE_COPIES = _obs_counter(
-    "consensus_resilience_writable_copies_total",
-    "packed batches copied host-side so sentinels could be installed "
-    "(native prep_pack hands back read-only views)",
 )
 
 
@@ -228,29 +222,14 @@ class SentinelSet:
 _rotation = 0
 
 
-def ensure_writable(args: Tuple) -> Tuple[Tuple, bool]:
-    """Return `(args, copied)` with every packed buffer host-writable.
-
-    The native bridge's ``prep_pack`` hands back read-only views over the
-    C-owned arena; sentinels must be written in place, so those batches
-    are copied once host-side (a memcpy of the packed lanes — counted in
-    ``consensus_resilience_writable_copies_total``). Already-writable
-    batches pass through untouched.
-    """
-    if all(getattr(a, "flags", None) is not None and a.flags.writeable
-           for a in args):
-        return args, False
-    _WRITABLE_COPIES.inc()
-    return tuple(np.array(a) for a in args), True
-
-
 def install_sentinels(
     args: Tuple, n: int, rotation: Optional[int] = None
 ) -> Optional[SentinelSet]:
     """Write sentinel lanes into the pad region of a packed batch, in place.
 
-    `args` is the verifier's packed 7-tuple (fields, want_odd, parity,
-    has_t2, neg1, neg2, valid); `n` is the real lane count, so rows
+    `args` is the kernel's 7-tuple (fields, want_odd, parity, has_t2,
+    neg1, neg2, valid), as separate arrays or as the views over one packed
+    buffer (`lane_wire._lane_views`); `n` is the real lane count, so rows
     [n, size) are pad. Templates rotate across dispatches (a process-wide
     counter advances the starting template each call) so consecutive
     batches of the same shape carry *different* expected patterns — a
@@ -258,9 +237,8 @@ def install_sentinels(
     pattern mismatches. Pass `rotation` to pin the phase (tests).
 
     Returns the SentinelSet to check at settle, or None (counted) when
-    the batch has no pad room or the buffers are not writable — callers
-    that must not dispatch sentinel-less copy first via
-    ``ensure_writable``.
+    the batch has no pad room or the buffers are not writable (the
+    dispatch layer writes through the views of its own fresh buffer).
     """
     fields = args[0]
     size = int(fields.shape[0])
@@ -327,9 +305,9 @@ def check_sentinels(
 
 # --- verdict checksum -------------------------------------------------------
 #
-# The single-flip detector. The dispatch layer chains a tiny jitted
-# reduction onto the in-flight verdict buffer: (sum of lanes, sum of
-# lane·weight) with weight[i] = i % CHECKSUM_MOD + 1. At settle the same
+# The single-flip detector. The dispatch's program ends in a tiny
+# reduction over its pristine verdict buffer: (sum of lanes, sum of
+# lane·weight) with weight[i] = i % CHECKSUM_MOD + 1, the tail of its result. At settle the same
 # two sums are recomputed host-side from the materialized buffer and must
 # match exactly. Any single-lane flip changes the count sum by ±1; the
 # weighted sum localizes most multi-lane corruptions the count parity

@@ -137,8 +137,8 @@ class Ticket:
         self.born = born
         self.deadline = deadline    # wall-clock settle deadline
         self.sset = None            # SentinelSet installed at prepare
-        self.result = None          # unsynchronized device arrays
-        self.aux = None             # in-flight (count, weighted) checksum
+        self.result = None          # unsynchronized device result
+        self.aux = None             # what else the settle pulls (None: one result)
         self.error = None           # launch exception, if any
         self.settled = False
         self.outcome = None         # (ok, needs) after settle; None=host
@@ -153,10 +153,13 @@ class InflightQueue:
     *mechanism* via callbacks:
 
     - ``prepare(args, n) -> (args, sset)`` — runs once per ticket at
-      dispatch time: copy read-only buffers, install sentinel lanes.
+      dispatch time: the lanes packed into the one buffer that travels
+      (out of buffers that may be read-only), sentinel lanes installed.
     - ``launch(args, n, level, sset) -> (result, aux)`` — start the
-      device work; returns unsynchronized arrays plus the in-flight
-      checksum pair (or None). `sset` is whatever `prepare` returned
+      device work; returns the unsynchronized result, its host copy
+      already asked for, plus whatever else the settle must pull (None
+      where the checksum pair rides inside the result, as both verifiers'
+      do). `sset` is whatever `prepare` returned
       (sentinel set or the sharded verifier's shard layout), so a launch
       can route by how the batch was laid out. Must not block.
       Exceptions are captured on the ticket and handled at settle (a

@@ -71,6 +71,8 @@ REQUIRED_METRICS = [
     "consensus_dispatch_padded_lanes_total",
     "consensus_dispatch_fill_ratio",
     "consensus_dispatch_new_shapes_total",
+    # a one-device dispatch travels packed: one piece in, one out
+    "consensus_dispatch_transfers_total",
     # mesh (fault-domain counters light up via the workload's eviction
     # leg; consensus_mesh_repromotions_total is chaos-sweep-only)
     "consensus_mesh_devices",

@@ -382,6 +382,7 @@ def check_packing() -> None:
     from bitcoinconsensus_tpu.parallel import mesh as M
     from bitcoinconsensus_tpu.resilience.guards import verdict_checksum_host
     from mesh_stub import traced_unpack
+    from packed_stub import xla_lane_verdicts
 
     config, _d = _worst_block()
     v = make_verifier(config)
@@ -409,7 +410,7 @@ def check_packing() -> None:
             assert M._MESH_TRANSFERS.value(dir=way) == was + 4, way
         assert raw.dtype == np.int32 and raw.shape == (16 + 3 * 4,)
         ok, needs, all_ok, cnts, wsums = M.unpack_result(raw, 4)
-        want_ok = np.asarray(v._kernel(*host[:7]))  # the one-device XLA kernel
+        want_ok = xla_lane_verdicts(*host[:7])  # the one-device XLA program
         assert np.array_equal(ok, want_ok) and not needs.any()
         assert list(ok[layout.positions]) == [i != bad for i in range(n)]
         assert all_ok is (bad is None) and all_ok == bool(ok[host[7]].all())

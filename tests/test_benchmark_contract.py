@@ -42,6 +42,7 @@ from bitcoinconsensus_tpu.utils.blockgen import (
 )
 
 from mesh_stub import host_step
+from packed_stub import xla_lane_verdicts
 from test_batch import make_p2tr_scriptpath_spend, make_p2wpkh_spend
 from test_native_block import HEIGHT, to_native_view
 
@@ -67,6 +68,7 @@ READ = (
     "consensus_dispatch_new_shapes_total",
     "consensus_dispatch_padded_lanes_total",
     "consensus_dispatch_total",
+    "consensus_dispatch_transfers_total",
     "consensus_fixpoint_reinterpreted_inputs_total",
     "consensus_ingress_seconds",
     "consensus_mesh_dispatch_total",
@@ -216,7 +218,7 @@ def workload():
     # children); what the benchmark reads of the mesh is all on the host
     # side of it
     sharded = ShardedSecpVerifier(mesh=make_mesh(4), min_batch=16, chunk=16)
-    sharded.phases, sharded._step = verifier.phases, host_step(sharded, sharded._kernel)
+    sharded.phases, sharded._step = verifier.phases, host_step(sharded, xla_lane_verdicts)
     res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
                         verifier=sharded, sig_cache=SigCache(),
                         script_cache=ScriptExecutionCache())
@@ -327,7 +329,10 @@ def test_tile_rows_are_the_ones_read(monkeypatch):
     counts nothing."""
     from types import SimpleNamespace
 
-    from bitcoinconsensus_tpu.ops import pallas_kernel
+    import jax.numpy as jnp
+
+    from bitcoinconsensus_tpu.crypto import jax_backend
+    from bitcoinconsensus_tpu.crypto.lane_wire import ROW_BYTES
 
     def rows():
         samples = get_registry().snapshot()["consensus_dispatch_tiles_total"]["samples"]
@@ -337,14 +342,11 @@ def test_tile_rows_are_the_ones_read(monkeypatch):
         return {k: v - before.get(k, 0) for k, v in rows().items() if v != before.get(k, 0)}
 
     def launch(verifier, lanes):
-        flags = (np.zeros(lanes, np.int32),) * 5
-        verifier._run_kernel(
-            (np.zeros((lanes, 4, 32), np.uint8),) + flags + (np.zeros(lanes, bool),), lanes - 1)
+        verifier._run_packed(np.zeros((lanes, ROW_BYTES), np.uint8), lanes - 1)
 
-    monkeypatch.setattr(pallas_kernel, "verify_tiles",
-                        lambda fields, *flags: (np.zeros(len(fields), bool),) * 2)
+    monkeypatch.setattr(jax_backend, "_packed_program", lambda backend: (
+        lambda packed: jnp.zeros(len(packed) + 2, jnp.int32)))
     verifier = TpuSecpVerifier()
-    verifier._kernel = lambda fields, *flags: np.zeros(len(fields), bool)
     before = rows()
     launch(verifier, 512)  # a CPU verifier: the XLA rung, whatever the shape
     assert rose(before) == {}

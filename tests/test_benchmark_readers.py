@@ -316,6 +316,56 @@ def test_coin_probes_per_input_returns_none_with_nothing_to_read(metric, ctx):
     assert reader(metric)(ctx) is None
 
 
+# -- the pieces a dispatch travels in (PR 43) ----------------------------------
+
+
+def transfers_ctx(kind, pieces_in, pieces_out, dispatches, counter=True):
+    """A window over which `dispatches` dispatches went out (some on each
+    backend) and the transfers counter rose by (`pieces_in`, `pieces_out`)
+    from (40, 40); `counter` False is a program without the counter."""
+    def snap(i, o, d):
+        out = {"consensus_dispatch_total": {"samples": [
+            {"labels": {"backend": "pallas"}, "value": 20 + d},
+            {"labels": {"backend": "xla"}, "value": 3}]}}
+        if counter:
+            out["consensus_dispatch_transfers_total"] = {"samples": [
+                {"labels": {"dir": "in"}, "value": i}, {"labels": {"dir": "out"}, "value": o}]}
+        return out
+    return {"cell": "made-up", "trace": None, "driver": {
+        "kind": kind, "counters_before": snap(40, 40, 0),
+        "counters_after": snap(40 + pieces_in, 40 + pieces_out, dispatches)}}
+
+
+@pytest.mark.parametrize("metric,kind", [("transfers_per_dispatch.connect", "connect"),
+                                         ("transfers_per_dispatch.stream", "stream")])
+@pytest.mark.parametrize("pieces_in,pieces_out,dispatches,want", [
+    (10, 10, 10, 2.0),   # packed: one put, one host copy asked for
+    (70, 40, 10, 11.0),  # the seven-argument launch, its checksum program, four pulls
+    (35, 35, 35, 2.0),
+])
+def test_transfers_per_dispatch_is_pieces_both_ways_over_dispatches(
+        metric, kind, pieces_in, pieces_out, dispatches, want):
+    assert reader(metric)(transfers_ctx(kind, pieces_in, pieces_out, dispatches)) == ms(want)
+
+
+@pytest.mark.parametrize("metric,ctx", [
+    # the parent: no such counter, in a cell of the reader's own kind
+    ("transfers_per_dispatch.connect", transfers_ctx("connect", 0, 0, 10, counter=False)),
+    ("transfers_per_dispatch.stream", transfers_ctx("stream", 0, 0, 10, counter=False)),
+    # a window that dispatched nothing
+    ("transfers_per_dispatch.connect", transfers_ctx("connect", 0, 0, 0)),
+    ("transfers_per_dispatch.stream", transfers_ctx("stream", 0, 0, 0)),
+    # another kind of cell, a window without snapshots
+    ("transfers_per_dispatch.connect", transfers_ctx("stream", 10, 10, 10)),
+    ("transfers_per_dispatch.connect", transfers_ctx("serve", 10, 10, 10)),
+    ("transfers_per_dispatch.stream", transfers_ctx("connect", 10, 10, 10)),
+    ("transfers_per_dispatch.stream", {"cell": "made-up", "trace": None,
+                                       "driver": {"kind": "stream"}}),
+])
+def test_transfers_per_dispatch_returns_none_with_nothing_to_read(metric, ctx):
+    assert reader(metric)(ctx) is None
+
+
 # -- the walk's share of the pre-recorded pairings (PR 41) ---------------------
 
 _WALK = "consensus_multisig_walk_pairings_total"
@@ -439,6 +489,11 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "walk_share_of_spec.connect": ["worst-block-multisig20.fanout", "worst-block.sigops"],
         # PR 42: the dense tile's share of the kernel's grid steps
         "full_tile_share.connect": connect,
+        # PR 43: the pieces a one-device dispatch travels in (the mesh cell is packed already)
+        "transfers_per_dispatch.stream": ["ibd-stream.cold"],
+        "transfers_per_dispatch.connect":
+            ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "taproot-block.cold",
+             "worst-block-multisig20.fanout"],
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name

@@ -97,11 +97,13 @@ def test_main_refuses_without_the_native_core(monkeypatch, capsys):
     assert "native host core did not load: g++: not found" in out.err
 
 
-def test_launch_failure_fails_the_smoke_and_keeps_its_text(verifier, capsys):
-    def refuse(*args):
+def test_launch_failure_fails_the_smoke_and_keeps_its_text(verifier, capsys, monkeypatch):
+    from bitcoinconsensus_tpu.crypto import jax_backend
+
+    def refuse(packed):
         raise RuntimeError("Mosaic failed to compile: scoped vmem exceeded")
 
-    verifier._kernel = refuse
+    monkeypatch.setattr(jax_backend, "_packed_program", lambda backend: refuse)
     dev = chip_guard.device_info()
     assert chip_smoke.run(dev, seed=21, backend="xla", **TINY) == 1
     out = capsys.readouterr()

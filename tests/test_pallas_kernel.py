@@ -166,19 +166,20 @@ def test_exceptional_case_deferred_to_host(children):
     v = TpuSecpVerifier(min_batch=8)
 
     # Full fixup loop through verify_checks (device part simulated: the
-    # CPU test env runs the XLA kernel, so inject the pallas-shaped
-    # (ok, needs) result).
-    orig = v._run_kernel
+    # CPU test env runs the XLA program, so inject the pallas-shaped
+    # result: lane 0 deferred, its ok False, the checksum pair over that).
+    from packed_stub import pack_result, unpack_result
 
-    def pallas_shaped(args, n):
-        res = np.asarray(orig(args, n))
-        needs = np.zeros(res.shape[0], dtype=bool)
-        needs[0] = True
-        res = res.copy()
-        res[0] = False
-        return res, needs
+    orig = v._run_packed
 
-    v._run_kernel = pallas_shaped
+    def pallas_shaped(packed, n):
+        ok, needs, _sums = unpack_result(orig(packed, n))
+        assert ok[0] and not needs.any()  # the XLA complete adds resolve it
+        ok, needs = ok.copy(), needs.copy()
+        ok[0], needs[0] = False, True
+        return pack_result(ok, needs)
+
+    v._run_packed = pallas_shaped
     out = v.verify_checks(checks)
     assert out.all(), "host fixup must resolve the deferred lane TRUE"
     assert not v._fixup_failed

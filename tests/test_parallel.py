@@ -25,6 +25,7 @@ from conftest import *  # noqa: F401,F403 (env setup)
 
 from child_checks import Children
 from mesh_stub import host_step, pack_result
+from packed_stub import install_kernel
 
 from bitcoinconsensus_tpu.crypto import secp_host as H
 from bitcoinconsensus_tpu.crypto.jax_backend import SigCheck
@@ -148,7 +149,7 @@ def _mesh_stub_verifier(checks, n_devices=8, evict_after=None):
         return ok, np.zeros(len(ok), dtype=bool)
 
     v._step = step
-    v._run_kernel = kernel
+    install_kernel(v, kernel)
 
     def install(mesh):
         M.ShardedSecpVerifier._install_mesh(v, mesh)

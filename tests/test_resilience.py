@@ -27,6 +27,8 @@ from bitcoinconsensus_tpu.resilience import faults as F
 from bitcoinconsensus_tpu.resilience import guards as G
 from bitcoinconsensus_tpu.resilience.faults import FaultPlan, FaultSpec, inject
 
+from packed_stub import install_kernel
+
 pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
 
 
@@ -72,7 +74,7 @@ def _stub_verifier(checks, explode=0):
 
     def kernel(args, n):
         state["calls"] += 1
-        F.maybe_raise("jax_backend.dispatch")  # same seam as _run_kernel
+        F.maybe_raise("jax_backend.dispatch")  # same seam as _run_packed
         if state["fails"] > 0:
             state["fails"] -= 1
             raise RuntimeError("injected dispatch explosion")
@@ -85,7 +87,7 @@ def _stub_verifier(checks, explode=0):
                 ok[pos] = exp_by_raw[fields[pos].tobytes()]
         return ok, np.zeros(padded, dtype=bool)
 
-    v._run_kernel = kernel
+    install_kernel(v, kernel)
     return v, oracle, state
 
 
@@ -224,21 +226,26 @@ def test_sentinel_skip_no_room_and_readonly():
 
 def test_sentinel_rotation_and_writable_copy():
     """Consecutive dispatches carry different expected patterns (a stuck
-    replayed buffer mismatches), and read-only packed batches are copied
-    writable so no dispatch goes out sentinel-less."""
+    replayed buffer mismatches), and read-only batches are packed into a
+    fresh buffer whose views take the sentinels, so no dispatch goes out
+    sentinel-less."""
     seen = set()
     for _ in range(len(G._SENTINEL_SCALARS)):
         sset = G.install_sentinels(_sentinel_args(size=8), 6)
         seen.add(tuple(sset.expected.tolist()))
     assert len(seen) > 1  # the phase really rotates
+    from bitcoinconsensus_tpu.crypto import lane_wire as W
+
     ro = _sentinel_args(size=8, readonly=True)
-    copies = G._WRITABLE_COPIES.value()
-    args, copied = G.ensure_writable(ro)
-    assert copied and G._WRITABLE_COPIES.value() == copies + 1
+    packed = W.pack_lanes(ro, 4)
+    args = W._lane_views(packed)[:-1]
     assert all(a.flags.writeable for a in args)
-    assert G.install_sentinels(args, 4, rotation=0) is not None
-    args2, copied2 = G.ensure_writable(args)
-    assert args2 is args and not copied2  # already writable: passthrough
+    sset = G.install_sentinels(args, 4, rotation=0)
+    assert sset is not None and list(sset.positions) == [4, 5, 6, 7]
+    skipped = G._SENTINEL_SKIPPED.value(reason="readonly")
+    (packed2, sset2) = TpuSecpVerifier()._pack_ticket(ro, 4)
+    assert sset2 is not None and G._SENTINEL_SKIPPED.value(reason="readonly") == skipped
+    assert W.unpack_lanes(packed2)[6][4:].all()  # the pad rows hold valid sentinels
 
 
 def test_verdict_checksum_catches_single_flip():
