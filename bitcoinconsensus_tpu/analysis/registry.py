@@ -284,8 +284,6 @@ def _schedule_specs() -> List[ScheduleSpec]:
                      note="signed window recoder: exhaustive carry automaton"),
         ScheduleSpec("glv.split_lambda",
                      note="lattice constants + |k1|,|k2| < 2^128 certificate"),
-        ScheduleSpec("curve.double_scalar_mult", heavy=True,
-                     note="Strauss ladder weight ledger + differential"),
         ScheduleSpec("curve.double_scalar_mult_glv", heavy=True,
                      note="GLV ladder weight ledger + differential"),
         ScheduleSpec("pallas.kernel_schedule", heavy=True,

@@ -42,8 +42,8 @@ never VACUOUS):
    cube identity, so the certificate is not refutable by re-deriving
    from the corrupted values.
 
-4. **Schedule ledger**: the production ladders (`double_scalar_mult`,
-   `double_scalar_mult_glv`, the Pallas `_kernel_body`) are executed
+4. **Schedule ledger**: the production ladders
+   (`double_scalar_mult_glv`, the Pallas `_kernel_body`) are executed
    eagerly under an instrumented `lax.fori_loop` that runs every window
    iteration with a concrete Python index while spies record each
    jacobian double/add and each digit-array read.  From the recording we
@@ -1283,98 +1283,28 @@ def certify_g_table() -> Tuple[Dict[str, Any], List[str]]:
                         "rule": "row (w,j) == (j+1)·256^w·G"}}, []
 
 
-def _target_double_scalar_mult(quick: bool = False) -> CertResult:
-    facts: Dict[str, Any] = {}
-    failures: List[str] = []
-    rec = _Recorder()
-
-    a_int = 0x1234567890ABCDEF1234567890ABCDEF0DDBA11FEEDFACE8BADF00D5EED
-    b_int = 0xC0FFEE0FF1CE0DDC0DE0FACADE0BEEFF00DBABB1E0CAFE0DEAF0D00DAD
-    a = _limb_col(a_int)
-    b = _limb_col(b_int)
-    px, py = _limb_col(host.G_X), _limb_col(host.G_Y)
-
-    digit_calls: List[Tuple[int, int, str]] = []
-    orig_digits = curve_mod._digits
-
-    def digits_spy(limbs, width, count):
-        name = f"digits{len(digit_calls)}"
-        digit_calls.append((width, count, name))
-        return _SpyArray(orig_digits(limbs, width, count), name, rec)
-
-    patches = _jacobian_spies(rec, curve_mod)
-    patches[(curve_mod, "_digits")] = digits_spy
-    patches[(jax.lax, "fori_loop")] = _fake_fori(rec)
-    patches[(lax, "fori_loop")] = patches[(jax.lax, "fori_loop")]
-    try:
-        with _Patched(patches):
-            R = curve_mod.double_scalar_mult(a, b, px, py)
-    except Exception as e:  # noqa: BLE001
-        failures.append(f"ledger walk: {type(e).__name__}: {e}")
-        return _finish("curve.double_scalar_mult", facts, failures)
-
-    if [(w, c) for w, c, _ in digit_calls] != [(4, 64), (8, 32)]:
-        failures.append(f"recoder calls {digit_calls} != expected "
-                        "[(4,64) P digits, (8,32) G digits]")
-        return _finish("curve.double_scalar_mult", facts, failures)
-    wloops = _window_loops(rec)
-    if len(wloops) != 2:
-        failures.append(f"found {len(wloops)} jacobian window loops, "
-                        "expected 2 (P ladder + G madd loop)")
-        return _finish("curve.double_scalar_mult", facts, failures)
-    pl = _check_ladder_loop(
-        wloops[0], count=64, width=4, digit_arrays=["digits0"],
-        expect_events=["jacobian_double"] * 4 + ["jacobian_add_complete"],
-        label="P ladder", failures=failures)
-    if pl:
-        facts["p_ladder"] = pl
-    # G loop: no doublings — weights live in the table rows (j+1)·256^w·G
-    gl = wloops[1]
+def _check_g_loop(gl: Dict[str, Any], digits: str, facts: Dict[str, Any],
+                  failures: List[str]) -> None:
+    """`_fixed_base_mult`'s loop: 32 madds, no doublings — the weights live
+    in the table rows (j+1)·256^w·G, so window i reads digit i of `digits`,
+    ascending."""
     if (gl["lo"], gl["hi"]) != (0, 32) or not gl.get("complete"):
         failures.append("G loop bounds/completeness wrong")
-    else:
-        for i in range(32):
-            it = gl["iters"][i]
-            if [e[0] for e in it["events"]] != ["jacobian_madd_complete"]:
-                failures.append(f"G loop iteration {i}: schedule "
-                                f"{[e[0] for e in it['events']]}")
-                break
-            reads = [idx for arr, idx in it["reads"] if arr == "digits1"]
-            if reads != [i]:
-                failures.append(f"G loop iteration {i} reads digit "
-                                f"window(s) {reads}, expected [{i}] "
-                                "(ascending: weights are in the table)")
-                break
-        else:
-            facts["g_loop"] = {"windows": 32, "doubles_per_window": 0,
-                               "order": "ascending, table row (j+1)·256^w·G"}
-    # final join: exactly one add after the loops
-    post_jac = [e[0] for e in rec.preamble if e[0] in _JAC_EVENTS]
-    if post_jac != ["jacobian_madd_complete", "jacobian_add_complete"]:
-        failures.append(f"out-of-loop jacobian events {post_jac} != "
-                        "[p-table scan madd, final join add]")
-    else:
-        facts["join"] = {"final_adds": 1}
-
-    # every iteration really ran in order on concrete values, so the walk
-    # doubles as an end-to-end differential against the exact host math.
-    got = _affine_of(*R[:3]) if isinstance(R, tuple) else None
-    want_pt = host.G.mul(a_int).add(host.G.mul(b_int))
-    if got != want_pt.to_affine():
-        failures.append("differential: eager ladder result != "
-                        "a·G + b·P computed with exact host arithmetic")
-    else:
-        facts["differential"] = {"scalars": 2,
-                                 "rule": "eager walk == a·G + b·P (host)"}
-
-    f2, fail2 = certify_p_table()
-    facts.update(f2)
-    failures.extend(fail2)
-    if not quick:
-        f3, fail3 = certify_g_table()
-        facts.update(f3)
-        failures.extend(fail3)
-    return _finish("curve.double_scalar_mult", facts, failures)
+        return
+    for i in range(32):
+        it = gl["iters"][i]
+        if [e[0] for e in it["events"]] != ["jacobian_madd_complete"]:
+            failures.append(f"G loop iteration {i}: schedule "
+                            f"{[e[0] for e in it['events']]}")
+            return
+        reads = [idx for arr, idx in it["reads"] if arr == digits]
+        if reads != [i]:
+            failures.append(f"G loop iteration {i} reads digit "
+                            f"window(s) {reads}, expected [{i}] "
+                            "(ascending: weights are in the table)")
+            return
+    facts["g_loop"] = {"windows": 32, "doubles_per_window": 0,
+                       "order": "ascending, table row (j+1)·256^w·G"}
 
 
 def _target_double_scalar_mult_glv(quick: bool = False) -> CertResult:
@@ -1414,6 +1344,10 @@ def _target_double_scalar_mult_glv(quick: bool = False) -> CertResult:
         failures.append(f"ledger walk: {type(e).__name__}: {e}")
         return _finish("curve.double_scalar_mult_glv", facts, failures)
 
+    if [(w, c) for w, c, _ in digit_calls] != [(8, 32)]:
+        failures.append(f"recoder calls {digit_calls} != expected "
+                        "[(8,32) G digits]")
+        return _finish("curve.double_scalar_mult_glv", facts, failures)
     wloops = _window_loops(rec)
     if len(wloops) != 2:
         failures.append(f"found {len(wloops)} jacobian window loops, "
@@ -1430,6 +1364,14 @@ def _target_double_scalar_mult_glv(quick: bool = False) -> CertResult:
     if gl:
         gl["beta"] = "fe_mul(Σ TX·onehot, β) precedes only the d2 add"
         facts["glv_ladder"] = gl
+    _check_g_loop(wloops[1], "digits0", facts, failures)
+    # final join: exactly one add after the loops
+    post_jac = [e[0] for e in rec.preamble if e[0] in _JAC_EVENTS]
+    if post_jac != ["jacobian_madd_complete", "jacobian_add_complete"]:
+        failures.append(f"out-of-loop jacobian events {post_jac} != "
+                        "[p-table scan madd, final join add]")
+    else:
+        facts["join"] = {"final_adds": 1}
 
     # differential: ±a1 ± λ·a2 must reproduce k, and the eager walk must
     # equal the host's exact a·G + k·P.
@@ -1448,6 +1390,10 @@ def _target_double_scalar_mult_glv(quick: bool = False) -> CertResult:
     f2, fail2 = certify_p_table()
     facts.update(f2)
     failures.extend(fail2)
+    if not quick:
+        f3, fail3 = certify_g_table()
+        facts.update(f3)
+        failures.extend(fail3)
     return _finish("curve.double_scalar_mult_glv", facts, failures)
 
 
@@ -1655,7 +1601,6 @@ TARGETS: Dict[str, Callable[..., CertResult]] = {
     "scalar._signed_digits128":
         lambda quick=False: _target_signed_digits128(),
     "glv.split_lambda": lambda quick=False: _target_glv(),
-    "curve.double_scalar_mult": _target_double_scalar_mult,
     "curve.double_scalar_mult_glv": _target_double_scalar_mult_glv,
     "pallas.kernel_schedule": _target_pallas_schedule,
 }
@@ -1673,10 +1618,9 @@ REGISTERED_RECODERS: Dict[str, str] = {
     "_bytes_from_words": "sha256.bytes_from_words",
     "ints_to_limbs_batch": "scalar._signed_digits128",
     "split_lambda": "glv.split_lambda",
-    "double_scalar_mult": "curve.double_scalar_mult",
     "double_scalar_mult_glv": "curve.double_scalar_mult_glv",
-    "double_scalar_mult_bits": "curve.double_scalar_mult",
-    "_fixed_base_mult": "curve.double_scalar_mult",
+    "_fixed_base_mult": "curve.double_scalar_mult_glv",
+    "_p_table": "curve.double_scalar_mult_glv",
     "_kernel_body": "pallas.kernel_schedule",
 }
 
@@ -1685,7 +1629,6 @@ REGISTERED_RECODERS: Dict[str, str] = {
 # CPU); the stats mini-workload and test suite certify only the fast set,
 # CI's --schedule leg runs everything.
 HEAVY_TARGETS = {
-    "curve.double_scalar_mult",
     "curve.double_scalar_mult_glv",
     "pallas.kernel_schedule",
 }

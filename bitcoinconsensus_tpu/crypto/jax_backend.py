@@ -6,7 +6,7 @@ The reference verifies one signature per call on one core
 `secp256k1_xonly_pubkey_tweak_add_check`, `modules/extrakeys/main_impl.h:109`).
 All three reduce to the same algebra — compute R = a·G + b·P and compare R
 against a target — so this backend folds a *mixed* batch of all three check
-kinds into ONE device program over `double_scalar_mult`:
+kinds into ONE device program over `double_scalar_mult_glv`:
 
     kind      a        b      P            accept
     ECDSA     m/s      r/s    pubkey       R.x ∈ {r, r+n} (mod p)

@@ -1,10 +1,10 @@
 """Crash-safe persistent signature cache: hot RAM tier over shard logs.
 
 `SigCache` (models/sigcache.py) is the product for repeat mainnet
-traffic — the cached-replay bench configs run 104-130k verifies/s
-because most real-world inputs re-verify previously-seen signatures —
-but it evaporates on every restart, forcing a cold device warm-up
-exactly when a recovering server is most fragile. `PersistentSigCache`
+traffic — most real-world inputs re-verify previously-seen signatures,
+and a hit answers an input without a device lane — but it evaporates on
+every restart, forcing a cold device warm-up exactly when a recovering
+server is most fragile. `PersistentSigCache`
 promotes it to a sharded two-tier store:
 
 - **Hot tier**: the inherited bounded LRU (`_SaltedLRU`), sized by

@@ -18,8 +18,10 @@ per call on one core; here every lane advances in lockstep on the VPU:
   `gen_gtable.py`) — 32 complete mixed additions, zero doublings; the
   one-hot row select runs as an exact f32 matmul on the MXU;
 - variable-base half b·P: per-lane Jacobian table {0..15}·P built by a
-  14-step `lax.scan`, then 64 windows of 4 doublings + one complete
-  Jacobian addition with a one-hot table select;
+  14-step `lax.scan`, then, with b split by the GLV endomorphism into two
+  signed 128-bit halves on the host, 32 windows of 4 doublings + two
+  complete Jacobian additions with a one-hot table select
+  (`double_scalar_mult_glv`, the one ladder);
 - one final complete addition joins the halves.
 
 No secret data is involved on the verify path, so uniform (non-constant-
@@ -63,9 +65,7 @@ __all__ = [
     "jacobian_double",
     "jacobian_madd_complete",
     "jacobian_add_complete",
-    "double_scalar_mult",
     "double_scalar_mult_glv",
-    "double_scalar_mult_bits",
     "jacobian_to_affine",
     "scalar_bits",
 ]
@@ -88,7 +88,6 @@ _BETA_LIMBS = int_to_limbs(BETA)
 _ONE = int_to_limbs(1)
 
 NBITS = NLIMB * RADIX  # 260 bit positions per scalar (top 4 always zero)
-P_WINDOWS = 64
 P_WINDOW_BITS = 4
 G_WINDOWS = 32
 G_WINDOW_BITS = 8
@@ -399,42 +398,6 @@ def _p_table(px, py):
     return TX, TY, TZ
 
 
-@named_region("scalar_mult")
-def double_scalar_mult(a, b, px, py):
-    """R = a·G + b·P per lane (the ECDSA/Schnorr verify hot kernel).
-
-    `a`, `b`: (20, ...) scalar limb vectors, **reduced mod n** (the group
-    order; the final join assumes a·G is infinite iff a ≡ 0). `px`, `py`:
-    (20, ...) affine point, never infinity (the host substitutes a dummy
-    for invalid lanes and masks them). Returns a Jacobian triple.
-
-    Schedule per lane: 14 madds (P table, lax.scan) + 64x(4 doublings +
-    1 complete J-add) + 32 G madds (MXU-select) + 1 final join.
-    """
-    digits_b = _digits(b, P_WINDOW_BITS, P_WINDOWS)  # (64, B)
-    digits_a = _digits(a, G_WINDOW_BITS, G_WINDOWS)  # (32, B)
-
-    TX, TY, TZ = _p_table(px, py)
-    k16 = jnp.arange(16, dtype=jnp.int32).reshape((16,) + (1,) * px.ndim)
-
-    def body(i, R):
-        w = P_WINDOWS - 1 - i
-        R = jacobian_double(*R)
-        R = jacobian_double(*R)
-        R = jacobian_double(*R)
-        R = jacobian_double(*R)
-        db = digits_b[w]  # (B,)
-        oh = (db[None] == k16).astype(jnp.int32)  # (16, 1, B)
-        selx = jnp.sum(TX * oh, axis=0)
-        sely = jnp.sum(TY * oh, axis=0)
-        selz = jnp.sum(TZ * oh, axis=0)
-        return jacobian_add_complete(*R, selx, sely, selz, db == 0)
-
-    R = lax.fori_loop(0, P_WINDOWS, body, _inf_like(px))
-    RG, rg_inf = _fixed_base_mult(digits_a)
-    return jacobian_add_complete(*R, *RG, rg_inf)
-
-
 GLV_WINDOWS = 32  # 4-bit windows over the 128-bit split halves
 
 
@@ -510,26 +473,6 @@ def double_scalar_mult_glv(a, db1, db2, neg1, neg2, px, py):
         X, Y, Z, *RG, rg_inf, inf1=r_inf
     )
     return X, Y, Z, out_inf
-
-
-def double_scalar_mult_bits(a, b, px, py):
-    """Naive 256-step bitwise ladder; kept as an independent reference
-    schedule for differential tests against the windowed kernel."""
-    bits_a = scalar_bits(a)
-    bits_b = scalar_bits(b)
-    gx = jnp.broadcast_to(_col(_GX_LIMBS, px), px.shape).astype(px.dtype)
-    gy = jnp.broadcast_to(_col(_GY_LIMBS, py), py.shape).astype(py.dtype)
-
-    def body(i, R):
-        t = 255 - i
-        R = jacobian_double(*R)
-        Ra = jacobian_madd_complete(*R, gx, gy)
-        R = _select(bits_a[t] == 1, Ra, R)
-        Rb = jacobian_madd_complete(*R, px, py)
-        R = _select(bits_b[t] == 1, Rb, R)
-        return R
-
-    return lax.fori_loop(0, 256, body, _inf_like(px))
 
 
 @named_region("to_affine")

@@ -26,7 +26,7 @@ constants spread over a tile and the double-buffered operands
 The math is literally the same code — `fe_mul`, `jacobian_double`,
 `jacobian_add_complete`, ... are pure jnp functions over (20, ...) int32
 arrays and are called here on VMEM-resident values. Differences from the
-XLA path (`curve.double_scalar_mult` + `jax_backend._verify_kernel`):
+XLA path (`curve.double_scalar_mult_glv` + `jax_backend._verify_kernel`):
 
 - The final x-compare uses the reference's z²-scaled trick where
   possible, but lanes may also need R.y parity (Schnorr/taproot), so the

@@ -1,14 +1,20 @@
 """Standalone multi-chip sharding checks, run in a FRESH process.
 
-Same rationale as pallas_equality_check.py: the 8-device shard_map
-programs are among the largest compiles in the suite, and XLA:CPU
-intermittently segfaults compiling them late in a long-lived pytest
-process (observed inside backend_compile_and_load and in the
-compilation-cache read/write paths, with the persistent cache on AND
-off, with the native core on AND off — jaxlib-internal; the identical
-compile in a clean process always passes). test_parallel.py runs each
-check here in its own interpreter; the subprocess uses the persistent
-compile cache, so repeat runs are fast.
+Same rationale as pallas_equality_check.py: the shard_map programs are
+among the largest compiles in the suite, and XLA:CPU intermittently
+segfaults compiling them late in a long-lived pytest process (observed
+inside backend_compile_and_load and in the compilation-cache read/write
+paths, with the persistent cache on AND off, with the native core on AND
+off — jaxlib-internal; the identical compile in a clean process always
+passes). test_parallel.py runs the checks here in children of their own.
+
+Tier-1 compiles two mesh programs: the four-device step at 16 lanes (every
+check but `np2` and the `slow` `faultdomains`; the four-chip cell's mesh)
+and `np2`'s six-device one. The checks on four devices share one child,
+which keeps ONE jitted step a mesh (`_one_step_a_mesh`). Eight virtual
+devices still run in `contrib/test.sh` and CI
+(`__graft_entry__.dryrun_multichip(8)`), `scripts/multichip_run.py` and
+the `slow` `faultdomains`.
 
 Usage: python tests/mesh_checks.py {dryrun|sharded|np2|hostreject|faultdomains|connect|connectflip|packing}...
 Exit code 0 = every named check passed.
@@ -31,11 +37,13 @@ import hashlib  # noqa: E402
 
 import numpy as np  # noqa: E402
 
+from child_checks import warm_rung  # noqa: E402
+
 
 def check_dryrun() -> None:
     import __graft_entry__ as g
 
-    g.dryrun_multichip(8)
+    g.dryrun_multichip(4)
 
 
 def check_sharded() -> None:
@@ -45,7 +53,7 @@ def check_sharded() -> None:
     from bitcoinconsensus_tpu.parallel.mesh import ShardedSecpVerifier, make_mesh
 
     checks = []
-    for i in range(8):  # 8 lanes + 8 sentinels: the 16-lane step dryrun compiled
+    for i in range(8):  # 8 lanes + 4 sentinels: the 16-lane step dryrun compiled
         sk = (i * 7919 + 3) % (H.N - 1) + 1
         msg = hashlib.sha256(b"shard-%d" % i).digest()
         if i % 2:
@@ -61,7 +69,7 @@ def check_sharded() -> None:
                 msg = hashlib.sha256(b"other").digest()
             checks.append(SigCheck("ecdsa", (pub, sig, msg)))
 
-    sharded = ShardedSecpVerifier(make_mesh(8))
+    sharded = ShardedSecpVerifier(make_mesh(4))
     res, all_ok = sharded.verify_checks_with_verdict(checks)
     assert not all_ok  # lanes 4 and 5 are corrupted
     assert list(np.nonzero(~res)[0]) == [4, 5]
@@ -70,6 +78,7 @@ def check_sharded() -> None:
     res2, ok2 = sharded.verify_checks_with_verdict(good)
     assert res2.all() and ok2  # collective verdict from the psum step
 
+    warm_rung(16)  # the one-device program: wait for a worker that compiles it
     plain = TpuSecpVerifier().verify_checks(checks)
     assert np.array_equal(plain, res)
 
@@ -108,7 +117,10 @@ def check_hostreject() -> None:
         SigCheck("ecdsa", (H.pubkey_create(sk), H.sign_ecdsa(sk, msg), msg)),
         SigCheck("ecdsa", (b"\x02" + b"\x00" * 31, b"junk-not-der", msg)),
     ]
-    res, all_ok = ShardedSecpVerifier(make_mesh(8)).verify_checks_with_verdict(checks)
+    # min_batch=16: two checks on four devices would pad to an 8-lane step
+    # of their own; this is the 16-lane one the other checks compile
+    sharded = ShardedSecpVerifier(make_mesh(4), min_batch=16)
+    res, all_ok = sharded.verify_checks_with_verdict(checks)
     assert list(res) == [True, False]
     assert not all_ok
 
@@ -270,6 +282,7 @@ def check_connect() -> None:
     assert (n, lanes) == (15, 300)
     mesh_v = make_verifier(config)
     assert int(mesh_v.mesh.devices.size) == 4 and mesh_v.lane_capacity == 12
+    warm_rung(16)  # the one-device program: wait for a worker that compiles it
     base_v = TpuSecpVerifier(min_batch=16, chunk=16)
     dispatches = -(-lanes // mesh_v.lane_capacity)  # 25
 
@@ -386,6 +399,7 @@ def check_packing() -> None:
 
     config, _d = _worst_block()
     v = make_verifier(config)
+    warm_rung(16)  # `xla_lane_verdicts` below: wait for a worker that compiles it
     unpack = traced_unpack(v.mesh)
     # mixed kinds (ECDSA's parity is -1, don't-care); 12 lanes fill the four
     # shards, 7 leave the last one empty; one bad signature, one not
@@ -431,7 +445,28 @@ CHECKS = {
     "packing": check_packing,
 }
 
+def _one_step_a_mesh() -> None:
+    """Every `ShardedSecpVerifier` builds a jit object of its own for the
+    step, and a second one in a process traces, lowers and loads the same
+    program again: two minutes and more on the CPU (step 0 of PR 44: `dryrun`
+    225 s and `sharded` 146 s behind `hostreject`'s compile, on cache hits).
+    Here the process keeps one step a mesh, whoever builds the verifier: a
+    stand-in for a cache that belongs in `parallel/mesh.py` (ROADMAP D6);
+    until it is there, no tier-1 check runs two verifiers that each build
+    a step of their own, as two of them in one program do."""
+    import functools
+
+    from bitcoinconsensus_tpu.parallel import mesh as M
+
+    M.make_sharded_step = functools.lru_cache(maxsize=None)(M.make_sharded_step)
+
+
 if __name__ == "__main__":
     from child_checks import main
 
-    sys.exit(main(CHECKS, sys.argv[1:]))
+    _one_step_a_mesh()
+    # the checks that compare with the one-device program at 16 lanes find it
+    # loaded beside the step's compile, not behind it
+    names = sys.argv[1:]
+    compares = {"sharded", "connect", "connectflip", "packing"}.intersection(names)
+    sys.exit(main(CHECKS, names, beside=16 if compares else None))

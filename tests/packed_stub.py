@@ -47,6 +47,36 @@ def install_kernel(verifier, kernel):
     return verifier
 
 
+def host_lane_verdicts(fields, want_odd, parity, has_t2, neg1, neg2, valid):
+    """The kernel's verdict a lane in host integers, for the kernel's seven
+    arguments (`jax_backend._verify_kernel`'s docstring is the format):
+    lift P from `(px, want_odd)`, `R = a*G + (+-b1 +- lambda*b2)*P`, accept
+    `R.x == t1` (or `t1 + n` with `has_t2`) under the parity asked for. For
+    the tests whose assertion is about the driver around a dispatch of a
+    size the real kernel is not compiled at (`conftest.py`)."""
+    from bitcoinconsensus_tpu.crypto import secp_host as H
+    from bitcoinconsensus_tpu.ops.curve import LAMBDA
+
+    def le(b):
+        return int.from_bytes(bytes(b), "little")
+
+    ok = np.zeros(len(valid), dtype=bool)
+    for i in np.nonzero(valid)[0]:
+        a, b1, b2 = le(fields[i, 0]), le(fields[i, 1, :16]), le(fields[i, 1, 16:])
+        point = H.lift_x(le(fields[i, 2]), odd=want_odd[i] == 1)
+        if point is None:
+            continue
+        b = ((-b1 if neg1[i] == 1 else b1) + (-b2 if neg2[i] == 1 else b2) * LAMBDA) % H.N
+        r = H.G.mul(a).add(H.PointJ.from_affine(*point).mul(b)).to_affine()
+        if r is None:
+            continue
+        t1 = le(fields[i, 3])
+        ok[i] = (r[0] == t1 or (has_t2[i] == 1 and r[0] == t1 + H.N)) and (
+            parity[i] < 0 or (r[1] & 1) == (parity[i] == 1)
+        )
+    return ok
+
+
 def xla_lane_verdicts(*lanes):
     """The one-device XLA program's verdict a lane, for the kernel's seven
     arguments: the packed program at that many rows (the rungs

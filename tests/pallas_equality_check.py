@@ -1,20 +1,21 @@
 """Standalone pallas-vs-XLA equality checks, run in a FRESH process.
 
 Why a subprocess: the interpret-mode pallas compiles are the largest XLA
-programs in the suite (`verify_tiles` alone compiles for 7 minutes on an
-idle core), and XLA:CPU segfaults compiling (or cache-writing) them late
-in a long-lived pytest process that has already compiled ~100 other
-programs — reproducibly at `tests/test_pallas_kernel.py`, and
+programs in the suite (`verify_tiles` alone traces, lowers and compiles
+for 12 minutes on an idle core), and XLA:CPU segfaults compiling (or
+cache-writing) them late in a long-lived pytest process that has already
+compiled ~100 other programs — reproducibly at `tests/test_pallas_kernel.py`, and
 reproducibly NOT when the same compile runs in a clean process (the crash
 is inside jaxlib, with the native core disabled too).
 
 Checks that compile the same programs share a process: `small` and
-`collision` both run the 16-lane `_verify_kernel` and the `tile=16`
-interpret-mode `verify_tiles` (two sublane rows of 8 lanes, so the CPU
-walks a tile with S > 1), so `test_pallas_kernel.py` starts them as one
-child (`production`, the 512-lane tile of four rows of 128 lanes, is a
-`slow` test with a child of its own). Each check is reported by name on a line of its own, so each
-stays a test of its own.
+`collision` both run the `tile=16` interpret-mode `verify_tiles` (two
+sublane rows of 8 lanes, so the CPU walks a tile with S > 1) and compare
+it with the one-device XLA program at 16 lanes (`_xla_kernel`), so
+`test_pallas_kernel.py` starts them as one child with one large compile
+(`production`, the 512-lane tile of four rows of 128 lanes, is a `slow`
+test with a child of its own). Each check is reported by name on a line of
+its own, so each stays a test of its own.
 
 Usage: python tests/pallas_equality_check.py {small|production|collision}...
 Exit code 0 = every named check passed.
@@ -30,17 +31,21 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import numpy as np  # noqa: E402
 
+from child_checks import warm_rung  # noqa: E402
+from packed_stub import xla_lane_verdicts  # noqa: E402
 
-def _xla_kernel(*args):
-    """The XLA side as the verifier dispatches it: `_verify_kernel` under
-    jit. At 16 lanes that is a program the suite's workers compile for
-    themselves (`warm_kernel`'s second rung), so called after the Pallas side (minutes into the run) it
-    loads from the persistent cache; run op by op it would compile a
-    dozen scans of its own."""
-    import jax
-    from bitcoinconsensus_tpu.crypto.jax_backend import _verify_kernel
 
-    return jax.jit(_verify_kernel)(*args)
+def _xla_kernel(*lanes):
+    """The XLA side as the verifier dispatches it: the one-device packed
+    program on the kernel's seven arrays. At 16 lanes that is the executable
+    `warm_kernel`'s second rung puts into the persistent cache: this child
+    loads it from there on a second thread beside the Pallas side's compile
+    (`child_checks.warm_rung_beside`), so the call here finds it loaded, or
+    waits for a worker that is still compiling it; tier-1 compiles the XLA
+    kernel in no other form (`conftest.py`)."""
+    assert len(lanes[-1]) == 16
+    warm_rung(16)
+    return xla_lane_verdicts(*lanes)
 
 
 def check_small() -> None:
@@ -69,9 +74,7 @@ def check_small() -> None:
         tile=16, interpret=True,
     )
     got = np.asarray(got_ok)
-    want = np.asarray(
-        _xla_kernel(fields, want_odd, parity, has_t2, neg1, neg2, valid)
-    )
+    want = _xla_kernel(fields, want_odd, parity, has_t2, neg1, neg2, valid)
     assert not np.asarray(got_needs).any()  # no group-law deferrals here
     assert (got == want).all(), (got, want)
     bad = [2, 3, 4, 5, 10, 11, 13]
@@ -167,7 +170,7 @@ def check_collision() -> None:
     assert needs[0] and not ok[0], "collision lane must defer"
     assert not needs[1:15].any() and ok[1:15].all(), "others unaffected"
 
-    want = np.asarray(_xla_kernel(*args))
+    want = _xla_kernel(*args)
     assert want[:15].all()  # XLA complete kernel: collision resolves TRUE
 
 
@@ -180,4 +183,7 @@ CHECKS = {
 if __name__ == "__main__":
     from child_checks import main
 
-    sys.exit(main(CHECKS, sys.argv[1:]))
+    # `small` and `collision` compare with the 16-lane rung: it loads beside
+    # the interpret-mode compile, not behind it
+    names = sys.argv[1:]
+    sys.exit(main(CHECKS, names, beside=16 if "production" not in names else None))

@@ -357,14 +357,17 @@ def _p2wsh_multisig_item(m, n, sign_keys, seed, corrupt_first=False):
     return BatchItem(tx.serialize(), 0, VERIFY_ALL_LIBCONSENSUS, spk, amount)
 
 
-@pytest.mark.limit(600)  # the suite's only 256-lane dispatch: a cold compile
 def test_adversarial_multisig_oracle_work_is_bounded():
     """VERDICT r2 weak #7: an adversarial batch of maximally-misaligned
     deep CHECKMULTISIGs must stay bounded — the speculative pairing
     pre-record answers every cursor-reachable oracle read from the FIRST
     dispatch, so the whole batch resolves in <= 2 device dispatches and
     <= 2 interpretation passes per input, with verdicts (and exact
-    ScriptErrors for the failing lanes) bit-identical to the single API."""
+    ScriptErrors for the failing lanes) bit-identical to the single API.
+    The count is the driver's, so the 256-lane dispatch is answered by the
+    host stand-in: the EC kernel is not compiled at that size."""
+    from packed_stub import host_lane_verdicts, install_kernel
+
     from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
     from bitcoinconsensus_tpu.models.sigcache import (
         ScriptExecutionCache,
@@ -381,7 +384,9 @@ def test_adversarial_multisig_oracle_work_is_bounded():
         # aligned control lane
         _p2wsh_multisig_item(2, 3, [0, 1], "advok"),
     ]
-    verifier = TpuSecpVerifier(min_batch=8)
+    verifier = install_kernel(
+        TpuSecpVerifier(min_batch=8), lambda args, n: host_lane_verdicts(*args)
+    )
     dispatches = []
     orig = verifier.verify_checks
     orig_lanes = verifier.dispatch_lanes

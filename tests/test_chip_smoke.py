@@ -16,6 +16,7 @@ import pytest
 
 import chip_guard
 import chip_smoke
+from conftest import launch_times
 
 pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
 
@@ -48,6 +49,7 @@ def _json_lines(text):
 
 def test_legs_pass_at_tiny_size_on_the_top_rung(verifier, capsys):
     dev = chip_guard.device_info()
+    before = launch_times()
     assert chip_smoke.run(dev, seed=21, backend="xla", **TINY) == 0
     lines = _json_lines(capsys.readouterr().out)
     assert [l.get("leg") for l in lines[:3]] == [
@@ -59,7 +61,10 @@ def test_legs_pass_at_tiny_size_on_the_top_rung(verifier, capsys):
     assert lines[0]["cached_replay"]["dispatches"] == 0
     assert lines[1]["corrupted_block"]["reason"] == "block-validation-failed"
     assert lines[2]["shed"] == 0 and lines[2]["pending_after_close"] == 0
-    shapes = lines[3]["launch_seconds"]
+    # the gauge is the process's: the legs' shapes are the ones they changed
+    launched = {dict(labels)["padded"] for labels, secs in launch_times().items()
+                if before.get(labels) != secs}
+    shapes = [s for s in lines[3]["launch_seconds"] if str(s["padded"]) in launched]
     assert shapes and all(
         s["backend"] == "xla" and s["first_seconds"] is not None for s in shapes
     )

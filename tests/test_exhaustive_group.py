@@ -20,6 +20,8 @@ equivalent enumerates, not samples, the *branch space* of the complete
    ladder.
 """
 
+import random
+
 import numpy as np
 import pytest
 
@@ -234,22 +236,35 @@ def test_exhaustive_small_scalar_rectangle_through_glv_kernel():
     """Every (a, b) in [0, 24) x [0, 24) through the GLV double-scalar
     schedule in ONE batch: a·G + b·P vs the oracle. Covers all-zero
     windows, b = 0 (pure fixed-base), a = 0 (pure variable-base), and
-    the infinity join combinations exhaustively."""
+    the infinity join combinations exhaustively. Behind the rectangle, in
+    the same batch, full-width scalars on other points: every window of
+    both halves live, a cancelling pair, sparse digits, the top bits."""
     N1 = N2 = 24
     sk = 7  # P = 7·G, arbitrary small point
-    P_aff = H.G.mul(sk).to_affine()
-    combos = [(a, b) for a in range(N1) for b in range(N2)]
-    B = len(combos)
+    rng = random.Random(0xEC)
+    big = lambda: rng.randrange(1, H.N)  # noqa: E731
+    lanes = [(a, b, sk) for a in range(N1) for b in range(N2)] + [
+        (big(), big(), big()),                # generic
+        (0, big(), big()),                    # a = 0 (RG infinite)
+        (big(), 0, big()),                    # b = 0 (R infinite)
+        (5, H.N - 5, 1),                      # aG + bG = inf
+        (0x8000, 0x10, big()),                # sparse digits
+        ((1 << 256) % H.N, H.N - 1, big()),   # high bits set
+    ]
+    B = len(lanes)
 
     a_l = np.zeros((NLIMB, B), dtype=np.int32)
-    db1 = np.zeros(B, dtype=object)
-    px = np.stack([int_to_limbs(P_aff[0])] * B, axis=1).astype(np.int32)
-    py = np.stack([int_to_limbs(P_aff[1])] * B, axis=1).astype(np.int32)
+    px = np.zeros((NLIMB, B), dtype=np.int32)
+    py = np.zeros((NLIMB, B), dtype=np.int32)
     b1m = np.zeros((10, B), dtype=np.int32)
     b2m = np.zeros((10, B), dtype=np.int32)
     neg1 = np.zeros(B, dtype=bool)
     neg2 = np.zeros(B, dtype=bool)
-    for i, (a, b) in enumerate(combos):
+    points = {}
+    for i, (a, b, k) in enumerate(lanes):
+        if k not in points:
+            points[k] = tuple(int_to_limbs(c) for c in H.G.mul(k).to_affine())
+        px[:, i], py[:, i] = points[k]
         a_l[:, i] = int_to_limbs(a)
         a1, n1, a2, n2 = split_lambda(b)
         b1m[:, i] = int_to_limbs(a1, 10)
@@ -269,9 +284,9 @@ def test_exhaustive_small_scalar_rectangle_through_glv_kernel():
     x, y, _ = C.jacobian_to_affine(X, Y, Z, inf=out_inf)
     out_inf = np.asarray(out_inf)
     got = _affine_ints(x, y, out_inf)
-    for i, (a, b) in enumerate(combos):
-        k = (a + b * sk) % H.N
-        want = H.G.mul(k).to_affine() if k else None
-        assert (got[i] is None) == (want is None), (a, b, "infinity")
+    for i, (a, b, k) in enumerate(lanes):
+        r = (a + b * k) % H.N
+        want = H.G.mul(r).to_affine() if r else None
+        assert (got[i] is None) == (want is None), (a, b, k, "infinity")
         if want is not None:
-            assert got[i] == want, (a, b)
+            assert got[i] == want, (a, b, k)

@@ -1,4 +1,4 @@
-"""Kernel tests: batched group ops + double-scalar-mult vs the host oracle.
+"""Kernel tests: batched group ops vs the host oracle.
 
 Mirrors the reference's approach of exercising the whole group logic over
 adversarial cases (`secp256k1/src/tests_exhaustive.c`): every exceptional
@@ -11,8 +11,6 @@ import random
 
 import numpy as np
 
-import pytest
-
 from conftest import *  # noqa: F401,F403 (pins CPU platform before jax import)
 
 import jax
@@ -21,8 +19,6 @@ from bitcoinconsensus_tpu.crypto.secp_host import G, N, P, PointJ
 from bitcoinconsensus_tpu.ops.curve import (
     G_X,
     G_Y,
-    double_scalar_mult,
-    double_scalar_mult_bits,
     jacobian_add_complete,
     jacobian_double,
     jacobian_madd_complete,
@@ -141,54 +137,3 @@ def test_add_complete_all_branches():
     for a, b, f in cases:
         want.append(_oracle_affine(a.add(b if not f else PointJ.infinity())))
     assert got == want
-
-
-def _dsm_cases():
-    """(a, b, point) triples covering the windowed schedule's edge space."""
-    px, py = _rand_point()
-    qx, qy = _rand_point()
-    cases = [
-        (RNG.randrange(N), RNG.randrange(N), (px, py)),  # generic
-        (0, RNG.randrange(N), (px, py)),                 # a = 0 (RG infinite)
-        (RNG.randrange(N), 0, (qx, qy)),                 # b = 0 (R infinite)
-        (0, 0, (px, py)),                                # both zero -> inf
-        (1, 1, (G_X, G_Y)),                              # tiny scalars -> 2G
-        (5, N - 5, (G_X, G_Y)),                          # aG + bG = inf
-        (0x8000, 0x10, (qx, qy)),                        # sparse digits
-        ((1 << 256) % N, RNG.randrange(N), (px, py)),    # high bits set
-    ]
-    return cases
-
-
-def _pack_dsm(cases):
-    a = np.stack([int_to_limbs(c[0]) for c in cases], axis=-1).astype(np.int32)
-    b = np.stack([int_to_limbs(c[1]) for c in cases], axis=-1).astype(np.int32)
-    px = np.stack([int_to_limbs(c[2][0]) for c in cases], axis=-1).astype(np.int32)
-    py = np.stack([int_to_limbs(c[2][1]) for c in cases], axis=-1).astype(np.int32)
-    return a, b, px, py
-
-
-@pytest.mark.limit(600)  # a cold compile of minutes beside five other workers
-def test_double_scalar_mult_vs_oracle():
-    cases = _dsm_cases()
-    a, b, px, py = _pack_dsm(cases)
-    got = _unpack_affine(*jax.jit(double_scalar_mult)(a, b, px, py))
-    want = []
-    for av, bv, (x, y) in cases:
-        want.append(
-            _oracle_affine(G.mul(av).add(PointJ.from_affine(x, y).mul(bv)))
-        )
-    assert got == want
-
-
-@pytest.mark.limit(600)  # a cold compile of minutes beside five other workers
-def test_windowed_vs_bitwise_ladder():
-    """The production windowed schedule and the naive 256-step ladder are
-    independent programs; they must agree lane-for-lane."""
-    # All 8 cases: the lane count `test_double_scalar_mult_vs_oracle`
-    # compiled the windowed program for, so only the ladder is new here.
-    cases = _dsm_cases()
-    a, b, px, py = _pack_dsm(cases)
-    w = _unpack_affine(*jax.jit(double_scalar_mult)(a, b, px, py))
-    n = _unpack_affine(*jax.jit(double_scalar_mult_bits)(a, b, px, py))
-    assert w == n

@@ -42,10 +42,20 @@ pytestmark = pytest.mark.usefixtures("warm_kernel")  # conftest.py: first calls
 REF = load_reference_lib()
 
 
+@pytest.fixture(autouse=True)
+def default_verifier_on_a_warm_rung(monkeypatch):
+    """The batch engine's default verifier cuts a batch into 16-lane
+    dispatches of the real kernel, a rung `warm_kernel` has made: the stock
+    one would compile a 128-lane program for the corpus (`conftest.py`
+    holds a test to the warm rungs)."""
+    from bitcoinconsensus_tpu.crypto import jax_backend
+
+    monkeypatch.setattr(jax_backend, "_default", jax_backend.TpuSecpVerifier(chunk=16))
+
+
 # ---------------------------------------------------------------- corpus
 
 
-@pytest.mark.limit(600)  # the suite's only 128-lane dispatch: a cold compile
 def test_corpus_pins_hold_on_every_engine():
     """Every adversarial entry reproduces its pinned (ok, Error,
     ScriptError) triple on the python, batch/device and (when built)
