@@ -119,6 +119,19 @@ _SIGHASHES = _obs_counter(
     "again from a CHECKMULTISIG's record of its signatures (reused)",
     ("result",),
 )
+_SIGHASH_BYTES = _obs_counter(
+    "consensus_sighash_bytes_total",
+    "bytes of the preimages the native interpreter hashed for the ECDSA "
+    "message digests it computed, by kind (legacy: the whole transaction "
+    "an input; bip143)",
+    ("kind",),
+)
+_SIGHASH_SECONDS = _obs_counter(
+    "consensus_sighash_seconds_total",
+    "thread seconds the native interpreter spent building and hashing "
+    "those preimages, summed over its workers, by kind",
+    ("kind",),
+)
 _TAPROOT_HASHES = _obs_counter(
     "consensus_taproot_hash_total",
     "taproot hashes the native interpreter made: BIP 341 message digests "
@@ -593,6 +606,7 @@ class IdxFixpoint:
         # ahead of the walk, `walk_pairings` tried by the walk of the
         # interpretation each input's verdict was taken from
         self.multisig: Optional[Dict[str, int]] = None
+        self.sighash_bytes: Optional[int] = None  # ECDSA preimages hashed, at finish
         self._walk_pairings = 0
         self._round_walks = None  # the in-flight round's, by pending position
 
@@ -672,6 +686,11 @@ class IdxFixpoint:
         computed, reused = self.nsess.sighashes()
         _SIGHASHES.inc(computed, result="computed")
         _SIGHASHES.inc(reused, result="reused")
+        self.sighash_bytes = 0
+        for kind, (n_bytes, seconds) in self.nsess.sighash_work().items():
+            _SIGHASH_BYTES.inc(n_bytes, kind=kind)
+            _SIGHASH_SECONDS.inc(seconds, kind=kind)
+            self.sighash_bytes += n_bytes
         self.lanes = self.nsess.lane_kinds()
         for kind, n in self.lanes.items():
             _CHECKS_TOTAL.inc(n, kind=kind)

@@ -305,9 +305,9 @@ def _connect_block_native(
     begun and finished back to back, with no speculation (the view is
     written after the verdicts). `connect_block_stream` drives the same
     two halves with other blocks' halves in between. The lanes its
-    fixpoint sent, by kind, and its CHECKMULTISIG pairings (pre-recorded
-    ahead of the walk; tried by the walk) ride the `block.connect` span's
-    record (`sp`)."""
+    fixpoint sent, by kind, its CHECKMULTISIG pairings (pre-recorded
+    ahead of the walk; tried by the walk) and the preimage bytes its ECDSA
+    digests hashed ride the `block.connect` span's record (`sp`)."""
     run = _NativeConnect(
         block, coins, height, flags, verifier, check_pow, check_scripts,
         enforce_witness_commitment, pow_limit, sig_cache, script_cache,
@@ -317,6 +317,7 @@ def _connect_block_native(
     if run.lanes is not None:
         sp.attrs.update({f"lanes_{k}": n for k, n in run.lanes.items()})
         sp.attrs.update(run.multisig)
+        sp.attrs["sighash_bytes"] = run.sighash_bytes
     return res
 
 
@@ -378,6 +379,7 @@ class _NativeConnect:
         self._run = None  # the script phase's IdxFixpoint, once begun
         self.lanes = None  # the lanes it sent, by kind, once finished
         self.multisig = None  # its CHECKMULTISIG pairings, spec and walk, too
+        self.sighash_bytes = None  # and the ECDSA preimage bytes it hashed
         self._undo = None  # the speculative apply's undo record, until commit
         self._phase = phases_of(verifier)  # times nothing without a verifier
 
@@ -511,6 +513,7 @@ class _NativeConnect:
             self._run.finish()
             self.lanes = self._run.lanes
             self.multisig = self._run.multisig
+            self.sighash_bytes = self._run.sighash_bytes
             self._run.release()
             with self._phase("results"):
                 # ok/err are written on the live rows only; a hit passed

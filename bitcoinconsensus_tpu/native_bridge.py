@@ -105,6 +105,12 @@ def store_pool_bytes() -> int:
     return int(lib().nat_store_pool_bytes())
 
 
+def sha256_transform() -> str:
+    """Which SHA-256 compression the native core chose for this CPU at run
+    time (native/sha256.hpp): `sha-ni` or `generic`."""
+    return "sha-ni" if lib().nat_sha256_uses_sha_ni() else "generic"
+
+
 def why_absent() -> Optional[str]:
     """Why `available()` is False (None while the core is loaded)."""
     if os.environ.get("BITCOINCONSENSUS_TPU_NATIVE", "") in ("0", "off"):
@@ -146,8 +152,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 14:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 14)")
+        if L.nat_version() < 15:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 15)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -233,6 +239,10 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_call_walks.restype = ctypes.c_int64
         L.nat_session_sighashes.argtypes = [vp, i64p]
         L.nat_session_sighashes.restype = None
+        L.nat_session_sighash_work.argtypes = [vp, i64p]
+        L.nat_session_sighash_work.restype = None
+        L.nat_sha256_uses_sha_ni.argtypes = []
+        L.nat_sha256_uses_sha_ni.restype = ctypes.c_int32
         L.nat_session_lane_kinds.argtypes = [vp, i64p]
         L.nat_session_lane_kinds.restype = None
         L.nat_session_taproot_hashes.argtypes = [vp, i64p]
@@ -846,6 +856,20 @@ class NativeSession:
         out = (ctypes.c_int64 * 2)()
         lib().nat_session_sighashes(self._ptr, out)
         return int(out[0]), int(out[1])
+
+    SIGHASH_KINDS = ("legacy", "bip143")
+
+    def sighash_work(self) -> Dict[str, Tuple[int, float]]:
+        """What the digests `sighashes()` counts as computed cost so far, by
+        kind (`SIGHASH_KINDS`): (bytes of the preimages hashed, seconds of
+        thread time from building one to its double hash, which the core
+        counts in nanoseconds). A legacy preimage is the whole transaction
+        with the other inputs' scripts blanked; a BIP 143 one is the 156
+        bytes and the script code. Monotone over the session's life."""
+        out = (ctypes.c_int64 * 4)()
+        lib().nat_session_sighash_work(self._ptr, out)
+        return {k: (int(out[i]), int(out[2 + i]) / 1e9)
+                for i, k in enumerate(self.SIGHASH_KINDS)}
 
     LANE_KINDS = ("ecdsa", "schnorr", "tweak")
     TAPROOT_HASHES = ("sighash", "leaf", "branch", "tweak")

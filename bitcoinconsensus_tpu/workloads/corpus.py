@@ -237,7 +237,12 @@ def _cases_multisig_fanout() -> List[CorpusCase]:
 # quadratic_sighash — pre-BIP143 legacy inputs: every input's SIGHASH_ALL
 # serializes the ENTIRE transaction (interpreter.cpp:1577-1642), so a
 # K-input legacy tx hashes O(K²) bytes. BIP143 killed this for segwit;
-# legacy spends still pay it.
+# legacy spends still pay it. Pinned here at K = 16; the shape runs at size
+# (mainnet block 364292's: K = 5,569, 1.27 GB hashed a connect) in the
+# benchmark's cell `worst-block-quadratic.sighash`
+# (benchmarks/configs/worst-block-quadratic.json), and every hash type goes
+# through `connect_block` against the plain SignatureHash in
+# tests/test_quadratic_block.py.
 # --------------------------------------------------------------------------
 
 def _quadratic_tx(tag: str, k: int) -> Tuple[Tx, List[Tuple[int, bytes]]]:
@@ -256,6 +261,9 @@ def _quadratic_tx(tag: str, k: int) -> Tuple[Tx, List[Tuple[int, bytes]]]:
 
 
 def _cases_quadratic() -> List[CorpusCase]:
+    """The quadratic shape at pin size, 16 inputs. Where it runs at size:
+    the benchmark's cell `worst-block-quadratic.sighash` (one transaction of
+    5,569 inputs through `connect_block`, 228,404 bytes hashed an input)."""
     k = 16
     tx, outs = _quadratic_tx("quad16", k)
     raw = tx.serialize()

@@ -15,6 +15,7 @@
 // script/script.h:218-391 (CScriptNum).
 #pragma once
 
+#include <chrono>
 #include <cstdint>
 #include <cstring>
 #include <map>
@@ -704,8 +705,10 @@ inline Bytes serialize_script_code(const Bytes& sc) {
     return out;
 }
 
-inline void legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
-                           int hash_type, u8 out[32]) {
+// Both digests return the bytes of the preimage they hashed (0 for the
+// SIGHASH_SINGLE "one" digest, which hashes nothing).
+inline size_t legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
+                             int hash_type, u8 out[32]) {
     bool anyone = (hash_type & SH_ANYONECANPAY) != 0;
     int base = hash_type & 0x1F;
     bool hash_single = base == SH_SINGLE;
@@ -713,7 +716,7 @@ inline void legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
     if (hash_single && n_in >= tx.vout.size()) {
         std::memset(out, 0, 32);
         out[0] = 1;
-        return;
+        return 0;
     }
     Bytes s;
     put_u32(s, (u32)tx.version);
@@ -753,6 +756,7 @@ inline void legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
     put_u32(s, tx.locktime);
     put_u32(s, (u32)(i32)hash_type);
     sha256d(s.data(), s.size(), out);
+    return s.size();
 }
 
 // The tx-wide single-SHA aggregates + BIP143 doubles of a Precomp whose
@@ -817,8 +821,8 @@ inline void precompute(NTx& tx, const std::vector<NTxOut>* spent) {
     precompute_hashes(tx);
 }
 
-inline void bip143_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
-                           int hash_type, i64 amount, u8 out[32]) {
+inline size_t bip143_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
+                             int hash_type, i64 amount, u8 out[32]) {
     const Precomp& pc = tx.precomp;
     bool cacheready = pc.ready && pc.bip143_ready;
     u8 hash_prevouts[32] = {0}, hash_sequence[32] = {0}, hash_outputs[32] = {0};
@@ -872,6 +876,7 @@ inline void bip143_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
     put_u32(s, tx.locktime);
     put_u32(s, (u32)(i32)hash_type);
     sha256d(s.data(), s.size(), out);
+    return s.size();
 }
 
 // Returns false on invalid hash type / SINGLE out of range.
@@ -1216,6 +1221,12 @@ struct Session {
     // made (eval.hpp MultisigSigs); monotone, worker scratches summed in.
     i64 sighash_computed = 0;
     i64 sighash_reused = 0;
+    // What those digests cost, by kind (legacy, bip143): the bytes of the
+    // preimages hashed and the nanoseconds of thread time from building
+    // one to its double hash; monotone and summed like the two above.
+    enum : int { SK_LEGACY = 0, SK_BIP143, SK_COUNT };
+    i64 sighash_bytes[SK_COUNT] = {0, 0};
+    i64 sighash_ns[SK_COUNT] = {0, 0};
     // Taproot's hashing by this session's interpretations, monotone and
     // summed like the two above: BIP 341 digests (key path and tapscript),
     // and the commitment's tagged hashes (TapLeaf, TapBranch, TapTweak).
@@ -1311,12 +1322,17 @@ struct Checker {
     // function of the signature's hash-type byte and never of the key.
     void ecdsa_sighash(int hash_type, const Bytes& script_code, int sigversion,
                        u8 out[32]) {
-        if (sigversion == SV_WITNESS_V0) {
-            bip143_sighash(script_code, *tx, n_in, hash_type, amount, out);
-        } else {
-            legacy_sighash(script_code, *tx, n_in, hash_type, out);
-        }
-        if (sess) sess->sighash_computed++;
+        auto t0 = std::chrono::steady_clock::now();
+        int kind = sigversion == SV_WITNESS_V0 ? Session::SK_BIP143 : Session::SK_LEGACY;
+        size_t hashed =
+            kind == Session::SK_BIP143
+                ? bip143_sighash(script_code, *tx, n_in, hash_type, amount, out)
+                : legacy_sighash(script_code, *tx, n_in, hash_type, out);
+        if (!sess) return;
+        sess->sighash_computed++;
+        sess->sighash_bytes[kind] += (i64)hashed;
+        sess->sighash_ns[kind] += std::chrono::duration_cast<std::chrono::nanoseconds>(
+                                      std::chrono::steady_clock::now() - t0).count();
     }
 
     // OP_CHECKSIG's check: one signature, one key, one digest.

@@ -277,7 +277,8 @@ extern "C" {
 // 13: nat_block_coin_probes; the coin tables key on a fixed 36-byte outpoint
 //     under a salted hash (same symbols, another NView).
 // 14: nat_session_call_walks, nat_store_pool_bytes.
-int nat_version() { return 14; }
+// 15: nat_session_sighash_work, nat_sha256_uses_sha_ni.
+int nat_version() { return 15; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -1040,6 +1041,10 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
         const Session& sc = scratch[t];
         sess->sighash_computed += sc.sighash_computed;
         sess->sighash_reused += sc.sighash_reused;
+        for (int k = 0; k < Session::SK_COUNT; k++) {
+            sess->sighash_bytes[k] += sc.sighash_bytes[k];
+            sess->sighash_ns[k] += sc.sighash_ns[k];
+        }
         for (int k = 0; k < Session::TH_COUNT; k++)
             sess->taproot_hashes[k] += sc.taproot_hashes[k];
         std::vector<i32> remap(sc.uniq.size());
@@ -1094,6 +1099,26 @@ void nat_session_sighashes(void* s, i64* out) {
     auto* sess = static_cast<Session*>(s);
     out[0] = sess->sighash_computed;
     out[1] = sess->sighash_reused;
+}
+
+// What the digests of out[0] above cost so far, by kind: out[0], out[1] the
+// preimage bytes hashed for legacy and BIP 143 digests, out[2], out[3] the
+// nanoseconds of thread time spent building and hashing them.
+void nat_session_sighash_work(void* s, i64* out) {
+    auto* sess = static_cast<Session*>(s);
+    for (int k = 0; k < Session::SK_COUNT; k++) {
+        out[k] = sess->sighash_bytes[k];
+        out[Session::SK_COUNT + k] = sess->sighash_ns[k];
+    }
+}
+
+// 1 where SHA-256 runs on the CPU's SHA extensions, 0 on the generic transform.
+i32 nat_sha256_uses_sha_ni() {
+#ifdef NAT_SHA_NI_POSSIBLE
+    return sha_ni_available() ? 1 : 0;
+#else
+    return 0;
+#endif
 }
 
 // Lanes nat_session_uniq_lanes has prepped out of this session so far, by

@@ -53,6 +53,9 @@ REQUIRED_METRICS = [
     "consensus_uniq_checks_total",
     "consensus_prep_lanes_total",
     "consensus_sighash_total",
+    # what those digests cost: preimage bytes hashed and thread seconds, by kind
+    "consensus_sighash_bytes_total",
+    "consensus_sighash_seconds_total",
     "consensus_taproot_hash_total",
     # CHECKMULTISIG on the index path: the pairings pre-recorded ahead of
     # the key walk, and those the walk behind a returned verdict tried

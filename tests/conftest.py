@@ -94,6 +94,7 @@ _KERNEL_SCOPES = {
     "rungs-b": (
         "test_parallel",
         "test_worst_block",
+        "test_quadratic_block",
         "test_ops_sha256",
         "test_benchmark_contract",
         "test_chip_smoke",

@@ -81,6 +81,8 @@ READ = (
     "consensus_serving_batches_total",
     "consensus_serving_queue_wait_seconds",
     "consensus_serving_shed_total",
+    "consensus_sighash_bytes_total",
+    "consensus_sighash_seconds_total",
     "consensus_sighash_total",
     "consensus_span_duration_seconds",
     "consensus_stream_blocks_in_flight",
@@ -289,6 +291,18 @@ def test_sighash_results_are_the_ones_read(workload):
     results = {s["labels"]["result"]: s["value"]
                for s in snapshot["consensus_sighash_total"]["samples"]}
     assert results.get("computed", 0) > 0 and results.get("reused", 0) > 0, results
+
+
+def test_sighash_work_is_counted_by_kind(workload):
+    """`layers/sighash_kb_per_input.connect.py` and
+    `layers/sighash_mb_per_s.connect.py` sum
+    `consensus_sighash_bytes_total` and `consensus_sighash_seconds_total`
+    over their `kind` label. Every fixpoint raises both kinds; the
+    workload's P2WPKH connects hash BIP 143 digests, and time them."""
+    _, snapshot = workload
+    for name in ("consensus_sighash_bytes_total", "consensus_sighash_seconds_total"):
+        kinds = {s["labels"]["kind"]: s["value"] for s in snapshot[name]["samples"]}
+        assert set(kinds) == {"legacy", "bip143"} and kinds["bip143"] > 0, (name, kinds)
 
 
 def test_coin_probe_tables_are_the_ones_counted(workload):

@@ -1,4 +1,4 @@
-"""The per-layer readers PRs 36 to 42 added, each on a made-up `ctx`.
+"""The per-layer readers PRs 36 to 45 added, each on a made-up `ctx`.
 
 `benchmarks/tests` is not part of tier-1, and a reader runs for real only
 in a `--trace 1` run on the chip. Here every new reader gets a context
@@ -402,6 +402,66 @@ def test_walk_share_of_spec_divides_the_walk_by_the_band(walk, spec, want):
 def test_walk_share_of_spec_returns_none_with_nothing_to_read(ctx):
     assert reader("walk_share_of_spec.connect")(ctx) is None
 
+# -- the bytes behind the ECDSA digests, and the rate a thread hashes them at (PR 45) ------
+
+_SH_BYTES = "consensus_sighash_bytes_total"
+_SH_SECONDS = "consensus_sighash_seconds_total"
+
+
+def sighash_work_ctx(kind, legacy, bip143, thread_s, connects=3, inputs=5, names=(_SH_BYTES, _SH_SECONDS)):
+    """A window of `connects` connects of `inputs` inputs over which the two
+    labeled counters rose from made-up levels by (`legacy`, `bip143`) bytes
+    and `thread_s` seconds, split between the kinds; `names`: what the
+    program has."""
+    def snap(leg, seg, secs):
+        both = {_SH_BYTES: [("legacy", leg), ("bip143", seg)],
+                _SH_SECONDS: [("legacy", secs * 0.75), ("bip143", secs * 0.25)]}
+        return {n: {"samples": [{"labels": {"kind": k}, "value": v} for k, v in both[n]]}
+                for n in names}
+    return {"cell": "made-up", "trace": None, "driver": {
+        "kind": kind, "walls_s": [0.3] * connects, "n_inputs": inputs,
+        "counters_before": snap(9_000, 4_000, 2.0),
+        "counters_after": snap(9_000 + legacy, 4_000 + bip143, 2.0 + thread_s)}}
+
+
+@pytest.mark.parametrize("legacy,bip143,want", [
+    (15 * 228_404, 0, 228.404),   # the megatransaction: the whole transaction an input
+    (15 * 300, 15 * 182, 0.482),  # a block of small transactions, both kinds summed
+    (0, 0, 0.0),
+])
+def test_sighash_kb_per_input_divides_the_bytes_by_inputs_verified(legacy, bip143, want):
+    ctx = sighash_work_ctx("connect", legacy, bip143, 0.5)
+    assert reader("sighash_kb_per_input.connect")(ctx) == ms(want)
+
+
+@pytest.mark.parametrize("legacy,bip143,thread_s,want", [
+    (3_000_000_000, 0, 6.0, 500.0),     # 3 GB in six thread seconds
+    (1_000_000, 500_000, 0.003, 500.0),  # both kinds, bytes and seconds alike
+])
+def test_sighash_mb_per_s_divides_the_bytes_by_the_thread_seconds(legacy, bip143, thread_s, want):
+    ctx = sighash_work_ctx("connect", legacy, bip143, thread_s)
+    assert reader("sighash_mb_per_s.connect")(ctx) == ms(want)
+
+
+@pytest.mark.parametrize("metric,ctx", [
+    # the parent: neither counter; a program with the bytes and no clock
+    ("sighash_kb_per_input.connect", sighash_work_ctx("connect", 900, 0, 0.5, names=())),
+    ("sighash_mb_per_s.connect", sighash_work_ctx("connect", 900, 0, 0.5, names=())),
+    ("sighash_mb_per_s.connect", sighash_work_ctx("connect", 900, 0, 0.5, names=(_SH_BYTES,))),
+    # a window that spent no time hashing, one that timed no connect
+    ("sighash_mb_per_s.connect", sighash_work_ctx("connect", 0, 0, 0.0)),
+    ("sighash_kb_per_input.connect", sighash_work_ctx("connect", 900, 0, 0.5, connects=0)),
+    # another kind of cell, a window without snapshots
+    ("sighash_kb_per_input.connect", sighash_work_ctx("stream", 900, 0, 0.5)),
+    ("sighash_mb_per_s.connect", sighash_work_ctx("serve", 900, 0, 0.5)),
+    ("sighash_kb_per_input.connect", {"cell": "made-up", "trace": None,
+                                      "driver": {"kind": "connect", "walls_s": [0.3], "n_inputs": 5}}),
+    ("sighash_mb_per_s.connect", {"cell": "made-up", "trace": None,
+                                  "driver": {"kind": "connect", "walls_s": [0.3], "n_inputs": 5}}),
+])
+def test_sighash_work_readers_return_none_with_nothing_to_read(metric, ctx):
+    assert reader(metric)(ctx) is None
+
 
 _TILES = "consensus_dispatch_tiles_total"
 
@@ -465,7 +525,8 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
     by_name = {m["name"]: m for m in bench["per_layer"]}
     connect = ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "worst-block-mesh4.sigops",
                "taproot-block.cold",  # PR 39: a new cell is appended to a list, nothing else changed
-               "worst-block-multisig20.fanout"]  # PR 41
+               "worst-block-multisig20.fanout",  # PR 41
+               "worst-block-quadratic.sighash"]  # PR 45
     every = [w["name"] for w in bench["workloads"]]
     want = {
         "unphased_ms.connect": connect, "sig_cache_ms.connect": connect,
@@ -475,15 +536,16 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "trace_lower_s.setup": every, "compile_s.setup": every,
         "sighashes_per_input.connect":  # PR 38
             ["worst-block.sigops", "worst-block-mesh4.sigops", "taproot-block.cold",
-             "worst-block-multisig20.fanout"],
+             "worst-block-multisig20.fanout", "worst-block-quadratic.sighash"],
         # PR 39: the lanes by kind and the taproot hashes of the index path
-        "schnorr_lane_share.connect": ["taproot-block.cold", "tip-block.cold"],
+        "schnorr_lane_share.connect":
+            ["taproot-block.cold", "tip-block.cold", "worst-block-quadratic.sighash"],
         "tweak_lane_share.connect": ["taproot-block.cold"],
         "taphashes_per_input.connect": ["taproot-block.cold"],
         # PR 40: the coin tables' probes
         "coin_probes_per_input.connect":
             ["tip-block.cold", "tip-block.warm", "taproot-block.cold", "worst-block.sigops",
-             "worst-block-multisig20.fanout"],
+             "worst-block-multisig20.fanout", "worst-block-quadratic.sighash"],
         "coin_probes_per_input.stream": ["ibd-stream.cold"],
         # PR 41: the walk's share of the pre-recorded CHECKMULTISIG pairings
         "walk_share_of_spec.connect": ["worst-block-multisig20.fanout", "worst-block.sigops"],
@@ -493,7 +555,10 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "transfers_per_dispatch.stream": ["ibd-stream.cold"],
         "transfers_per_dispatch.connect":
             ["tip-block.cold", "tip-block.warm", "worst-block.sigops", "taproot-block.cold",
-             "worst-block-multisig20.fanout"],
+             "worst-block-multisig20.fanout", "worst-block-quadratic.sighash"],
+        # PR 45: the bytes behind the ECDSA digests, and the rate a thread hashes them at
+        "sighash_kb_per_input.connect": ["worst-block-quadratic.sighash", "tip-block.cold"],
+        "sighash_mb_per_s.connect": ["worst-block-quadratic.sighash"],
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name
