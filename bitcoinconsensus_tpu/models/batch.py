@@ -132,6 +132,14 @@ _SIGHASH_SECONDS = _obs_counter(
     "those preimages, summed over its workers, by kind",
     ("kind",),
 )
+_SIGHASH_TEMPLATES = _obs_counter(
+    "consensus_sighash_template_total",
+    "a transaction's blanked legacy serialisation, which its legacy "
+    "digests are hashed from as spans: built (laid down, once a "
+    "transaction whatever the thread count, once more where SIGHASH_NONE "
+    "or SIGHASH_SINGLE is also signed) and served (digests hashed from one)",
+    ("event",),
+)
 _TAPROOT_HASHES = _obs_counter(
     "consensus_taproot_hash_total",
     "taproot hashes the native interpreter made: BIP 341 message digests "
@@ -607,6 +615,8 @@ class IdxFixpoint:
         # interpretation each input's verdict was taken from
         self.multisig: Optional[Dict[str, int]] = None
         self.sighash_bytes: Optional[int] = None  # ECDSA preimages hashed, at finish
+        # legacy templates `built` and digests `served` from one, at finish
+        self.sighash_templates: Optional[Dict[str, int]] = None
         self._walk_pairings = 0
         self._round_walks = None  # the in-flight round's, by pending position
 
@@ -691,6 +701,9 @@ class IdxFixpoint:
             _SIGHASH_BYTES.inc(n_bytes, kind=kind)
             _SIGHASH_SECONDS.inc(seconds, kind=kind)
             self.sighash_bytes += n_bytes
+        self.sighash_templates = self.nsess.sighash_templates()
+        for event, n in self.sighash_templates.items():
+            _SIGHASH_TEMPLATES.inc(n, event=event)
         self.lanes = self.nsess.lane_kinds()
         for kind, n in self.lanes.items():
             _CHECKS_TOTAL.inc(n, kind=kind)

@@ -306,8 +306,9 @@ def _connect_block_native(
     written after the verdicts). `connect_block_stream` drives the same
     two halves with other blocks' halves in between. The lanes its
     fixpoint sent, by kind, its CHECKMULTISIG pairings (pre-recorded
-    ahead of the walk; tried by the walk) and the preimage bytes its ECDSA
-    digests hashed ride the `block.connect` span's record (`sp`)."""
+    ahead of the walk; tried by the walk), the preimage bytes its ECDSA
+    digests hashed and the legacy templates those were built and served
+    from ride the `block.connect` span's record (`sp`)."""
     run = _NativeConnect(
         block, coins, height, flags, verifier, check_pow, check_scripts,
         enforce_witness_commitment, pow_limit, sig_cache, script_cache,
@@ -318,6 +319,8 @@ def _connect_block_native(
         sp.attrs.update({f"lanes_{k}": n for k, n in run.lanes.items()})
         sp.attrs.update(run.multisig)
         sp.attrs["sighash_bytes"] = run.sighash_bytes
+        sp.attrs.update({f"sighash_template_{k}": n
+                         for k, n in run.sighash_templates.items()})
     return res
 
 
@@ -380,6 +383,7 @@ class _NativeConnect:
         self.lanes = None  # the lanes it sent, by kind, once finished
         self.multisig = None  # its CHECKMULTISIG pairings, spec and walk, too
         self.sighash_bytes = None  # and the ECDSA preimage bytes it hashed
+        self.sighash_templates = None  # from legacy templates built and served
         self._undo = None  # the speculative apply's undo record, until commit
         self._phase = phases_of(verifier)  # times nothing without a verifier
 
@@ -514,6 +518,7 @@ class _NativeConnect:
             self.lanes = self._run.lanes
             self.multisig = self._run.multisig
             self.sighash_bytes = self._run.sighash_bytes
+            self.sighash_templates = self._run.sighash_templates
             self._run.release()
             with self._phase("results"):
                 # ok/err are written on the live rows only; a hit passed

@@ -152,8 +152,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 15:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 15)")
+        if L.nat_version() < 16:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 16)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -862,14 +862,32 @@ class NativeSession:
     def sighash_work(self) -> Dict[str, Tuple[int, float]]:
         """What the digests `sighashes()` counts as computed cost so far, by
         kind (`SIGHASH_KINDS`): (bytes of the preimages hashed, seconds of
-        thread time from building one to its double hash, which the core
-        counts in nanoseconds). A legacy preimage is the whole transaction
-        with the other inputs' scripts blanked; a BIP 143 one is the 156
-        bytes and the script code. Monotone over the session's life."""
-        out = (ctypes.c_int64 * 4)()
-        lib().nat_session_sighash_work(self._ptr, out)
-        return {k: (int(out[i]), int(out[2 + i]) / 1e9)
+        thread time from a digest's first byte to its double hash, a legacy
+        template's build included, which the core counts in nanoseconds). A
+        legacy preimage is the whole transaction with the other inputs'
+        scripts blanked; a BIP 143 one is the 156 bytes and the script code.
+        Monotone over the session's life."""
+        out = self._sighash_counts()
+        return {k: (out[i], out[2 + i] / 1e9)
                 for i, k in enumerate(self.SIGHASH_KINDS)}
+
+    def _sighash_counts(self) -> List[int]:
+        out = (ctypes.c_int64 * 6)()
+        lib().nat_session_sighash_work(self._ptr, out)
+        return [int(v) for v in out]
+
+    TEMPLATE_EVENTS = ("built", "served")
+
+    def sighash_templates(self) -> Dict[str, int]:
+        """The blanked templates those legacy digests were hashed from
+        (`TEMPLATE_EVENTS`): `built`, the times a transaction laid its
+        template down (once, on the first legacy digest anyone asks of it,
+        whatever the thread count; once more where SIGHASH_NONE or
+        SIGHASH_SINGLE is also signed), and `served`, the digests hashed
+        from one (every legacy digest without SIGHASH_ANYONECANPAY but the
+        SIGHASH_SINGLE one that is the number one). Monotone over the
+        session's life."""
+        return dict(zip(self.TEMPLATE_EVENTS, self._sighash_counts()[4:]))
 
     LANE_KINDS = ("ecdsa", "schnorr", "tweak")
     TAPROOT_HASHES = ("sighash", "leaf", "branch", "tweak")

@@ -51,6 +51,7 @@ EOF
 python -m pytest \
     tests/test_native.py \
     tests/test_native_interp.py \
+    tests/test_legacy_template.py \
     tests/test_native_batch.py \
     tests/test_native_idx.py \
     tests/test_native_block.py \

@@ -768,6 +768,7 @@ inline void block_acct_fill(NBlock& blk, SpentOutputs& all, u32 flags,
             }
         }
         tx.precomp = Precomp();
+        tx.legacy.clear();
         tx.precomp.spent_outputs = std::move(spent);
         tx.precomp.spent_ready = true;
         precompute_hashes(tx);

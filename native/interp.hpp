@@ -15,6 +15,7 @@
 // script/script.h:218-391 (CScriptNum).
 #pragma once
 
+#include <atomic>
 #include <chrono>
 #include <cstdint>
 #include <cstring>
@@ -336,6 +337,39 @@ struct Precomp {
     u8 spent_digest[32] = {0};  // cache key over the registered prevouts
 };
 
+// The part of a legacy preimage (CTransactionSignatureSerializer's output)
+// that does not depend on which input signs, laid down once a transaction,
+// on the first legacy digest anyone asks of it, and hashed as spans by
+// `legacy_sighash`. `all`: for every input prevout (36) || 0x00 || sequence
+// (4), a stride of STRIDE bytes, then compact_size(n_out) || outputs.
+// `zero_seq`, what SIGHASH_NONE and SIGHASH_SINGLE sign: the same inputs
+// string with every sequence zero, and no outputs. The interpreter's workers
+// share an NTx (inputs of one transaction fall in several shards), so a
+// first asker builds under `mu` while the others wait for it; a reader
+// after `ready` takes no lock. Use never changes a string.
+struct NTx;
+struct LegacyTemplate {
+    static constexpr size_t STRIDE = 41, SCRIPT_AT = 36;
+    enum : int { EV_BUILT = 0, EV_SERVED, EV_COUNT };  // Session's counts
+    struct Blank {
+        std::atomic<bool> ready{false};
+        Bytes bytes;
+    };
+    std::mutex mu;
+    Blank all, zero_seq;
+
+    // The string a digest hashes from, built if this is its first asker;
+    // `events`, where given, counts the build and the serving.
+    inline const Bytes& get(const NTx& tx, bool zeroed, i64* events);
+
+    void clear() {
+        for (Blank* b : {&all, &zero_seq}) {
+            b->ready.store(false, std::memory_order_relaxed);
+            b->bytes.clear();
+        }
+    }
+};
+
 struct NTx {
     i32 version;
     std::vector<NTxIn> vin;
@@ -349,6 +383,7 @@ struct NTx {
     size_t span_lo, body_lo, body_hi;
     i64 ser_size;  // serialized size incl. witness (for the size check)
     Precomp precomp;
+    mutable LegacyTemplate legacy;  // cleared wherever `precomp` is
 
     bool has_witness() const {
         for (const auto& in : vin)
@@ -386,6 +421,35 @@ struct NTx {
         return b;
     }
 };
+
+inline const Bytes& LegacyTemplate::get(const NTx& tx, bool zeroed, i64* events) {
+    Blank& b = zeroed ? zero_seq : all;
+    if (events) events[EV_SERVED]++;
+    if (b.ready.load(std::memory_order_acquire)) return b.bytes;
+    std::lock_guard<std::mutex> lock(mu);
+    if (b.ready.load(std::memory_order_relaxed)) return b.bytes;
+    Bytes& s = b.bytes;
+    size_t outputs = 9;  // an upper bound: a compact size is nine bytes at most
+    if (!zeroed)
+        for (const NTxOut& out : tx.vout) outputs += 8 + 9 + out.spk.size();
+    s.reserve(STRIDE * tx.vin.size() + (zeroed ? 0 : outputs));
+    for (const NTxIn& in : tx.vin) {
+        s.insert(s.end(), in.prevout_hash, in.prevout_hash + 32);
+        put_u32(s, in.prevout_n);
+        s.push_back(0);  // the blanked script: an empty string
+        put_u32(s, zeroed ? 0 : in.sequence);
+    }
+    if (!zeroed) {
+        put_compact_size(s, tx.vout.size());
+        for (const NTxOut& out : tx.vout) {
+            put_i64(s, out.value);
+            put_string(s, out.spk);
+        }
+    }
+    if (events) events[EV_BUILT]++;
+    b.ready.store(true, std::memory_order_release);
+    return s;
+}
 
 // Exact mirror of UnserializeTransaction (transaction.h:187-224 /
 // core/tx.py _deserialize_from). Throws SerErr. Vectors grow
@@ -668,8 +732,9 @@ inline const TagMidstate& TAG_TAPTWEAK() {
     return t;
 }
 
-// SerializeScriptCode (core/sighash.py _serialize_script_code semantics).
-inline Bytes serialize_script_code(const Bytes& sc) {
+// SerializeScriptCode (core/sighash.py _serialize_script_code semantics),
+// written into the hash.
+inline void hash_script_code(Sha256& h, const Bytes& sc) {
     Span sp = span_of(sc);
     size_t n_codeseps = 0;
     size_t pos = 0;
@@ -680,35 +745,45 @@ inline Bytes serialize_script_code(const Bytes& sc) {
         if (!decode_op(sp, pos, opcode, &d, &dl)) break;
         if (opcode == OP_CODESEPARATOR) n_codeseps++;
     }
-    Bytes out;
-    put_compact_size(out, sc.size() - n_codeseps);
+    hash_compact_size(h, sc.size() - n_codeseps);
     size_t seg_start = 0;
     pos = 0;
     while (pos < sp.size()) {
         int opcode;
         const u8* d;
         size_t dl;
-        size_t before = pos;
         if (!decode_op(sp, pos, opcode, &d, &dl)) {
             // truncated push: decoder consumed opcode/length bytes only;
             // write the segment up to that point, drop the tail.
-            (void)before;
-            out.insert(out.end(), sp.p + seg_start, sp.p + pos);
-            return out;
+            h.write(sp.p + seg_start, pos - seg_start);
+            return;
         }
         if (opcode == OP_CODESEPARATOR) {
-            out.insert(out.end(), sp.p + seg_start, sp.p + pos - 1);
+            h.write(sp.p + seg_start, pos - 1 - seg_start);
             seg_start = pos;
         }
     }
-    if (seg_start != sp.size()) out.insert(out.end(), sp.p + seg_start, sp.p + sp.size());
-    return out;
+    h.write(sp.p + seg_start, sp.size() - seg_start);
+}
+
+// `n` outputs as SIGHASH_SINGLE blanks them: value -1, an empty script.
+inline void hash_blank_outputs(Sha256& h, size_t n) {
+    constexpr size_t EACH = 9, CHUNK = 64;  // 576 bytes: nine whole blocks
+    u8 blank[EACH * CHUNK];
+    std::memset(blank, 0xFF, sizeof blank);
+    for (size_t k = 0; k < CHUNK; k++) blank[EACH * k + 8] = 0;
+    for (; n >= CHUNK; n -= CHUNK) h.write(blank, sizeof blank);
+    h.write(blank, EACH * n);
 }
 
 // Both digests return the bytes of the preimage they hashed (0 for the
-// SIGHASH_SINGLE "one" digest, which hashes nothing).
+// SIGHASH_SINGLE "one" digest, which hashes nothing). The legacy one
+// builds no preimage: without ANYONECANPAY it hashes the transaction's
+// blanked template (LegacyTemplate; `events` counts its use) as the span
+// before this input's script, the script code, and the span after it; with
+// ANYONECANPAY there is one input, and its fields go in one by one.
 inline size_t legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_in,
-                             int hash_type, u8 out[32]) {
+                             int hash_type, u8 out[32], i64* events = nullptr) {
     bool anyone = (hash_type & SH_ANYONECANPAY) != 0;
     int base = hash_type & 0x1F;
     bool hash_single = base == SH_SINGLE;
@@ -718,45 +793,46 @@ inline size_t legacy_sighash(const Bytes& script_code, const NTx& tx, size_t n_i
         out[0] = 1;
         return 0;
     }
-    Bytes s;
-    put_u32(s, (u32)tx.version);
-    size_t n_inputs = anyone ? 1 : tx.vin.size();
-    put_compact_size(s, n_inputs);
-    for (size_t k = 0; k < n_inputs; k++) {
-        size_t i = anyone ? n_in : k;
-        const NTxIn& txin = tx.vin[i];
-        s.insert(s.end(), txin.prevout_hash, txin.prevout_hash + 32);
-        put_u32(s, txin.prevout_n);
-        if (i != n_in) {
-            put_compact_size(s, 0);
-        } else {
-            Bytes ssc = serialize_script_code(script_code);
-            put_bytes(s, ssc);
+    const NTxIn& own = tx.vin[n_in];
+    Sha256 h;
+    hash_u32(h, (u32)tx.version);
+    if (anyone) {
+        hash_compact_size(h, 1);
+        h.write(own.prevout_hash, 32);
+        hash_u32(h, own.prevout_n);
+        hash_script_code(h, script_code);
+        hash_u32(h, own.sequence);
+    } else {
+        constexpr size_t STRIDE = LegacyTemplate::STRIDE;
+        bool zeroed = hash_single || hash_none;  // the others' sequences
+        const Bytes& t = tx.legacy.get(tx, zeroed, events);
+        size_t at = STRIDE * n_in + LegacyTemplate::SCRIPT_AT;
+        hash_compact_size(h, tx.vin.size());
+        h.write(t.data(), at);
+        hash_script_code(h, script_code);
+        if (zeroed) {
+            hash_u32(h, own.sequence);
+            at += 4;
         }
-        if (i != n_in && (hash_single || hash_none)) {
-            put_u32(s, 0);
-        } else {
-            put_u32(s, txin.sequence);
-        }
+        h.write(t.data() + at + 1, t.size() - at - 1);  // SIGHASH_ALL: the outputs too
     }
-    size_t n_outputs;
-    if (hash_none) n_outputs = 0;
-    else if (hash_single) n_outputs = n_in + 1;
-    else n_outputs = tx.vout.size();
-    put_compact_size(s, n_outputs);
-    for (size_t i = 0; i < n_outputs; i++) {
-        if (hash_single && i != n_in) {
-            put_i64(s, -1);
-            put_compact_size(s, 0);
-        } else {
-            put_i64(s, tx.vout[i].value);
-            put_string(s, tx.vout[i].spk);
-        }
+    if (hash_none) {
+        hash_compact_size(h, 0);
+    } else if (hash_single) {
+        hash_compact_size(h, n_in + 1);
+        hash_blank_outputs(h, n_in);
+        hash_txout(h, tx.vout[n_in]);
+    } else if (anyone) {
+        hash_compact_size(h, tx.vout.size());
+        for (const NTxOut& o : tx.vout) hash_txout(h, o);
     }
-    put_u32(s, tx.locktime);
-    put_u32(s, (u32)(i32)hash_type);
-    sha256d(s.data(), s.size(), out);
-    return s.size();
+    hash_u32(h, tx.locktime);
+    hash_u32(h, (u32)(i32)hash_type);
+    size_t hashed = (size_t)h.bytes;
+    u8 once[32];
+    h.finalize(once);
+    sha256(once, 32, out);
+    return hashed;
 }
 
 // The tx-wide single-SHA aggregates + BIP143 doubles of a Precomp whose
@@ -811,6 +887,7 @@ inline void precompute_hashes(NTx& tx) {
 inline void precompute(NTx& tx, const std::vector<NTxOut>* spent) {
     Precomp& pc = tx.precomp;
     pc = Precomp();
+    tx.legacy.clear();
     // A prevout list is only usable when it has exactly one entry per
     // input (interpreter.cpp:1512 readiness contract); a wrong-length
     // list is ignored rather than indexed out of bounds.
@@ -1227,6 +1304,10 @@ struct Session {
     enum : int { SK_LEGACY = 0, SK_BIP143, SK_COUNT };
     i64 sighash_bytes[SK_COUNT] = {0, 0};
     i64 sighash_ns[SK_COUNT] = {0, 0};
+    // Blanked templates its legacy digests laid down (one a transaction, a
+    // second where NONE or SINGLE is also signed) and digests hashed from
+    // one (LegacyTemplate::EV_BUILT, EV_SERVED); monotone and summed alike.
+    i64 sighash_template[LegacyTemplate::EV_COUNT] = {0, 0};
     // Taproot's hashing by this session's interpretations, monotone and
     // summed like the two above: BIP 341 digests (key path and tapscript),
     // and the commitment's tagged hashes (TapLeaf, TapBranch, TapTweak).
@@ -1327,7 +1408,8 @@ struct Checker {
         size_t hashed =
             kind == Session::SK_BIP143
                 ? bip143_sighash(script_code, *tx, n_in, hash_type, amount, out)
-                : legacy_sighash(script_code, *tx, n_in, hash_type, out);
+                : legacy_sighash(script_code, *tx, n_in, hash_type, out,
+                                 sess ? sess->sighash_template : nullptr);
         if (!sess) return;
         sess->sighash_computed++;
         sess->sighash_bytes[kind] += (i64)hashed;

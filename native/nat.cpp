@@ -278,7 +278,8 @@ extern "C" {
 //     under a salted hash (same symbols, another NView).
 // 14: nat_session_call_walks, nat_store_pool_bytes.
 // 15: nat_session_sighash_work, nat_sha256_uses_sha_ni.
-int nat_version() { return 15; }
+// 16: nat_session_sighash_work also writes the legacy template's two counts.
+int nat_version() { return 16; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -1045,6 +1046,8 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
             sess->sighash_bytes[k] += sc.sighash_bytes[k];
             sess->sighash_ns[k] += sc.sighash_ns[k];
         }
+        for (int k = 0; k < LegacyTemplate::EV_COUNT; k++)
+            sess->sighash_template[k] += sc.sighash_template[k];
         for (int k = 0; k < Session::TH_COUNT; k++)
             sess->taproot_hashes[k] += sc.taproot_hashes[k];
         std::vector<i32> remap(sc.uniq.size());
@@ -1103,13 +1106,17 @@ void nat_session_sighashes(void* s, i64* out) {
 
 // What the digests of out[0] above cost so far, by kind: out[0], out[1] the
 // preimage bytes hashed for legacy and BIP 143 digests, out[2], out[3] the
-// nanoseconds of thread time spent building and hashing them.
+// nanoseconds of thread time spent on them, the legacy template's build
+// included; out[4], out[5] the legacy templates built and the digests
+// hashed from one.
 void nat_session_sighash_work(void* s, i64* out) {
     auto* sess = static_cast<Session*>(s);
     for (int k = 0; k < Session::SK_COUNT; k++) {
         out[k] = sess->sighash_bytes[k];
         out[Session::SK_COUNT + k] = sess->sighash_ns[k];
     }
+    for (int k = 0; k < LegacyTemplate::EV_COUNT; k++)
+        out[2 * Session::SK_COUNT + k] = sess->sighash_template[k];
 }
 
 // 1 where SHA-256 runs on the CPU's SHA extensions, 0 on the generic transform.

@@ -56,6 +56,8 @@ REQUIRED_METRICS = [
     # what those digests cost: preimage bytes hashed and thread seconds, by kind
     "consensus_sighash_bytes_total",
     "consensus_sighash_seconds_total",
+    # the blanked template a transaction's legacy digests are hashed from
+    "consensus_sighash_template_total",
     "consensus_taproot_hash_total",
     # CHECKMULTISIG on the index path: the pairings pre-recorded ahead of
     # the key walk, and those the walk behind a returned verdict tried

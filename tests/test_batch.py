@@ -443,6 +443,9 @@ def test_fixpoint_round_cap_exact_fallback():
         def sighash_work(self):
             return {"legacy": (0, 0), "bip143": (0, 0)}
 
+        def sighash_templates(self):
+            return {"built": 0, "served": 0}
+
         def lane_kinds(self):
             return {"ecdsa": 0, "schnorr": 0, "tweak": 0}
 

@@ -118,7 +118,11 @@ CHIP_ONLY = ("consensus_dispatch_tiles_total",)
 NO_SAMPLE_NEEDED = ZERO + MESH_STILL + CHIP_ONLY + ("consensus_serving_shed_total",)
 # `PERF.md` section 3 names it with no reader under `benchmarks/` yet (PR 35):
 # the pieces a mesh dispatch crosses the host-device seam in, by direction.
-NO_READER_YET = ("consensus_mesh_transfers_total",)
+NO_READER_YET = (
+    "consensus_mesh_transfers_total",
+    # PR 46: a transaction's blanked legacy template, built and served
+    "consensus_sighash_template_total",
+)
 # `PERF.md` section 6 reads it beside `compile_s.setup`, not as a metric: the
 # persistent compile cache's hits and the misses it wrote an entry for.
 # Registered is all a process that found every program in the cache shows.
@@ -303,6 +307,18 @@ def test_sighash_work_is_counted_by_kind(workload):
     for name in ("consensus_sighash_bytes_total", "consensus_sighash_seconds_total"):
         kinds = {s["labels"]["kind"]: s["value"] for s in snapshot[name]["samples"]}
         assert set(kinds) == {"legacy", "bip143"} and kinds["bip143"] > 0, (name, kinds)
+
+
+def test_sighash_template_events_are_the_ones_named(workload):
+    """`PERF.md` section 3 reads `consensus_sighash_template_total` by its
+    `event` label. Every fixpoint raises both events, by nothing where, as
+    in the workload, every digest is BIP 143's or BIP 341's; a template is
+    laid down to serve a digest, so `built` never passes `served`."""
+    _, snapshot = workload
+    events = {s["labels"]["event"]: s["value"]
+              for s in snapshot["consensus_sighash_template_total"]["samples"]}
+    assert set(events) == {"built", "served"}, events
+    assert 0 <= events["built"] <= events["served"], events
 
 
 def test_coin_probe_tables_are_the_ones_counted(workload):

@@ -16,7 +16,10 @@ and the reference (its own curve code). **The corruptions** are the
 benchmark driver's three. **The counter**:
 `consensus_sighash_bytes_total{kind="legacy"}` rises by exactly the
 reference's summed preimage lengths, and the `block.connect` span record
-carries the same number.
+carries the same number. `consensus_sighash_template_total{event}`: the
+transaction lays its blanked serialisation down once, and once more with
+the sequences zeroed for SIGHASH_NONE and SIGHASH_SINGLE, and every digest
+without SIGHASH_ANYONECANPAY is hashed from one of the two.
 """
 
 import hashlib
@@ -63,6 +66,7 @@ ACP = SIGHASH_ANYONECANPAY
 OP_DROP, OP_CODESEPARATOR, OP_CHECKSIG = b"\x75", b"\xab", b"\xac"
 
 BYTES, SECONDS = "consensus_sighash_bytes_total", "consensus_sighash_seconds_total"
+TEMPLATES = "consensus_sighash_template_total"
 
 
 def _sk(tag: str) -> int:
@@ -97,6 +101,10 @@ CASES = {
     "all-hybrid": (SIGHASH_ALL, "hybrid", "p2pkh"),
 }
 NAMES = list(CASES)
+# the digests hashed from the transaction's blanked template: every one
+# without SIGHASH_ANYONECANPAY but the one that is the number one
+SERVED = [n for n, (hash_type, _, _) in CASES.items()
+          if not hash_type & ACP and n != "single-past-the-outputs"]
 assert NAMES.index("single-anyonecanpay") < N_OUTPUTS <= NAMES.index("single-past-the-outputs")
 
 
@@ -194,8 +202,11 @@ class _Records:
 
 
 def _work() -> dict:
-    return {(name, kind): _total(name, kind=kind)
+    work = {(name, kind): _total(name, kind=kind)
             for name in (BYTES, SECONDS) for kind in ("legacy", "bip143")}
+    work.update({(TEMPLATES, event): _total(TEMPLATES, event=event)
+                 for event in ("built", "served")})
+    return work
 
 
 def connect(block, coins) -> dict:
@@ -283,6 +294,10 @@ def test_the_block_connects_and_counts_the_bytes_the_reference_hashed(connected)
     assert got["rose"][BYTES, "bip143"] == 0 == got["rose"][SECONDS, "bip143"]
     assert got["rose"][SECONDS, "legacy"] > 0
     assert got["span"]["sighash_bytes"] == hashed
+    # one template with the sequences as they are, one with them zeroed
+    assert got["rose"][TEMPLATES, "built"] == 2 == got["span"]["sighash_template_built"]
+    assert got["rose"][TEMPLATES, "served"] == len(SERVED) == 7
+    assert got["span"]["sighash_template_served"] == len(SERVED)
 
 
 # -- the driver's three corruptions ---------------------------------------------------
@@ -304,6 +319,10 @@ def test_a_corrupted_block_is_rejected_for_its_victim_three_ways(connected, corr
     hashed = sum(v.preimage_bytes for v in b["refs"])
     assert got["rose"][BYTES, "legacy"] == hashed + b["refs"][VICTIM].preimage_bytes
     assert got["span"]["sighash_bytes"] == got["rose"][BYTES, "legacy"]
+    # and from the template round one laid down: use never changes it
+    assert got["rose"][TEMPLATES, "built"] == 2
+    assert got["rose"][TEMPLATES, "served"] == len(SERVED) + 1
+    assert got["span"]["sighash_template_served"] == len(SERVED) + 1
 
 
 # -- the reference's digest against the program's two serialisers ------------------------
