@@ -121,9 +121,9 @@ _SIGHASHES = _obs_counter(
 )
 _SIGHASH_BYTES = _obs_counter(
     "consensus_sighash_bytes_total",
-    "bytes of the preimages the native interpreter hashed for the ECDSA "
-    "message digests it computed, by kind (legacy: the whole transaction "
-    "an input; bip143)",
+    "bytes the native interpreter fed to SHA-256 for the ECDSA message "
+    "digests it computed, by kind (legacy: the whole transaction an input, "
+    "less what a resumed digest's starting state had absorbed; bip143)",
     ("kind",),
 )
 _SIGHASH_SECONDS = _obs_counter(
@@ -137,8 +137,17 @@ _SIGHASH_TEMPLATES = _obs_counter(
     "a transaction's blanked legacy serialisation, which its legacy "
     "digests are hashed from as spans: built (laid down, once a "
     "transaction whatever the thread count, once more where SIGHASH_NONE "
-    "or SIGHASH_SINGLE is also signed) and served (digests hashed from one)",
+    "or SIGHASH_SINGLE is also signed), served (digests hashed from one) "
+    "and resumed (served digests that started from one of the SHA-256 "
+    "states it keeps every 4,096 bytes of their shared prefix)",
     ("event",),
+)
+_WORKER_SECONDS = _obs_counter(
+    "consensus_interpret_worker_seconds_total",
+    "busy seconds of the native interpreter's workers inside its index-mode "
+    "calls: sum (over the workers) and max (the slowest worker's), added a "
+    "call; max times the width over sum says how level the calls ended",
+    ("stat",),
 )
 _TAPROOT_HASHES = _obs_counter(
     "consensus_taproot_hash_total",
@@ -704,6 +713,8 @@ class IdxFixpoint:
         self.sighash_templates = self.nsess.sighash_templates()
         for event, n in self.sighash_templates.items():
             _SIGHASH_TEMPLATES.inc(n, event=event)
+        for stat, seconds in self.nsess.worker_seconds().items():
+            _WORKER_SECONDS.inc(seconds, stat=stat)
         self.lanes = self.nsess.lane_kinds()
         for kind, n in self.lanes.items():
             _CHECKS_TOTAL.inc(n, kind=kind)

@@ -307,8 +307,8 @@ def _connect_block_native(
     two halves with other blocks' halves in between. The lanes its
     fixpoint sent, by kind, its CHECKMULTISIG pairings (pre-recorded
     ahead of the walk; tried by the walk), the preimage bytes its ECDSA
-    digests hashed and the legacy templates those were built and served
-    from ride the `block.connect` span's record (`sp`)."""
+    digests hashed and the legacy templates those were built, served and
+    resumed from ride the `block.connect` span's record (`sp`)."""
     run = _NativeConnect(
         block, coins, height, flags, verifier, check_pow, check_scripts,
         enforce_witness_commitment, pow_limit, sig_cache, script_cache,
@@ -383,7 +383,7 @@ class _NativeConnect:
         self.lanes = None  # the lanes it sent, by kind, once finished
         self.multisig = None  # its CHECKMULTISIG pairings, spec and walk, too
         self.sighash_bytes = None  # and the ECDSA preimage bytes it hashed
-        self.sighash_templates = None  # from legacy templates built and served
+        self.sighash_templates = None  # from legacy templates built, served, resumed
         self._undo = None  # the speculative apply's undo record, until commit
         self._phase = phases_of(verifier)  # times nothing without a verifier
 

@@ -152,8 +152,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 16:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 16)")
+        if L.nat_version() < 17:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 17)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -241,6 +241,8 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_sighashes.restype = None
         L.nat_session_sighash_work.argtypes = [vp, i64p]
         L.nat_session_sighash_work.restype = None
+        L.nat_session_worker_ns.argtypes = [vp, i64p]
+        L.nat_session_worker_ns.restype = None
         L.nat_sha256_uses_sha_ni.argtypes = []
         L.nat_sha256_uses_sha_ni.restype = ctypes.c_int32
         L.nat_session_lane_kinds.argtypes = [vp, i64p]
@@ -865,29 +867,47 @@ class NativeSession:
         thread time from a digest's first byte to its double hash, a legacy
         template's build included, which the core counts in nanoseconds). A
         legacy preimage is the whole transaction with the other inputs'
-        scripts blanked; a BIP 143 one is the 156 bytes and the script code.
-        Monotone over the session's life."""
+        scripts blanked, and the bytes are those fed to SHA-256: a digest
+        that resumed from its template's grid (`sighash_templates`) does not
+        count what that state had absorbed; a BIP 143 one is the 156 bytes
+        and the script code. Monotone over the session's life."""
         out = self._sighash_counts()
         return {k: (out[i], out[2 + i] / 1e9)
                 for i, k in enumerate(self.SIGHASH_KINDS)}
 
     def _sighash_counts(self) -> List[int]:
-        out = (ctypes.c_int64 * 6)()
+        out = (ctypes.c_int64 * 7)()
         lib().nat_session_sighash_work(self._ptr, out)
         return [int(v) for v in out]
 
-    TEMPLATE_EVENTS = ("built", "served")
+    TEMPLATE_EVENTS = ("built", "served", "resumed")
 
     def sighash_templates(self) -> Dict[str, int]:
         """The blanked templates those legacy digests were hashed from
         (`TEMPLATE_EVENTS`): `built`, the times a transaction laid its
         template down (once, on the first legacy digest anyone asks of it,
         whatever the thread count; once more where SIGHASH_NONE or
-        SIGHASH_SINGLE is also signed), and `served`, the digests hashed
+        SIGHASH_SINGLE is also signed), `served`, the digests hashed
         from one (every legacy digest without SIGHASH_ANYONECANPAY but the
-        SIGHASH_SINGLE one that is the number one). Monotone over the
-        session's life."""
+        SIGHASH_SINGLE one that is the number one), and `resumed`, those of
+        the served that started from a SHA-256 state the template keeps
+        every 4,096 bytes of the stream its digests share (none in a
+        transaction under ~100 inputs: its stream has no such point).
+        Monotone over the session's life."""
         return dict(zip(self.TEMPLATE_EVENTS, self._sighash_counts()[4:]))
+
+    WORKER_STATS = ("sum", "max")
+
+    def worker_seconds(self) -> Dict[str, float]:
+        """What the interpreter's workers spent inside this session's
+        `verify_inputs_idx` calls (`WORKER_STATS`): their busy seconds
+        summed, and the slowest worker's, added a call; `max` times the
+        width over `sum` says how level the calls ended (1.0: every worker
+        was busy as long as the slowest). A call that ran on the caller's
+        thread counts as one worker. Monotone over the session's life."""
+        out = (ctypes.c_int64 * 2)()
+        lib().nat_session_worker_ns(self._ptr, out)
+        return {k: out[i] / 1e9 for i, k in enumerate(self.WORKER_STATS)}
 
     LANE_KINDS = ("ecdsa", "schnorr", "tweak")
     TAPROOT_HASHES = ("sighash", "leaf", "branch", "tweak")

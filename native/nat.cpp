@@ -146,8 +146,12 @@ i32 run_verify_input(Session* sess, NTx* tx, i32 n_in, i64 amount,
     return r.ok ? 1 : 0;
 }
 
-// The one fan-out of this file (spawn, join; no pool): fn(t, lo, hi) on
-// worker t of T over the contiguous shards [n*t/T, n*(t+1)/T) of [0, n).
+// The one spawn-and-join of this file (no pool): fn(t, lo, hi) on worker t
+// of T over the contiguous shards [n*t/T, n*(t+1)/T) of [0, n). That split
+// is the whole schedule where a row costs what its neighbour does and writes
+// rows of its own (uniq_lanes, uniq_digests); the interpreter's inputs do
+// not cost alike, so nat_verify_inputs_idx asks for one unit a worker
+// (n == T) and lets each draw its inputs from a shared cursor instead.
 // Workers get WORKER_STACK bytes, not the 8 MB default: glibc keeps only
 // 40 MB of exited threads' stacks, so from the sixth worker on every
 // spawn mapped a fresh stack and every exit unmapped one (0.3 ms a thread
@@ -279,7 +283,8 @@ extern "C" {
 // 14: nat_session_call_walks, nat_store_pool_bytes.
 // 15: nat_session_sighash_work, nat_sha256_uses_sha_ni.
 // 16: nat_session_sighash_work also writes the legacy template's two counts.
-int nat_version() { return 16; }
+// 17: and its third (`resumed`); nat_session_worker_ns.
+int nat_version() { return 17; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -977,27 +982,50 @@ void nat_verify_inputs(void* s, void** txs, const i32* n_ins,
 // cache digests, verdict publication, exact host fallback — reads it in
 // place. Python sees only int32 indices; no check bytes ever cross the
 // bridge twice. This is the TPU-era CCheckQueue fan-out
-// (checkqueue.h:29-163): `n_threads` shards the per-input interpretation
-// across worker threads that share the session's oracle read-only and
-// merge their discovered checks serially (order-preserving, so lane
-// order is deterministic regardless of thread count).
+// (checkqueue.h:29-163): `n_threads` workers share the session's oracle
+// read-only, draw blocks of consecutive inputs from one cursor, each into a
+// scratch session of its own, and a serial merge walks the inputs in index
+// order whoever interpreted them: uniq order, rec_idx and every per-input
+// array equal the single-threaded run's at any thread count and under any
+// timing, so lane order is deterministic.
+
+static inline i64 steady_ns() {
+    return std::chrono::duration_cast<std::chrono::nanoseconds>(
+               std::chrono::steady_clock::now().time_since_epoch()).count();
+}
 
 // Interpret inputs [lo, hi) against `sess` (which may be a worker
-// scratch whose `oracle` points at the shared session). Per-input
-// rec_idx bounds are recorded into local_bounds[lo..hi].
+// scratch whose `oracle` points at the shared session). rec_end[i] and,
+// where asked for, uniq_end[i] get the sizes of `sess`'s rec_idx and uniq
+// after input i: what i added lies between its predecessor's and its own.
 static void run_idx_range(Session* sess, void** txs, const i32* n_ins,
                           const i64* amounts, const u8* spk_blob,
                           const i64* spk_offs, const i32* flags, i32 lo,
                           i32 hi, i32* ok, i32* err, i32* unk, i64* walk,
-                          i64* local_bounds) {
+                          i64* rec_end, i64* uniq_end) {
     for (i32 i = lo; i < hi; i++) {
         ok[i] = run_verify_input(sess, static_cast<NTx*>(txs[i]), n_ins[i],
                                  amounts[i], spk_blob + spk_offs[i],
                                  spk_offs[i + 1] - spk_offs[i], flags[i],
                                  MODE_DEFER, &err[i], &unk[i], &walk[i]);
-        local_bounds[i + 1] = (i64)sess->rec_idx.size();
+        rec_end[i] = (i64)sess->rec_idx.size();
+        if (uniq_end) uniq_end[i] = (i64)sess->uniq.size();
     }
 }
+
+// Blocks a worker draws on average: enough that the last block anyone
+// draws is small against the phase (1/32 of a worker's even share where
+// inputs cost alike; in index order a long legacy transaction's dear
+// digests go first, so its tail is the cheap end), few enough that the
+// cursor's atomic add and a transaction changing hands stay unseen. Where
+// every worker still draws MIN_DRAWS blocks of them, a block is whole cache
+// lines of the per-input arrays (LINE_SLOTS four-byte slots): neighbouring
+// blocks belong to different workers, and smaller ones had all of them
+// writing every line of ok, err, unk and the merge's marks (3-5 % of the
+// workers' summed busy time on the chip's host, PR 47).
+constexpr i32 DRAWS_A_WORKER = 32;
+constexpr i32 MIN_DRAWS = 8;
+constexpr i32 LINE_SLOTS = 16;
 
 void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
                            const i64* amounts, const u8* spk_blob,
@@ -1013,33 +1041,50 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     pool.take(sess->uniq, true);
     rec_bounds[0] = 0;
     if (n_threads < 2 || n < 2 * n_threads) {
-        // rec_idx was just cleared, so per-input bounds are global bounds.
+        // rec_idx was just cleared, so an input's end is its global bound.
+        i64 t0 = steady_ns();
         run_idx_range(sess, txs, n_ins, amounts, spk_blob, spk_offs, flags, 0,
-                      n, ok, err, unk, walk, rec_bounds);
+                      n, ok, err, unk, walk, rec_bounds + 1, nullptr);
+        i64 ns = steady_ns() - t0;
+        sess->note_workers(ns, ns);
         return;
     }
     i32 T = n_threads;
     std::vector<Session> scratch((size_t)T);
-    std::vector<std::vector<i64>> bounds((size_t)T);
     for (i32 t = 0; t < T; t++) {
         scratch[t].index_mode = true;
         scratch[t].oracle = sess;
         pool.take(scratch[t].uniq, false);
-        bounds[t].assign((size_t)n + 1, 0);
     }
-    fan_out(n, T, [&](i32 t, i32 lo, i32 hi) {
-        // The scratch session's rec_idx is empty at entry, so the
-        // worker's bounds slots [lo+1, hi] are relative to 0.
-        run_idx_range(&scratch[t], txs, n_ins, amounts, spk_blob, spk_offs,
-                      flags, lo, hi, ok, err, unk, walk, bounds[t].data());
+    // By input: who interpreted it, and its scratch's rec_idx and uniq
+    // sizes after it. A worker draws ever later blocks, so its inputs lie
+    // in its scratch in index order.
+    std::vector<i32> owner((size_t)n);
+    std::vector<i64> rec_end((size_t)n), uniq_end((size_t)n), busy_ns((size_t)T, 0);
+    i32 block = std::max(1, n / (DRAWS_A_WORKER * T));
+    if (n >= LINE_SLOTS * MIN_DRAWS * T)
+        block = (block + LINE_SLOTS - 1) / LINE_SLOTS * LINE_SLOTS;
+    std::atomic<i32> cursor{0};
+    fan_out(T, T, [&](i32 t, i32, i32) {
+        i64 t0 = steady_ns();
+        for (;;) {
+            i32 lo = cursor.fetch_add(block, std::memory_order_relaxed);
+            if (lo >= n) break;
+            i32 hi = std::min(n, lo + block);
+            std::fill(owner.begin() + lo, owner.begin() + hi, t);
+            run_idx_range(&scratch[t], txs, n_ins, amounts, spk_blob,
+                          spk_offs, flags, lo, hi, ok, err, unk, walk,
+                          rec_end.data(), uniq_end.data());
+        }
+        busy_ns[(size_t)t] = steady_ns() - t0;
     });
-    // Serial merge in shard order: dedup each scratch's uniq into the
-    // shared session (a new entry's bytes are copied once, its hash is
-    // the scratch's), remap its rec_idx entries, and lay down global
-    // rec_bounds — identical discovery order to a single-threaded run
-    // over the same shard sequence.
-    for (i32 t = 0; t < T; t++) {
-        const Session& sc = scratch[t];
+    i64 busy_sum = 0, busy_max = 0;
+    for (i64 ns : busy_ns) {
+        busy_sum += ns;
+        busy_max = std::max(busy_max, ns);
+    }
+    sess->note_workers(busy_sum, busy_max);
+    for (const Session& sc : scratch) {
         sess->sighash_computed += sc.sighash_computed;
         sess->sighash_reused += sc.sighash_reused;
         for (int k = 0; k < Session::SK_COUNT; k++) {
@@ -1050,21 +1095,31 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
             sess->sighash_template[k] += sc.sighash_template[k];
         for (int k = 0; k < Session::TH_COUNT; k++)
             sess->taproot_hashes[k] += sc.taproot_hashes[k];
-        std::vector<i32> remap(sc.uniq.size());
-        for (size_t j = 0; j < sc.uniq.size(); j++)
-            remap[j] = sess->uniq.intern(sc.uniq.entries[j].hash,
-                                         sc.uniq.view(j),
-                                         sc.uniq.entries[j].spec);
-        i32 lo = (i32)((i64)n * t / T);
-        i32 hi = (i32)((i64)n * (t + 1) / T);
-        for (i32 i = lo; i < hi; i++) {
-            for (i64 j = bounds[t][(size_t)i]; j < bounds[t][(size_t)i + 1];
-                 j++)
-                sess->rec_idx.push_back(remap[(size_t)sc.rec_idx[(size_t)j]]);
-            rec_bounds[i + 1] = (i64)sess->rec_idx.size();
-        }
-        pool.give(std::move(scratch[t].uniq));
     }
+    // Serial merge in index order. Input i's worker first met the scratch
+    // entries between its previous input's uniq mark and i's own: they are
+    // interned into the shared session here (a new entry's bytes are copied
+    // once, its hash is the scratch's), the pre-recorded CHECKMULTISIG
+    // pairings no rec_idx names among them, then i's rec_idx entries are
+    // remapped. An entry a worker met at an earlier input was interned
+    // there, and one another worker met first was interned at that input
+    // if it comes earlier: discovery order is the single-threaded run's.
+    std::vector<std::vector<i32>> remap((size_t)T);
+    std::vector<i64> rec_at((size_t)T, 0);
+    for (i32 t = 0; t < T; t++) remap[(size_t)t].reserve(scratch[t].uniq.size());
+    for (i32 i = 0; i < n; i++) {
+        size_t t = (size_t)owner[(size_t)i];
+        const Session& sc = scratch[t];
+        std::vector<i32>& map = remap[t];
+        for (size_t j = map.size(); j < (size_t)uniq_end[(size_t)i]; j++)
+            map.push_back(sess->uniq.intern(sc.uniq.entries[j].hash,
+                                            sc.uniq.view(j),
+                                            sc.uniq.entries[j].spec));
+        for (i64& j = rec_at[t]; j < rec_end[(size_t)i]; j++)
+            sess->rec_idx.push_back(map[(size_t)sc.rec_idx[(size_t)j]]);
+        rec_bounds[i + 1] = (i64)sess->rec_idx.size();
+    }
+    for (Session& sc : scratch) pool.give(std::move(sc.uniq));
 }
 
 // Bytes of retired check stores parked for the next session (StorePool).
@@ -1105,10 +1160,10 @@ void nat_session_sighashes(void* s, i64* out) {
 }
 
 // What the digests of out[0] above cost so far, by kind: out[0], out[1] the
-// preimage bytes hashed for legacy and BIP 143 digests, out[2], out[3] the
+// bytes fed to SHA-256 for legacy and BIP 143 digests, out[2], out[3] the
 // nanoseconds of thread time spent on them, the legacy template's build
-// included; out[4], out[5] the legacy templates built and the digests
-// hashed from one.
+// included; out[4], out[5], out[6] the legacy templates built, the digests
+// hashed from one, and those of them resumed from a grid point.
 void nat_session_sighash_work(void* s, i64* out) {
     auto* sess = static_cast<Session*>(s);
     for (int k = 0; k < Session::SK_COUNT; k++) {
@@ -1117,6 +1172,15 @@ void nat_session_sighash_work(void* s, i64* out) {
     }
     for (int k = 0; k < LegacyTemplate::EV_COUNT; k++)
         out[2 * Session::SK_COUNT + k] = sess->sighash_template[k];
+}
+
+// What the interpreter's workers spent inside this session's index-mode
+// calls so far: out[0] their busy nanoseconds summed, out[1] the slowest
+// worker's, a call at a time (Session::worker_ns).
+void nat_session_worker_ns(void* s, i64* out) {
+    auto* sess = static_cast<Session*>(s);
+    out[0] = sess->worker_ns[0];
+    out[1] = sess->worker_ns[1];
 }
 
 // 1 where SHA-256 runs on the CPU's SHA extensions, 0 on the generic transform.

@@ -121,7 +121,10 @@ NO_SAMPLE_NEEDED = ZERO + MESH_STILL + CHIP_ONLY + ("consensus_serving_shed_tota
 NO_READER_YET = (
     "consensus_mesh_transfers_total",
     # PR 46: a transaction's blanked legacy template, built and served
+    # (PR 47: and resumed from its grid of SHA-256 states)
     "consensus_sighash_template_total",
+    # PR 47: the interpreter's workers' busy seconds, summed and the slowest's
+    "consensus_interpret_worker_seconds_total",
 )
 # `PERF.md` section 6 reads it beside `compile_s.setup`, not as a metric: the
 # persistent compile cache's hits and the misses it wrote an entry for.
@@ -311,14 +314,28 @@ def test_sighash_work_is_counted_by_kind(workload):
 
 def test_sighash_template_events_are_the_ones_named(workload):
     """`PERF.md` section 3 reads `consensus_sighash_template_total` by its
-    `event` label. Every fixpoint raises both events, by nothing where, as
-    in the workload, every digest is BIP 143's or BIP 341's; a template is
-    laid down to serve a digest, so `built` never passes `served`."""
+    `event` label. Every fixpoint raises the three events, by nothing where,
+    as in the workload, every digest is BIP 143's or BIP 341's; a template is
+    laid down to serve a digest, so `built` never passes `served`, and a
+    digest resumes from a template that serves it."""
     _, snapshot = workload
     events = {s["labels"]["event"]: s["value"]
               for s in snapshot["consensus_sighash_template_total"]["samples"]}
-    assert set(events) == {"built", "served"}, events
+    assert set(events) == {"built", "served", "resumed"}, events
     assert 0 <= events["built"] <= events["served"], events
+    assert 0 <= events["resumed"] <= events["served"], events
+
+
+def test_interpreter_worker_seconds_are_a_sum_and_its_largest_part(workload):
+    """`PERF.md` section 3 reads `consensus_interpret_worker_seconds_total`
+    by its `stat` label: the workers' busy seconds summed and the slowest
+    worker's, a call. Every fixpoint raises both; no worker is busy longer
+    than all of them together."""
+    _, snapshot = workload
+    stats = {s["labels"]["stat"]: s["value"]
+             for s in snapshot["consensus_interpret_worker_seconds_total"]["samples"]}
+    assert set(stats) == {"sum", "max"}, stats
+    assert 0 < stats["max"] <= stats["sum"], stats
 
 
 def test_coin_probe_tables_are_the_ones_counted(workload):

@@ -423,6 +423,111 @@ def test_threads_identical_with_duplicates_across_shards(
         assert np.asarray(mine).tobytes() == np.asarray(ref).tobytes()
 
 
+# -- the workers draw blocks of inputs from one cursor (PR 47) ------------------------
+
+_QUEUE_INPUTS = 2999
+
+
+def _fake_sig(tag: bytes, hash_type: int) -> bytes:
+    """DER in shape only: with no flag set nothing reads a signature before
+    the curve does, and a deferring round never asks the curve."""
+    r, s = hashlib.sha256(b"r" + tag).digest()[:20], hashlib.sha256(b"s" + tag).digest()[:20]
+    body = b"\x02\x14" + r + b"\x02\x14" + s
+    return b"\x30" + bytes([len(body)]) + body + bytes([hash_type])
+
+
+@functools.lru_cache(maxsize=None)
+def _queue_block():
+    """One legacy transaction of 2,999 inputs, two outputs: bare `<key>
+    CHECKSIG` and bare 2-of-3 CHECKMULTISIG coins in turn under ALL, NONE and
+    SINGLE, every key and signature distinct but one pair that every
+    seventh input shares under SIGHASH_SINGLE past the outputs, whose digest
+    is the number one: the same check, met by whichever workers draw them.
+    Its digests cost by position (PR 47: the later the input, the fewer
+    bytes), so no worker ends with the inputs it would have been dealt."""
+    key = lambda tag: b"\x02" + hashlib.sha256(b"idx-queue/key/%s" % tag).digest()
+    vin, spks = [], []
+    for i in range(_QUEUE_INPUTS):
+        hash_type = (SIGHASH_ALL, SIGHASH_NONE, SIGHASH_SINGLE)[i % 3]
+        if i % 7 == 0:
+            spks.append(push_data(key(b"shared")) + bytes([0xAC]))
+            script_sig = push_data(_fake_sig(b"shared", SIGHASH_SINGLE))
+        elif i % 2:
+            spks.append(push_data(key(b"%d" % i)) + bytes([0xAC]))
+            script_sig = push_data(_fake_sig(b"%d" % i, hash_type))
+        else:
+            spks.append(multisig_script(2, [key(b"%d/%d" % (i, k)) for k in range(3)]))
+            script_sig = b"\x00" + b"".join(
+                push_data(_fake_sig(b"%d/%d" % (i, k), hash_type)) for k in range(2))
+        vin.append(TxIn(OutPoint(hashlib.sha256(b"idx-queue/%d" % i).digest(), i), script_sig,
+                        0xFFFFFFF0))
+    tx = Tx(version=1, vin=vin, vout=[TxOut(1, b"\x51"), TxOut(2, b"\x52")], locktime=0)
+    return tx.serialize(), spks
+
+
+def _queue_rounds(n, n_threads):
+    """Two rounds of one session over the block's first `n` inputs, a verdict
+    published for every check of round one in between (a bit of its salted
+    digest: every run publishes the same): a round's verdict arrays, index
+    stream, walk counts, and the uniq list in its order."""
+    raw, spks = _queue_block()
+    ntx = native_bridge.NativeTx(raw)
+    ntx.precompute()
+    args = ([ntx] * n, list(range(n)), [0] * n, spks[:n], [0] * n)
+    sess, rounds = native_bridge.NativeSession(), []
+    for _round in range(2):
+        ok, err, unk, rec_idx, bounds = sess.verify_inputs_idx(*args, n_threads=n_threads)
+        uniq = _uniq_digests(sess)
+        rounds.append({"ok": ok.tolist(), "err": err.tolist(), "unk": unk.tolist(),
+                       "rec_idx": rec_idx.tolist(), "rec_bounds": bounds.tolist(),
+                       "call_walk": sess.call_walks(n).tolist(), "uniq": uniq,
+                       "spec_pairings": sess.spec_pairings()})
+        sess.publish_uniq(np.arange(len(uniq), dtype=np.int32),
+                          np.array([d[0] & 1 for d in uniq], dtype=bool))
+    return rounds, sess.worker_seconds()
+
+
+@pytest.fixture(scope="module")
+def queue_one_thread():
+    made = {}
+
+    def get(n):
+        if n not in made:
+            made[n] = _queue_rounds(n, 1)
+        return made[n]
+
+    return get
+
+
+# 26 is the fewest inputs thirteen workers take; none of the counts divides
+# into the blocks drawn (of 1 to 46 inputs, by n and the width)
+@pytest.mark.parametrize("n", [27, 131, _QUEUE_INPUTS])
+@pytest.mark.parametrize("n_threads", [1, 2, 5, 13])
+def test_workers_on_one_queue_leave_what_one_thread_leaves(queue_one_thread, n_threads, n):
+    (first, second), seconds = queue_one_thread(n)
+    assert seconds["sum"] == seconds["max"] > 0  # the caller's thread: one worker
+    # the block has what the merge must carry: pairings pre-recorded ahead of
+    # a CHECKMULTISIG's walk that no rec_idx entry names, and a check met again
+    referenced = set(first["rec_idx"])
+    assert 0 < len(first["uniq"]) - len(referenced) < first["spec_pairings"]
+    assert len(first["rec_idx"]) > len(referenced)
+    assert any(first["call_walk"]) and all(first["unk"]) and all(first["ok"])
+    # round two re-interprets against the published verdicts: some inputs
+    # fail now, walks go other ways, and nothing new is recorded
+    assert 0 < sum(second["ok"]) < n and not any(second["unk"])
+    assert second["uniq"] == first["uniq"] and second["call_walk"] != first["call_walk"]
+    shared = []
+    for _run in range(3):  # other timings, other owners of each block
+        got, seconds = _queue_rounds(n, n_threads)
+        for r, want in enumerate((first, second)):
+            for what, value in want.items():
+                assert got[r][what] == value, (r, what)
+        assert 0 < seconds["max"] <= seconds["sum"]
+        shared.append(seconds["max"] < seconds["sum"])
+    if n == _QUEUE_INPUTS and n_threads > 1:
+        assert any(shared)  # more than one worker drew inputs
+
+
 def test_release_frees_once_and_a_released_session_raises(monkeypatch):
     L = native_bridge.lib()
     freed = []
