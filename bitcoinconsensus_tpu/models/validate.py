@@ -19,7 +19,7 @@ they sit above `CheckInputScripts` in the reference.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import (
     Callable,
     Dict,
@@ -66,9 +66,12 @@ from .sigcache import ScriptExecutionCache, SigCache
 __all__ = [
     "Coin",
     "CoinsView",
+    "BlockUndo",
     "ConnectResult",
+    "DisconnectResult",
     "connect_block",
     "connect_block_stream",
+    "disconnect_block",
     "count_witness_sigops",
     "get_transaction_sigop_cost",
     "get_block_subsidy",
@@ -105,8 +108,22 @@ _COIN_PROBES = _obs_counter(
     "consensus_coin_probes_total",
     "hash-table probes (a find, an insert or an erase by outpoint) the "
     "native accounting and apply of a connected block made, by table: the "
-    "view, and pass 1's table of the block's own coins",
+    "view, and pass 1's table of the block's own coins; and `undo`: the "
+    "view's probes by a disconnect_block",
     ("table",),
+)
+# disconnect_block: how each call ended (validation.h DisconnectResult), and
+# the coins the clean ones moved.
+_DISCONNECTED = _obs_counter(
+    "consensus_blocks_disconnected_total",
+    "disconnect_block calls by result (ok, unclean, failed)",
+    ("result",),
+)
+_UNDO_COINS = _obs_counter(
+    "consensus_undo_coins_total",
+    "coins a clean disconnect_block moved: spent coins restored to the "
+    "view, outputs of the block removed from it",
+    ("what",),
 )
 _STREAM_IN_FLIGHT = _obs_histogram(
     "consensus_stream_blocks_in_flight",
@@ -207,12 +224,42 @@ def get_transaction_sigop_cost(
 
 
 @dataclass
+class BlockUndo:
+    """undo.h CBlockUndo for a Python `CoinsView`: a transaction at a
+    time, coinbase included (an empty list), the coins its inputs removed,
+    in input order, each with its outpoint's key. The native view's record
+    is `native_bridge.NativeBlockUndo`. `len` counts the coins."""
+
+    spent: List[List[Tuple[Tuple[bytes, int], Coin]]] = field(default_factory=list)
+
+    def __len__(self) -> int:
+        return sum(len(tx) for tx in self.spent)
+
+
+@dataclass
+class DisconnectResult:
+    """How a `disconnect_block` ended: `reason` is one of Core's three
+    outcomes (validation.h DisconnectResult), `"ok"`, `"unclean"` or
+    `"failed"`, and `ok` is the first. `restored` and `removed` count the
+    coins a clean disconnect moved; the others move none."""
+
+    ok: bool
+    reason: str
+    restored: int = 0
+    removed: int = 0
+
+
+@dataclass
 class ConnectResult:
     ok: bool
     reason: Optional[str] = None
     fees: int = 0
     sigop_cost: int = 0
     input_results: Optional[List[BatchResult]] = None
+    # The block's undo record, where the caller asked for one
+    # (`want_undo`) and the block was connected: what `disconnect_block`
+    # takes. None otherwise: a result with `ok` false carries no record.
+    undo: Optional[object] = None
 
     @property
     def script_failures(self) -> List[int]:
@@ -233,6 +280,7 @@ def connect_block(
     pow_limit: int = POW_LIMIT_MAINNET,
     sig_cache: Optional[SigCache] = None,
     script_cache: Optional[ScriptExecutionCache] = None,
+    want_undo: bool = False,
 ) -> ConnectResult:
     """Validate and apply one block against the UTXO view.
 
@@ -248,7 +296,10 @@ def connect_block(
     4. coinbase reward cap, then the view update (spend + add).
 
     The view is mutated only when every check passes. `flags` defaults to
-    the mainnet `height_to_flags(height, extended=True)` schedule.
+    the mainnet `height_to_flags(height, extended=True)` schedule. With
+    `want_undo` the apply keeps the coins it removes and an ok result
+    carries them (`ConnectResult.undo`): the record `disconnect_block`
+    needs to take the block off the tip again. It changes nothing else.
 
     Cycle collection is paused for the duration (utils/gcpause.py; see
     verify_batch) — the accounting loops over thousands of inputs
@@ -284,13 +335,13 @@ def connect_block(
             res = _connect_block_native(
                 block, coins, height, flags, verifier, check_pow,
                 check_scripts, enforce_witness_commitment, pow_limit,
-                sig_cache, script_cache, sp,
+                sig_cache, script_cache, sp, want_undo,
             )
         else:
             res = _connect_block_impl(
                 block, coins, height, flags, verifier, check_pow,
                 check_scripts, enforce_witness_commitment, pow_limit,
-                sig_cache, script_cache,
+                sig_cache, script_cache, want_undo,
             )
         _count_block(res)
     return res
@@ -299,6 +350,7 @@ def connect_block(
 def _connect_block_native(
     block, coins, height, flags, verifier, check_pow, check_scripts,
     enforce_witness_commitment, pow_limit, sig_cache, script_cache, sp,
+    want_undo=False,
 ) -> ConnectResult:
     """`connect_block` with the block layer in C++ (native/block.hpp) and
     the script phase on the index-mode session protocol: `_NativeConnect`
@@ -312,6 +364,7 @@ def _connect_block_native(
     run = _NativeConnect(
         block, coins, height, flags, verifier, check_pow, check_scripts,
         enforce_witness_commitment, pow_limit, sig_cache, script_cache,
+        want_undo,
     )
     run.begin()
     res = run.finish()
@@ -348,13 +401,17 @@ class _NativeConnect:
     `abandon()` for a block whose verdicts nobody will read), and owes the
     view the order: blocks applied later are rolled back first.
 
+    There is one apply, in `begin` or in `finish`. With `want_undo` it is
+    made with a record wherever it is made, and an ok result carries the
+    record (a speculative apply's, which `commit()` would drop, is kept).
+
     `result` is None until the connect has ended: a failure inside
     `begin()` sets it there (nothing was launched or applied)."""
 
     def __init__(
         self, block, coins, height, flags, verifier, check_pow,
         check_scripts, enforce_witness_commitment, pow_limit, sig_cache,
-        script_cache,
+        script_cache, want_undo=False,
     ):
         if flags is None:
             flags = height_to_flags(height, extended=True)
@@ -377,6 +434,7 @@ class _NativeConnect:
         self.check_scripts = check_scripts
         self.enforce_witness_commitment = enforce_witness_commitment
         self.sig_cache, self.script_cache = sig_cache, script_cache
+        self.want_undo = want_undo
         self.result: Optional[ConnectResult] = None
         self._nblk = None
         self._run = None  # the script phase's IdxFixpoint, once begun
@@ -543,13 +601,15 @@ class _NativeConnect:
                 if self._undo is None:
                     self._free_block()
                 return self.result
+        record = self._undo if self.want_undo else None
         if self._undo is None:  # not applied speculatively in begin
             with self._phase("apply"):
-                self.coins.apply_block(self._nblk, self.height)
+                record = self.coins.apply_block(
+                    self._nblk, self.height, undo=self.want_undo)
                 self._count_probes()
             self._free_block()
         self.result = ConnectResult(
-            True, None, self._fees, self._sigop_cost, input_results
+            True, None, self._fees, self._sigop_cost, input_results, record
         )
         return self.result
 
@@ -573,7 +633,8 @@ class _NativeConnect:
             self._run = None
 
     def commit(self) -> None:
-        """The speculative apply stands: drop its undo record."""
+        """The speculative apply stands: drop its undo record (the result
+        holds it on where the caller wanted it)."""
         self._free_block()
 
     def rollback(self) -> bool:
@@ -608,6 +669,7 @@ def connect_block_stream(
     pow_limit: int = POW_LIMIT_MAINNET,
     sig_cache: Optional[SigCache] = None,
     script_cache: Optional[ScriptExecutionCache] = None,
+    want_undo: bool = False,
 ) -> Iterator[ConnectResult]:
     """Connect successive blocks through one overlapped stream (initial
     block download: `ActivateBestChain -> ConnectTip -> ConnectBlock`).
@@ -622,7 +684,11 @@ def connect_block_stream(
     creates, so the view takes each block speculatively when it is begun
     and keeps the coins it spent (an undo record) until its verdicts are
     in. `connect_block` is the depth-1, unspeculative case of the same
-    two halves (`_NativeConnect`).
+    two halves (`_NativeConnect`). With `want_undo` every ok result
+    carries that record (`ConnectResult.undo`, what `disconnect_block`
+    takes) where the stream would drop it at the block's commit; it holds
+    its coins by value, so it stays sound when a later block of the stream
+    is rejected and rolled back.
 
     What a stream guarantees:
 
@@ -667,6 +733,7 @@ def connect_block_stream(
                 block, coins, start_height + k, flags_at(start_height + k),
                 verifier, check_pow, pow_limit=pow_limit,
                 sig_cache=sig_cache, script_cache=script_cache,
+                want_undo=want_undo,
             )
             _STREAM_BLOCKS.inc(result="ok" if res.ok else "reject")
             yield res
@@ -679,7 +746,7 @@ def connect_block_stream(
     def begin(block, height: int) -> _NativeConnect:
         run = _NativeConnect(
             block, coins, height, flags_at(height), verifier, check_pow,
-            True, None, pow_limit, sig_cache, script_cache,
+            True, None, pow_limit, sig_cache, script_cache, want_undo,
         )
         _STREAM_IN_FLIGHT.observe(len(window) + 1)
         with gc_paused(run._phase), _span("block.stream_begin", height=height):
@@ -753,6 +820,7 @@ def _count_block(res: ConnectResult) -> None:
 def _connect_block_impl(
     block, coins, height, flags, verifier, check_pow, check_scripts,
     enforce_witness_commitment, pow_limit, sig_cache, script_cache,
+    want_undo=False,
 ) -> ConnectResult:
     if flags is None:
         flags = height_to_flags(height, extended=True)
@@ -867,13 +935,140 @@ def _connect_block_impl(
                 False, "block-validation-failed", fees, sigop_cost, input_results
             )
 
-    # Phase 4: apply to the view (UpdateCoins, coins.cpp).
+    # Phase 4: apply to the view (UpdateCoins, coins.cpp), keeping the
+    # coins it removes where the caller wants the record.
+    record = BlockUndo() if want_undo else None
     for tx in block.vtx:
+        gone = []
         for txin in tx.vin:
             if not tx.is_coinbase():
-                coins.spend(txin.prevout)
+                coin = coins.spend(txin.prevout)
+                gone.append(((txin.prevout.hash, txin.prevout.n), coin))
         coins.add_tx(tx, height)
-    return ConnectResult(True, None, fees, sigop_cost, input_results)
+        if record is not None:
+            record.spent.append(gone)
+    return ConnectResult(True, None, fees, sigop_cost, input_results, record)
+
+
+def disconnect_block(
+    block: Union[bytes, Block],
+    coins: CoinsView,
+    undo,
+    height: int,
+    *,
+    verifier: Optional[TpuSecpVerifier] = None,
+) -> DisconnectResult:
+    """Take the block at the tip of the view off it again (validation.cpp
+    `DisconnectBlock`, the view half `DisconnectTip` flushes): `block` is
+    the block that was connected at `height`, raw bytes or a `Block`, and
+    `undo` the record its connect handed out (`ConnectResult.undo` under
+    `want_undo`; a `NativeBlockUndo` for a `NativeCoinsView`, a
+    `BlockUndo` for a Python `CoinsView`).
+
+    Transactions last to first: every output of the transaction must be
+    in the view exactly as the block made it (amount, script, height,
+    coinbase flag) and is removed; every coin the transaction spent is put
+    back, and nothing may stand where it goes. The outcomes are Core's:
+
+    - `"ok"`: the view is the view before the block's connect, coin for
+      coin.
+    - `"unclean"`: the view is not where this block left it (an output
+      gone or different, a coin in a restored coin's place): a block
+      disconnected out of order, or twice. The view is untouched.
+    - `"failed"`: the record is not this block's (its transaction count,
+      a transaction's input count or a coin's outpoint disagrees). The
+      view is untouched.
+
+    Blocks are disconnected newest first. No cache is consulted or
+    changed (Core's disconnect touches neither), nothing is verified and
+    nothing reaches the device; `verifier` lends its phase clock only
+    (`parse`, `undo_check`, `undo`, `block_free`). Unlike Core, outputs it
+    would call unspendable are checked and removed too (this view holds
+    them), and there are no BIP30 height exceptions.
+    """
+    from .. import native_bridge
+
+    phase = phases_of(verifier)
+    with _span("block.disconnect", height=height) as sp:
+        if (
+            isinstance(coins, native_bridge.NativeCoinsView)
+            and native_bridge.available()
+        ):
+            if not isinstance(undo, native_bridge.NativeBlockUndo):
+                raise TypeError("a NativeCoinsView takes a NativeBlockUndo")
+            with phase("parse"):
+                nblk = native_bridge.NativeBlock(
+                    block if isinstance(block, (bytes, bytearray))
+                    else block.serialize())
+            with phase("undo_check"):
+                matches = undo.matches(nblk)
+            reason, probes, restored, removed = "failed", 0, 0, 0
+            if matches:
+                with phase("undo"):
+                    reason, probes, restored, removed = (
+                        coins.disconnect_block(nblk, undo, height, checked=True))
+                _COIN_PROBES.inc(probes, table="undo")
+            with phase("block_free"):
+                nblk = None
+        else:
+            if not isinstance(undo, BlockUndo):
+                raise TypeError("a Python CoinsView takes a BlockUndo")
+            with phase("parse"):
+                if isinstance(block, (bytes, bytearray)):
+                    block = Block.deserialize(bytes(block))
+            reason, restored, removed = _disconnect_block_impl(
+                block, coins, undo, height, phase)
+        res = DisconnectResult(reason == "ok", reason, restored, removed)
+        _DISCONNECTED.inc(result=reason)
+        if res.ok:
+            _UNDO_COINS.inc(restored, what="restored")
+            _UNDO_COINS.inc(removed, what="removed")
+        sp.attrs.update(result=reason, restored=restored, removed=removed)
+    return res
+
+
+def _disconnect_block_impl(block, coins, undo, height, phase):
+    """`disconnect_block` on a Python `CoinsView`: the native path's
+    semantics (native/block.hpp view_disconnect_block), step for step."""
+    txs = block.vtx
+    with phase("undo_check"):
+        if len(undo.spent) != len(txs):
+            return "failed", 0, 0
+        for tx, gone in zip(txs, undo.spent):
+            want = [] if tx.is_coinbase() else [
+                (i.prevout.hash, i.prevout.n) for i in tx.vin]
+            if [key for key, _ in gone] != want:
+                return "failed", 0, 0
+    with phase("undo"):
+        steps: list = []  # (key, the coin taken out, or None for one put in)
+
+        def take_back():
+            for key, coin in reversed(steps):
+                if coin is None:
+                    del coins._map[key]
+                else:
+                    coins._map[key] = coin
+            return "unclean", 0, 0
+
+        restored = removed = 0
+        for tx, gone in zip(reversed(txs), reversed(undo.spent)):
+            cb = tx.is_coinbase()
+            for n, out in enumerate(tx.vout):
+                key = (tx.txid, n)
+                coin = coins._map.get(key)
+                if (coin is None or coin.out.value != out.value
+                        or coin.out.script_pubkey != out.script_pubkey
+                        or coin.height != height or coin.coinbase != cb):
+                    return take_back()
+                steps.append((key, coins._map.pop(key)))
+                removed += 1
+            for key, coin in reversed(gone):
+                if key in coins._map:
+                    return take_back()
+                coins._map[key] = coin
+                steps.append((key, None))
+                restored += 1
+    return "ok", restored, removed
 
 
 def overlay_tx_outputs(

@@ -152,8 +152,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 17:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 17)")
+        if L.nat_version() < 18:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 18)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -315,10 +315,15 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_view_apply_block_undo.restype = vp
         L.nat_view_undo_block.argtypes = [vp, vp, vp]
         L.nat_view_undo_block.restype = ctypes.c_int32
+        L.nat_view_disconnect_block.argtypes = [
+            vp, vp, vp, ctypes.c_int64, ctypes.c_int32, i64p]
+        L.nat_view_disconnect_block.restype = ctypes.c_int32
+        L.nat_undo_matches_block.argtypes = [vp, vp]
+        L.nat_undo_matches_block.restype = ctypes.c_int32
         L.nat_undo_len.argtypes = [vp]
         L.nat_undo_len.restype = ctypes.c_int64
         L.nat_undo_free.argtypes = [vp]
-        L.nat_view_digest.argtypes = [vp, u8p]
+        L.nat_view_digest.argtypes = [vp, u8p, ctypes.c_int32]
         _lib = L
         return _lib
 
@@ -1378,17 +1383,44 @@ class NativeCoinsView:
         if not lib().nat_view_undo_block(self._ptr, blk._ptr, undo._ptr):
             raise ValueError("undo record was not made from this block")
 
+    def disconnect_block(self, blk: NativeBlock, undo: "NativeBlockUndo",
+                         height: int, checked: bool = False,
+                         ) -> Tuple[str, int, int, int]:
+        """DisconnectBlock's view half with its checks, for a block that
+        was applied at `height` with the record `undo`: `blk` is any parse
+        of the same raw bytes, as Core reads block and record back from
+        disk. Returns (`DISCONNECT_RESULTS` outcome, the view's probes,
+        coins restored, outputs removed). `"failed"`: the record is not
+        this block's (a count or an outpoint disagrees); `"unclean"`: an
+        output of the block is not in the view as the block made it, or a
+        coin stands where a spent one returns; the view is written on
+        `"ok"` alone. The record keeps its coins. `checked`: the caller
+        has `undo.matches(blk)` already (it times the two halves apart) and
+        the record is not held against the block a second time."""
+        out = np.zeros(3, dtype=np.int64)
+        code = lib().nat_view_disconnect_block(
+            self._ptr, blk._ptr, undo._ptr, height, int(bool(checked)),
+            out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        return DISCONNECT_RESULTS[code], int(out[0]), int(out[1]), int(out[2])
+
     def digest(self) -> bytes:
         """32 bytes over every coin, independent of order: with `len`,
-        equal exactly when two views hold the same coins."""
+        equal exactly when two views hold the same coins. The native call
+        cuts the map's buckets over one worker a 65,536 coins, up to the
+        host's cores (a million coins take a third of a second on one)."""
         out = np.zeros(32, np.uint8)
-        lib().nat_view_digest(self._ptr, _u8p(out))
+        lib().nat_view_digest(self._ptr, _u8p(out), os.cpu_count() or 1)
         return out.tobytes()
+
+
+# native/block.hpp DisconnectResult, by code (validation.h DisconnectResult).
+DISCONNECT_RESULTS = ("ok", "unclean", "failed")
 
 
 class NativeBlockUndo:
     """The coins one `apply_block` removed from a view (native/block.hpp
-    NBlockUndo; undo.h CBlockUndo). `len` counts them."""
+    NBlockUndo; undo.h CBlockUndo), each with its outpoint, by value: the
+    record outlives the parsed block it was made from. `len` counts them."""
 
     __slots__ = ("_ptr",)
 
@@ -1406,6 +1438,13 @@ class NativeBlockUndo:
 
     def __len__(self) -> int:
         return int(lib().nat_undo_len(self._ptr))
+
+    def matches(self, blk: NativeBlock) -> bool:
+        """Whether this record was made from a block of `blk`'s
+        transactions: their count, each one's input count, every coin's
+        outpoint (DisconnectBlock's DISCONNECT_FAILED checks; the view is
+        not looked at)."""
+        return bool(lib().nat_undo_matches_block(self._ptr, blk._ptr))
 
 
 class NativeSecp:

@@ -68,7 +68,7 @@ using Hash32 = std::array<u8, 32>;
 
 // A coin's key, everywhere a coin is keyed (the view, a block's own table,
 // the undo record, the duplicate-input check): the 36 bytes txid[32] || n
-// little-endian, held inline. view_digest hashes exactly these bytes.
+// little-endian, held inline. The view's digest hashes exactly these bytes.
 struct NOutPoint {
     u8 b[36];
 
@@ -873,27 +873,137 @@ inline bool view_undo_block(NView& view, const NBlock& blk,
     return true;
 }
 
+// DisconnectBlock (validation.cpp) with its checks, for a caller who was
+// handed a block's record (`apply` with `undo`) and now takes the block off
+// the tip: the outcomes are Core's three.
+enum DisconnectResult : i32 {
+    DISCONNECT_OK = 0,
+    DISCONNECT_UNCLEAN = 1,  // the view is not where this block left it
+    DISCONNECT_FAILED = 2,   // the record is not this block's
+};
+
+// What a disconnect did, for the caller's counters: the view's probes (a
+// find with its erase, or an insert, by outpoint), the coins put back and
+// the outputs taken out.
+struct DisconnectStats {
+    i64 probes = 0, restored = 0, removed = 0;
+};
+
+// The record against the block, before the view is looked at (FAILED in
+// Core wherever a count disagrees; here also where a coin of the record is
+// not the one the block's input names: this record keeps each coin's
+// outpoint, Core's takes it from the block, and a coin restored under
+// another block's outpoint would pass as clean). A record that holds an
+// overwritten coin was not made behind the BIP30 scan of a connect.
+inline bool undo_matches_block(const NBlock& blk, const NBlockUndo& undo) {
+    size_t n_tx = blk.vtx.size();
+    if (undo.spent_end.size() != n_tx || undo.replaced_end.size() != n_tx ||
+        !undo.replaced.empty())
+        return false;
+    size_t at = 0;
+    for (size_t t = 0; t < n_tx; t++) {
+        const NTx& tx = *blk.vtx[t];
+        size_t want = tx_is_coinbase(tx) ? 0 : tx.vin.size();
+        if (undo.spent_end[t] != at + want || undo.spent_end[t] > undo.spent.size())
+            return false;
+        for (size_t i = 0; i < want; i++, at++)
+            if (!(undo.spent[at].key ==
+                  NView::key(tx.vin[i].prevout_hash, tx.vin[i].prevout_n)))
+                return false;
+    }
+    return at == undo.spent.size();
+}
+
+// The view half, transactions last to first as DisconnectBlock goes: each
+// output of the transaction has to be in the view as the block made it
+// (value, script, `height`, coinbase flag) and is taken out; each coin the
+// transaction spent is put back, last input first, and nothing may stand
+// where it goes (ApplyTxInUndo). Unlike Core the outputs it would call
+// unspendable are checked and removed too: this view holds them. Core
+// works on a cache that DisconnectTip flushes on DISCONNECT_OK alone; here
+// the steps are made on the view and noted, and the first that is not
+// clean takes all of them back, newest first, so that a caller sees a
+// view written only by a clean disconnect. One probe a coin on that path.
+// The record is read, never consumed, and is one undo_matches_block has
+// passed for this block: its counts index the record unchecked here.
+inline i32 view_disconnect_block(NView& view, const NBlock& blk,
+                                 const NBlockUndo& undo, i64 height,
+                                 DisconnectStats& st) {
+    st = DisconnectStats();
+    using Node = decltype(view.map)::node_type;
+    std::vector<Node> taken;              // outputs removed, in order
+    std::vector<const NOutPoint*> put;    // coins restored, in order
+    std::vector<bool> was_put;            // the steps' kinds, in order
+    auto take_back = [&] {
+        size_t ti = taken.size(), pi = put.size();
+        for (size_t s = was_put.size(); s-- > 0;) {
+            if (was_put[s])
+                view.map.erase(*put[--pi]);
+            else
+                view.map.insert(std::move(taken[--ti]));
+        }
+        st.restored = st.removed = 0;
+        return DISCONNECT_UNCLEAN;
+    };
+    for (size_t t = blk.vtx.size(); t-- > 0;) {
+        const NTx& tx = *blk.vtx[t];
+        bool cb = tx_is_coinbase(tx);
+        for (u32 n = 0; n < tx.vout.size(); n++) {
+            st.probes++;
+            auto it = view.map.find(NView::key(blk.txids[t].data(), n));
+            if (it == view.map.end()) return take_back();
+            const NCoin& c = it->second;
+            if (c.value != tx.vout[n].value || c.spk != tx.vout[n].spk ||
+                c.height != (i32)height || c.coinbase != cb)
+                return take_back();
+            taken.push_back(view.map.extract(it));
+            was_put.push_back(false);
+            st.removed++;
+        }
+        size_t lo = t ? undo.spent_end[t - 1] : 0;
+        for (size_t i = undo.spent_end[t]; i-- > lo;) {
+            st.probes++;
+            auto at = view.map.try_emplace(undo.spent[i].key);
+            if (!at.second) return take_back();
+            at.first->second = undo.spent[i].coin;
+            put.push_back(&undo.spent[i].key);
+            was_put.push_back(true);
+            st.restored++;
+        }
+    }
+    return DISCONNECT_OK;
+}
+
 // Order-free digest of the whole view: the XOR of sha256(outpoint ||
 // value || height || coinbase || scriptPubKey) over its coins. Two views
 // hold the same coins exactly when their sizes and digests agree (a map
 // has no duplicate key, so no pair cancels).
-inline void view_digest(const NView& view, u8 out[32]) {
+inline void coin_digest_xor(const NOutPoint& key, const NCoin& coin,
+                            u8 acc[32]) {
+    Sha256 h;
+    h.write(key.b, sizeof key.b);
+    u8 meta[13];
+    u64 v = (u64)coin.value;
+    for (int j = 0; j < 8; j++) meta[j] = u8(v >> (8 * j));
+    u32 ht = (u32)coin.height;
+    for (int j = 0; j < 4; j++) meta[8 + j] = u8(ht >> (8 * j));
+    meta[12] = coin.coinbase ? 1 : 0;
+    h.write(meta, 13);
+    h.write(coin.spk.data(), coin.spk.size());
+    u8 d[32];
+    h.finalize(d);
+    for (int j = 0; j < 32; j++) acc[j] ^= d[j];
+}
+
+// Over the coins of the map's buckets [lo, hi): the XOR of such parts over
+// a cut of [0, bucket_count()) is the view's digest, so several threads
+// can make a large view's (nat_view_digest).
+inline void view_digest_buckets(const NView& view, size_t lo, size_t hi,
+                                u8 out[32]) {
     std::memset(out, 0, 32);
-    for (const auto& kv : view.map) {
-        Sha256 h;
-        h.write(kv.first.b, sizeof kv.first.b);
-        u8 meta[13];
-        u64 v = (u64)kv.second.value;
-        for (int j = 0; j < 8; j++) meta[j] = u8(v >> (8 * j));
-        u32 ht = (u32)kv.second.height;
-        for (int j = 0; j < 4; j++) meta[8 + j] = u8(ht >> (8 * j));
-        meta[12] = kv.second.coinbase ? 1 : 0;
-        h.write(meta, 13);
-        h.write(kv.second.spk.data(), kv.second.spk.size());
-        u8 d[32];
-        h.finalize(d);
-        for (int j = 0; j < 32; j++) out[j] ^= d[j];
-    }
+    for (size_t b = lo; b < hi; b++)
+        for (auto it = view.map.begin(b); it != view.map.end(b); ++it)
+            coin_digest_xor(it->first, it->second, out);
 }
 
 }  // namespace nat

@@ -284,7 +284,9 @@ extern "C" {
 // 15: nat_session_sighash_work, nat_sha256_uses_sha_ni.
 // 16: nat_session_sighash_work also writes the legacy template's two counts.
 // 17: and its third (`resumed`); nat_session_worker_ns.
-int nat_version() { return 17; }
+// 18: nat_view_disconnect_block, nat_undo_matches_block; nat_view_digest
+//     takes n_threads.
+int nat_version() { return 18; }
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 
@@ -498,6 +500,36 @@ i32 nat_view_undo_block(void* v, void* b, void* u) {
                : 0;
 }
 
+// DisconnectBlock for a block connected with a record (block.hpp
+// undo_matches_block, then view_disconnect_block): 0 ok, 1 unclean, 2
+// failed; the view is written on 0 alone. `checked`: the caller has held
+// the record against this block already (nat_undo_matches_block gave 1)
+// and the check is not made again. out[0]: the view's probes, out[1]:
+// coins restored, out[2]: outputs removed.
+i32 nat_view_disconnect_block(void* v, void* b, void* u, i64 height,
+                              i32 checked, i64* out) {
+    const NBlock& blk = *static_cast<NBlock*>(b);
+    const NBlockUndo& undo = *static_cast<NBlockUndo*>(u);
+    DisconnectStats st;
+    i32 r = checked || undo_matches_block(blk, undo)
+                ? view_disconnect_block(*static_cast<NView*>(v), blk, undo,
+                                        height, st)
+                : (i32)DISCONNECT_FAILED;
+    out[0] = st.probes;
+    out[1] = st.restored;
+    out[2] = st.removed;
+    return r;
+}
+
+// Whether the record was made from a block of these transactions
+// (block.hpp undo_matches_block): 1 or 0.
+i32 nat_undo_matches_block(void* u, void* b) {
+    return undo_matches_block(*static_cast<NBlock*>(b),
+                              *static_cast<NBlockUndo*>(u))
+               ? 1
+               : 0;
+}
+
 // Coins the record holds (spent + overwritten).
 i64 nat_undo_len(void* u) {
     auto* undo = static_cast<NBlockUndo*>(u);
@@ -506,9 +538,25 @@ i64 nat_undo_len(void* u) {
 
 void nat_undo_free(void* u) { delete static_cast<NBlockUndo*>(u); }
 
-// out: 32 bytes (block.hpp view_digest).
-void nat_view_digest(void* v, u8* out) {
-    view_digest(*static_cast<NView*>(v), out);
+// out: 32 bytes (block.hpp view_digest_buckets), the map's buckets cut
+// over one worker a DIGEST_SHARD_MIN coins up to `n_threads`: a million
+// coins take a third of a second on one, chasing a node a coin.
+void nat_view_digest(void* v, u8* out, i32 n_threads) {
+    const NView& view = *static_cast<NView*>(v);
+    constexpr size_t DIGEST_SHARD_MIN = 1 << 16;
+    i32 T = (i32)std::max<size_t>(
+        1, std::min<size_t>((size_t)std::max(n_threads, 1),
+                            view.map.size() / DIGEST_SHARD_MIN));
+    size_t buckets = view.map.bucket_count();
+    std::vector<Hash32> parts((size_t)T);
+    fan_out(T, T, [&](i32 t, i32, i32) {
+        view_digest_buckets(view, buckets * (size_t)t / (size_t)T,
+                            buckets * (size_t)(t + 1) / (size_t)T,
+                            parts[(size_t)t].data());
+    });
+    std::memset(out, 0, 32);
+    for (const Hash32& p : parts)
+        for (int j = 0; j < 32; j++) out[j] ^= p[(size_t)j];
 }
 
 // The three libbitcoinconsensus exports (bitcoinconsensus.h:67-75).

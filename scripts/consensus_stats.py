@@ -96,8 +96,12 @@ REQUIRED_METRICS = [
     "consensus_stream_blocks_total",
     "consensus_stream_rollbacks_total",
     "consensus_stream_blocks_in_flight",
-    # native coin tables (the stream leg's native connects: both tables)
+    # native coin tables (the stream leg's native connects: both tables;
+    # its disconnect: `table="undo"`)
     "consensus_coin_probes_total",
+    # disconnect_block: how each call ended, and the coins a clean one moved
+    "consensus_blocks_disconnected_total",
+    "consensus_undo_coins_total",
     # resilience (clean-path samples: ladder gauge set at verifier
     # construction, sentinel lanes ride every padded dispatch; the fault
     # counters only light up under scripts/consensus_chaos.py)
@@ -248,6 +252,63 @@ def run_mini_workload() -> None:
         res = verify_batch(items)
         assert [r.ok for r in res] == [True] * 4 + [False]
 
+    # --- block connect: one valid block, one failing replay (this leg and
+    # the stream's run before the serving legs, so that a replica lost in
+    # those cannot hide what the disconnect's own asserts hold) ---
+    bview, bfunded = blockgen.make_funded_view(4, height=1, seed="stats-blk")
+    good = blockgen.build_spend_tx(bfunded, fee=2000)
+    blk = blockgen.build_block([good], height=200, fees=2000)
+    r = connect_block(blk, bview, 200, check_pow=False)
+    assert r.ok, r.reason
+    r2 = connect_block(blk, bview, 200, check_pow=False)  # inputs now spent
+    assert not r2.ok
+
+    # --- block stream: a block that connects, then one whose bad signature
+    # shows in its finish, after its speculative apply (one rollback) ---
+    from bitcoinconsensus_tpu import native_bridge
+
+    if native_bridge.available():
+        from bitcoinconsensus_tpu.models.validate import connect_block_stream
+
+        sview, sfunded = blockgen.make_funded_view(8, height=1, seed="stats-stream")
+        nview = native_bridge.NativeCoinsView()
+        nview.add_coins_batch([
+            (op_txid, n, c.out.value, c.height, c.coinbase, c.out.script_pubkey)
+            for (op_txid, n), c in sview._map.items()
+        ])
+        chain = [
+            blockgen.build_block(
+                [blockgen.build_spend_tx(sfunded[:4], fee=2000)], height=200, fees=2000),
+            blockgen.build_block(
+                [blockgen.build_spend_tx(sfunded[4:], fee=2000, corrupt_input=0)],
+                height=201, fees=2000),
+        ]
+        streamed = list(connect_block_stream(
+            chain, nview, 200, check_pow=False, want_undo=True))
+        assert [r.ok for r in streamed] == [True, False]
+        # the block that stood is taken off the tip by the record the stream
+        # handed out (its second block's rollback left it sound), and the same
+        # record offered again is refused: the block's outputs are gone
+        from bitcoinconsensus_tpu.models.validate import (
+            _COIN_PROBES,
+            _DISCONNECTED,
+            _UNDO_COINS,
+            disconnect_block,
+        )
+
+        before = (len(nview), nview.digest())
+        assert streamed[1].undo is None
+        gone = disconnect_block(chain[0].serialize(), nview, streamed[0].undo, 200)
+        assert gone.ok and (gone.restored, gone.removed) == (4, 3)
+        assert (len(nview), nview.digest()) != before
+        again = disconnect_block(chain[0].serialize(), nview, streamed[0].undo, 200)
+        assert again.reason == "unclean"
+        # both began, so both were accounted and applied: the counter holds
+        # the label values its readers sum, and the disconnect's own
+        assert all(_COIN_PROBES.value(table=t) > 0 for t in ("view", "block", "undo"))
+        assert _DISCONNECTED.value(result="ok") == _DISCONNECTED.value(result="unclean") == 1
+        assert (_UNDO_COINS.value(what="restored"), _UNDO_COINS.value(what="removed")) == (4, 3)
+
     # --- serving front end: coalesced fan-in from two tenants, then a
     # deliberate overload (tenant_depth=1, no time flush) so the shed
     # counter and both admission outcomes sample ---
@@ -366,43 +427,6 @@ def run_mini_workload() -> None:
     # keys whose records now live elsewhere.
     assert not store3.peek_key(b"\x07" * 32) and len(store3) == 0
     store3.close()
-
-    # --- block connect: one valid block, one failing replay ---
-    bview, bfunded = blockgen.make_funded_view(4, height=1, seed="stats-blk")
-    good = blockgen.build_spend_tx(bfunded, fee=2000)
-    blk = blockgen.build_block([good], height=200, fees=2000)
-    r = connect_block(blk, bview, 200, check_pow=False)
-    assert r.ok, r.reason
-    r2 = connect_block(blk, bview, 200, check_pow=False)  # inputs now spent
-    assert not r2.ok
-
-    # --- block stream: a block that connects, then one whose bad signature
-    # shows in its finish, after its speculative apply (one rollback) ---
-    from bitcoinconsensus_tpu import native_bridge
-
-    if native_bridge.available():
-        from bitcoinconsensus_tpu.models.validate import connect_block_stream
-
-        sview, sfunded = blockgen.make_funded_view(8, height=1, seed="stats-stream")
-        nview = native_bridge.NativeCoinsView()
-        nview.add_coins_batch([
-            (op_txid, n, c.out.value, c.height, c.coinbase, c.out.script_pubkey)
-            for (op_txid, n), c in sview._map.items()
-        ])
-        chain = [
-            blockgen.build_block(
-                [blockgen.build_spend_tx(sfunded[:4], fee=2000)], height=200, fees=2000),
-            blockgen.build_block(
-                [blockgen.build_spend_tx(sfunded[4:], fee=2000, corrupt_input=0)],
-                height=201, fees=2000),
-        ]
-        streamed = list(connect_block_stream(chain, nview, 200, check_pow=False))
-        assert [r.ok for r in streamed] == [True, False]
-        # both began, so both were accounted and applied: the counter holds
-        # the two label values its readers sum
-        from bitcoinconsensus_tpu.models.validate import _COIN_PROBES
-
-        assert all(_COIN_PROBES.value(table=t) > 0 for t in ("view", "block"))
 
     # --- mesh: a sharded dispatch over the (virtual) device mesh ---
     sv = ShardedSecpVerifier(mesh=make_mesh())

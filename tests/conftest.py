@@ -80,6 +80,7 @@ _KERNEL_SCOPES = {
     "rungs-a": (
         "test_pallas_kernel",
         "test_block_stream",
+        "test_reorg_block",
         "test_multisig_block",
         "test_taproot_block",
         "test_ingress",

@@ -29,7 +29,11 @@ from bitcoinconsensus_tpu.core.flags import VERIFY_ALL_EXTENDED, VERIFY_ALL_LIBC
 from bitcoinconsensus_tpu.crypto.jax_backend import TpuSecpVerifier
 from bitcoinconsensus_tpu.models.batch import BatchItem, verify_batch
 from bitcoinconsensus_tpu.models.sigcache import ScriptExecutionCache, SigCache
-from bitcoinconsensus_tpu.models.validate import connect_block, connect_block_stream
+from bitcoinconsensus_tpu.models.validate import (
+    connect_block,
+    connect_block_stream,
+    disconnect_block,
+)
 from bitcoinconsensus_tpu.obs import get_registry
 from bitcoinconsensus_tpu.obs import spans as S
 from bitcoinconsensus_tpu.parallel.mesh import ShardedSecpVerifier, make_mesh
@@ -59,6 +63,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # must have a sample after the workload, except the shed counter, which a
 # run that sheds nothing never bumps.
 READ = (
+    "consensus_blocks_disconnected_total",
     "consensus_cache_hits_total",
     "consensus_cache_lookups_total",
     "consensus_checks_total",
@@ -88,6 +93,7 @@ READ = (
     "consensus_stream_blocks_in_flight",
     "consensus_stream_rollbacks_total",
     "consensus_taproot_hash_total",
+    "consensus_undo_coins_total",
 )
 # What the benchmark's chip guard holds at zero (`harness/chipguard.py`):
 # registered is all a sound run shows of them.
@@ -146,6 +152,9 @@ PHASES = (
     "host_fixup",
     # under the mesh verifier alone, inside `dispatch` and `sync`
     "shard_layout", "shard_put", "shard_exec", "shard_check",
+    # PR 48, a `disconnect_block`: the record held against the block, before
+    # `undo` (a stream's rollback has that one too) moves the coins
+    "undo_check",
 )
 # The spans whose seconds the served cell's readers take from
 # `consensus_span_duration_seconds{span}` (`layers/_spans.py`).
@@ -181,6 +190,12 @@ def workload():
     res = connect_block(raw, to_native_view(coins), HEIGHT, sig_cache=sig,
                         script_cache=ScriptExecutionCache(), **connect)
     assert res.ok and len(res.input_results) == 6
+
+    # the block connected with its record and taken off the tip again
+    view = to_native_view(coins)
+    res = connect_block(raw, view, HEIGHT, sig_cache=SigCache(),
+                        script_cache=ScriptExecutionCache(), want_undo=True, **connect)
+    assert disconnect_block(raw, view, res.undo, HEIGHT, verifier=verifier).ok
 
     # a verifier that will not answer for any lane: the driver resolves
     # every one on the exact host oracle (`host_fixup`)
@@ -342,12 +357,31 @@ def test_coin_probe_tables_are_the_ones_counted(workload):
     """`layers/_probes.py` sums `consensus_coin_probes_total` over its
     `table` label: the view, and pass 1's table of the block's own coins.
     Every native connect of the workload raises both, once, after its
-    apply."""
+    apply. `drivers/reorg.py` reads `table="undo"` apart: the view's probes
+    by a `disconnect_block`, one a coin it moved (the workload's: six spent
+    coins put back, the transaction's output and the coinbase's two taken
+    out)."""
     _, snapshot = workload
     tables = {s["labels"]["table"]: s["value"]
               for s in snapshot["consensus_coin_probes_total"]["samples"]}
-    assert set(tables) == {"view", "block"}, tables
-    assert tables["view"] > tables["block"] > 0, tables
+    assert set(tables) == {"view", "block", "undo"}, tables
+    assert tables["view"] > tables["block"] > 0 and tables["undo"] == 9, tables
+
+
+def test_disconnect_outcomes_and_moved_coins_are_the_ones_read(workload):
+    """`drivers/reorg.py` differences `consensus_blocks_disconnected_total`
+    by `result` and `consensus_undo_coins_total` by `what` around every timed
+    reorganisation, and holds them to the generator's counts."""
+    _, snapshot = workload
+    ended = {s["labels"]["result"]: s["value"]
+             for s in snapshot["consensus_blocks_disconnected_total"]["samples"]}
+    assert ended == {"ok": 1}, ended
+    moved = {s["labels"]["what"]: s["value"]
+             for s in snapshot["consensus_undo_coins_total"]["samples"]}
+    assert moved == {"restored": 6, "removed": 3}, moved
+    timed = {s["labels"]["span"]: s["count"]
+             for s in snapshot["consensus_span_duration_seconds"]["samples"]}
+    assert timed.get("block.disconnect") == 1, sorted(timed)
 
 
 def test_lane_kinds_and_taproot_hashes_are_the_ones_read(workload):

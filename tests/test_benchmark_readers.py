@@ -1,4 +1,4 @@
-"""The per-layer readers PRs 36 to 45 added, each on a made-up `ctx`.
+"""The per-layer readers PRs 36 to 48 added, each on a made-up `ctx`.
 
 `benchmarks/tests` is not part of tier-1, and a reader runs for real only
 in a `--trace 1` run on the chip. Here every new reader gets a context
@@ -517,6 +517,95 @@ def test_full_tile_share_returns_none_with_nothing_to_read(ctx):
     assert reader("full_tile_share.connect")(ctx) is None
 
 
+# -- the reorganisation cell (PR 48) -------------------------------------------
+
+REORG = ("disconnect_ms.reorg", "undo_probes_per_input.reorg", "cache_hit_share.reorg",
+         "warm_result_gap_ms.reorg", "fresh_result_gap_ms.reorg", "settle_wait_ms.reorg",
+         "kernel_ms.reorg", "device_idle.reorg",
+         # the stream's layers, read over the timed reorganisations
+         "host_ms.reorg", "coin_probes_per_input.reorg", "unphased_ms.reorg",
+         "overlap_share.reorg", "transfers_per_dispatch.reorg")
+
+
+def reorg_ctx(turns=3, trace=True, **over):
+    """A window of `turns` sound reorganisations: two disconnects each
+    (8 and 10 ms, a millisecond more a turn), gaps first call -> B1 -> B2 ->
+    B3 of 30, 20 and 40 ms (two more a turn), `sync` 4 ms and one more a
+    turn, `undo` 3 ms and `accounting` 80 of which 10 lie inside another
+    phase; the probes, hits, lookups, dispatches and pieces a
+    reorganisation's calls made; and a traced slice that holds two of them."""
+    driver = {
+        "kind": "reorg", "walls_s": [0.1 + 0.01 * k for k in range(turns)],
+        "disconnect_s": [x + 0.001 * k for k in range(turns) for x in (0.008, 0.010)],
+        "gaps_s": [[g + 0.002 * k for g in (0.030, 0.020, 0.040)] for k in range(turns)],
+        "phases": [{"sync": phase(0.004 + 0.001 * k), "undo": phase(0.003),
+                    "accounting": phase(0.080, outer=0.070)} for k in range(turns)],
+        "deltas": [{"undo_probes": 17104.0, "connect_probes": 76518.0,
+                    "consensus_cache_hits_total": 12000.0,
+                    "consensus_cache_lookups_total": 18000.0,
+                    "consensus_dispatch_total": 3.0,
+                    "consensus_dispatch_transfers_total": 6.0} for _ in range(turns)],
+        "disconnected_inputs": 12000, "n_inputs": 6000, "verdicts": 18000,
+        "counters_before": {}, "counters_after": {}, **over}
+    within = {"bench.reorg": {"count": 2, "span_s": 0.25, "busy_s": 0.02, "modules": {
+        "jit_packed_verify_tiles(3)": 0.016, "jit_something_else": 0.5}}}
+    return {"cell": "made-up.reorg", "driver": driver, "trace": {
+        "busy_s": 1.0, "window_s": 4.0, "within": within} if trace else None}
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("disconnect_ms.reorg", 10.0),          # median of 8, 10, 9, 11, 10, 12
+    ("undo_probes_per_input.reorg", 17104 / 12000),
+    ("cache_hit_share.reorg", 100.0 * 12 / 18),
+    ("warm_result_gap_ms.reorg", 27.0),     # median of 30, 20, 32, 22, 34, 24
+    ("fresh_result_gap_ms.reorg", 42.0),
+    ("host_ms.reorg", 83.0),                # every phase but `sync`
+    ("coin_probes_per_input.reorg", 76518 / 18000),  # (3 x 18,000 + 3 x 7,506) / 18,000
+    ("unphased_ms.reorg", 110.0 - 5.0 - 3.0 - 70.0),  # the middle turn: wall less the outer seconds
+    ("overlap_share.reorg", 100.0 * (1 - 5.0 / 8.0)),  # mean `sync` 5 ms under 8 ms of kernel
+    ("transfers_per_dispatch.reorg", 2.0),
+    ("settle_wait_ms.reorg", 5.0),
+    ("kernel_ms.reorg", 8.0),               # 16 ms of the verify program in two calls
+    ("device_idle.reorg", 92.0),
+])
+def test_reorg_readers_read_the_timed_reorganisations(metric, want):
+    assert reader(metric)(reorg_ctx()) == ms(want)
+
+
+@pytest.mark.parametrize("metric", REORG)
+@pytest.mark.parametrize("ctx", [
+    reorg_ctx(turns=0),                                        # a window that timed none
+    connect_ctx(_reports(), WALLS),                            # another kind of cell
+    {"cell": "made-up", "trace": None, "driver": {"kind": "stream"}},
+], ids=["no-turn", "connect", "stream"])
+def test_reorg_readers_return_none_with_nothing_to_read(metric, ctx):
+    assert reader(metric)(ctx) is None
+
+
+@pytest.mark.parametrize("metric,ctx", [
+    ("kernel_ms.reorg", reorg_ctx(trace=False)),
+    ("device_idle.reorg", reorg_ctx(trace=False)),
+    # a program that counts no `undo` probes, and one whose reports lack `sync`
+    ("undo_probes_per_input.reorg", reorg_ctx(deltas=[{"consensus_cache_hits_total": 1.0}])),
+    ("cache_hit_share.reorg", reorg_ctx(deltas=[{"undo_probes": 1.0}])),
+    ("cache_hit_share.reorg", reorg_ctx(deltas=[
+        {"consensus_cache_hits_total": 0.0, "consensus_cache_lookups_total": 0.0}])),
+    ("settle_wait_ms.reorg", reorg_ctx(phases=[{"undo": phase(0.003)}])),
+    ("overlap_share.reorg", reorg_ctx(trace=False)),
+    ("overlap_share.reorg", reorg_ctx(phases=[{"undo": phase(0.003)}] * 3)),
+    # a program whose tables are not told apart, whose launch counts no piece,
+    # and a driver that kept `secs` alone
+    ("coin_probes_per_input.reorg", reorg_ctx(deltas=[{"undo_probes": 1.0}])),
+    ("transfers_per_dispatch.reorg", reorg_ctx(deltas=[{"consensus_dispatch_total": 3.0}])),
+    ("transfers_per_dispatch.reorg", reorg_ctx(deltas=[
+        {"consensus_dispatch_total": 0.0, "consensus_dispatch_transfers_total": 0.0}])),
+    ("unphased_ms.reorg", reorg_ctx(phases=[{"undo": {"secs": 0.003}}] * 3)),
+    ("unphased_ms.reorg", reorg_ctx(phases=[{"undo": phase(0.003)}])),  # three walls, one report
+])
+def test_a_reorg_reader_returns_none_without_its_source(metric, ctx):
+    assert reader(metric)(ctx) is None
+
+
 def test_benchmark_json_lists_each_new_metric_with_its_cells():
     import json
 
@@ -559,6 +648,8 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         # PR 45: the bytes behind the ECDSA digests, and the rate a thread hashes them at
         "sighash_kb_per_input.connect": ["worst-block-quadratic.sighash", "tip-block.cold"],
         "sighash_mb_per_s.connect": ["worst-block-quadratic.sighash"],
+        # PR 48: the reorganisation cell's own
+        **{name: ["tip-reorg.depth2"] for name in REORG},
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name
