@@ -21,11 +21,18 @@ TPU-era equivalents, same contract:
 Keys are salted per process (`os.urandom`) exactly as the reference salts
 its hashers (sigcache.cpp:22-30) — entries are never addressable across
 processes, so a poisoned entry cannot be constructed offline. Storage is
-a bounded LRU (OrderedDict) rather than a cuckoo table: the reference's
-cuckoo design buys lock-free concurrent probes on 32 B entries; under the
-GIL an LRU dict has the same asymptotics with far less machinery. All
-methods hold a mutex, making concurrent `verify_batch` calls safe — the
-thread contract the reference documents for its own globals
+a bounded LRU set rather than a cuckoo table: the reference's cuckoo
+design buys lock-free concurrent probes on 32 B entries; one mutex a cache
+has the same asymptotics with far less machinery. Where the native core
+is loaded the keys live in its LRU set (`native/lru.hpp`): a call, bulk or
+single, is ONE C call that takes the set's mutex once and walks its keys
+with the GIL released, so a block's probes and inserts cost the memory's
+time and two threads run side by side up to that lock. Otherwise, and in
+`PersistentSigCache`, they live in an OrderedDict behind a lock: the same
+contract key for key, and the reference `tests/test_sigcache.py` holds the
+native set to. Nothing but `native_bridge.available()` and the class
+chooses. All methods hold the mutex, making concurrent `verify_batch` calls
+safe — the thread contract the reference documents for its own globals
 (`pubkey.h:257-258`) and SURVEY §5 requires of ours.
 """
 
@@ -39,6 +46,7 @@ from typing import Iterable, Optional, Tuple
 
 import numpy as np
 
+from .. import native_bridge
 from ..obs import counter as _obs_counter
 from ..obs import gauge as _obs_gauge
 from ..resilience import faults as _faults
@@ -76,8 +84,43 @@ _C_ENTRIES = _obs_gauge(
 )
 
 
+_C_BULK = _obs_counter(
+    "consensus_cache_bulk_keys_total",
+    "keys walked by bulk probes and inserts, by where the set lives "
+    "(native: one C call under one lock hold; python: a loop a key)",
+    ("cache", "store"),
+)
+
+
+def _counter(name: str) -> property:
+    """One of the five counters: the native set's own, which moves under the
+    set's mutex, where the keys live there; else a plain attribute, written
+    under `_lock`."""
+    slot = "_" + name
+
+    def read(self) -> int:
+        if self._nat is not None:
+            return self._nat.counters()[name]
+        return self.__dict__[slot]
+
+    def write(self, value: int) -> None:
+        self.__dict__[slot] = value
+
+    return property(read, write)
+
+
 class _SaltedLRU:
     """Bounded success-set with a per-process salted key digest."""
+
+    # A subclass that reads and writes `_set` as a dict and journals a key at
+    # a time (models/sigstore.py) keeps the Python set, native core or not.
+    _python_set = False
+
+    hits = _counter("hits")
+    misses = _counter("misses")
+    insertions = _counter("insertions")
+    evictions = _counter("evictions")
+    erases = _counter("erases")
 
     def __init__(self, max_entries: int, cache_label: str = "cache"):
         assert max_entries > 0
@@ -87,16 +130,23 @@ class _SaltedLRU:
         # "poison" fault makes one probe report a fabricated hit, the
         # observable a genuinely poisoned entry would produce.
         self._poison_site = "sigcache." + cache_label
-        self._set: OrderedDict[bytes, None] = OrderedDict()
-        self._lock = threading.Lock()
-        self.hits = 0
-        self.misses = 0
-        self.evictions = 0
-        self.erases = 0
-        self.insertions = 0
+        if self._python_set or not native_bridge.available():
+            self._nat = None
+            self._set: OrderedDict[bytes, None] = OrderedDict()
+            self._lock = threading.Lock()
+            self.hits = 0
+            self.misses = 0
+            self.evictions = 0
+            self.erases = 0
+            self.insertions = 0
+        else:
+            self._nat = native_bridge.NativeLruSet(max_entries)
         # Bound metric children: one dict lookup + label-key build at
         # construction, plain locked adds on the probe/insert hot paths.
         lbl = {"cache": cache_label}
+        self._m_bulk = _C_BULK.labels(
+            store="python" if self._nat is None else "native", **lbl
+        )
         self._m_lookups = _C_LOOKUPS.labels(**lbl)
         self._m_hits = _C_HITS.labels(**lbl)
         self._m_misses = _C_MISSES.labels(**lbl)
@@ -115,23 +165,26 @@ class _SaltedLRU:
     def contains_key(self, k: bytes, erase: bool = False) -> bool:
         """Probe by a precomputed digest (see SigCache.keys_for_checks)."""
         poisoned = _faults.poison_hit(self._poison_site)
-        with self._lock:
-            present = k in self._set
-            hit = present or poisoned
-            if present:
-                self.hits += 1
-                if erase:
-                    del self._set[k]
-                    self.erases += 1
+        if self._nat is not None:
+            present, size = self._nat.probe_one(k, erase, poisoned)
+        else:
+            with self._lock:
+                present = k in self._set
+                if present:
+                    self.hits += 1
+                    if erase:
+                        del self._set[k]
+                        self.erases += 1
+                    else:
+                        self._set.move_to_end(k)
+                elif poisoned:
+                    # Fabricated hit, set untouched: counted as a hit so the
+                    # hits+misses==lookups invariant holds under chaos.
+                    self.hits += 1
                 else:
-                    self._set.move_to_end(k)
-            elif poisoned:
-                # Fabricated hit, dict untouched: counted as a hit so the
-                # hits+misses==lookups invariant holds under chaos.
-                self.hits += 1
-            else:
-                self.misses += 1
-            size = len(self._set)
+                    self.misses += 1
+                size = len(self._set)
+        hit = present or poisoned
         # Registry updates outside the cache lock: no nested-lock ordering
         # to reason about, and a slow metrics path can never stall probes.
         self._m_lookups.inc()
@@ -155,22 +208,27 @@ class _SaltedLRU:
         one by one through `contains_key`: the poison site counts visits."""
         if _faults.active() is not None:
             return self._contains_each(blob, n, erase)
-        hit = [False] * n
-        with self._lock:
-            s = self._set
-            touch = s.__delitem__ if erase else s.move_to_end
-            for j in range(n):
-                k = blob[32 * j : 32 * j + 32]
-                if k in s:
-                    touch(k)
-                    hit[j] = True
-            hits = hit.count(True)
-            self.hits += hits
-            self.misses += n - hits
-            if erase:
-                self.erases += hits
-            size = len(s)
+        if self._nat is not None:
+            hit, hits, size = self._nat.probe(blob, n, erase)
+        else:
+            hit = [False] * n
+            with self._lock:
+                s = self._set
+                touch = s.__delitem__ if erase else s.move_to_end
+                for j in range(n):
+                    k = blob[32 * j : 32 * j + 32]
+                    if k in s:
+                        touch(k)
+                        hit[j] = True
+                hits = hit.count(True)
+                self.hits += hits
+                self.misses += n - hits
+                if erase:
+                    self.erases += hits
+                size = len(s)
+            hit = np.array(hit, dtype=bool)
         if n:
+            self._m_bulk.inc(n)
             self._m_lookups.inc(n)
         if hits:
             self._m_hits.inc(hits)
@@ -179,40 +237,46 @@ class _SaltedLRU:
         if erase and hits:
             self._m_erases.inc(hits)
             self._m_entries.set(size)
-        return np.array(hit, dtype=bool)
+        return hit
 
     def discard_key(self, k: bytes) -> None:
         """Drop a proven-wrong entry (resilience cache-audit containment).
 
         No-op when absent. Counted as an erase so the entry-count
         invariant (insertions - evictions - erases == entries) holds."""
-        with self._lock:
-            present = k in self._set
-            if present:
-                del self._set[k]
-                self.erases += 1
-            size = len(self._set)
+        if self._nat is not None:
+            present, size = self._nat.discard(k)
+        else:
+            with self._lock:
+                present = k in self._set
+                if present:
+                    del self._set[k]
+                    self.erases += 1
+                size = len(self._set)
         if present:
             self._m_erases.inc()
             self._m_entries.set(size)
 
     def add_key(self, k: bytes) -> None:
-        with self._lock:
-            # A re-add of a present key is a freshness touch, not an
-            # insertion: counting it would break the entry-accounting
-            # invariant (insertions - evictions - erases == entries)
-            # that concurrent writers rely on to detect lost entries.
-            new = k not in self._set
-            self._set[k] = None
-            self._set.move_to_end(k)
-            evicted = 0
-            while len(self._set) > self._max:
-                self._set.popitem(last=False)
-                evicted += 1
-            self.evictions += evicted
-            if new:
-                self.insertions += 1
-            size = len(self._set)
+        # A re-add of a present key is a freshness touch, not an insertion:
+        # counting it would break the entry-accounting invariant
+        # (insertions - evictions - erases == entries) that concurrent
+        # writers rely on to detect lost entries.
+        if self._nat is not None:
+            new, evicted, size = self._nat.add_one(k)
+        else:
+            with self._lock:
+                new = k not in self._set
+                self._set[k] = None
+                self._set.move_to_end(k)
+                evicted = 0
+                while len(self._set) > self._max:
+                    self._set.popitem(last=False)
+                    evicted += 1
+                self.evictions += evicted
+                if new:
+                    self.insertions += 1
+                size = len(self._set)
         if new:
             self._m_inserts.inc()
         if evicted:
@@ -227,22 +291,26 @@ class _SaltedLRU:
         idx = self._selected(blob, select)
         if not len(idx):
             return
-        inserted = evicted = 0
-        with self._lock:
-            s = self._set
-            for j in idx:
-                k = blob[32 * j : 32 * j + 32]
-                if k in s:  # a freshness touch, not an insertion
-                    s.move_to_end(k)
-                    continue
-                s[k] = None
-                inserted += 1
-                while len(s) > self._max:
-                    s.popitem(last=False)
-                    evicted += 1
-            self.insertions += inserted
-            self.evictions += evicted
-            size = len(s)
+        if self._nat is not None:
+            inserted, evicted, size = self._nat.add(blob, idx)
+        else:
+            inserted = evicted = 0
+            with self._lock:
+                s = self._set
+                for j in idx.tolist():
+                    k = blob[32 * j : 32 * j + 32]
+                    if k in s:  # a freshness touch, not an insertion
+                        s.move_to_end(k)
+                        continue
+                    s[k] = None
+                    inserted += 1
+                    while len(s) > self._max:
+                        s.popitem(last=False)
+                        evicted += 1
+                self.insertions += inserted
+                self.evictions += evicted
+                size = len(s)
+        self._m_bulk.inc(len(idx))
         if inserted:
             self._m_inserts.inc(inserted)
         if evicted:
@@ -250,11 +318,14 @@ class _SaltedLRU:
         self._m_entries.set(size)
 
     @staticmethod
-    def _selected(blob: bytes, select):
+    def _selected(blob: bytes, select) -> np.ndarray:
+        """The rows of `blob` that `select` names, in insertion order."""
         if select is None:
-            return range(len(blob) // 32)
+            return np.arange(len(blob) // 32, dtype=np.int64)
         sel = np.asarray(select)
-        return (np.nonzero(sel)[0] if sel.dtype == bool else sel).tolist()
+        if sel.dtype == bool:
+            return np.flatnonzero(sel)
+        return np.ascontiguousarray(sel, dtype=np.int64)
 
     # The bulk forms as `n` single-key calls: what `contains_keys` does
     # under a fault plan, and what a subclass with a `contains_key` /
@@ -270,7 +341,7 @@ class _SaltedLRU:
         )
 
     def _add_each(self, blob: bytes, select=None) -> None:
-        for j in self._selected(blob, select):
+        for j in self._selected(blob, select).tolist():
             self.add_key(blob[32 * j : 32 * j + 32])
 
     def contains(self, parts: Iterable[bytes], erase: bool = False) -> bool:
@@ -283,14 +354,19 @@ class _SaltedLRU:
         """Digests for many part-tuples in one native call (byte-identical
         to `_key`; Python fallback otherwise). Pair with
         `contains_key`/`add_key` to amortize hashing over a batch."""
-        from .. import native_bridge
-
         if native_bridge.available():
             return native_bridge.digest_streams(self._salt, items)
         return [self._key(parts) for parts in items]
 
+    def keys_oldest_first(self) -> list:
+        """The keys in the order eviction would take them."""
+        if self._nat is not None:
+            return self._nat.keys_oldest_first()
+        with self._lock:
+            return list(self._set)
+
     def __len__(self) -> int:
-        return len(self._set)
+        return len(self._set) if self._nat is None else len(self._nat)
 
 
 class SigCache(_SaltedLRU):
@@ -326,8 +402,6 @@ class SigCache(_SaltedLRU):
         native call (byte-identical to `_key(_parts(...))`, asserted by
         tests/test_sigcache.py); Python fallback otherwise. Use with
         `contains_key`/`add_key` to amortize hashing over a batch."""
-        from .. import native_bridge
-
         pairs = [(c.kind, c.data) for c in checks]
         if native_bridge.available():
             return native_bridge.digest_checks(self._salt, pairs)

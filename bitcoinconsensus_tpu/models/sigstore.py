@@ -224,6 +224,10 @@ class PersistentSigCache(SigCache):
     store; entries remain non-addressable without the store directory.
     """
 
+    # The hot tier is read and written as a dict below, and every key takes
+    # a journal append of its own: one lock hold a block has nothing to give.
+    _python_set = True
+
     def __init__(
         self,
         store_dir: str,

@@ -42,7 +42,7 @@ _SO_PATH = os.path.join(_NATIVE_DIR, "libnat.so")
 _PACKAGED_SO = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "_native", "libnat.so"
 )
-_SOURCES = ("nat.cpp", "secp.hpp", "sha256.hpp", "hash_extra.hpp", "interp.hpp", "eval.hpp", "block.hpp")
+_SOURCES = ("nat.cpp", "secp.hpp", "sha256.hpp", "hash_extra.hpp", "interp.hpp", "eval.hpp", "block.hpp", "lru.hpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -152,8 +152,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 18:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 18)")
+        if L.nat_version() < 19:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 19)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -324,6 +324,26 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_undo_len.restype = ctypes.c_int64
         L.nat_undo_free.argtypes = [vp]
         L.nat_view_digest.argtypes = [vp, u8p, ctypes.c_int32]
+        # the success caches' key set (native/lru.hpp); keys go in as bytes
+        keys = ctypes.c_char_p
+        L.nat_lru_new.argtypes = [ctypes.c_int64]
+        L.nat_lru_new.restype = vp
+        L.nat_lru_free.argtypes = [vp]
+        L.nat_lru_free.restype = None
+        L.nat_lru_len.argtypes = [vp]
+        L.nat_lru_len.restype = ctypes.c_int64
+        L.nat_lru_probe.argtypes = [
+            vp, keys, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, u8p, i64p]
+        L.nat_lru_probe.restype = ctypes.c_int64
+        L.nat_lru_add.argtypes = [
+            vp, keys, ctypes.c_int64, i64p, ctypes.c_int64, i64p]
+        L.nat_lru_add.restype = ctypes.c_int64
+        L.nat_lru_discard.argtypes = [vp, keys, i32p]
+        L.nat_lru_discard.restype = ctypes.c_int64
+        L.nat_lru_keys.argtypes = [vp, u8p, ctypes.c_int64]
+        L.nat_lru_keys.restype = ctypes.c_int64
+        L.nat_lru_counters.argtypes = [vp, i64p]
+        L.nat_lru_counters.restype = None
         _lib = L
         return _lib
 
@@ -1445,6 +1465,116 @@ class NativeBlockUndo:
         outpoint (DisconnectBlock's DISCONNECT_FAILED checks; the view is
         not looked at)."""
         return bool(lib().nat_undo_matches_block(self._ptr, blk._ptr))
+
+
+class NativeLruSet:
+    """Bounded LRU set of 32-byte digests (native/lru.hpp LruSet): where
+    models/sigcache.py's caches keep their keys. Every method is one C call
+    that takes the set's mutex once, with the GIL released; the five
+    counters live with the set and move under the same hold."""
+
+    __slots__ = ("_ptr", "_lib")
+
+    COUNTERS = ("hits", "misses", "insertions", "evictions", "erases")
+
+    def __init__(self, max_entries: int):
+        L = lib()
+        assert L is not None
+        self._lib = L
+        self._ptr = L.nat_lru_new(max_entries)
+        if not self._ptr:
+            raise MemoryError("nat_lru_new")
+
+    def __del__(self):
+        if getattr(self, "_ptr", None):
+            self._lib.nat_lru_free(self._ptr)
+            self._ptr = None
+
+    def __len__(self) -> int:
+        return int(self._lib.nat_lru_len(self._ptr))
+
+    def probe(self, blob: bytes, n: int, erase: bool):
+        """Probe the first `n` digests of `blob`, in order: a hit is erased
+        or touched. Returns (present mask, hits, size afterwards)."""
+        if not 0 <= 32 * n <= len(blob):
+            raise ValueError(f"{n} keys asked of a {len(blob)}-byte blob")
+        present = np.empty(n, dtype=bool)
+        hits = ctypes.c_int64()
+        size = self._lib.nat_lru_probe(
+            self._ptr, _as_bytes(blob), n, bool(erase), False,
+            present.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            ctypes.byref(hits),
+        )
+        return present, hits.value, size
+
+    def probe_one(self, key: bytes, erase: bool, fabricated: bool):
+        """One probe; `fabricated` counts an absent key as a hit and leaves
+        the set alone. Returns (present, size afterwards)."""
+        present = ctypes.c_uint8()
+        hits = ctypes.c_int64()
+        size = self._lib.nat_lru_probe(
+            self._ptr, _key32(key), 1, bool(erase), bool(fabricated),
+            ctypes.byref(present), ctypes.byref(hits),
+        )
+        return bool(present.value), size
+
+    def add(self, blob: bytes, idx: Optional[np.ndarray] = None):
+        """Insert the digests of `blob` that the int64 array `idx` names, in
+        its order; all of them for None. Returns (inserted, evicted, size
+        afterwards)."""
+        blob = _as_bytes(blob)
+        n_keys = len(blob) // 32
+        out = (ctypes.c_int64 * 2)()
+        if idx is None:
+            size = self._lib.nat_lru_add(
+                self._ptr, blob, n_keys, None, n_keys, out)
+        else:
+            assert idx.dtype == np.int64 and idx.flags.c_contiguous
+            size = self._lib.nat_lru_add(
+                self._ptr, blob, n_keys,
+                idx.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+                len(idx), out)
+        if size == -1:
+            raise IndexError(f"an index outside the blob's {n_keys} keys")
+        if size < 0:
+            raise MemoryError("nat_lru_add")
+        return out[0], out[1], size
+
+    def add_one(self, key: bytes):
+        return self.add(_key32(key))
+
+    def discard(self, key: bytes):
+        """Drop one key. Returns (was present, size afterwards)."""
+        present = ctypes.c_int32()
+        size = self._lib.nat_lru_discard(
+            self._ptr, _key32(key), ctypes.byref(present))
+        return bool(present.value), size
+
+    def keys_oldest_first(self) -> List[bytes]:
+        room = len(self)
+        while True:
+            out = np.empty((max(room, 1), 32), dtype=np.uint8)
+            n = self._lib.nat_lru_keys(self._ptr, _u8p(out), room)
+            if n <= room:  # else it grew between the two calls
+                raw = out[:n].tobytes()
+                return [raw[32 * j : 32 * j + 32] for j in range(n)]
+            room = n
+
+    def counters(self) -> Dict[str, int]:
+        """The five counters, of one instant."""
+        out = (ctypes.c_int64 * len(self.COUNTERS))()
+        self._lib.nat_lru_counters(self._ptr, out)
+        return dict(zip(self.COUNTERS, out))
+
+
+def _as_bytes(blob) -> bytes:
+    return blob if isinstance(blob, bytes) else bytes(blob)
+
+
+def _key32(key) -> bytes:
+    if len(key) != 32:
+        raise ValueError(f"a cache key is 32 bytes, not {len(key)}")
+    return _as_bytes(key)
 
 
 class NativeSecp:

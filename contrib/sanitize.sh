@@ -7,7 +7,9 @@
 # explicit -fsanitize=shift,signed-integer-overflow for the consensus
 # arithmetic, -fno-sanitize-recover=all: any diagnostic aborts the run)
 # and replays
-# the native byte-identity suites, the batched driver tests, and the
+# the native byte-identity suites, the batched driver tests, the success
+# caches' native key set against its Python twin (tests/test_sigcache.py),
+# and the
 # drop-in ABI corpus (script_tests.json + byte mutations — the
 # adversarial codec paths) through the sanitized library.
 #
@@ -57,5 +59,6 @@ python -m pytest \
     tests/test_native_block.py \
     tests/test_native_front.py \
     tests/test_drop_in_abi.py \
+    tests/test_sigcache.py \
     -q "$@"
 echo "sanitize: ASAN+UBSAN clean"

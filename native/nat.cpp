@@ -14,6 +14,7 @@
 
 #include "block.hpp"
 #include "eval.hpp"
+#include "lru.hpp"
 #include "secp.hpp"
 
 #include <algorithm>
@@ -286,7 +287,66 @@ extern "C" {
 // 17: and its third (`resumed`); nat_session_worker_ns.
 // 18: nat_view_disconnect_block, nat_undo_matches_block; nat_view_digest
 //     takes n_threads.
-int nat_version() { return 18; }
+// 19: nat_lru_* (native/lru.hpp): the success caches' key set.
+int nat_version() { return 19; }
+
+// --- Success caches' key set (native/lru.hpp) ------------------------------
+//
+// Every call takes the set's mutex once. A return of -2 is an allocation
+// that failed before the set was touched.
+
+void* nat_lru_new(i64 max_entries) {
+    try {
+        return new LruSet(max_entries);
+    } catch (...) {
+        return nullptr;
+    }
+}
+
+void nat_lru_free(void* s) { delete static_cast<LruSet*>(s); }
+
+i64 nat_lru_len(void* s) { return static_cast<LruSet*>(s)->size(); }
+
+// present[j] = keys[32 j ..] is in the set, for j < n; `erase` erases a hit,
+// `fabricated` counts an absent key as a hit (LruSet::probe). Returns the
+// size afterwards.
+i64 nat_lru_probe(void* s, const u8* keys, i64 n, i32 erase, i32 fabricated,
+                  u8* present, i64* n_present) {
+    return static_cast<LruSet*>(s)->probe(keys, n, erase != 0, fabricated != 0,
+                                          present, n_present);
+}
+
+// Insert keys[32 idx[j] ..] for j < n, or keys[32 j ..] where idx is null;
+// `n_keys` digests lie in `keys`. out: inserted, evicted. Returns the size
+// afterwards, -1 for an index outside the blob (nothing inserted).
+i64 nat_lru_add(void* s, const u8* keys, i64 n_keys, const i64* idx, i64 n,
+                i64* out) {
+    if (idx) {
+        for (i64 j = 0; j < n; j++)
+            if (idx[j] < 0 || idx[j] >= n_keys) return -1;
+    } else if (n > n_keys) {
+        return -1;
+    }
+    try {
+        return static_cast<LruSet*>(s)->add(keys, idx, n, out, out + 1);
+    } catch (...) {
+        return -2;
+    }
+}
+
+i64 nat_lru_discard(void* s, const u8* key, i32* was_present) {
+    return static_cast<LruSet*>(s)->discard(key, was_present);
+}
+
+// The keys oldest first into out[32 room]; returns the set's size.
+i64 nat_lru_keys(void* s, u8* out, i64 room) {
+    return static_cast<LruSet*>(s)->keys(out, room);
+}
+
+// out[5]: hits, misses, insertions, evictions, erases.
+void nat_lru_counters(void* s, i64* out) {
+    static_cast<LruSet*>(s)->counters(out);
+}
 
 // --- Block layer (native/block.hpp) ---------------------------------------
 

@@ -71,6 +71,8 @@ REQUIRED_METRICS = [
     "consensus_cache_misses_total",
     "consensus_cache_insertions_total",
     "consensus_cache_entries",
+    # the keys bulk probes and inserts walked, by where the set lives
+    "consensus_cache_bulk_keys_total",
     # device dispatch
     "consensus_checks_total",
     "consensus_dispatch_total",

@@ -131,6 +131,8 @@ NO_READER_YET = (
     "consensus_sighash_template_total",
     # PR 47: the interpreter's workers' busy seconds, summed and the slowest's
     "consensus_interpret_worker_seconds_total",
+    # PR 49: the keys a bulk cache call walked, by where the set lives
+    "consensus_cache_bulk_keys_total",
 )
 # `PERF.md` section 6 reads it beside `compile_s.setup`, not as a metric: the
 # persistent compile cache's hits and the misses it wrote an entry for.
@@ -524,6 +526,33 @@ def test_a_connect_runs_its_seams_inside_named_phases(seams):
     report = verifier.phases.report()
     assert report["block_free"]["calls"] == report["session_setup"]["calls"] == 2
     assert report["gc_sweep"]["calls"] == 2 and report["accept"]["calls"] == 2
+
+
+def test_a_connect_walks_its_cache_keys_in_the_native_set():
+    """The counter that says the native set engaged: a connect's bulk probes
+    and inserts raise `consensus_cache_bulk_keys_total{store="native"}` by
+    the block's keys (six inputs, one check each) and `{store="python"}` by
+    nothing."""
+    tag = os.urandom(4).hex()
+    bulk = get_registry().get("consensus_cache_bulk_keys_total")
+    verifier = TpuSecpVerifier()
+    raw, coins = _block("contract/bulk", HEIGHT)
+    sig = SigCache(cache_label=f"sig-{tag}")
+    walked = {}
+    for turn in ("cold", "warm-sig"):
+        script = ScriptExecutionCache(cache_label=f"script-{turn}-{tag}")
+        res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                            verifier=verifier, sig_cache=sig, script_cache=script)
+        assert res.ok and len(res.input_results) == 6
+        for cache in (sig, script):
+            label = cache._poison_site[len("sigcache."):]
+            walked[turn, label[:3]] = bulk.value(cache=label, store="native")
+            assert bulk.value(cache=label, store="python") == 0
+    # cold: six successes inserted into each cache, no probe of an empty one;
+    # on the warm signature cache: six probes more, all hits, nothing to
+    # insert; the fresh script cache takes its six results
+    assert walked == {("cold", "sig"): 6, ("cold", "scr"): 6,
+                      ("warm-sig", "sig"): 12, ("warm-sig", "scr"): 6}
 
 
 def test_a_stream_runs_its_seams_inside_named_phases(seams):

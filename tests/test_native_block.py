@@ -364,8 +364,8 @@ def test_hits_multisig_and_two_failures_parity(monkeypatch):
         True, True, True, False, False, True, True]
     assert len(nview) == len(coins) == n_coins  # view untouched on reject
     # successes, and only successes, went into both caches of both paths
-    assert set(nat[1]._set) == set(py[1]._set) and len(nat[1]) == 5
-    assert set(nat[0]._set) == set(py[0]._set)
+    assert set(nat[1].keys_oldest_first()) == set(py[1].keys_oldest_first()) and len(nat[1]) == 5
+    assert set(nat[0].keys_oldest_first()) == set(py[0].keys_oldest_first())
     for a, b in zip(py, nat):
         assert (a.hits, a.misses, a.insertions) == (b.hits, b.misses, b.insertions)
 

@@ -333,7 +333,7 @@ def test_bulk_methods_match_single_key_methods(name, monkeypatch):
         got, want = run(bulk, True), run(single, False)
         assert per_key_probes.count(bulk) == 0  # one lock hold, no fan-out
     assert got == want
-    assert list(bulk._set) == list(single._set)  # LRU order, oldest first
+    assert bulk.keys_oldest_first() == single.keys_oldest_first()  # LRU order, oldest first
     for attr in ("hits", "misses", "insertions", "evictions", "erases"):
         assert getattr(bulk, attr) == getattr(single, attr), attr
     assert bulk.hits + bulk.misses == sum(len(a) for a in got)
@@ -347,6 +347,242 @@ def test_bulk_methods_match_single_key_methods(name, monkeypatch):
             cache=single._poison_site[9:]
         ), metric
     if name == "eviction":
-        assert bulk.evictions == 6 and list(bulk._set) == [_k(i) for i in (0, 6, 7, 8)]
+        assert bulk.evictions == 6 and bulk.keys_oldest_first() == [_k(i) for i in (0, 6, 7, 8)]
     if name == "erase":
         assert bulk.erases == 3 and got[0] == [True, True, False, False, True]
+
+
+# -- the native set (native/lru.hpp) against the Python set ---------------
+
+from bitcoinconsensus_tpu import native_bridge  # noqa: E402
+
+needs_native = pytest.mark.skipif(
+    not native_bridge.available(), reason="native core unavailable"
+)
+_COUNTERS = native_bridge.NativeLruSet.COUNTERS
+_REGISTRY = ("lookups_total", "hits_total", "misses_total",
+             "insertions_total", "evictions_total", "erases_total")
+
+
+def _python_set_cache(monkeypatch, max_entries, label):
+    """A `SigCache` made where the native core cannot be loaded: its keys
+    live in the OrderedDict, the plain reference."""
+    with monkeypatch.context() as mp:
+        mp.setattr(native_bridge, "available", lambda: False)
+        cache = SigCache(max_entries, cache_label=label)
+    assert cache._nat is None
+    return cache
+
+
+def _registry(label, name, **more):
+    from bitcoinconsensus_tpu.obs import get_registry
+
+    return get_registry().get(f"consensus_cache_{name}").value(
+        cache=label, **more)
+
+
+@needs_native
+@pytest.mark.parametrize("max_entries", [5, 24])
+@pytest.mark.parametrize("seed", range(6))
+def test_native_set_matches_python_set(seed, max_entries, monkeypatch):
+    """Seeded random sequences of every operation on a native-set cache
+    and on its Python-set twin, over a pool of keys a few times the bound,
+    so that bulk calls evict in their middle and re-add what they evicted:
+    after every step the answers, the five counters and the keys oldest
+    first are equal; at the end so is every registry series, and each
+    side's bulk keys were counted under its own store."""
+    import os
+    import random
+
+    import numpy as np
+
+    rng = random.Random(1000 * max_entries + seed)
+    tag = os.urandom(4).hex()
+    labels = f"nat-{tag}", f"py-{tag}"
+    nat = SigCache(max_entries, cache_label=labels[0])
+    py = _python_set_cache(monkeypatch, max_entries, labels[1])
+    assert nat._nat is not None
+    pool = [rng.randbytes(32) for _ in range(3 * max_entries)]
+    bulk_keys = 0
+
+    def step(cache):
+        r = random.Random(step_seed)
+        op = r.choice(("add_key", "add_keys", "add_keys_mask", "add_keys_idx",
+                       "contains_key", "contains_keys", "discard_key"))
+        ks = [r.choice(pool) for _ in range(r.randrange(0, 2 * max_entries))]
+        blob, erase = b"".join(ks), r.random() < 0.4
+        if op == "add_key":
+            return op, 0, cache.add_key(r.choice(pool))
+        if op == "add_keys":
+            return op, len(ks), cache.add_keys(blob)
+        if op == "add_keys_mask":
+            mask = np.array([r.random() < 0.6 for _ in ks], dtype=bool)
+            return op, int(mask.sum()), cache.add_keys(blob, mask)
+        if op == "add_keys_idx":  # any order, with repeats, int32 as batch.py's
+            idx = np.array([r.randrange(len(ks)) for _ in ks if r.random() < 0.7],
+                           dtype=np.int32)
+            return op, len(idx), cache.add_keys(blob, idx)
+        if op == "contains_key":
+            return op, 0, cache.contains_key(r.choice(pool), erase=erase)
+        if op == "contains_keys":
+            got = cache.contains_keys(blob, len(ks), erase=erase)
+            assert got.dtype == bool and got.shape == (len(ks),)
+            return op, len(ks), got.tolist()
+        return op, 0, cache.discard_key(r.choice(pool))
+
+    for i in range(300):
+        step_seed = f"{seed}-{max_entries}-{i}"
+        got, want = step(nat), step(py)
+        assert got == want, (i, got[0])
+        bulk_keys += got[1]
+        for attr in _COUNTERS:
+            assert getattr(nat, attr) == getattr(py, attr), (i, got[0], attr)
+        assert nat.keys_oldest_first() == py.keys_oldest_first(), (i, got[0])
+        assert len(nat) == len(py) <= max_entries
+    assert nat.evictions > 0 and nat.erases > 0 and nat.hits > 0
+    assert nat.insertions - nat.evictions - nat.erases == len(nat)
+    for name in _REGISTRY + ("entries",):
+        assert _registry(labels[0], name) == _registry(labels[1], name), name
+    assert _registry(labels[0], "hits_total") == nat.hits
+    assert bulk_keys > 0
+    for label, own, other in ((labels[0], "native", "python"),
+                              (labels[1], "python", "native")):
+        assert _registry(label, "bulk_keys_total", store=own) == bulk_keys
+        assert _registry(label, "bulk_keys_total", store=other) == 0
+
+
+@needs_native
+def test_native_set_grows_with_what_it_holds_and_refuses_bad_input():
+    """A fresh 1 Mi-entry cache is a few hundred bytes until it is filled
+    (the benchmark makes one before every timed connect); a blob shorter
+    than the keys asked of it, an index outside it and a key that is not
+    32 bytes raise before the set is touched."""
+    import time
+
+    import numpy as np
+
+    t0 = time.perf_counter()
+    caches = [SigCache(1 << 20, cache_label="grow") for _ in range(200)]
+    assert time.perf_counter() - t0 < 1.0  # 40 MB each would take seconds
+    cache = caches[0]
+    blob = _blob(range(4))
+    with pytest.raises(ValueError):
+        cache.contains_keys(blob, 5)
+    with pytest.raises(IndexError):
+        cache.add_keys(blob, np.array([0, 4]))
+    with pytest.raises(IndexError):
+        cache.add_keys(blob, np.array([-1]))
+    for call in (cache.add_key, cache.contains_key, cache.discard_key):
+        with pytest.raises(ValueError):
+            call(b"\x01" * 31)
+    assert len(cache) == 0 and cache.keys_oldest_first() == []
+    assert (cache.hits, cache.misses, cache.insertions) == (0, 0, 0)
+    cache.add_keys(blob, np.array([3, 0]))
+    assert cache.keys_oldest_first() == [_k(3), _k(0)]
+
+
+@needs_native
+def test_native_set_concurrent_bulk_calls_keep_the_accounts():
+    """Four threads of bulk inserts, bulk probes with `erase` and discards
+    on ONE native-set cache, the interpreter switching every few
+    instructions: no entry and no count is lost. `insertions - evictions -
+    erases == len(cache)` and `hits + misses == lookups`, in the object and
+    in the registry."""
+    import os
+    import random
+    import sys
+    import threading
+
+    import numpy as np
+
+    label = "conc-" + os.urandom(4).hex()
+    cache = SigCache(max_entries=96, cache_label=label)
+    assert cache._nat is not None
+    rng = random.Random(7)
+    pool = [rng.randbytes(32) for _ in range(400)]
+    n_threads, n_ops = 4, 600
+    barrier = threading.Barrier(n_threads)
+    lookups = [0] * n_threads
+    errors = []
+
+    def worker(tid):
+        r = random.Random(tid)
+        try:
+            barrier.wait()
+            for _ in range(n_ops):
+                ks = r.sample(pool, r.randrange(1, 64))
+                blob = b"".join(ks)
+                op = r.randrange(4)
+                if op == 0:
+                    cache.add_keys(blob)
+                elif op == 1:
+                    cache.add_keys(
+                        blob, np.array([r.random() < 0.5 for _ in ks]))
+                elif op == 2:
+                    hit = cache.contains_keys(blob, len(ks),
+                                              erase=r.random() < 0.3)
+                    assert hit.shape == (len(ks),)
+                    lookups[tid] += len(ks)
+                else:
+                    cache.discard_key(ks[0])
+        except Exception as e:  # pragma: no cover - surfaced below
+            errors.append(e)
+
+    threads = [threading.Thread(target=worker, args=(t,))
+               for t in range(n_threads)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors
+    assert not any(t.is_alive() for t in threads)
+    keys = cache.keys_oldest_first()
+    assert len(keys) == len(set(keys)) == len(cache) <= 96
+    assert cache.insertions - cache.evictions - cache.erases == len(cache)
+    assert cache.hits + cache.misses == sum(lookups) > 0
+    assert cache.evictions > 0 and cache.erases > 0
+    for attr, name in zip(_COUNTERS, _REGISTRY[1:]):
+        assert _registry(label, name) == getattr(cache, attr), name
+    assert _registry(label, "lookups_total") == sum(lookups)
+
+
+@needs_native
+def test_native_set_fault_plan_visits_the_site_once_a_key(monkeypatch):
+    """With a `poison` fault armed on `sigcache.sig`, `contains_keys` on a
+    native-set cache still goes key by key through `contains_key`: the site
+    is asked once a key, in blob order, the absent keys among its first three
+    answers are fabricated hits that count as hits, and the set is as it was."""
+    from bitcoinconsensus_tpu.models import sigcache as sigcache_mod
+    from bitcoinconsensus_tpu.resilience.faults import (
+        FaultPlan,
+        FaultSpec,
+        inject,
+        poison_hit,
+    )
+
+    cache = SigCache()  # the default label: the site every connect asks
+    assert cache._nat is not None and cache._poison_site == "sigcache.sig"
+    cache.add_keys(_blob([0, 1]))
+    visits = []
+    monkeypatch.setattr(
+        sigcache_mod._faults, "poison_hit",
+        lambda site: (visits.append(site), poison_hit(site))[1],
+    )
+    ids = [9, 0, 8, 1, 7]
+    with inject(FaultPlan([FaultSpec("sigcache.sig", "poison", count=3)])) as inj:
+        got = cache.contains_keys(_blob(ids), len(ids))
+    assert visits == ["sigcache.sig"] * 5
+    assert inj.total_fired() == 3  # on 9, 0 and 8; 0 was there anyway
+    assert got.tolist() == [True, True, True, True, False]  # 9 and 8: fabricated
+    assert (cache.hits, cache.misses) == (4, 1)
+    assert cache.keys_oldest_first() == [_k(0), _k(1)]
+    # the plan gone, the same call is one native walk again
+    visits.clear()
+    assert cache.contains_keys(_blob(ids), len(ids)).tolist() == [
+        False, True, False, True, False]
+    assert visits == []
