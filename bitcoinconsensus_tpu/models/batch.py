@@ -142,13 +142,36 @@ _SIGHASH_TEMPLATES = _obs_counter(
     "states it keeps every 4,096 bytes of their shared prefix)",
     ("event",),
 )
-_WORKER_SECONDS = _obs_counter(
-    "consensus_interpret_worker_seconds_total",
-    "busy seconds of the native interpreter's workers inside its index-mode "
-    "calls: sum (over the workers) and max (the slowest worker's), added a "
-    "call; max times the width over sum says how level the calls ended",
-    ("stat",),
+# The native stage clock (native/interp.hpp), raised once a fixpoint from the
+# session's table and once a block from the parsed block's.
+_NATIVE_STAGES = _obs_counter(
+    "consensus_native_stage_seconds_total",
+    "seconds the native core spent beneath the ctypes boundary, by the call "
+    "and its serial stage: interpret (setup, workers, merge), lanes (order, "
+    "shards), digests (shards), accounting (decide, fill, copy); a call's "
+    "stages tile it",
+    ("call", "stage"),
 )
+_FAN_OUT = _obs_counter(
+    "consensus_fan_out_seconds_total",
+    "what the native core's thread fan-outs say of themselves, by the "
+    "session call (interpret, lanes, digests): wall (entry to joined), held "
+    "(the width times wall), sum and max (the workers' busy seconds summed "
+    "and the slowest's), start_lag (entry to the latest worker's first "
+    "instruction), tail (the last worker's end to joined)",
+    ("call", "stat"),
+)
+
+
+def raise_native_stages(read) -> None:
+    """One read of a handle's stage clock (`native_bridge.NativeStages`)
+    into the registry's two families."""
+    for (call, stage), (seconds, _calls) in read.stages.items():
+        _NATIVE_STAGES.inc(seconds, call=call, stage=stage)
+    for (call, stat), seconds in read.fans.items():
+        _FAN_OUT.inc(seconds, call=call, stat=stat)
+
+
 _TAPROOT_HASHES = _obs_counter(
     "consensus_taproot_hash_total",
     "taproot hashes the native interpreter made: BIP 341 message digests "
@@ -713,8 +736,7 @@ class IdxFixpoint:
         self.sighash_templates = self.nsess.sighash_templates()
         for event, n in self.sighash_templates.items():
             _SIGHASH_TEMPLATES.inc(n, event=event)
-        for stat, seconds in self.nsess.worker_seconds().items():
-            _WORKER_SECONDS.inc(seconds, stat=stat)
+        raise_native_stages(self.nsess.stages())
         self.lanes = self.nsess.lane_kinds()
         for kind, n in self.lanes.items():
             _CHECKS_TOTAL.inc(n, kind=kind)

@@ -60,7 +60,7 @@ from ..obs import histogram as _obs_histogram
 from ..obs import span as _span
 from ..utils.gcpause import gc_paused
 from ..utils.profiling import phases_of
-from .batch import BatchItem, BatchResult, verify_batch
+from .batch import BatchItem, BatchResult, raise_native_stages, verify_batch
 from .sigcache import ScriptExecutionCache, SigCache
 
 __all__ = [
@@ -615,9 +615,12 @@ class _NativeConnect:
 
     def _count_probes(self) -> None:
         """One read a block, after its apply: the probes its accounting and
-        that apply made (the parsed block counted them as it went)."""
+        that apply made (the parsed block counted them as it went), and
+        beside them what the accounting spent in each of its native
+        stages."""
         for table, n in self._nblk.coin_probes().items():
             _COIN_PROBES.inc(n, table=table)
+        raise_native_stages(self._nblk.stages())
 
     def _free_block(self) -> None:
         """The `block_free` phase: what ends with the run, dropped after its

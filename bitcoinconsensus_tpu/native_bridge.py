@@ -27,7 +27,7 @@ import os
 import subprocess
 import threading
 import warnings
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -152,8 +152,8 @@ def lib() -> Optional[ctypes.CDLL]:
         # the typed prototypes below would mis-call it. Fall back to the
         # pure-Python paths instead.
         L.nat_version.restype = ctypes.c_int
-        if L.nat_version() < 19:
-            return _absent(f"{so} exports ABI v{L.nat_version()} (< 19)")
+        if L.nat_version() < 20:
+            return _absent(f"{so} exports ABI v{L.nat_version()} (< 20)")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -241,8 +241,8 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_session_sighashes.restype = None
         L.nat_session_sighash_work.argtypes = [vp, i64p]
         L.nat_session_sighash_work.restype = None
-        L.nat_session_worker_ns.argtypes = [vp, i64p]
-        L.nat_session_worker_ns.restype = None
+        L.nat_session_stages.argtypes = [vp, i64p]
+        L.nat_session_stages.restype = None
         L.nat_sha256_uses_sha_ni.argtypes = []
         L.nat_sha256_uses_sha_ni.restype = ctypes.c_int32
         L.nat_session_lane_kinds.argtypes = [vp, i64p]
@@ -296,6 +296,8 @@ def lib() -> Optional[ctypes.CDLL]:
         L.nat_block_script_keys.restype = ctypes.c_int64
         L.nat_block_coin_probes.argtypes = [vp, i64p]
         L.nat_block_coin_probes.restype = None
+        L.nat_block_stages.argtypes = [vp, i64p]
+        L.nat_block_stages.restype = None
         L.nat_view_new.restype = vp
         L.nat_view_free.argtypes = [vp]
         L.nat_view_clone.argtypes = [vp]
@@ -578,6 +580,21 @@ class NativeTx:
 
     def precompute(self) -> None:
         lib().nat_tx_precompute(self._ptr)
+
+
+class NativeStages(NamedTuple):
+    """One read of a handle's native stage clock (`NativeSession.stages()`,
+    `NativeBlock.stages()`): `stages[(call, stage)]` = (seconds, times
+    stamped), `fans[(call, stat)]` = seconds."""
+
+    stages: Dict[Tuple[str, str], Tuple[float, int]]
+    fans: Dict[Tuple[str, str], float]
+
+    @staticmethod
+    def table(keys, out) -> Dict[Tuple[str, str], Tuple[float, int]]:
+        """The core's layout: a nanosecond count a key, then a stamp count a key."""
+        n = len(keys)
+        return {k: (out[i] / 1e9, int(out[n + i])) for i, k in enumerate(keys)}
 
 
 class NativeSession:
@@ -921,18 +938,42 @@ class NativeSession:
         Monotone over the session's life."""
         return dict(zip(self.TEMPLATE_EVENTS, self._sighash_counts()[4:]))
 
-    WORKER_STATS = ("sum", "max")
+    # The native stage clock (native/interp.hpp): the session's three calls
+    # that fan out, each call's serial stages in the order the core keeps
+    # them, and what a fan-out says of itself.
+    STAGES = (("interpret", "setup"), ("interpret", "workers"), ("interpret", "merge"),
+              ("lanes", "order"), ("lanes", "shards"), ("digests", "shards"))
+    FAN_CALLS = ("interpret", "lanes", "digests")
+    FAN_STATS = ("wall", "held", "sum", "max", "start_lag", "tail")
 
-    def worker_seconds(self) -> Dict[str, float]:
-        """What the interpreter's workers spent inside this session's
-        `verify_inputs_idx` calls (`WORKER_STATS`): their busy seconds
-        summed, and the slowest worker's, added a call; `max` times the
-        width over `sum` says how level the calls ended (1.0: every worker
-        was busy as long as the slowest). A call that ran on the caller's
-        thread counts as one worker. Monotone over the session's life."""
-        out = (ctypes.c_int64 * 2)()
-        lib().nat_session_worker_ns(self._ptr, out)
-        return {k: out[i] / 1e9 for i, k in enumerate(self.WORKER_STATS)}
+    def stages(self) -> "NativeStages":
+        """What this session's native calls spent beneath the ctypes
+        boundary so far, on the clock of `time.perf_counter` (one read of
+        the core's table). `.stages[(call, stage)]` is (seconds, times
+        stamped) for the serial stages that tile a call: `interpret`
+        (`verify_inputs_idx`) is `setup` (the scratch sessions and the store
+        pool), `workers` (around the fan-out) and `merge` (the scratches'
+        counters summed and the serial merge in index order, to the return);
+        `lanes` (`uniq_lanes`) is `order` (the serial `lanes_order` loop) and
+        `shards` (around the fan-out); `digests` (`uniq_digests`) is
+        `shards`. `.fans[(call, stat)]` is seconds, what the call's fan-outs
+        said of themselves (`FAN_STATS`): `wall` entry to joined, `held` the
+        width times that, `sum` and `max` the workers' busy seconds summed
+        and the slowest's, `start_lag` entry to the latest worker's first
+        instruction, `tail` the last-ended worker's last instruction to
+        joined. `max` times the width over `sum` says how level the calls
+        ended (1.0: every worker busy as long as the slowest); a call on the
+        caller's thread is one worker, `start_lag` 0 and `sum` = `max` =
+        `wall`. Monotone over the session's life."""
+        n, f = len(self.STAGES), len(self.FAN_STATS)
+        out = (ctypes.c_int64 * (2 * n + len(self.FAN_CALLS) * f))()
+        lib().nat_session_stages(self._ptr, out)
+        return NativeStages(
+            NativeStages.table(self.STAGES, out),
+            {(call, stat): out[2 * n + c * f + j] / 1e9
+             for c, call in enumerate(self.FAN_CALLS)
+             for j, stat in enumerate(self.FAN_STATS)},
+        )
 
     LANE_KINDS = ("ecdsa", "schnorr", "tweak")
     TAPROOT_HASHES = ("sighash", "leaf", "branch", "tweak")
@@ -1246,6 +1287,21 @@ class NativeBlock:
         lib().nat_block_coin_probes(
             self._ptr, out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
         return {"view": int(out[0]), "block": int(out[1])}
+
+    STAGES = (("accounting", "decide"), ("accounting", "fill"), ("accounting", "copy"))
+
+    def stages(self) -> "NativeStages":
+        """What the last `accounting()` of this block spent beneath the
+        ctypes boundary, `.stages[(call, stage)]` = (seconds, times
+        stamped): `decide` (pass 1: everything that can refuse the block),
+        `fill` (pass 2: the per-input records, the spent-output digests, the
+        hash precomputes, the script cache's keys) and `copy` (the five
+        arrays copied out into `accounting()`'s buffers). A block pass 1
+        refused shows `fill` and `copy` unstamped. An accounting starts all
+        at zero."""
+        out = (ctypes.c_int64 * (2 * len(self.STAGES)))()
+        lib().nat_block_stages(self._ptr, out)
+        return NativeStages(NativeStages.table(self.STAGES, out), {})
 
     def spent_digests(self) -> np.ndarray:
         """(n_tx, 32) per-tx spent-output digests (coinbase rows zero);

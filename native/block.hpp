@@ -181,6 +181,11 @@ struct NBlock {
     // of the block's own coin table. Pass 1 starts both at zero; `mutable`
     // because an apply reads the block and counts on it.
     mutable i64 view_probes = 0, block_probes = 0;
+    // The native stage clock of the `accounting` phase (interp.hpp): pass 1
+    // and pass 2 inside block_accounting, and nat_block_acct_data's copy of
+    // the five arrays out. Pass 1 starts all three at zero.
+    enum : int { ST_DECIDE = 0, ST_FILL, ST_COPY, ST_COUNT };
+    StageClock<ST_COUNT> stages;
 };
 
 // The parse's per-transaction stage, from the block's own wire bytes
@@ -779,11 +784,15 @@ inline void block_acct_fill(NBlock& blk, SpentOutputs& all, u32 flags,
 // Both passes. `salt` (the script-execution cache's) NULL makes no key.
 inline i32 block_accounting(NBlock& blk, const NView& view, i64 height,
                             u32 flags, const u8* salt, size_t salt_len) {
+    blk.stages = {};
+    i64 at = steady_ns();
     SpentOutputs spent;
     i32 r = block_acct_decide(blk, view, height, flags, salt != nullptr, spent);
+    at = blk.stages.stamp(NBlock::ST_DECIDE, at);
     if (r != BR_OK) return r;
     block_acct_fill(blk, spent, flags, salt, salt_len);
     blk.acct.ready = true;
+    blk.stages.stamp(NBlock::ST_FILL, at);
     return BR_OK;
 }
 

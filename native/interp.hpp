@@ -1265,6 +1265,64 @@ struct StorePool {
     }
 };
 
+// --- The native stage clock --------------------------------------------------
+//
+// What the host phases (`verifier.phases`) hold beneath the ctypes boundary,
+// kept as the other counters here are: plain fields added to where the work
+// runs and read out once, by nat_session_stages once a fixpoint and by
+// nat_block_stages once a block. steady_clock is CLOCK_MONOTONIC, the clock of
+// Python's time.perf_counter and of the phases' spans, so a stage lies inside
+// its phase on one axis. Two reads a stage a call, two a worker a fan-out:
+// never one an input, a lane or a coin.
+
+inline i64 steady_ns() {
+    return std::chrono::duration_cast<std::chrono::nanoseconds>(
+               std::chrono::steady_clock::now().time_since_epoch()).count();
+}
+
+// A fan-out's own account (nat.cpp fan_out, which alone writes it), added a
+// call, nanoseconds: `wall` from entry to the last join; `held` the width
+// times that, the thread time the call held; `sum` and `max` the workers'
+// busy time, first instruction to last, summed and the slowest's; `start_lag`
+// from entry to the latest worker's first instruction (the pthread_create
+// loop and the scheduler); `tail` from the last instruction of the worker
+// that ended last to joined (the exits and the joins), so start_lag + tail
+// never passes wall. Work on the caller's thread is one worker that starts
+// at once: start_lag 0, sum = max = wall.
+struct FanStats {
+    enum : int { WALL = 0, HELD, SUM, MAX, START_LAG, TAIL, COUNT };
+    i64 ns[COUNT] = {0, 0, 0, 0, 0, 0};
+};
+
+// Serial stages of N kinds: nanoseconds and times stamped.
+template <int N>
+struct StageClock {
+    i64 ns[N] = {};
+    i64 calls[N] = {};
+    // Stage k ran from t0 to now; returns now, the next stage's start.
+    i64 stamp(int k, i64 t0) {
+        i64 t1 = steady_ns();
+        ns[k] += t1 - t0;
+        calls[k]++;
+        return t1;
+    }
+    // out[0..N): the nanoseconds, out[N..2N): the times stamped.
+    void read(i64* out) const {
+        std::memcpy(out, ns, sizeof ns);
+        std::memcpy(out + N, calls, sizeof calls);
+    }
+};
+
+// Stamps stage k from `at` when it leaves scope: declared ahead of a call's
+// locals, it counts their destructors into the call's last stage.
+template <int N>
+struct StageEnd {
+    StageClock<N>& clock;
+    int k;
+    i64& at;
+    ~StageEnd() { clock.stamp(k, at); }
+};
+
 struct Session {
     // Oracle verdicts published WITH their bytes (nat_session_add_known,
     // _batch: the wire driver and the tests' executable spec). Index mode
@@ -1350,16 +1408,23 @@ struct Session {
     // (LegacyTemplate::EV_BUILT, EV_SERVED, EV_RESUMED); monotone and summed
     // alike.
     i64 sighash_template[LegacyTemplate::EV_COUNT] = {0, 0, 0};
-    // The interpreter's workers inside this session's index-mode calls:
-    // [0] their busy nanoseconds summed, [1] the slowest worker's, added a
-    // call; [1] times the width over [0] says how level the calls ended
-    // (1.0: every worker as long as the slowest). A call on the caller's
-    // thread is one worker. Monotone.
-    i64 worker_ns[2] = {0, 0};
-    void note_workers(i64 sum, i64 max) {
-        worker_ns[0] += sum;
-        worker_ns[1] += max;
-    }
+    // The session's three calls that fan out, each with the fan-out's own
+    // account (FanStats) and its serial stages, which tile the call: their
+    // sum is the C call's duration less a few clock reads. interpret
+    // (nat_verify_inputs_idx): setup, the scratch sessions and the pool;
+    // workers, around the fan-out; merge, the scratches' counters summed
+    // and the serial merge in index order, to the return. lanes
+    // (nat_session_uniq_lanes): order, the serial lanes_order loop; shards,
+    // around the fan-out. digests (nat_session_uniq_digests): shards.
+    // `max` times the width over `sum` says how level a call's workers
+    // ended (1.0: every one as long as the slowest). Monotone.
+    enum : int { FAN_INTERPRET = 0, FAN_LANES, FAN_DIGESTS, FAN_COUNT };
+    FanStats fans[FAN_COUNT];
+    enum : int {
+        ST_INTERPRET_SETUP = 0, ST_INTERPRET_WORKERS, ST_INTERPRET_MERGE,
+        ST_LANES_ORDER, ST_LANES_SHARDS, ST_DIGESTS_SHARDS, ST_COUNT
+    };
+    StageClock<ST_COUNT> stages;
     // Taproot's hashing by this session's interpretations, monotone and
     // summed like the two above: BIP 341 digests (key path and tapscript),
     // and the commitment's tagged hashes (TapLeaf, TapBranch, TapTweak).

@@ -159,12 +159,23 @@ i32 run_verify_input(Session* sess, NTx* tx, i32 n_in, i64 amount,
 // on the chip's host, more than a 600-lane shard's work). Nothing a
 // worker runs recurses or holds more than a few KB of locals. A worker
 // that cannot be made runs on the caller, and so does everything at T < 2.
+//
+// Given `stats` (a session's: interp.hpp FanStats) it is also the one place
+// that times threads: it stamps its entry and the last join, every worker
+// its first and last instruction into its own Job, and the six sums are
+// added after the join. Without, no clock is read.
 constexpr size_t WORKER_STACK = 1 << 20;
 
 template <class Fn>
-void fan_out(i32 n, i32 T, Fn fn) {
+void fan_out(i32 n, i32 T, Fn fn, FanStats* stats = nullptr) {
+    const i64 entered = stats ? steady_ns() : 0;
     if (T < 2) {
         fn(0, 0, n);
+        if (stats) {
+            i64 wall = steady_ns() - entered;
+            for (int k : {FanStats::WALL, FanStats::HELD, FanStats::SUM, FanStats::MAX})
+                stats->ns[k] += wall;
+        }
         return;
     }
     struct Job {
@@ -172,6 +183,13 @@ void fan_out(i32 n, i32 T, Fn fn) {
         i32 t, lo, hi;
         pthread_t id;
         bool spawned;
+        bool timed;
+        i64 first, last;
+        void run() {
+            if (timed) first = steady_ns();
+            (*fn)(t, lo, hi);
+            if (timed) last = steady_ns();
+        }
     };
     std::vector<Job> jobs((size_t)T);
     pthread_attr_t attr;
@@ -180,18 +198,32 @@ void fan_out(i32 n, i32 T, Fn fn) {
     for (i32 t = 0; t < T; t++) {
         Job& j = jobs[(size_t)t];
         j = Job{&fn, t, (i32)((i64)n * t / T), (i32)((i64)n * (t + 1) / T),
-                pthread_t(), false};
+                pthread_t(), false, stats != nullptr, 0, 0};
         auto body = [](void* p) -> void* {
-            Job* job = static_cast<Job*>(p);
-            (*job->fn)(job->t, job->lo, job->hi);
+            static_cast<Job*>(p)->run();
             return nullptr;
         };
         j.spawned = pthread_create(&j.id, &attr, body, &j) == 0;
-        if (!j.spawned) fn(j.t, j.lo, j.hi);
+        if (!j.spawned) j.run();
     }
     pthread_attr_destroy(&attr);
     for (Job& j : jobs)
         if (j.spawned) pthread_join(j.id, nullptr);
+    if (!stats) return;
+    const i64 joined = steady_ns();
+    i64 sum = 0, max = 0, first = entered, last = entered;
+    for (const Job& j : jobs) {
+        sum += j.last - j.first;
+        max = std::max(max, j.last - j.first);
+        first = std::max(first, j.first);
+        last = std::max(last, j.last);
+    }
+    stats->ns[FanStats::WALL] += joined - entered;
+    stats->ns[FanStats::HELD] += (joined - entered) * T;
+    stats->ns[FanStats::SUM] += sum;
+    stats->ns[FanStats::MAX] += max;
+    stats->ns[FanStats::START_LAG] += first - entered;
+    stats->ns[FanStats::TAIL] += joined - last;
 }
 
 // Width of the lane-prep fan-out (uniq_lanes, uniq_digests): one shard per
@@ -288,7 +320,9 @@ extern "C" {
 // 18: nat_view_disconnect_block, nat_undo_matches_block; nat_view_digest
 //     takes n_threads.
 // 19: nat_lru_* (native/lru.hpp): the success caches' key set.
-int nat_version() { return 19; }
+// 20: nat_session_stages, nat_block_stages (the native stage clock); 17's
+//     accessor went into the former.
+int nat_version() { return 20; }
 
 // --- Success caches' key set (native/lru.hpp) ------------------------------
 //
@@ -440,7 +474,9 @@ void nat_block_acct_meta(void* b, i64* fees, i64* sigop_cost, i64* n_inputs,
 
 void nat_block_acct_data(void* b, i32* tx_index, i32* n_in, i64* amounts,
                          i64* spk_offs, u8* spk_blob) {
-    const BlockAcct& A = static_cast<NBlock*>(b)->acct;
+    auto* blk = static_cast<NBlock*>(b);
+    i64 at = steady_ns();
+    const BlockAcct& A = blk->acct;
     size_t n = A.tx_index.size();
     if (n) {
         std::memcpy(tx_index, A.tx_index.data(), n * sizeof(i32));
@@ -450,6 +486,7 @@ void nat_block_acct_data(void* b, i32* tx_index, i32* n_in, i64* amounts,
     std::memcpy(spk_offs, A.spk_offs.data(), (n + 1) * sizeof(i64));
     if (!A.spk_blob.empty())
         std::memcpy(spk_blob, A.spk_blob.data(), A.spk_blob.size());
+    blk->stages.stamp(NBlock::ST_COPY, at);
 }
 
 // Per-tx spent-output digests (models/sigcache.py spent_digest stream);
@@ -477,6 +514,14 @@ void nat_block_coin_probes(void* b, i64* out) {
     auto* blk = static_cast<NBlock*>(b);
     out[0] = blk->view_probes;
     out[1] = blk->block_probes;
+}
+
+// The block's native stage clock (block.hpp NBlock::stages): what its last
+// accounting spent in pass 1 (decide), in pass 2 (fill) and in
+// nat_block_acct_data's copy since. out[0..3) nanoseconds, out[3..6) the
+// times each was stamped. An accounting starts all at zero.
+void nat_block_stages(void* b, i64* out) {
+    static_cast<NBlock*>(b)->stages.read(out);
 }
 
 void* nat_view_new() { return new NView(); }
@@ -836,14 +881,15 @@ void prep_lanes_range(const PartsView* parts, i32 n, u8* fields,
 // its slice of the outputs. No shared mutable state, no merge.
 void prep_lanes_impl(const std::vector<PartsView>& parts, i32 n_threads,
                      u8* fields, i32* want_odd, i32* parity, i32* has_t2,
-                     i32* neg1, i32* neg2, i32* valid) {
+                     i32* neg1, i32* neg2, i32* valid,
+                     FanStats* stats = nullptr) {
     const i32 n = (i32)parts.size();
     fan_out(n, prep_shards(n, n_threads), [&](i32, i32 lo, i32 hi) {
         prep_lanes_range(parts.data() + lo, hi - lo,
                          fields + (size_t)lo * 128, want_odd + lo,
                          parity + lo, has_t2 + lo, neg1 + lo, neg2 + lo,
                          valid + lo);
-    });
+    }, stats);
 }
 
 // Wire-shape entry (Python packs blob/offs/kinds; kinds[i]&0xff is the
@@ -1097,11 +1143,6 @@ void nat_verify_inputs(void* s, void** txs, const i32* n_ins,
 // array equal the single-threaded run's at any thread count and under any
 // timing, so lane order is deterministic.
 
-static inline i64 steady_ns() {
-    return std::chrono::duration_cast<std::chrono::nanoseconds>(
-               std::chrono::steady_clock::now().time_since_epoch()).count();
-}
-
 // Interpret inputs [lo, hi) against `sess` (which may be a worker
 // scratch whose `oracle` points at the shared session). rec_end[i] and,
 // where asked for, uniq_end[i] get the sizes of `sess`'s rec_idx and uniq
@@ -1141,6 +1182,8 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
                            i32 n_threads, i32* ok, i32* err, i32* unk,
                            i64* rec_bounds) {
     auto* sess = static_cast<Session*>(s);
+    i64 at = steady_ns();
+    StageEnd<Session::ST_COUNT> merged{sess->stages, Session::ST_INTERPRET_MERGE, at};
     sess->index_mode = true;
     sess->rec_idx.clear();
     sess->call_walk.assign((size_t)n, 0);
@@ -1148,13 +1191,16 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     StorePool& pool = StorePool::get();
     pool.take(sess->uniq, true);
     rec_bounds[0] = 0;
+    FanStats* fan = &sess->fans[Session::FAN_INTERPRET];
     if (n_threads < 2 || n < 2 * n_threads) {
-        // rec_idx was just cleared, so an input's end is its global bound.
-        i64 t0 = steady_ns();
-        run_idx_range(sess, txs, n_ins, amounts, spk_blob, spk_offs, flags, 0,
-                      n, ok, err, unk, walk, rec_bounds + 1, nullptr);
-        i64 ns = steady_ns() - t0;
-        sess->note_workers(ns, ns);
+        // One worker, the caller: rec_idx was just cleared, so an input's
+        // end is its global bound, and there is nothing to merge.
+        at = sess->stages.stamp(Session::ST_INTERPRET_SETUP, at);
+        fan_out(n, 1, [&](i32, i32 lo, i32 hi) {
+            run_idx_range(sess, txs, n_ins, amounts, spk_blob, spk_offs, flags,
+                          lo, hi, ok, err, unk, walk, rec_bounds + 1, nullptr);
+        }, fan);
+        at = sess->stages.stamp(Session::ST_INTERPRET_WORKERS, at);
         return;
     }
     i32 T = n_threads;
@@ -1168,13 +1214,13 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
     // sizes after it. A worker draws ever later blocks, so its inputs lie
     // in its scratch in index order.
     std::vector<i32> owner((size_t)n);
-    std::vector<i64> rec_end((size_t)n), uniq_end((size_t)n), busy_ns((size_t)T, 0);
+    std::vector<i64> rec_end((size_t)n), uniq_end((size_t)n);
     i32 block = std::max(1, n / (DRAWS_A_WORKER * T));
     if (n >= LINE_SLOTS * MIN_DRAWS * T)
         block = (block + LINE_SLOTS - 1) / LINE_SLOTS * LINE_SLOTS;
     std::atomic<i32> cursor{0};
+    at = sess->stages.stamp(Session::ST_INTERPRET_SETUP, at);
     fan_out(T, T, [&](i32 t, i32, i32) {
-        i64 t0 = steady_ns();
         for (;;) {
             i32 lo = cursor.fetch_add(block, std::memory_order_relaxed);
             if (lo >= n) break;
@@ -1184,14 +1230,8 @@ void nat_verify_inputs_idx(void* s, void** txs, const i32* n_ins,
                           spk_offs, flags, lo, hi, ok, err, unk, walk,
                           rec_end.data(), uniq_end.data());
         }
-        busy_ns[(size_t)t] = steady_ns() - t0;
-    });
-    i64 busy_sum = 0, busy_max = 0;
-    for (i64 ns : busy_ns) {
-        busy_sum += ns;
-        busy_max = std::max(busy_max, ns);
-    }
-    sess->note_workers(busy_sum, busy_max);
+    }, fan);
+    at = sess->stages.stamp(Session::ST_INTERPRET_WORKERS, at);
     for (const Session& sc : scratch) {
         sess->sighash_computed += sc.sighash_computed;
         sess->sighash_reused += sc.sighash_reused;
@@ -1282,13 +1322,18 @@ void nat_session_sighash_work(void* s, i64* out) {
         out[2 * Session::SK_COUNT + k] = sess->sighash_template[k];
 }
 
-// What the interpreter's workers spent inside this session's index-mode
-// calls so far: out[0] their busy nanoseconds summed, out[1] the slowest
-// worker's, a call at a time (Session::worker_ns).
-void nat_session_worker_ns(void* s, i64* out) {
+// The session's native stage clock so far (interp.hpp Session::stages,
+// Session::fans), nanoseconds and counts. out[0..6): the stages' nanoseconds
+// in the enum's order (interpret setup, workers, merge; lanes order, shards;
+// digests shards); out[6..12): the times each was stamped; out[12..30): the
+// three fan-outs' accounts (interpret, lanes, digests), six each (wall,
+// held, sum, max, start_lag, tail).
+void nat_session_stages(void* s, i64* out) {
     auto* sess = static_cast<Session*>(s);
-    out[0] = sess->worker_ns[0];
-    out[1] = sess->worker_ns[1];
+    sess->stages.read(out);
+    i64* fan = out + 2 * Session::ST_COUNT;
+    for (int c = 0; c < Session::FAN_COUNT; c++)
+        std::memcpy(fan + c * FanStats::COUNT, sess->fans[c].ns, sizeof sess->fans[c].ns);
 }
 
 // 1 where SHA-256 runs on the CPU's SHA extensions, 0 on the generic transform.
@@ -1355,6 +1400,7 @@ void nat_session_uniq_lanes(void* s, const i32* idxs, i32 nidx, i32 n_threads,
                             u8* fields, i32* want_odd, i32* parity,
                             i32* has_t2, i32* neg1, i32* neg2, i32* valid) {
     auto* sess = static_cast<Session*>(s);
+    i64 at = steady_ns();
     std::vector<PartsView> parts;
     parts.reserve((size_t)nidx);
     for (i32 j = 0; j < nidx; j++) {
@@ -1362,8 +1408,10 @@ void nat_session_uniq_lanes(void* s, const i32* idxs, i32 nidx, i32 n_threads,
         int kind = parts.back().kind;
         if (kind >= KIND_ECDSA && kind <= KIND_TWEAK) sess->lanes_by_kind[kind]++;
     }
+    at = sess->stages.stamp(Session::ST_LANES_ORDER, at);
     prep_lanes_impl(parts, n_threads, fields, want_odd, parity, has_t2, neg1,
-                    neg2, valid);
+                    neg2, valid, &sess->fans[Session::FAN_LANES]);
+    sess->stages.stamp(Session::ST_LANES_SHARDS, at);
 }
 
 // Salted cache-key digests for uniq[idxs[0..nidx)] (models/sigcache.py
@@ -1373,11 +1421,13 @@ void nat_session_uniq_digests(void* s, const u8* salt, i64 salt_len,
                               const i32* idxs, i32 nidx, i32 n_threads,
                               u8* out) {
     auto* sess = static_cast<Session*>(s);
+    i64 at = steady_ns();
     fan_out(nidx, prep_shards(nidx, n_threads), [&](i32, i32 lo, i32 hi) {
         for (i32 j = lo; j < hi; j++)
             digest_one(salt, salt_len, uniq_at(sess, idxs[j]),
                        out + 32 * (size_t)j);
-    });
+    }, &sess->fans[Session::FAN_DIGESTS]);
+    sess->stages.stamp(Session::ST_DIGESTS_SHARDS, at);
 }
 
 // Publish device/cache verdicts for uniq[idxs[0..nidx)] into the oracle:

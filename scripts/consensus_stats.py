@@ -58,8 +58,10 @@ REQUIRED_METRICS = [
     "consensus_sighash_seconds_total",
     # the blanked template a transaction's legacy digests are hashed from
     "consensus_sighash_template_total",
-    # the interpreter's workers' busy seconds in its index-mode calls: sum, max
-    "consensus_interpret_worker_seconds_total",
+    # the native stage clock: the serial stages that tile a native call, and
+    # what its thread fan-outs say of themselves (wall, held, sum, max, ...)
+    "consensus_native_stage_seconds_total",
+    "consensus_fan_out_seconds_total",
     "consensus_taproot_hash_total",
     # CHECKMULTISIG on the index path: the pairings pre-recorded ahead of
     # the key walk, and those the walk behind a returned verdict tried

@@ -446,8 +446,10 @@ def test_fixpoint_round_cap_exact_fallback():
         def sighash_templates(self):
             return {"built": 0, "served": 0, "resumed": 0}
 
-        def worker_seconds(self):
-            return {"sum": 0.0, "max": 0.0}
+        def stages(self):
+            from bitcoinconsensus_tpu.native_bridge import NativeStages
+
+            return NativeStages({}, {})
 
         def lane_kinds(self):
             return {"ecdsa": 0, "schnorr": 0, "tweak": 0}

@@ -74,12 +74,14 @@ READ = (
     "consensus_dispatch_padded_lanes_total",
     "consensus_dispatch_total",
     "consensus_dispatch_transfers_total",
+    "consensus_fan_out_seconds_total",
     "consensus_fixpoint_reinterpreted_inputs_total",
     "consensus_ingress_seconds",
     "consensus_mesh_dispatch_total",
     "consensus_mesh_shard_lanes",
     "consensus_multisig_spec_pairings_total",
     "consensus_multisig_walk_pairings_total",
+    "consensus_native_stage_seconds_total",
     "consensus_serving_admitted_total",
     "consensus_serving_batch_fill",
     "consensus_serving_batch_seconds",
@@ -129,8 +131,6 @@ NO_READER_YET = (
     # PR 46: a transaction's blanked legacy template, built and served
     # (PR 47: and resumed from its grid of SHA-256 states)
     "consensus_sighash_template_total",
-    # PR 47: the interpreter's workers' busy seconds, summed and the slowest's
-    "consensus_interpret_worker_seconds_total",
     # PR 49: the keys a bulk cache call walked, by where the set lives
     "consensus_cache_bulk_keys_total",
 )
@@ -343,16 +343,73 @@ def test_sighash_template_events_are_the_ones_named(workload):
     assert 0 <= events["resumed"] <= events["served"], events
 
 
-def test_interpreter_worker_seconds_are_a_sum_and_its_largest_part(workload):
-    """`PERF.md` section 3 reads `consensus_interpret_worker_seconds_total`
-    by its `stat` label: the workers' busy seconds summed and the slowest
-    worker's, a call. Every fixpoint raises both; no worker is busy longer
-    than all of them together."""
+# The label pairs of the native stage clock's two families (`layers/_stages.py`
+# asks for them by `call` and by `stage` or `stat`; `README.md` "Observability").
+STAGE_PAIRS = {
+    ("interpret", "setup"), ("interpret", "workers"), ("interpret", "merge"),
+    ("lanes", "order"), ("lanes", "shards"), ("digests", "shards"),
+    ("accounting", "decide"), ("accounting", "fill"), ("accounting", "copy"),
+}
+FAN_CALLS = ("interpret", "lanes", "digests")
+FAN_STATS = ("wall", "held", "sum", "max", "start_lag", "tail")
+
+
+def _by_pair(snapshot, name, second):
+    return {(s["labels"]["call"], s["labels"][second]): s["value"]
+            for s in snapshot[name]["samples"]}
+
+
+@pytest.mark.parametrize("call", FAN_CALLS)
+def test_fan_out_seconds_are_a_sum_its_largest_part_and_what_held_them(workload, call):
+    """`layers/_stages.py` reads `consensus_fan_out_seconds_total` by `call`
+    and `stat`: what the fan-outs of the interpreter, of lane prep and of
+    the digests say of themselves. Every fixpoint raises all six of each; no
+    worker is busy longer than all of them together, nor they than the
+    thread time the call held, and a fan-out's start lag and tail lie inside
+    its wall. The workload's calls are too small to make a thread: one
+    worker each, which starts at once."""
     _, snapshot = workload
-    stats = {s["labels"]["stat"]: s["value"]
-             for s in snapshot["consensus_interpret_worker_seconds_total"]["samples"]}
-    assert set(stats) == {"sum", "max"}, stats
-    assert 0 < stats["max"] <= stats["sum"], stats
+    stats = {stat: v for (c, stat), v in
+             _by_pair(snapshot, "consensus_fan_out_seconds_total", "stat").items() if c == call}
+    assert set(stats) == set(FAN_STATS), stats
+    assert 0 < stats["max"] <= stats["sum"] <= stats["held"] + 1e-12, stats
+    assert stats["max"] <= stats["wall"] <= stats["held"], stats
+    assert 0 <= stats["start_lag"] and 0 <= stats["tail"], stats
+    assert stats["start_lag"] + stats["tail"] <= stats["wall"] + 1e-12, stats
+
+
+def test_a_connect_raises_exactly_the_stage_and_fan_out_labels_named():
+    """One native connect raises the nine (`call`, `stage`) pairs of
+    `consensus_native_stage_seconds_total` and the eighteen (`call`, `stat`)
+    pairs of `consensus_fan_out_seconds_total`, and no other: the session's
+    at the fixpoint's end, the accounting's after the apply. The stages of a
+    call lie inside the phase that holds the call."""
+    verifier = TpuSecpVerifier()
+    raw, coins = _block("contract/stages", HEIGHT)
+    names = {"consensus_native_stage_seconds_total": "stage",
+             "consensus_fan_out_seconds_total": "stat"}
+    before = get_registry().snapshot()
+    res = connect_block(raw, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        verifier=verifier, sig_cache=SigCache(),
+                        script_cache=ScriptExecutionCache())
+    assert res.ok
+    after = get_registry().snapshot()
+    rose = {}
+    for name, second in names.items():
+        was = _by_pair(before, name, second) if name in before else {}
+        rose[name] = {k: v - was.get(k, 0.0) for k, v in _by_pair(after, name, second).items()}
+    stages, fans = rose["consensus_native_stage_seconds_total"], rose["consensus_fan_out_seconds_total"]
+    assert set(stages) == STAGE_PAIRS
+    assert set(fans) == {(c, s) for c in FAN_CALLS for s in FAN_STATS}
+    assert all(v > 0 for k, v in stages.items()), stages
+    phases = verifier.phases.report()
+    of = lambda call: sum(v for (c, _), v in stages.items() if c == call)
+    assert of("interpret") <= phases["interpret"]["secs"]
+    assert of("lanes") + of("digests") <= phases["host_prep"]["secs"]
+    assert of("accounting") <= phases["accounting"]["secs"]
+    for call in FAN_CALLS:  # a fan-out runs inside the stage around it
+        around = stages[call, "workers" if call == "interpret" else "shards"]
+        assert 0 < fans[call, "wall"] <= around
 
 
 def test_coin_probe_tables_are_the_ones_counted(workload):

@@ -606,6 +606,98 @@ def test_a_reorg_reader_returns_none_without_its_source(metric, ctx):
     assert reader(metric)(ctx) is None
 
 
+# -- the native stage clock (PR 50) ----------------------------------------
+
+_STAGE_SECONDS = "consensus_native_stage_seconds_total"
+_FAN_SECONDS = "consensus_fan_out_seconds_total"
+STAGE_CELLS = ["tip-block.cold", "worst-block.sigops", "worst-block-mesh4.sigops",
+               "taproot-block.cold", "worst-block-multisig20.fanout",
+               "worst-block-quadratic.sighash"]
+ACCT_STAGES = ("decide", "fill", "copy")
+# reader -> what it reads: the (call, stage) pair of the stage family, or the
+# calls whose `stat` it sums of the fan-out family ("share": sum over held)
+SESSION_STAGE_READERS = {
+    "prep_order_ms.connect": ("lanes", "order"),
+    "prep_shards_ms.connect": ("lanes", "shards"),
+    "prep_digests_ms.connect": ("digests", "shards"),
+    "prep_busy_share.connect": (("lanes", "digests"), "share"),
+    "interp_workers_ms.connect": ("interpret", "workers"),
+    "interp_merge_ms.connect": ("interpret", "merge"),
+    "interp_busy_share.connect": (("interpret",), "share"),
+    "fan_start_lag_ms.connect": (("interpret", "lanes", "digests"), "start_lag"),
+}
+STAGE_READERS = {
+    **{name: ("connect", reads) for name, reads in SESSION_STAGE_READERS.items()},
+    **{f"acct_{stage}_ms.connect": ("connect", ("accounting", stage)) for stage in ACCT_STAGES},
+    **{f"acct_{stage}_ms.stream": ("stream", ("accounting", stage)) for stage in ACCT_STAGES},
+}
+# seconds the window adds to every label pair: by position in these lists, so
+# that no two pairs rise alike and a reader that took a neighbour's is found out
+_STAGE_PAIRS = [("interpret", "setup"), ("interpret", "workers"), ("interpret", "merge"),
+                ("lanes", "order"), ("lanes", "shards"), ("digests", "shards"),
+                ("accounting", "decide"), ("accounting", "fill"), ("accounting", "copy")]
+_FAN_PAIRS = [(call, stat) for call in ("interpret", "lanes", "digests")
+              for stat in ("wall", "held", "sum", "max", "start_lag", "tail")]
+_STAGE_ROSE = {pair: 0.003 * (i + 1) for i, pair in enumerate(_STAGE_PAIRS)}
+_FAN_ROSE = {pair: 0.0007 * (i + 1) for i, pair in enumerate(_FAN_PAIRS)}
+
+
+def stage_ctx(kind, families=(_STAGE_SECONDS, _FAN_SECONDS), calls=3, n_blocks=4, scale=1.0):
+    """A window of `calls` timed connects, or passes of `n_blocks` blocks,
+    over which every label pair of the two families rose from 5.0 by its
+    `_ROSE` seconds times `scale`."""
+    def snap(more):
+        out = {"consensus_dispatch_total": {"samples": []}}
+        if _STAGE_SECONDS in families:
+            out[_STAGE_SECONDS] = {"samples": [
+                {"labels": {"call": c, "stage": st}, "value": 5.0 + more * v}
+                for (c, st), v in _STAGE_ROSE.items()]}
+        if _FAN_SECONDS in families:
+            out[_FAN_SECONDS] = {"samples": [
+                {"labels": {"call": c, "stat": st}, "value": 5.0 + more * v}
+                for (c, st), v in _FAN_ROSE.items()]}
+        return out
+    return {"cell": "made-up", "trace": None, "driver": {
+        "kind": kind, "walls_s": [0.05] * calls, "pass_walls_s": [0.4] * calls,
+        "n_inputs": 6, "n_blocks": n_blocks, "phases": [],
+        "counters_before": snap(0.0), "counters_after": snap(scale)}}
+
+
+def _stage_answer(kind, reads, calls=3, n_blocks=4):
+    over = calls if kind == "connect" else calls * n_blocks
+    first, second = reads
+    if second == "share":
+        return 100.0 * sum(_FAN_ROSE[c, "sum"] for c in first) / sum(_FAN_ROSE[c, "held"] for c in first)
+    if isinstance(first, tuple):
+        return sum(_FAN_ROSE[c, second] for c in first) / over * 1000.0
+    return _STAGE_ROSE[first, second] / over * 1000.0
+
+
+@pytest.mark.parametrize("metric", list(STAGE_READERS))
+def test_a_stage_reader_takes_its_label_pairs_rise_over_the_timed_calls(metric):
+    kind, reads = STAGE_READERS[metric]
+    assert reader(metric)(stage_ctx(kind)) == pytest.approx(_stage_answer(kind, reads), rel=1e-9)
+
+
+@pytest.mark.parametrize("metric", list(STAGE_READERS))
+def test_a_stage_reader_returns_none_on_the_parent_and_in_another_kind_of_cell(metric):
+    kind, _ = STAGE_READERS[metric]
+    other = "stream" if kind == "connect" else "connect"
+    assert reader(metric)(stage_ctx(kind, families=())) is None  # the parent: no such family
+    assert reader(metric)(stage_ctx(other)) is None
+    assert reader(metric)(stage_ctx("serve")) is None
+    assert reader(metric)(stage_ctx(kind, calls=0)) is None  # a window that timed no call
+    no_snapshots = stage_ctx(kind)
+    no_snapshots["driver"]["counters_before"] = no_snapshots["driver"]["counters_after"] = None
+    assert reader(metric)(no_snapshots) is None
+
+
+@pytest.mark.parametrize("metric", ["prep_busy_share.connect", "interp_busy_share.connect"])
+def test_a_busy_share_is_none_where_no_thread_time_was_held(metric):
+    assert reader(metric)(stage_ctx("connect", scale=0.0)) is None
+    assert reader(metric)(stage_ctx("connect", families=(_STAGE_SECONDS,))) is None
+
+
 def test_benchmark_json_lists_each_new_metric_with_its_cells():
     import json
 
@@ -650,6 +742,11 @@ def test_benchmark_json_lists_each_new_metric_with_its_cells():
         "sighash_mb_per_s.connect": ["worst-block-quadratic.sighash"],
         # PR 48: the reorganisation cell's own
         **{name: ["tip-reorg.depth2"] for name in REORG},
+        # PR 50: the native stage clock; the warm cell's precharge runs the session's
+        # calls outside its timed connects, its accounting does not
+        **{name: STAGE_CELLS for name in SESSION_STAGE_READERS},
+        **{f"acct_{stage}_ms.connect": STAGE_CELLS + ["tip-block.warm"] for stage in ACCT_STAGES},
+        **{f"acct_{stage}_ms.stream": ["ibd-stream.cold"] for stage in ACCT_STAGES},
     }
     for name, cells in want.items():
         assert by_name[name]["workloads"] == cells, name

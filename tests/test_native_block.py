@@ -556,3 +556,63 @@ def test_coin_probes_count_one_block_table_probe_an_input():
     assert _COIN_PROBES.value(table="block") - was["block"] == n_in + n_out
     assert _COIN_PROBES.value(table="view") - was["view"] == (
         2 * (n_in + n_out) - in_block)
+
+
+# -- the native stage clock of the accounting phase (PR 50) ------------------
+
+_BLOCK_STAGES = [("accounting", s) for s in ("decide", "fill", "copy")]
+
+
+@pytest.mark.parametrize("case,reason", [
+    (_case_spends_earlier_output, None),
+    (_case_spends_later_output, "bad-txns-inputs-missingorspent"),
+    (_case_twice_in_two_txs, "bad-txns-inputs-missingorspent"),
+    (_case_own_coinbase, "bad-txns-premature-spend-of-coinbase"),
+    (_case_bip30, "bad-txns-BIP30"),
+], ids=lambda x: x.__name__[len("_case_"):] if callable(x) else None)
+def test_accounting_stamps_the_passes_it_ran(case, reason):
+    """`NativeBlock.stages()`: an accounting stamps pass 1, and pass 2 and
+    the copy-out only where pass 1 let the block through; the next
+    accounting of the same parsed block starts from zero."""
+    block, coins = case()
+    nview = to_native_view(coins)
+    nblk = native_bridge.NativeBlock(block.serialize())
+    assert list(nblk.stages().stages) == _BLOCK_STAGES and not nblk.stages().fans
+    assert set(nblk.stages().stages.values()) == {(0.0, 0)}
+    for _again in range(2):
+        assert nblk.accounting(nview, HEIGHT, 0, salt=b"salt")[0] == reason
+        stamped = nblk.stages().stages
+        assert stamped["accounting", "decide"][1] == 1
+        assert stamped["accounting", "decide"][0] > 0
+        ran = 0 if reason else 1
+        for stage in ("fill", "copy"):
+            seconds, calls = stamped["accounting", stage]
+            assert calls == ran and (seconds > 0) == bool(ran), (stage, stamped)
+
+
+def test_a_connect_raises_its_accounting_stages_inside_the_phase():
+    """`consensus_native_stage_seconds_total{call="accounting"}` rises once
+    a connected block, beside the coin probes, by what the native calls of
+    the `accounting` phase spent: no more than the phase, on the one clock."""
+    import types
+
+    from bitcoinconsensus_tpu.models.batch import _NATIVE_STAGES
+    from bitcoinconsensus_tpu.utils.profiling import Phases
+
+    block, coins = _case_spends_earlier_output()
+    clock = types.SimpleNamespace(phases=Phases())
+    value = lambda stage: _NATIVE_STAGES.value(call="accounting", stage=stage)
+    was = {stage: value(stage) for _, stage in _BLOCK_STAGES}
+    res = connect_block(block, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                        check_scripts=False, verifier=clock)
+    assert res.ok
+    rose = {stage: value(stage) - was[stage] for stage in was}
+    phase = clock.phases.report()["accounting"]
+    assert phase["calls"] == 1 and all(v > 0 for v in rose.values()), rose
+    assert sum(rose.values()) <= phase["secs"]
+    # a block refused in pass 1 is not applied, and raises nothing
+    block, coins = _case_bip30()
+    was = {stage: value(stage) for stage in was}
+    assert not connect_block(block, to_native_view(coins), HEIGHT, pow_limit=REGTEST_POW_LIMIT,
+                             check_scripts=False, verifier=clock).ok
+    assert {stage: value(stage) for stage in was} == was

@@ -18,6 +18,7 @@ digest_checks + add_known_batch):
 
 import functools
 import hashlib
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -484,7 +485,8 @@ def _queue_rounds(n, n_threads):
                        "spec_pairings": sess.spec_pairings()})
         sess.publish_uniq(np.arange(len(uniq), dtype=np.int32),
                           np.array([d[0] & 1 for d in uniq], dtype=bool))
-    return rounds, sess.worker_seconds()
+    return rounds, {stat: seconds for (call, stat), seconds in sess.stages().fans.items()
+                    if call == "interpret"}
 
 
 @pytest.fixture(scope="module")
@@ -526,6 +528,86 @@ def test_workers_on_one_queue_leave_what_one_thread_leaves(queue_one_thread, n_t
         shared.append(seconds["max"] < seconds["sum"])
     if n == _QUEUE_INPUTS and n_threads > 1:
         assert any(shared)  # more than one worker drew inputs
+
+
+# -- the native stage clock: a call's stages tile it, a fan-out accounts for itself (PR 50) --
+
+
+def _stage_call(call, n_threads):
+    """One call of `call` over the queue block's 2,999 inputs (the 6,427
+    checks they record) at `n_threads`: the seconds around it on the
+    caller's clock, what the session's stage clock rose by over it, and what
+    it left (`_queue_rounds`' first round, the lanes, the digests)."""
+    raw, spks = _queue_block()
+    n = _QUEUE_INPUTS
+    ntx = native_bridge.NativeTx(raw)
+    ntx.precompute()
+    sess = native_bridge.NativeSession()
+    interpret = lambda: sess.verify_inputs_idx(
+        [ntx] * n, list(range(n)), [0] * n, spks, [0] * n, n_threads=n_threads)
+    if call == "interpret":
+        run = interpret
+    else:
+        interpret()
+        idx = np.arange(sess.uniq_count(), dtype=np.int32)
+        run = {"lanes": lambda: sess.uniq_lanes(idx, len(idx), n_threads),
+               "digests": lambda: sess.uniq_digests(b"salt", idx, n_threads)}[call]
+    before = sess.stages()
+    t0 = time.perf_counter()
+    out = run()
+    wall = time.perf_counter() - t0
+    after = sess.stages()
+    stages = {stage: (after.stages[c, stage][0] - before.stages[c, stage][0],
+                      after.stages[c, stage][1] - before.stages[c, stage][1])
+              for c, stage in after.stages if c == call}
+    others = {k: v for k, v in after.stages.items() if k[0] != call}
+    assert others == {k: v for k, v in before.stages.items() if k[0] != call}
+    fan = {stat: after.fans[c, stat] - before.fans[c, stat]
+           for c, stat in after.fans if c == call}
+    if call == "interpret":
+        left = (out[0].tolist(), out[1].tolist(), out[3].tolist(), _uniq_digests(sess))
+    elif call == "lanes":
+        left = [np.asarray(a).tobytes() for a in out]
+    else:
+        left = np.asarray(out).tobytes()
+    return wall, stages, fan, left
+
+
+_STAGES_OF = {"interpret": {"setup", "workers", "merge"}, "lanes": {"order", "shards"},
+              "digests": {"shards"}}
+
+
+@pytest.mark.parametrize("n_threads", [1, 4, 13])
+@pytest.mark.parametrize("call", ["interpret", "lanes", "digests"])
+def test_a_calls_stages_tile_it_and_its_fan_out_accounts_for_itself(call, n_threads):
+    """`NativeSession.stages()` around one call of each of the session's
+    three that fan out: every stage stamped once, their sum the call's
+    duration as the caller's clock sees it (the same clock: never more, and
+    all but the bridge's own work), the fan-out's six sums consistent, one
+    worker where one thread was asked for, and the output the one-thread
+    run's."""
+    for _attempt in range(5):  # a loaded machine may take the caller off its core
+        wall, stages, fan, left = _stage_call(call, n_threads)
+        tiled = sum(seconds for seconds, _ in stages.values())
+        if tiled >= 0.8 * wall:
+            break
+    assert set(stages) == _STAGES_OF[call]
+    assert all(stamped == 1 and seconds >= 0 for seconds, stamped in stages.values()), stages
+    assert 0.8 * wall <= tiled <= wall, (tiled, wall, stages)
+    around = stages["workers" if call == "interpret" else "shards"][0]
+    eps = 1e-12
+    assert 0 < fan["wall"] <= around
+    assert 0 < fan["max"] <= fan["wall"] and fan["max"] <= fan["sum"] <= fan["held"] + eps
+    assert 0 <= fan["start_lag"] and 0 <= fan["tail"]
+    assert fan["start_lag"] + fan["tail"] <= fan["wall"] + eps
+    if n_threads == 1:
+        assert fan["start_lag"] == 0 == fan["tail"]
+        assert fan["sum"] == fan["max"] == fan["wall"] == fan["held"]
+    else:
+        width = round(fan["held"] / fan["wall"])
+        assert width == min(n_threads, {"interpret": n_threads}.get(call, 6427 // 512))
+        assert fan["start_lag"] > 0 and fan["held"] == pytest.approx(width * fan["wall"])
+        assert left == _stage_call(call, 1)[3]
 
 
 def test_release_frees_once_and_a_released_session_raises(monkeypatch):
